@@ -89,7 +89,9 @@ def main() -> None:
     import numpy as np
     from jax.sharding import Mesh
 
-    from triton_dist_tpu.runtime import kprobe
+    from triton_dist_tpu.runtime import configure_compile_cache, kprobe
+
+    configure_compile_cache()
     from triton_dist_tpu.runtime.profiling import merge_rank_traces
 
     kernels = (list(kprobe.KERNELS) if args.kernel == "all"

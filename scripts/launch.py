@@ -56,10 +56,24 @@ def main() -> int:
     p.add_argument("--coordinator", default=None,
                    help="host:port (default: localhost, fresh port)")
     p.add_argument("--real-tpu", action="store_true",
-                   help="do not force the CPU backend (multi-host TPU)")
+                   help="do not force the CPU backend (one process per "
+                        "TPU HOST, started by the platform; on one host "
+                        "only --nproc 1)")
     p.add_argument("script")
     p.add_argument("args", nargs=argparse.REMAINDER)
     a = p.parse_args()
+    if a.real_tpu and a.nproc > 1:
+        # Every local child would open every chip of this host, and a
+        # chip belongs to one process: the second child hangs in backend
+        # init.  Refuse instead.
+        p.error(
+            f"--real-tpu --nproc {a.nproc}: {a.nproc} processes on ONE "
+            f"host would each open all of its chips, and a chip belongs "
+            f"to one process at a time.  Supported on one TPU host: ONE "
+            f"process driving N one-chip engines, or one N-chip mesh "
+            f"(examples/serve.py --engine --mesh N).  Across hosts the "
+            f"platform starts one process per host with the "
+            f"JAX_COORDINATOR_ADDRESS contract this script documents.")
 
     coord = a.coordinator or f"127.0.0.1:{free_port()}"
     procs = []

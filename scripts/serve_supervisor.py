@@ -44,11 +44,16 @@ subprocess half of the fleet's load signal).
             --snapshot-dir /tmp/serve-snap --snapshot-every 8 \
             --heartbeat /tmp/serve-snap/hb --hb-interval 2
 
-    python scripts/serve_supervisor.py --fleet 2 \
+    JAX_PLATFORMS=cpu python scripts/serve_supervisor.py --fleet 2 \
         --snapshot-dir /tmp/fleet --metrics-base-port 9300 -- \
         python examples/serve.py --engine --requests 16 \
             --snapshot-dir {dir} --heartbeat {hb} --hb-interval 2 \
             --metrics-port {port}
+
+``--fleet N`` with N > 1 is a CPU form (``JAX_PLATFORMS=cpu``) and is
+refused otherwise: every child opens every chip of its host and a chip
+belongs to one process at a time.  On a TPU host, supervise ONE child
+that holds N one-chip engines or one N-chip mesh.
 
 Exercised end-to-end (with children that kill themselves mid-run) by
 tests/test_serve_example.py and tests/test_serve_fleet.py.
@@ -144,6 +149,21 @@ def parse_args():
         p.error("no child command given (pass it after --)")
     if args.fleet is not None and args.fleet < 1:
         p.error(f"--fleet must be >= 1, got {args.fleet}")
+    if (args.fleet is not None and args.fleet > 1
+            and os.environ.get("JAX_PLATFORMS") != "cpu"):
+        # Each child is a JAX process that opens EVERY chip of this host,
+        # and a chip belongs to one process at a time: the second replica
+        # would hang in backend init.  (This supervisor stays off jax —
+        # it must never hold a chip itself — so the children's platform
+        # is read from the environment they inherit.)
+        p.error(
+            f"--fleet {args.fleet}: N > 1 replica processes on one host "
+            f"are only supported pinned to the CPU (JAX_PLATFORMS=cpu — "
+            f"the test fleet).  On a TPU host a chip belongs to one "
+            f"process, so the supported form is ONE process: N one-chip "
+            f"engines in it (examples/serve.py --engine --fleet N) or "
+            f"one N-chip mesh (--engine --mesh N), supervised as a "
+            f"single child (no --fleet).")
     if args.aggregate_port is not None and args.fleet is None:
         p.error("--aggregate-port needs --fleet")
     if (args.aggregate_port is not None

@@ -44,6 +44,7 @@ from triton_dist_tpu.analysis import (
     run_rules,
 )
 from triton_dist_tpu.analysis import rules as rules_mod
+from triton_dist_tpu.analysis.jaxpr_audit import lowered_mosaic_calls
 from triton_dist_tpu.analysis.schedule_check import check_kernel
 from triton_dist_tpu.runtime.jit_cache import CountingJit
 
@@ -272,6 +273,30 @@ def test_audit_flags_undeclared_collective(mesh2):
     assert any("declared seam count is 3" in f.message for f in fs)
 
 
+def test_audit_counts_a_tree_collective_as_one_seam(mesh2):
+    """One ``psum`` over a pytree binds one equation per leaf, back to
+    back; that is ONE seam (the seq layout's page gather psums a whole
+    per-layer scratch tree at one call site).  Two psums with work
+    between them are two."""
+    def tree(t):
+        return jax.lax.psum(t, "tp")
+
+    fn = jax.jit(jax.shard_map(tree, mesh=mesh2, in_specs=(P(),),
+                               out_specs=P(), check_vma=False))
+    cj = _capture(fn, [(jnp.ones((4,)), jnp.ones((4,)))] * 2)
+    assert audit_program(
+        {"name": "tree", "fn": cj, "seams": {"psum": 1}}) == []
+
+    def twice(x):
+        return jax.lax.psum(jax.lax.psum(x, "tp") * 2.0, "tp")
+
+    fn = jax.jit(jax.shard_map(twice, mesh=mesh2, in_specs=(P(),),
+                               out_specs=P(), check_vma=False))
+    cj = _capture(fn, jnp.ones((4,)))
+    assert audit_program(
+        {"name": "twice", "fn": cj, "seams": {"psum": 2}}) == []
+
+
 def test_audit_flags_off_ladder_static():
     def f(x, *, H):
         return x * H
@@ -363,6 +388,12 @@ def test_engine_registry_audits_clean_world1(tiny_serving):
     # the registry is real: the hot decode programs were audited
     assert {"paged_decode", "decode_horizon",
             "prefill_chunk"} <= set(rep["audited"])
+    # ... and re-lower from their captured signatures (chip_smoke.py's
+    # kernel evidence): on this CPU host they took the XLA path, so every
+    # served program lowers with zero Mosaic custom calls
+    calls = lowered_mosaic_calls(eng)
+    assert set(rep["audited"]) == set(calls), (rep["audited"], calls)
+    assert all(max(c) == 0 for c in calls.values()), calls
 
 
 def _assert_prefill_attend_sharded(eng, cfg):

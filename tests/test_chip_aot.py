@@ -1,0 +1,221 @@
+"""AOT: the serving programs compile for the v5e and hold their kernels.
+
+No chip is used: the programs are lowered and compiled against
+``jax.experimental.topologies.get_topology_desc("v5e:2x2")``, which the
+installed libtpu provides on a CPU host.  What this pins, at llama3-8B
+widths (depth cut to 2 layers — a compile check, not a run):
+
+- world-1 ``paged_decode``, ``decode_horizon[H=8]`` (greedy and sampled) and
+  ``prefill_chunk[c=128]`` compile for the v5e and hold exactly one Mosaic
+  custom call per layer;
+- the world-4 decode and chunked-prefill programs of ``serve/mesh.py``
+  compile for ``heads``, ``seq`` and ``heads+seq`` (2x2), with one Mosaic
+  call per layer under ``heads`` and two (attention kernel + SP combine)
+  under the seq layouts;
+- at the CPU-demo geometry (page 16, chunk 64) the same programs hold NO
+  Mosaic call, and the engine's construction-time kernel-reach report
+  (``attention_kernel_gaps``) says so in words.
+
+``resolve_impl`` reads the PROCESS platform, so ``impl="auto"`` would resolve
+to XLA when lowering from this CPU host; the fixture pins ``is_tpu`` to what
+the target is.  Slow tier (each compile is seconds; the sampled horizon's
+sort over a 128k vocabulary is ~25 s).
+"""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from triton_dist_tpu.analysis.jaxpr_audit import MOSAIC_CALL
+from triton_dist_tpu.models import llama
+from triton_dist_tpu.models.generate import _chunk_forward
+from triton_dist_tpu.runtime import topology
+from triton_dist_tpu.serve import engine as E
+from triton_dist_tpu.serve import mesh as serve_mesh
+
+LAYERS = 2
+B, MAX_SEQ = 8, 2048
+I32 = jnp.int32
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The 2x2 v5e topology description, or a LOUD skip."""
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any libtpu failure is a skip
+        pytest.skip(f"SKIPPED LOUDLY: libtpu cannot describe a v5e:2x2 "
+                    f"topology on this host, so NO serving program was "
+                    f"AOT-compiled for the chip: {type(e).__name__}: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo
+
+
+@pytest.fixture()
+def as_tpu(monkeypatch):
+    monkeypatch.setattr(topology, "is_tpu", lambda: True)
+
+
+def _cfg():
+    return dataclasses.replace(llama.LlamaConfig.llama3_8b(),
+                               n_layers=LAYERS, max_seq=MAX_SEQ)
+
+
+def _abstract_params(cfg):
+    return jax.eval_shape(functools.partial(llama.init_params, cfg),
+                          jax.random.key(0))
+
+
+def _on(tree, sharding):
+    """ShapeDtypeStructs of ``tree`` carrying ``sharding`` (one sharding,
+    or a matching tree of them)."""
+    if not isinstance(sharding, jax.sharding.Sharding):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, sharding)
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
+def _compile(jitted, *args, **kw) -> int:
+    """Lower + compile for the topology the args live on; the Mosaic
+    custom calls in the lowered text."""
+    lowered = jitted.lower(*args, **kw)
+    lowered.compile()
+    return lowered.as_text().count(MOSAIC_CALL)
+
+
+def _decode_args(cfg, page, num_blocks=257):
+    """Abstract argument tuples of ``paged_decode`` and
+    ``decode_horizon`` (no sharding yet — callers place them)."""
+    s = jax.ShapeDtypeStruct
+    pool = s((num_blocks, cfg.n_kv_heads, page, cfg.head_dim), cfg.dtype)
+    keys = jax.eval_shape(lambda: jnp.stack([jax.random.key(0)] * B))
+    vec = lambda dt: s((B,), dt)  # noqa: E731
+    decode = (_abstract_params(cfg), [(pool, pool)] * cfg.n_layers,
+              s((B, MAX_SEQ // page), I32), vec(I32), vec(I32), vec(bool))
+    horizon = decode + (vec(bool), vec(I32), vec(I32), keys,
+                        vec(jnp.float32), vec(I32), vec(jnp.float32),
+                        vec(bool), vec(I32))
+    return decode, horizon
+
+
+def _world1_programs(cfg, page):
+    kw = dict(cfg=cfg, page=page, impl="auto", interpret=False)
+    decode = jax.jit(functools.partial(E._paged_decode_forward, **kw),
+                     donate_argnums=(1,))
+    horizon = jax.jit(functools.partial(E._paged_decode_horizon, **kw),
+                      static_argnames=("H", "all_greedy"),
+                      donate_argnums=(1,))
+    chunk = jax.jit(functools.partial(_chunk_forward, cfg=cfg, impl="auto",
+                                      interpret=False, mesh=None,
+                                      axis=None),
+                    static_argnames=("quantized", "extent"),
+                    donate_argnums=(2,))
+    return decode, horizon, chunk
+
+
+def _chunk_args(cfg, c, extent):
+    """Abstract (positional args, n_valid) of ``prefill_chunk``."""
+    s = jax.ShapeDtypeStruct
+    sc = s((1, cfg.n_kv_heads, extent, cfg.head_dim), cfg.dtype)
+    return ((_abstract_params(cfg), s((1, c), I32),
+             [(sc, sc)] * cfg.n_layers, s((), I32)), s((), I32))
+
+
+def test_world1_programs_compile_with_one_mosaic_call_per_layer(v5e, as_tpu):
+    cfg = _cfg()
+    put = functools.partial(_on, sharding=SingleDeviceSharding(v5e.devices[0]))
+    decode, horizon, chunk = _world1_programs(cfg, page=128)
+    d_args, h_args = put(_decode_args(cfg, 128))
+    assert _compile(decode, *d_args) == LAYERS
+    for all_greedy in (True, False):
+        assert _compile(horizon, *h_args, H=8,
+                        all_greedy=all_greedy) == LAYERS
+    for extent in (128, MAX_SEQ):
+        args, n_valid = put(_chunk_args(cfg, 128, extent))
+        assert _compile(chunk, *args, quantized=False, extent=extent,
+                        n_valid=n_valid) == LAYERS
+    assert E.attention_kernel_gaps(
+        head_dim=cfg.head_dim, page_size=128, prefill_chunk=128,
+        ladder=[128, 256, 512, 1024, 2048], kv_itemsize=2, kv_quant=False,
+        impl="auto", interpret=False) == {}
+
+
+def test_cpu_demo_geometry_holds_no_kernel_and_the_engine_says_so(v5e,
+                                                                  as_tpu):
+    """page 16 / chunk 64 — ``examples/serve.py``'s and ``ServeEngine``'s
+    defaults — reach neither attention kernel, at real head_dim."""
+    cfg = _cfg()
+    put = functools.partial(_on, sharding=SingleDeviceSharding(v5e.devices[0]))
+    decode, horizon, chunk = _world1_programs(cfg, page=16)
+    d_args, h_args = put(_decode_args(cfg, 16))
+    assert _compile(decode, *d_args) == 0
+    assert _compile(horizon, *h_args, H=8, all_greedy=True) == 0
+    args, n_valid = put(_chunk_args(cfg, 64, 512))
+    assert _compile(chunk, *args, quantized=False, extent=512,
+                    n_valid=n_valid) == 0
+    gaps = E.attention_kernel_gaps(
+        head_dim=cfg.head_dim, page_size=16, prefill_chunk=64,
+        ladder=[64, 128, 256, 512], kv_itemsize=2, kv_quant=False,
+        impl="auto", interpret=False)
+    assert "page=16" in gaps["paged_decode"]
+    assert "chunk=64" in gaps["prefill_chunk"]
+    # int8 pools always take the XLA dequant path, whatever the page
+    gaps = E.attention_kernel_gaps(
+        head_dim=cfg.head_dim, page_size=128, prefill_chunk=128,
+        ladder=[128, 256], kv_itemsize=2, kv_quant=True, impl="auto",
+        interpret=False)
+    assert "int8" in gaps["paged_decode"] and "prefill_chunk" not in gaps
+
+
+@pytest.mark.parametrize("kv_shard,shape,per_layer", [
+    ("heads", (4,), 1), ("seq", (4,), 2), ("heads+seq", (2, 2), 2)])
+def test_world4_programs_compile(v5e, as_tpu, kv_shard, shape, per_layer):
+    cfg = _cfg()
+    mesh = Mesh(np.array(v5e.devices).reshape(shape),
+                ("tp", "sp")[:len(shape)])
+    build = functools.partial(
+        serve_mesh.build_programs, mesh=mesh, tp_axis="tp",
+        kv_shard=kv_shard, cfg=cfg, params=_abstract_params(cfg),
+        page_size=128, num_blocks=260, n_pages_max=MAX_SEQ // 128,
+        interpret=False, horizon=8, sp_axis="sp")
+    progs = build(impl="auto")
+
+    def on_mesh(prog, args):
+        return tuple(_on(a, p) for a, p in zip(args, prog._placements))
+
+    # 260 blocks: the pool splits evenly over the sp world
+    d_args, h_args = _decode_args(cfg, 128, num_blocks=260)
+    dec = progs["paged_decode"]
+    assert _compile(dec._prog(()), *on_mesh(dec, d_args)) \
+        == per_layer * LAYERS
+    hor = progs["decode_horizon"]
+    statics = (("H", 8), ("all_greedy", True))
+    assert _compile(hor._prog(statics), *on_mesh(hor, h_args)) \
+        == per_layer * LAYERS
+    # chunked prefill too: under seq its combine merges c x Hq partial
+    # rows, which overflowed the fused kernel's VMEM on the chip (PR 21)
+    # — a failure this compile reproduces without one
+    chunk = progs["prefill_chunk"]._maker(512)
+    args, n_valid = _chunk_args(cfg, 128, 512)
+    assert _compile(chunk._prog(()), *on_mesh(chunk, args + (n_valid,))) \
+        == per_layer * LAYERS
+    # and impl="xla", asked for by name, holds no kernel in any layout
+    # (the seq prefill attend once dispatched "auto" regardless: the chip
+    # smoke's XLA reference turned out to run the kernel it was judging)
+    xla = build(impl="xla")
+    assert _compile(xla["paged_decode"]._prog(()),
+                    *on_mesh(xla["paged_decode"], d_args)) == 0
+    chunk = xla["prefill_chunk"]._maker(512)
+    assert _compile(chunk._prog(()),
+                    *on_mesh(chunk, args + (n_valid,))) == 0

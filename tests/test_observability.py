@@ -22,6 +22,53 @@ from triton_dist_tpu.runtime import dump
 from triton_dist_tpu.runtime.profiling import group_profile, merge_rank_traces
 
 
+def test_unknown_device_kind_raises_instead_of_borrowing_a_peak():
+    """A roofline row is never a default: only platform ``cpu`` gets the
+    virtual-mesh row, and an accelerator the table does not know raises
+    (a peak guessed for it would make every utilization fiction)."""
+    from triton_dist_tpu.runtime import topology
+
+    assert topology._lookup("TPU v5 lite", "tpu")[0] == 197.0
+    assert topology._lookup("cpu", "cpu") == topology._CPU_SPEC
+    with pytest.raises(ValueError, match="no roofline row"):
+        topology._lookup("TPU v9 mega", "tpu")
+    # a TPU is never matched by the cpu row, whatever its kind says
+    with pytest.raises(ValueError, match="no roofline row"):
+        topology._lookup("cpu", "tpu")
+    with pytest.raises(ValueError, match="no measured dense-dot ceiling"):
+        topology.measured_dot_ceiling_tflops()      # device_kind "cpu"
+
+
+def test_require_tpu_refuses_the_cpu():
+    """Entry points that report on the device have no CPU fallback."""
+    from triton_dist_tpu.runtime import require_tpu
+
+    with pytest.raises(SystemExit, match="bench.py: needs a TPU"):
+        require_tpu("bench.py")
+
+
+def test_compile_cache_is_placed_from_outside_or_in_the_checkout(
+        monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself and the
+    helper sets nothing.  Unset: the fixed ``<checkout>/.jax_cache`` —
+    never a tempfile, pid or clock (the path is part of the cache key)."""
+    from triton_dist_tpu.runtime import bootstrap
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+        assert bootstrap.configure_compile_cache() == "/some/dir"
+        assert jax.config.jax_compilation_cache_dir == prev   # untouched
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(repo, ".jax_cache")
+        assert bootstrap.configure_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert bootstrap.configure_compile_cache() == want     # stable
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+
+
 def test_dump_lowered_writes_stablehlo(tmp_path):
     def f(x):
         return jnp.sin(x) * 2.0

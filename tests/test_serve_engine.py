@@ -411,6 +411,68 @@ def test_engine_custom_bucket_ladder_validated():
     assert eng._bucket_s_ext(63) == 64
 
 
+def test_attention_kernel_gaps_names_every_silent_xla_reroute():
+    """Kernel reach (docs/serving.md): under ``impl="auto"`` a geometry
+    the attention kernels cannot tile reroutes to XLA without a word, so
+    the engine computes — from the dispatchers' own guards — which of its
+    attention paths will miss the Pallas kernels, and why."""
+    from triton_dist_tpu.serve.engine import attention_kernel_gaps
+
+    kw = dict(head_dim=128, page_size=128, prefill_chunk=128,
+              ladder=[128, 256, 512], kv_itemsize=2, kv_quant=False,
+              impl="auto", interpret=True)
+    assert attention_kernel_gaps(**kw) == {}          # both kernels reach
+    gaps = attention_kernel_gaps(**{**kw, "page_size": 16})
+    assert set(gaps) == {"paged_decode"} and "page=16" in gaps["paged_decode"]
+    gaps = attention_kernel_gaps(**{**kw, "prefill_chunk": 64})
+    assert set(gaps) == {"prefill_chunk"} and "chunk=64" in \
+        gaps["prefill_chunk"]
+    # chunks <= 32 ride the decode kernel: reach again at a 128-row extent
+    assert attention_kernel_gaps(**{**kw, "prefill_chunk": 32}) == {}
+    gaps = attention_kernel_gaps(**{**kw, "head_dim": 64})
+    assert set(gaps) == {"paged_decode", "prefill_chunk"}
+    gaps = attention_kernel_gaps(**{**kw, "kv_quant": True})
+    assert set(gaps) == {"paged_decode"} and "int8" in gaps["paged_decode"]
+    # seq layouts attend over a 1/sp row span of each extent rung
+    gaps = attention_kernel_gaps(**{**kw, "sp_world": 4})
+    assert "[128, 256]" in gaps["prefill_chunk"], gaps
+    assert attention_kernel_gaps(**{**kw, "sp_world": 4,
+                                    "ladder": [512, 1024]}) == {}
+    # dispatch itself: xla by request, or auto off a TPU with no
+    # interpreter (this CPU host) — every path, with the reason
+    for impl, why in (("xla", "asked for"), ("auto", "off a TPU")):
+        gaps = attention_kernel_gaps(**{**kw, "impl": impl,
+                                        "interpret": False})
+        assert set(gaps) == {"paged_decode", "prefill_chunk"}
+        assert all(why in g for g in gaps.values()), gaps
+
+
+def test_warmup_raises_when_a_program_cannot_run():
+    """Containment quarantines a failing request and serves on; under
+    warm-up that would turn 'the prefill program does not compile' into a
+    warm-up that returns normally with nothing warmed (seen on the chip,
+    PR 21).  warmup() raises instead, naming the first failure."""
+    cfg, params, gen = _tiny_model()
+    eng = ServeEngine(gen, params, num_blocks=8, page_size=4, max_batch=1,
+                      prefill_chunk=4)
+
+    def broken(*a, **k):
+        raise ValueError("RESOURCE_EXHAUSTED: scoped vmem")
+
+    eng._chunk_fn = broken
+    with pytest.raises(RuntimeError, match="warm-up requests failed.*vmem"):
+        eng.warmup()
+    assert not eng.has_work() and eng.bm.num_free == eng.bm.num_allocatable
+
+
+def test_engine_exposes_its_kernel_gaps_in_the_summary():
+    cfg, params, gen = _tiny_model()
+    eng = ServeEngine(gen, params, num_blocks=8, page_size=4, max_batch=1,
+                      prefill_chunk=4)
+    assert set(eng.kernel_gaps) == {"paged_decode", "prefill_chunk"}
+    assert eng.metrics.summary()["kernel_gaps"] == eng.kernel_gaps
+
+
 # ---------------------------------------------------------------------------
 # fast tier: r5-advisor regressions
 # ---------------------------------------------------------------------------

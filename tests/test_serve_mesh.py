@@ -185,6 +185,32 @@ def test_mesh_geometry_rejection_matrix(model, mesh4, mesh2):
                            SamplingParams(max_new_tokens=16)))
 
 
+def test_params_drawn_on_their_mesh_layout_equal_the_default(mesh4):
+    """``init_params(shardings=)`` draws every leaf directly on its TP
+    layout — no leaf whole on one device (llama3-8B only fits a 16 GB
+    chip that way) — with the values of the unsharded draw, and a mesh
+    engine's pools are born on their sharding."""
+    cfg = llama.LlamaConfig(vocab=64, dim=32, n_layers=2, n_heads=4,
+                            n_kv_heads=4, ffn_dim=64, max_seq=64)
+    key = jax.random.key(11)
+    plain = llama.init_params(cfg, key)
+    placed = llama.init_params(cfg, key, llama.param_shardings(cfg, mesh4))
+    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(placed),
+                    strict=True):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    wq = placed["layers"][0]["wq"]
+    assert wq.sharding.spec == jax.sharding.PartitionSpec(None, "tp")
+    assert {s.data.shape for s in wq.addressable_shards} == {(32, 8)}
+    gen = Generator(cfg, Mesh(np.array(jax.devices()[:1]), ("sp",)),
+                    axis="sp", max_seq=64)
+    eng = ServeEngine(gen, placed, num_blocks=8, page_size=8, max_batch=2,
+                      prefill_chunk=8, mesh=mesh4, kv_shard="heads")
+    k_pool = eng._pools[0][0]
+    assert k_pool.sharding == eng._pool_sharding
+    assert {s.data.shape for s in k_pool.addressable_shards} == \
+        {(8, 1, 8, 8)}
+
+
 def test_mesh_block_manager_partitions():
     """Partitioned allocator units (kv_shard='seq'): placement, the
     per-partition free walk, COW locality, and the match-prefix

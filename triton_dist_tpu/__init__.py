@@ -42,12 +42,9 @@ Design stance (TPU-first, not a port):
 
 __version__ = "0.1.0"
 
-# Version shims first: everything below (and every later submodule import)
-# assumes the jax>=0.6 names (jax.shard_map, pltpu.CompilerParams).
-from triton_dist_tpu.runtime import compat as _compat
-
-_compat.apply()
-
+# Importing this package initialises no JAX backend (tests/test_chip_smoke.py
+# holds it to that): on a TPU host the first process to touch a backend owns
+# the chip, so entry points decide when that happens, not an import.
 from triton_dist_tpu.runtime import (  # noqa: F401
     initialize_distributed,
     get_mesh,

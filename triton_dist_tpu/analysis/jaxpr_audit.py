@@ -41,10 +41,12 @@ from collections import Counter
 from typing import Optional
 
 import jax
+from jax.extend import core as jex_core
 
-#: Wire/collective primitives (jax 0.4.x names; ``psum2`` is psum's
-#: shard_map spelling).  ``pbroadcast`` is NOT here: it is shard_map's
-#: type-level replication adjustment, no bytes move.
+#: Wire/collective primitives (``psum2`` is psum's shard_map spelling on
+#: older traces; kept so a seam table never has to know).  ``pbroadcast``
+#: is NOT here: it is shard_map's type-level replication adjustment, no
+#: bytes move.
 COLLECTIVE_PRIMS = frozenset({
     "psum", "psum2", "ppermute", "pgather", "all_gather",
     "all_gather_invariant", "all_to_all", "reduce_scatter",
@@ -85,35 +87,46 @@ class AuditFinding:
 
 def _iter_subjaxprs(params: dict):
     for v in params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jex_core.ClosedJaxpr):
             yield v.jaxpr
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jex_core.Jaxpr):
             yield v
         elif isinstance(v, (tuple, list)):
             for x in v:
-                if isinstance(x, jax.core.ClosedJaxpr):
+                if isinstance(x, jex_core.ClosedJaxpr):
                     yield x.jaxpr
-                elif isinstance(x, jax.core.Jaxpr):
+                elif isinstance(x, jex_core.Jaxpr):
                     yield x
 
 
 def jaxpr_stats(jaxpr) -> dict:
-    """Recursive walk: primitive counts + per-pjit donation records.
+    """Recursive walk: primitive counts (collectives counted per SEAM,
+    see ``walk``) + per-jit donation records.
 
     Returns ``{"prims": Counter, "donations": [(name, jaxpr,
-    donated_invars)]}`` — donations carry the pjit's inner jaxpr so
+    donated_invars)]}`` — donations carry the jit's inner jaxpr so
     :func:`_check_donation` can test use + aliasability."""
     prims: Counter = Counter()
     donations: list = []
 
     def walk(j):
+        prev = None
         for eqn in j.eqns:
-            prims[eqn.primitive.name] += 1
-            if eqn.primitive.name == "pjit":
+            name = eqn.primitive.name
+            # A collective over a pytree binds one equation per leaf,
+            # back to back with identical parameters: that is ONE seam
+            # (one call site, one point where ranks meet), so a run of
+            # adjacent identical collectives counts once.
+            run = ((name, str(eqn.params))
+                   if name in COLLECTIVE_PRIMS else None)
+            if run is None or run != prev:
+                prims[name] += 1
+            prev = run
+            if name == "jit":
                 donated = eqn.params.get("donated_invars", ())
                 if any(donated):
                     donations.append(
-                        (eqn.params.get("name", "pjit"),
+                        (eqn.params.get("name", "jit"),
                          eqn.params["jaxpr"].jaxpr, tuple(donated)))
             for sub in _iter_subjaxprs(eqn.params):
                 walk(sub)
@@ -126,10 +139,10 @@ def _used_vars(jaxpr) -> set:
     used = set()
     for eqn in jaxpr.eqns:
         for v in eqn.invars:
-            if not isinstance(v, jax.core.Literal):
+            if not isinstance(v, jex_core.Literal):
                 used.add(v)
     for v in jaxpr.outvars:
-        if not isinstance(v, jax.core.Literal):
+        if not isinstance(v, jex_core.Literal):
             used.add(v)
     return used
 
@@ -187,11 +200,14 @@ def _signatures(fn) -> list:
     return []
 
 
-def _trace(fn, args_abs, kwargs):
+def _replay(fn, args_abs, kwargs, via):
+    """Replay one captured signature of ``fn`` through ``via`` —
+    ``jax.make_jaxpr`` (the audits) or ``lambda f: jax.jit(f).lower``
+    (:func:`lowered_mosaic_calls`)."""
     inner = getattr(fn, "fn", fn)
     if hasattr(inner, "_prog"):
         prog = inner._prog(tuple(sorted(kwargs.items())))
-        return jax.make_jaxpr(prog)(*args_abs)
+        return via(prog)(*args_abs)
     # make_jaxpr turns EVERY argument it receives into a tracer — but
     # static kwargs (the horizon's H, prefill's n_valid) were concrete
     # Python values at the real call and must stay concrete here, or
@@ -206,7 +222,38 @@ def _trace(fn, args_abs, kwargs):
     def call(*args, **tkw):
         return inner(*args, **tkw, **static_kw)
 
-    return jax.make_jaxpr(call)(*args_abs, **traced_kw)
+    return via(call)(*args_abs, **traced_kw)
+
+
+def _trace(fn, args_abs, kwargs):
+    return _replay(fn, args_abs, kwargs, jax.make_jaxpr)
+
+
+#: How a Pallas TPU kernel appears in lowered StableHLO.
+MOSAIC_CALL = "tpu_custom_call"
+
+
+def lowered_mosaic_calls(engine) -> dict:
+    """``{program: [Mosaic custom calls per captured signature]}`` —
+    every program of ``engine.program_registry()`` re-lowered for THIS
+    process's backend from the signatures it was actually called with,
+    and the ``tpu_custom_call`` ops counted in the StableHLO text.
+
+    This is the evidence that a Pallas kernel was traced into what the
+    engine served (``chip_smoke.py`` fails on a zero): under
+    ``impl="auto"`` a guard that reroutes to XLA leaves no other trace,
+    and a flag or a config echo is not evidence.  Interpret-mode kernels
+    lower to plain HLO and count 0; programs never called have no
+    signature and are left out."""
+    out = {}
+    for rec in engine.program_registry():
+        counts = [
+            _replay(rec["fn"], args_abs, kwargs,
+                    lambda f: jax.jit(f).lower).as_text().count(MOSAIC_CALL)
+            for args_abs, kwargs in _signatures(rec["fn"])]
+        if counts:
+            out[rec["name"]] = counts
+    return out
 
 
 def audit_program(rec: dict) -> list:

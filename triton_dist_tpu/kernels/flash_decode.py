@@ -425,6 +425,16 @@ def quantize_kv(x):
     return symmetric_quantize(x, -1)
 
 
+def decode_kernel_gap(s: int, head_dim: int) -> str | None:
+    """Why :func:`gqa_decode_shard` would take its XLA path over a cache
+    of ``s`` rows (``None``: the split-KV kernel tiles it).  The ONE copy
+    of the guard — the dispatcher below and the serving engine's
+    construction-time kernel-reach report both read it."""
+    if head_dim % 128 or s % 128:
+        return f"(D={head_dim}, S={s}) needs D%128 == S%128 == 0"
+    return None
+
+
 @_register_aot()
 def gqa_decode_shard(q, k, v, local_lens, *, block_s=None, impl="auto",
                      interpret=False, k_scale=None, v_scale=None,
@@ -481,12 +491,10 @@ def gqa_decode_shard(q, k, v, local_lens, *, block_s=None, impl="auto",
     raw_impl = impl
     impl = resolve_impl(impl, interpret)
 
-    def shapes_ok():
-        return D % 128 == 0 and S % 128 == 0
-
+    gap = decode_kernel_gap(S, D)
     quantized = k_scale is not None
-    if use_fallback(raw_impl, impl, shapes_ok(), "flash_decode",
-                    f"(D={D}, S={S}) needs D%128 == S%128 == 0") or (
+    if use_fallback(raw_impl, impl, gap is None, "flash_decode",
+                    gap or "") or (
             quantized and impl != "pallas"):
         # int8-KV under resolved-XLA dispatch: dequant fuses into the XLA
         # attention stream.  ``impl='pallas'`` (explicit OR auto-on-TPU)
@@ -653,6 +661,29 @@ def _paged_gather_scale(scale_pool, table):
     return g.transpose(0, 2, 1, 3).reshape(B, Hkv, n * Pg)
 
 
+def paged_kernel_gap(page: int, head_dim: int, itemsize: int, *,
+                     quantized: bool = False) -> str | None:
+    """Why :func:`gqa_decode_paged_shard` would NOT run its Pallas
+    kernel over a pool of this geometry (``None``: it would).  The ONE
+    copy of the guard, like :func:`decode_kernel_gap`: off the kernel the
+    attend materialises the whole ``[B, Hkv, n*page, D]`` gathered view
+    per layer per step, so a serving engine must be able to say which
+    side of it it is on before the first request."""
+    if quantized:
+        return ("int8 pool: the paged attend is the XLA gather + "
+                "in-program dequant (no paged int8 kernel yet)")
+    if head_dim % 128 or page % 128:
+        return (f"(page={page}, D={head_dim}) needs "
+                f"page%128 == D%128 == 0")
+    # A page is the kernel's KV block — it cannot shrink (it IS the cache
+    # layout), so an over-budget page must reroute/raise, not reach
+    # Mosaic's opaque VMEM failure.
+    if 4 * page * head_dim * itemsize > 12 * 2 ** 20:
+        return (f"(page={page}, D={head_dim}): double-buffered K+V page "
+                f"blocks exceed 12 MiB VMEM")
+    return None
+
+
 def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
                            impl="auto", interpret=False, soft_cap=0.0,
                            window=0, window_lens=None, q_lens=None,
@@ -700,15 +731,9 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
             soft_cap=soft_cap, window=window, window_lens=window_lens,
             q_lens=q_lens)
 
-    # A page is the kernel's KV block — it cannot shrink (it IS the cache
-    # layout), so an over-budget page must reroute/raise, not reach
-    # Mosaic's opaque VMEM failure.
-    fits = 4 * Pg * D * jnp.dtype(k_pool.dtype).itemsize <= 12 * 2 ** 20
-    if use_fallback(raw_impl, impl,
-                    D % 128 == 0 and Pg % 128 == 0 and fits,
-                    "paged_decode",
-                    f"(page={Pg}, D={D}) needs page%128 == D%128 == 0 and "
-                    f"double-buffered K+V page blocks within 12 MiB VMEM"):
+    gap = paged_kernel_gap(Pg, D, jnp.dtype(k_pool.dtype).itemsize)
+    if use_fallback(raw_impl, impl, gap is None, "paged_decode",
+                    gap or ""):
         return _local_decode_xla(q, _paged_gather(k_pool, block_table),
                                  _paged_gather(v_pool, block_table),
                                  local_lens, scale=scale,
@@ -882,10 +907,25 @@ def _sp_combine_kernel(plane_in, final_ref, gath, send_sem, recv_sem,
     final_ref[:] = out / denom[:, :1]
 
 
+# Scoped VMEM the fused combine may plan for, of Mosaic's 16 MiB: the
+# gather scratch plus the merge that reads it back as one value — the v5e
+# measured 32.4 MB for a 16 MiB scratch and ran a 4 MiB one (chip runs,
+# PR 21), so the kernel is sized at twice its scratch.  The same 12 MiB
+# the paged kernel budgets for its page blocks.
+_SP_COMBINE_VMEM = 12 * 2 ** 20
+
+
 def sp_combine_shard(out, lse, *, axis, interpret=False,
                      collective_id=SP_DECODE_COLLECTIVE_ID):
     """Fused gather+combine of per-rank decode partials; call inside
-    shard_map.  out [B, Hq, D] f32, lse [B, Hq] f32 → [B, Hq, D] f32."""
+    shard_map.  out [B, Hq, D] f32, lse [B, Hq] f32 → [B, Hq, D] f32.
+
+    The kernel keeps every rank's plane in VMEM, which fits a decode
+    step's B*Hq rows but not a seq-layout prefill chunk's c*Hq or a
+    k-token verify's.  Rows are independent, so past the budget the plane
+    is cut into equal 8-row-aligned blocks (zero rows pad the last: they
+    merge to 0 and are dropped) and the SAME kernel runs once per block,
+    in order, under ``lax.map``."""
     world = jax.lax.axis_size(axis)
     if world == 1:
         return out
@@ -894,22 +934,34 @@ def sp_combine_shard(out, lse, *, axis, interpret=False,
     plane = jnp.concatenate(
         [out.reshape(BH, D),
          jnp.broadcast_to(lse.reshape(BH, 1), (BH, 128))], axis=1)
-    final = pl.pallas_call(
-        functools.partial(_sp_combine_kernel, axis=axis, world=world, d=D),
-        out_shape=jax.ShapeDtypeStruct((BH, D), jnp.float32),
-        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        scratch_shapes=[
-            # flat [world*BH, D+128]: dl.fcollect's slot layout
-            pltpu.VMEM((world * BH, D + 128), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-        compiler_params=dl.collective_compiler_params(world, collective_id),
-        interpret=maybe_interpret(interpret),
-    )(plane)
-    return final.reshape(B, Hq, D)
+
+    def fused(plane):
+        rows = plane.shape[0]
+        return pl.pallas_call(
+            functools.partial(_sp_combine_kernel, axis=axis, world=world,
+                              d=D),
+            out_shape=jax.ShapeDtypeStruct((rows, D), jnp.float32),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+            scratch_shapes=[
+                # flat [world*rows, D+128]: dl.fcollect's slot layout
+                pltpu.VMEM((world * rows, D + 128), jnp.float32),
+                pltpu.SemaphoreType.DMA,
+                pltpu.SemaphoreType.DMA,
+                pltpu.SemaphoreType.DMA,
+            ],
+            compiler_params=dl.collective_compiler_params(world,
+                                                          collective_id),
+            interpret=maybe_interpret(interpret),
+        )(plane)
+
+    n = -(-2 * world * BH * (D + 128) * 4 // _SP_COMBINE_VMEM)
+    if n == 1:
+        return fused(plane).reshape(B, Hq, D)
+    blk = -(-BH // (8 * n)) * 8
+    plane = jnp.pad(plane, ((0, n * blk - BH), (0, 0)))
+    final = jax.lax.map(fused, plane.reshape(n, blk, D + 128))
+    return final.reshape(n * blk, D)[:BH].reshape(B, Hq, D)
 
 
 def combine_partials(outs, lses):

@@ -64,19 +64,6 @@ _register_virtual_tpu_info()
 
 
 def interpret_params(detect_races: bool = False) -> "pltpu.InterpretParams":
-    if not hasattr(pltpu, "InterpretParams"):
-        # Pre-Mosaic-interpreter jax (< 0.5): the generic Pallas
-        # interpreter still runs single-device kernels (scalar prefetch,
-        # grids, VMEM scratch); kernels that touch device semaphores or
-        # remote DMA fail loudly there instead of here.  Race detection
-        # has no generic-interpreter equivalent — a silent True would
-        # turn race tests into vacuous passes, so refuse loudly.
-        if detect_races:
-            raise NotImplementedError(
-                "detect_races needs the Mosaic TPU interpreter "
-                "(pltpu.InterpretParams, jax >= 0.5); this jax only has "
-                "the generic Pallas interpreter")
-        return True
     return pltpu.InterpretParams(
         dma_execution_mode="eager",
         detect_races=detect_races,

@@ -424,6 +424,27 @@ def _multitoken_forward(params, caches, chunk, pos, *, cfg: LlamaConfig,
                                preferred_element_type=jnp.float32)
 
 
+# Chunks up to this many tokens ride the multi-token DECODE kernel in
+# :func:`_attend_prefix`; larger ones the flash prefill kernel.
+_DECODE_CHUNK_MAX = 32
+
+
+def prefill_kernel_gap(chunk: int, extent: int, head_dim: int) -> str | None:
+    """Why world-1 :func:`_attend_prefix` would run the dense XLA program
+    for one (chunk, extent) pair (``None``: a Pallas kernel tiles it) —
+    the same guards its two kernel dispatchers apply, for the serving
+    engine's construction-time kernel-reach report."""
+    from triton_dist_tpu.kernels.flash_attention import flash_shapes_ok
+    from triton_dist_tpu.kernels.flash_decode import decode_kernel_gap
+
+    if chunk <= _DECODE_CHUNK_MAX:
+        return decode_kernel_gap(extent, head_dim)
+    if not flash_shapes_ok(chunk, extent, head_dim):
+        return (f"(chunk={chunk}, extent={extent}, D={head_dim}) needs "
+                f"chunk%128 == extent%128 == D%128 == 0")
+    return None
+
+
 def _attend_prefix(q, k_all, v_all, prefix_len, *, k_scale=None,
                    v_scale=None, impl="auto", interpret=False,
                    mesh=None, axis=None, window=0, soft_cap=0.0):
@@ -472,7 +493,7 @@ def _attend_prefix(q, k_all, v_all, prefix_len, *, k_scale=None,
         # streams once at the decode kernel's HBM-floor blocks.  The
         # prefill kernel keeps the large-chunk path (its q tiling wins
         # when c itself is MXU-sized).
-        use_decode = c <= 32
+        use_decode = c <= _DECODE_CHUNK_MAX
         if world == 1:
             if use_decode:
                 lens = jnp.full((B,), c, jnp.int32) + prefix_len

@@ -111,41 +111,79 @@ class LlamaConfig:
 # ---------------------------------------------------------------------------
 
 
-def init_params(cfg: LlamaConfig, key) -> dict:
-    """Parameter pytree.  TP-sharded matrices carry their full (unsharded)
-    shapes; ``param_specs`` says how each leaf is laid out on the mesh."""
-    hd = cfg.head_dim
-    qkv_out = (cfg.n_heads + 2 * cfg.n_kv_heads) * hd
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "sharding"))
+def _dense_on(k, denom, *, shape, dtype, sharding):
+    """One seeded matrix drawn directly ONTO ``sharding``: each device
+    generates its own shard (the threefry PRNG is partitionable), so a
+    model that only fits spread over a mesh never exists whole on one
+    chip.  ``denom`` is a traced scalar on purpose: a constant divisor
+    would be folded into a multiply by its reciprocal, one ulp off the
+    eager draw — this way the values are bit-identical to it."""
+    w = (jax.random.normal(k, shape, jnp.float32) / denom).astype(dtype)
+    return jax.lax.with_sharding_constraint(w, sharding)
 
-    def dense(k, fan_in, shape):
+
+def init_params(cfg: LlamaConfig, key, shardings: dict | None = None) -> dict:
+    """Parameter pytree.  TP-sharded matrices carry their full (unsharded)
+    shapes; ``param_specs`` says how each leaf is laid out on the mesh.
+
+    ``shardings`` (a ``NamedSharding`` tree shaped like ``param_specs``)
+    draws every leaf directly on its mesh layout — same values, but no
+    leaf is ever materialised on a single device (llama3-8B is 15 GiB in
+    bf16: it fits four 16 GB chips TP-sharded and not the first of
+    them).  Without it, leaves land on the default device."""
+    hd = cfg.head_dim
+
+    def dense(k, fan_in, shape, sharding):
+        if sharding is not None:
+            return _dense_on(k, jnp.float32(math.sqrt(fan_in)), shape=shape,
+                             dtype=cfg.dtype, sharding=sharding)
         return (jax.random.normal(k, shape, jnp.float32)
                 / math.sqrt(fan_in)).astype(cfg.dtype)
 
-    del qkv_out
+    def ones(sharding):
+        return jnp.ones((cfg.dim,), cfg.dtype, device=sharding)
+
+    sh = shardings or jax.tree_util.tree_map(
+        lambda _: None, param_specs(cfg), is_leaf=lambda x: isinstance(x, P))
     keys = jax.random.split(key, 2 + cfg.n_layers)
     params = {
-        "embed": dense(keys[0], 1, (cfg.vocab, cfg.dim)),
-        "lm_head": dense(keys[1], cfg.dim, (cfg.dim, cfg.vocab)),
-        "final_norm": jnp.ones((cfg.dim,), cfg.dtype),
+        "embed": dense(keys[0], 1, (cfg.vocab, cfg.dim), sh["embed"]),
+        "lm_head": dense(keys[1], cfg.dim, (cfg.dim, cfg.vocab),
+                         sh["lm_head"]),
+        "final_norm": ones(sh["final_norm"]),
         "layers": [],
     }
-    for i in range(cfg.n_layers):
+    # Q/K/V are separate column-sharded matrices (head-major columns, so a
+    # contiguous tp split assigns whole heads per device); the forward
+    # concatenates the *local* shards and runs ONE fused AG-GEMM.
+    # name -> (subkey index, fan_in, shape)
+    matrices = {
+        "wq": (0, cfg.dim, (cfg.dim, cfg.n_heads * hd)),
+        "wk": (5, cfg.dim, (cfg.dim, cfg.n_kv_heads * hd)),
+        "wv": (2, cfg.dim, (cfg.dim, cfg.n_kv_heads * hd)),
+        "wo": (1, cfg.n_heads * hd, (cfg.n_heads * hd, cfg.dim)),
+        "wgate": (3, cfg.dim, (cfg.dim, cfg.ffn_dim)),
+        "wup": (4, cfg.dim, (cfg.dim, cfg.ffn_dim)),
+        "wdown": (6, cfg.ffn_dim, (cfg.ffn_dim, cfg.dim)),
+    }
+    for i, ls in enumerate(sh["layers"]):
         lk = jax.random.split(keys[2 + i], 7)
-        # Q/K/V are separate column-sharded matrices (head-major columns, so
-        # a contiguous tp split assigns whole heads per device); the forward
-        # concatenates the *local* shards and runs ONE fused AG-GEMM.
         params["layers"].append({
-            "attn_norm": jnp.ones((cfg.dim,), cfg.dtype),
-            "mlp_norm": jnp.ones((cfg.dim,), cfg.dtype),
-            "wq": dense(lk[0], cfg.dim, (cfg.dim, cfg.n_heads * hd)),
-            "wk": dense(lk[5], cfg.dim, (cfg.dim, cfg.n_kv_heads * hd)),
-            "wv": dense(lk[2], cfg.dim, (cfg.dim, cfg.n_kv_heads * hd)),
-            "wo": dense(lk[1], cfg.n_heads * hd, (cfg.n_heads * hd, cfg.dim)),
-            "wgate": dense(lk[3], cfg.dim, (cfg.dim, cfg.ffn_dim)),
-            "wup": dense(lk[4], cfg.dim, (cfg.dim, cfg.ffn_dim)),
-            "wdown": dense(lk[6], cfg.ffn_dim, (cfg.ffn_dim, cfg.dim)),
+            "attn_norm": ones(ls["attn_norm"]),
+            "mlp_norm": ones(ls["mlp_norm"]),
+            **{name: dense(lk[j], fan_in, shape, ls[name])
+               for name, (j, fan_in, shape) in matrices.items()},
         })
     return params
+
+
+def param_shardings(cfg: LlamaConfig, mesh: Mesh, axis: str = "tp") -> dict:
+    """``param_specs`` as a ``NamedSharding`` tree on ``mesh`` — what
+    :func:`init_params` takes as ``shardings``."""
+    return jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), param_specs(cfg, axis),
+        is_leaf=lambda x: isinstance(x, P))
 
 
 def param_specs(cfg: LlamaConfig, axis: str = "tp") -> dict:
@@ -379,6 +417,4 @@ def make_train_step(cfg: LlamaConfig, mesh: Mesh, *, axis="tp", dp_axis=None,
 
 def place_params(params, cfg: LlamaConfig, mesh: Mesh) -> dict:
     """Device-put a host param tree according to ``param_specs``."""
-    specs = param_specs(cfg)
-    return jax.tree.map(
-        lambda p, s: jax.device_put(p, NamedSharding(mesh, s)), params, specs)
+    return jax.tree.map(jax.device_put, params, param_shardings(cfg, mesh))

@@ -14,6 +14,8 @@ from triton_dist_tpu.runtime.bootstrap import (  # noqa: F401
     rank,
     num_ranks,
     init_seed,
+    configure_compile_cache,
+    require_tpu,
 )
 from triton_dist_tpu.runtime.utils import (  # noqa: F401
     assert_allclose,

@@ -32,6 +32,51 @@ from jax.sharding import Mesh
 _MESH: Mesh | None = None
 _INITIALIZED: bool = False
 
+#: The checkout this package was imported from (``<root>/triton_dist_tpu/
+#: runtime/bootstrap.py`` → ``<root>``).
+CHECKOUT_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+
+def configure_compile_cache() -> str:
+    """Point JAX's persistent compilation cache at a place that can be
+    chosen from OUTSIDE the program, and return that place.
+
+    Every entry point that can run on the chip calls this first.  Where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and this
+    sets nothing; otherwise the cache lives at the fixed, git-ignored
+    ``<checkout>/.jax_cache``.  The directory is part of every cache key,
+    so it must never derive from ``tempfile``, a pid or the clock — a
+    cache that moves never hits."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = os.path.join(CHECKOUT_ROOT, ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def require_tpu(what: str, n_devices: int = 1) -> None:
+    """Refuse to go on unless this process sees ``n_devices`` TPU chips.
+
+    For entry points whose output is about the device (``bench.py``,
+    ``scripts/bench_*.py``, the chip smokes): a time or a rate taken on
+    the CPU backend is not a slower device number, it is a different
+    quantity, so there is no fallback — the process exits non-zero.
+    Initialises the backend (a TPU process owns the chip from here on)."""
+    devs = jax.devices()
+    if devs[0].platform != "tpu":
+        raise SystemExit(
+            f"{what}: needs a TPU and found platform "
+            f"{devs[0].platform!r} ({devs[0].device_kind}); what it "
+            f"reports is about the device and the CPU backend cannot "
+            f"stand in. Run it on the chip through the chip tool "
+            f"(.claude/skills/verify/SKILL.md).")
+    if len(devs) < n_devices:
+        raise SystemExit(
+            f"{what}: needs {n_devices} TPU chips, this host has "
+            f"{len(devs)} ({devs[0].device_kind}).")
+
 
 def init_seed(seed: int = 42) -> jax.Array:
     """Seeded, deterministic RNG key (reference: utils.py:75-88 init_seed).

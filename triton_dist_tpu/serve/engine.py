@@ -113,18 +113,21 @@ import jax.numpy as jnp
 import numpy as np
 
 from triton_dist_tpu.kernels.flash_decode import (
+    decode_kernel_gap,
     gqa_decode_paged_shard,
+    paged_kernel_gap,
     quantize_kv,
 )
+from triton_dist_tpu.kernels.gemm import resolve_impl
 from triton_dist_tpu.models.generate import (
     GenerationState,
     Generator,
     _multitoken_forward,
     _token_forward,
     _write_rows,
+    prefill_kernel_gap,
 )
 from triton_dist_tpu.models.sampling import (
-    sample_logits,
     sample_logits_rowwise,
     sample_positions_rowwise,
 )
@@ -133,6 +136,7 @@ from triton_dist_tpu.models.speculative import (
     greedy_accept_chain_batched,
 )
 from triton_dist_tpu.runtime import dump as ir_dump
+from triton_dist_tpu.runtime import topology
 from triton_dist_tpu.runtime.faults import FaultInjector
 from triton_dist_tpu.runtime.jit_cache import (
     CountingJit,
@@ -377,6 +381,22 @@ def _paged_decode_horizon(params, pools, tables, kv_lens, token, active,
     (pools, kv_lens, token, eos_done, counts), (toks, mask) = jax.lax.scan(
         step, carry, jnp.arange(H, dtype=jnp.int32))
     return (pools, toks.T, mask.T, kv_lens, token, eos_done, counts)
+
+
+def _sample_token(logits_row, base_key, count, temperature, top_k, top_p):
+    """One sampled token from one logits row [V] — the host path's
+    sampler (:meth:`ServeEngine._choose_token`).  Every knob is traced,
+    so ONE executable serves every (temperature, top_k, top_p) a request
+    may carry and ``warmup()`` can compile it: the static-knob
+    ``sampling.sample_logits`` compiled a fresh sort over the vocabulary
+    for the first request of each sampler class, under traffic, where
+    ``compile_misses`` could not see it (18 s at a 128k vocabulary on the
+    v5e, PR 21).  The draw is emission ``count`` of the row's stream,
+    bit-identical to the fused horizon's."""
+    return sample_positions_rowwise(
+        logits_row[None, None], base_key[None], count[None],
+        temperature=temperature[None], top_k=top_k[None],
+        top_p=top_p[None], greedy=jnp.zeros((1,), bool))[0, 0]
 
 
 def _draft_decode_forward(params, caches, kv_lens, token, active, *,
@@ -670,6 +690,48 @@ def build_bucket_ladder(base: int, cap: int, page: int) -> list[int]:
         r *= 2
     rungs.append(cap)
     return rungs
+
+
+def attention_kernel_gaps(*, head_dim: int, page_size: int,
+                          prefill_chunk: int, ladder: list,
+                          kv_itemsize: int, kv_quant: bool, impl: str,
+                          interpret: bool, sp_world: int = 1) -> dict:
+    """Which of an engine's attention paths will NOT reach a Pallas
+    kernel, and why: ``{"paged_decode" | "prefill_chunk": reason}``,
+    empty when both do.  Under ``impl="auto"`` a shape the kernels cannot
+    tile reroutes to XLA without a word (that is what ``auto`` is for),
+    so an engine that "works" may have exercised no kernel of this repo;
+    :class:`ServeEngine` computes this at construction from the same
+    guards the dispatchers apply and puts it where it can be seen.
+
+    ``paged_decode`` stands for every program that attends through the
+    block table (``paged_decode``, ``decode_horizon``, ``paged_verify``,
+    ``spec_round``) — they share ``gqa_decode_paged_shard``.
+    ``prefill_chunk`` is judged per scratch-extent rung; ``sp_world`` > 1
+    is the seq / heads+seq layout, whose prefill attends over a
+    ``1/sp_world`` row span of the scratch through the decode kernel
+    (``serve.mesh.sp_chunk_forward_shard``)."""
+    if resolve_impl(impl, interpret) == "xla":
+        why = ("impl='xla' was asked for" if impl == "xla" else
+               "impl='auto' resolves to XLA off a TPU (no interpreter)")
+        return {"paged_decode": why, "prefill_chunk": why}
+    gaps = {}
+    gap = paged_kernel_gap(page_size, head_dim, kv_itemsize,
+                           quantized=kv_quant)
+    if gap is not None:
+        gaps["paged_decode"] = gap
+    if sp_world > 1:
+        def rung_gap(r):
+            return decode_kernel_gap(r // sp_world, head_dim)
+    else:
+        def rung_gap(r):
+            return prefill_kernel_gap(prefill_chunk, r, head_dim)
+    missed = {r: g for r in ladder if (g := rung_gap(r)) is not None}
+    if missed:
+        gaps["prefill_chunk"] = (
+            f"extent rungs {sorted(missed)} of {list(ladder)}: "
+            f"{next(iter(missed.values()))}")
+    return gaps
 
 
 # ---------------------------------------------------------------------------
@@ -1073,26 +1135,22 @@ class ServeEngine:
 
         impl = gen.attn.ctx.impl
         interpret = gen.attn.ctx.interpret
-        if self.kv_quant:
-            # int8 pools: the quant plane plus its per-(head, row) scale
-            # plane — one scale per (block, head, in-page row), the exact
-            # shape _scatter_kv's quantize_kv emits, living in the SAME
-            # pool tuple so pages and scales can never travel separately.
-            def _zpool():
-                return {"q": jnp.zeros((num_blocks, cfg.n_kv_heads,
-                                        page_size, cfg.head_dim),
-                                       jnp.int8),
-                        "s": jnp.zeros((num_blocks, cfg.n_kv_heads,
-                                        page_size), jnp.float32)}
-            self._pools = [(_zpool(), _zpool())
-                           for _ in range(cfg.n_layers)]
-        else:
-            self._pools = [
-                (jnp.zeros((num_blocks, cfg.n_kv_heads, page_size,
-                            cfg.head_dim), cfg.dtype),
-                 jnp.zeros((num_blocks, cfg.n_kv_heads, page_size,
-                            cfg.head_dim), cfg.dtype))
-                for _ in range(cfg.n_layers)]
+        # Kernel reach (docs/serving.md "Kernel reach"): the attention
+        # paths that geometry or dispatch will keep OFF the Pallas
+        # kernels, visible in metrics.summary()["kernel_gaps"] and, on a
+        # TPU — where a silent XLA reroute is a lost kernel, not a test
+        # convenience — said once at construction.
+        self.kernel_gaps = attention_kernel_gaps(
+            head_dim=cfg.head_dim, page_size=page_size,
+            prefill_chunk=prefill_chunk, ladder=self.ladder,
+            kv_itemsize=jnp.dtype(cfg.dtype).itemsize,
+            kv_quant=self.kv_quant, impl=impl, interpret=interpret,
+            sp_world=self.sp_world)
+        self.metrics.kernel_gaps = self.kernel_gaps
+        if self.kernel_gaps and topology.is_tpu():
+            for prog, why in self.kernel_gaps.items():
+                print(f"[serve] {prog} attention will run as XLA, not "
+                      f"the Pallas kernel — {why}", file=sys.stderr)
         # w8a8 swaps the weight tree ONCE, host-side, before any program
         # captures it; the hooks ride the same ffn=/out_proj= seams the
         # mesh TP bodies use, so every program below stays one copy.
@@ -1141,10 +1199,9 @@ class ServeEngine:
             self._mesh_progs = progs
             self._pool_sharding = NamedSharding(mesh, progs["pool_spec"])
             # Weights live TP-sharded (heads) / replicated (seq) on the
-            # mesh for the engine's lifetime; the pools move onto their
-            # shard layout once, here.
+            # mesh for the engine's lifetime (a no-op for params built
+            # on that layout already); the pools are born on theirs.
             self.params = progs["paged_decode"].place(0, params)
-            self._pools = progs["paged_decode"].place(1, self._pools)
             self._decode_fn = CountingJit(progs["paged_decode"],
                                           "paged_decode")
             self._verify_fn = (
@@ -1220,8 +1277,29 @@ class ServeEngine:
             else:
                 self._chunk_fn = CountingJit(gen._chunk_jit,
                                              "prefill_chunk")
+        # Zeroed pools, born on their mesh layout (None off-mesh): a mesh
+        # engine's pools never exist whole on one device — at real widths
+        # they would not fit beside its weight shard.
+        page_shape = (num_blocks, cfg.n_kv_heads, page_size, cfg.head_dim)
+        if self.kv_quant:
+            # int8 pools: the quant plane plus its per-(head, row) scale
+            # plane — one scale per (block, head, in-page row), the exact
+            # shape _scatter_kv's quantize_kv emits, living in the SAME
+            # pool tuple so pages and scales can never travel separately.
+            def zpool():
+                return {"q": jnp.zeros(page_shape, jnp.int8,
+                                       device=self._pool_sharding),
+                        "s": jnp.zeros(page_shape[:3], jnp.float32,
+                                       device=self._pool_sharding)}
+        else:
+            def zpool():
+                return jnp.zeros(page_shape, cfg.dtype,
+                                 device=self._pool_sharding)
+        self._pools = [(zpool(), zpool()) for _ in range(cfg.n_layers)]
+        self._sample_fn = CountingJit(jax.jit(_sample_token),
+                                      "sample_token")
         for c in (self._chunk_fn, self._fill_fn, self._decode_fn,
-                  self._verify_fn):
+                  self._verify_fn, self._sample_fn):
             if c is not None:
                 self.metrics.register_compiled(c)
         if self.horizon > 1:
@@ -2326,6 +2404,7 @@ class ServeEngine:
         # compile stalls, and the timers are bound to ``saved`` (the
         # production metrics object), so pause at the master gate
         saved_pt, saved.program_timing = saved.program_timing, False
+        failed: list = []   # dummies the containment path quarantined
         try:
             with guard:
                 prev, round_ = -1, 0
@@ -2369,6 +2448,14 @@ class ServeEngine:
                             self._warmup_try(f"wd{round_}_{i}", n_max,
                                              n_min)
                     self.run()
+                    # The host sampler: one program for every sampler
+                    # class (its knobs are traced), and no dummy above
+                    # is sampled.
+                    self._device_call(
+                        "sample_token", (), self._sample_fn,
+                        np.zeros((self.cfg.vocab,), np.float32),
+                        jax.random.key(0), np.int32(0), np.float32(1.0),
+                        np.int32(0), np.float32(1.0))
                     if self.horizon > 1 and not self.spec_k:
                         # Horizon rungs compile one program per (scan
                         # length, greedy-or-mixed sampler).  Each rung
@@ -2437,6 +2524,9 @@ class ServeEngine:
                             jnp.int32(0), jnp.int32(0))
                     for rid in [r for r in self._outputs
                                 if r.startswith("__warmup_")]:
+                        if (self._outputs[rid].finish_reason
+                                is FinishReason.ERROR):
+                            failed.append(self._outputs[rid])
                         del self._outputs[rid]
                         del self._states[rid]
                         self._trace_ctx.pop(rid, None)
@@ -2447,6 +2537,16 @@ class ServeEngine:
             self.trace.level = saved_lvl
             saved.program_timing = saved_pt
             self.metrics = saved
+        if failed:
+            # Containment quarantines a request whose forward raises and
+            # serves on — right under traffic, wrong here: a program that
+            # cannot compile fails EVERY dummy that needs it, and a
+            # warm-up that returns normally after that has warmed nothing
+            # (on the chip: 3 programs "compiled", then every request of
+            # the first real batch retired ERROR — PR 21).
+            raise RuntimeError(
+                f"warmup(): {len(failed)} warm-up requests failed; first: "
+                f"{failed[0].request_id}: {failed[0].error}")
         dt = time.perf_counter() - t0
         fresh = self.metrics.compile_misses - misses0
         self.metrics.warmup_time += dt
@@ -2762,23 +2862,23 @@ class ServeEngine:
     def _choose_token(self, rs: ReqState, logits_row) -> int:
         """HOST-side token choice — the prefill-first-token and
         single-step (H=1 / spec-verify fallback) path only; the fused
-        decode horizon samples ON DEVICE through
-        ``sampling.sample_logits_rowwise``, which is pinned bit-identical
-        to this path (same filter math, same ``fold_in(key(seed),
-        emission_index)`` stream — tests/test_sampling.py), so a stream
-        may cross between the two mid-request (preemption, horizon
-        clamps) without a token ever differing."""
+        decode horizon samples ON DEVICE.  Both draw through
+        ``sampling.sample_logits_rowwise`` (same filter math, same
+        ``fold_in(key(seed), emission_index)`` stream), so a stream may
+        cross between the two mid-request (preemption, horizon clamps)
+        without a token ever differing."""
         p = rs.req.params
+        row = np.asarray(logits_row, np.float32)
         if p.greedy:
-            return int(np.argmax(np.asarray(logits_row)))
+            return int(np.argmax(row))
         # Per-token PRNG stream keyed by (seed, emission index): a
         # preempted-and-recomputed request keeps drawing the same stream.
-        key = jax.random.fold_in(jax.random.key(p.seed),
-                                 len(rs.generated))
-        tok = sample_logits(jnp.asarray(logits_row)[None], key,
-                            temperature=p.temperature, top_k=p.top_k,
-                            top_p=p.top_p)
-        return int(tok[0])
+        # Host operands only (a mesh-placed row would fork the
+        # executable warmup() compiled).
+        return int(self._sample_fn(
+            row, jax.random.key(p.seed), np.int32(len(rs.generated)),
+            np.float32(p.temperature), np.int32(p.top_k or 0),
+            np.float32(1.0 if p.top_p is None else p.top_p)))
 
     def _commit_token(self, rs: ReqState, token: int,
                       now: Optional[float] = None

@@ -621,8 +621,12 @@ def sp_chunk_forward_shard(params, chunk, caches, prefix_len, n_valid,
 
         B, c = q.shape[0], q.shape[1]
         lens = jnp.full((B,), c, jnp.int32) + plen
+        # generate._attend_prefix's convention: attention dispatches
+        # "auto" unless "xla" was asked for by name, which pins the XLA
+        # program (an engine built for reference must hold no kernel)
         return sp_gqa_decode_shard(
-            q, loc(k_view), loc(v_view), lens, axis=axis, impl="auto",
+            q, loc(k_view), loc(v_view), lens, axis=axis,
+            impl="xla" if impl == "xla" else "auto",
             interpret=interpret, k_scale=loc(k_scale),
             v_scale=loc(v_scale), soft_cap=cfg.attn_soft_cap,
             window=cfg.attn_window).astype(jnp.float32)

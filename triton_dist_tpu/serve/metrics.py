@@ -379,6 +379,10 @@ class ServeMetrics:
     compiled_fns: list = field(default_factory=list, repr=False)
     warmup_time: float = 0.0
     warmup_compiles: int = 0
+    # attention paths that will run as XLA instead of a Pallas kernel,
+    # with the reason (engine.attention_kernel_gaps; stamped once at
+    # engine construction)
+    kernel_gaps: dict = field(default_factory=dict, repr=False)
     # per-step gauges as STREAMING aggregates (last / peak / running
     # sums) — never per-step lists, so a long-lived engine's metrics
     # stay O(1) regardless of how many steps it has served
@@ -893,6 +897,7 @@ class ServeMetrics:
             "net": self.net_stats(),
             "prefix_cache": self.prefix_stats(),
             "compilation": self.compile_stats(),
+            "kernel_gaps": dict(self.kernel_gaps),
             "requests": {rid: m.to_dict()
                          for rid, m in self.requests.items()},
         }
