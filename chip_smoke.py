@@ -471,6 +471,8 @@ def run_leg(leg: Leg, base_cfg, seed: int, tally: CompileTally, *,
            "ladder": list(engine.ladder)}
     res.update(serve_traffic(engine, leg, cfg, seed, tally))
     res["mosaic_calls"] = check_mosaic(engine, leg, interpret=interpret)
+    # how the paged decode call is blocked on this leg's ranks
+    res["paged_attn_blocking"] = engine.paged_attn_blocking
     res.update(check_reference(engine, leg, base_cfg, seed,
                                interpret=interpret))
     res["memory_after_build"] = mem

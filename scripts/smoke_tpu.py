@@ -231,7 +231,8 @@ def _single_device_legs(check, mesh, key):
         gqa_decode_shard, mesh, 4, impl="pallas", interpret=False,
         k_scale=ks8, v_scale=vs8)(q, kq8, vq8, lens))
 
-    # 7b'. paged decode (block_table via scalar-prefetch index_map — r4)
+    # 7b'. paged decode (block_table by scalar prefetch; the kernel copies
+    # each row's live pages in — r4, PR 25)
     from triton_dist_tpu.kernels.flash_decode import gqa_decode_paged_shard
     n_pages = S // 256
     pool_k = (kc.reshape(B, Hkv, n_pages, 256, hd)

@@ -94,15 +94,16 @@ def _compile(jitted, *args, **kw) -> int:
     return lowered.as_text().count(MOSAIC_CALL)
 
 
-def _decode_args(cfg, page, num_blocks=257):
+def _decode_args(cfg, page, num_blocks=257, batch=B, max_seq=MAX_SEQ):
     """Abstract argument tuples of ``paged_decode`` and
     ``decode_horizon`` (no sharding yet — callers place them)."""
     s = jax.ShapeDtypeStruct
     pool = s((num_blocks, cfg.n_kv_heads, page, cfg.head_dim), cfg.dtype)
-    keys = jax.eval_shape(lambda: jnp.stack([jax.random.key(0)] * B))
-    vec = lambda dt: s((B,), dt)  # noqa: E731
+    keys = jax.eval_shape(lambda: jnp.stack([jax.random.key(0)] * batch))
+    vec = lambda dt: s((batch,), dt)  # noqa: E731
     decode = (_abstract_params(cfg), [(pool, pool)] * cfg.n_layers,
-              s((B, MAX_SEQ // page), I32), vec(I32), vec(I32), vec(bool))
+              s((batch, max_seq // page), I32), vec(I32), vec(I32),
+              vec(bool))
     horizon = decode + (vec(bool), vec(I32), vec(I32), keys,
                         vec(jnp.float32), vec(I32), vec(jnp.float32),
                         vec(bool), vec(I32))
@@ -151,23 +152,33 @@ def test_world1_programs_compile_with_one_mosaic_call_per_layer(v5e, as_tpu):
         impl="auto", interpret=False) == {}
 
 
+# (batch, max_seq, pool blocks): the smoke's geometry, and the benchmark
+# cells' (Mistral-7B shares llama3-8B's 32 / 8 heads x 128): B 32, table
+# width 8192 / 128 = 64, 449 blocks
+_GEOMETRIES = [(B, MAX_SEQ, 257), (32, 8192, 449)]
+
+
+@pytest.mark.parametrize("batch,max_seq,num_blocks", _GEOMETRIES)
 def test_named_programs_keep_the_operation_names_the_benchmark_reads(
-        v5e, as_tpu):
+        v5e, as_tpu, batch, max_seq, num_blocks):
     """The engine's programs as it builds them since PR 24
     (``jit_cache.named``): the HLO module reads ``jit_<program>``, and the
     paged attention call — which XLA names after the scope around it —
     still reads ``_unknown_`` in the single-step program
     (``_paged_decode_step`` keeps it there) and ``closed_call`` in the
     horizon's scan: the two names ``benchmarks/layer_metrics/
-    paged_attn_roofline.json`` sums."""
+    paged_attn_roofline.json`` sums.  Exactly ONE Mosaic call a layer in
+    both: the paged kernel walks a row's live pages and carries every KV
+    head inside one call (no second call for a merge)."""
     import re
 
     from triton_dist_tpu.runtime.jit_cache import named
 
-    cfg = _cfg()
+    cfg = dataclasses.replace(_cfg(), max_seq=max_seq)
     put = functools.partial(_on, sharding=SingleDeviceSharding(v5e.devices[0]))
     kw = dict(cfg=cfg, page=128, impl="auto", interpret=False)
-    d_args, h_args = put(_decode_args(cfg, 128))
+    d_args, h_args = put(_decode_args(cfg, 128, num_blocks=num_blocks,
+                                      batch=batch, max_seq=max_seq))
 
     def calls(jitted, *args, **statics):
         text = jitted.lower(*args, **statics).compile().as_text()
@@ -187,6 +198,32 @@ def test_named_programs_keep_the_operation_names_the_benchmark_reads(
         *h_args, H=8, all_greedy=True)
     assert module == "HloModule jit_decode_horizon"
     assert names == ["closed_call"] * LAYERS
+
+
+@pytest.mark.parametrize("hkv", [8, 2, 1])
+@pytest.mark.parametrize("n_tok", [1, 5])
+def test_paged_kernel_compiles_at_the_cell_geometry_and_on_a_tp4_rank(
+        v5e, as_tpu, hkv, n_tok):
+    """The paged decode call alone, bf16, B 32, page 128, table width 64,
+    449 blocks: the whole model's 8 KV heads, the TP-4 rank's 2 and a
+    single head; one decode token and a 5-token verify.  The CPU host
+    reproduces the chip's scoped-VMEM refusals, so this is the check
+    before chip time."""
+    from triton_dist_tpu.kernels import flash_decode as fd
+
+    g, D, page = 4, 128, 128
+    s = functools.partial(jax.ShapeDtypeStruct,
+                          sharding=SingleDeviceSharding(v5e.devices[0]))
+    q = s((32, hkv * g, D) if n_tok == 1 else (32, n_tok, hkv * g, D),
+          jnp.bfloat16)
+    pool = s((449, hkv, page, D), jnp.bfloat16)
+    attend = jax.jit(functools.partial(fd.gqa_decode_paged_shard,
+                                       impl="pallas"))
+    assert _compile(attend, q, pool, pool, s((32, 64), I32),
+                    s((32,), I32)) == 1
+    assert fd.paged_kernel_blocking(hkv, page, D, 2, batch=32) == {
+        "heads_per_step": hkv, "steps_per_call": 32,
+        "pages_per_step": "dynamic", "vmem_bytes": 4 * hkv * page * D * 2}
 
 
 def test_cpu_demo_geometry_holds_no_kernel_and_the_engine_says_so(v5e,

@@ -471,6 +471,37 @@ def test_engine_exposes_its_kernel_gaps_in_the_summary():
                       prefill_chunk=4)
     assert set(eng.kernel_gaps) == {"paged_decode", "prefill_chunk"}
     assert eng.metrics.summary()["kernel_gaps"] == eng.kernel_gaps
+    # off the paged kernel there is no blocking to report
+    assert eng.paged_attn_blocking == {}
+    assert "serve_paged_attn_heads_per_step 0" in eng.metrics.to_prometheus()
+
+
+@pytest.mark.parametrize("tp,want_heads", [(1, 2), (2, 1)])
+def test_engine_reports_the_paged_attention_blocking(tp, want_heads):
+    """The start-up report of the paged decode call (ISSUE 25): static,
+    so computed once where the programs are built — for the KV heads THIS
+    rank holds — and exposed in the summary and as two gauges."""
+    cfg = llama.LlamaConfig(vocab=64, dim=512, n_layers=1, n_heads=4,
+                            n_kv_heads=2, ffn_dim=64, max_seq=256,
+                            dtype=jnp.float32)
+    params = llama.init_params(cfg, jax.random.key(3))
+    gen = Generator(cfg, Mesh(np.array(jax.devices()[:1]), ("sp",)),
+                    axis="sp", max_seq=256, interpret=True)
+    mesh = {} if tp == 1 else dict(
+        mesh=Mesh(np.array(jax.devices()[:tp]), ("tp",)), tp_axis="tp",
+        kv_shard="heads")
+    eng = ServeEngine(gen, params, num_blocks=8, page_size=128, max_batch=4,
+                      prefill_chunk=128, **mesh)
+    assert eng.kernel_gaps == {}
+    want = {"heads_per_step": want_heads,
+            "steps_per_call": 4 * (2 // tp) // want_heads,
+            "pages_per_step": "dynamic",
+            "vmem_bytes": 4 * want_heads * 128 * 128 * 4}
+    assert eng.paged_attn_blocking == want
+    assert eng.metrics.summary()["paged_attn_blocking"] == want
+    text = eng.metrics.to_prometheus()
+    assert f"serve_paged_attn_heads_per_step {want_heads}" in text
+    assert f"serve_paged_attn_steps_per_call {want['steps_per_call']}" in text
 
 
 # ---------------------------------------------------------------------------

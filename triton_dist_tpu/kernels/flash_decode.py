@@ -161,6 +161,64 @@ def _unfold_out(out, lse, multi, n_tok, Hq):
     return out.reshape(B, Hq, D), lse[..., 0].reshape(B, Hq)
 
 
+def _online_softmax_step(q, k, v, valid, acc_ref, m_ref, l_ref, *, scale,
+                         soft_cap):
+    """Fold one KV block into the online-softmax state: THE block update
+    of the contiguous and the paged bf16 kernels.  q [R, D] with k/v
+    [bs, D], or all three under ONE leading head axis (q [Hh, R, D], k/v
+    [Hh, bs, D]: a matmul batched over the heads of a page); ``valid``
+    [R, bs] is shared by the heads; the state refs are shaped like
+    q / ``[..., R, 128]``.
+
+    K/V stay in their storage dtype: the MXU multiplies bf16 natively
+    with f32 accumulation, and skipping the per-chunk [bs, D] VPU casts
+    is worth ~10% at S=8192 (the cast traffic used to rival the exp
+    math).  P is cast DOWN to the V dtype for the PV matmul — the
+    standard flash-attention practice, and what keeps both matmuls on
+    the MXU's double-rate path."""
+    heads = tuple(range(q.ndim - 2))
+    r = q.ndim - 1                                   # the D / bs axis
+    logits = jax.lax.dot_general(
+        q, k, (((r,), (r,)), (heads, heads)),
+        preferred_element_type=jnp.float32) * scale            # [.., R, bs]
+    logits = apply_soft_cap(logits, soft_cap)
+    logits = jnp.where(valid, logits, NEG_INF)
+
+    m_cur = m_ref[...]                                          # [.., R, 128]
+    row_max = jnp.max(logits, axis=-1, keepdims=True)           # [.., R, 1]
+    m_new = jnp.maximum(m_cur, row_max)                         # [.., R, 128]
+    alpha = jnp.exp(m_cur[..., :1] - m_new[..., :1])            # [.., R, 1]
+    p = jnp.where(valid, jnp.exp(logits - m_new[..., :1]), 0.0)
+    m_ref[...] = m_new
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((r,), (r - 1,)), (heads, heads)),
+        preferred_element_type=jnp.float32)
+
+
+def _softmax_state_init(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def _softmax_state_emit(acc_ref, m_ref, l_ref):
+    """(out [.., R, D], lse [.., R, 128]) of the accumulated state.  Rows
+    that saw no key (a shard wholly past kv_len, a dead q row, an empty
+    batch slot) give out = 0 and lse = NEG_INF, which the inter-rank
+    combine ignores.  lse rides a full-lane buffer (every lane the same
+    value): Mosaic requires output block lane dims of 128 or the full
+    array dim."""
+    l = l_ref[...]                                              # [.., R, 128]
+    nonempty = l > 0.0
+    out = jnp.where(nonempty[..., :1], acc_ref[...] / jnp.where(
+        nonempty[..., :1], l[..., :1], 1.0), 0.0)
+    lse = jnp.where(nonempty,
+                    m_ref[...] + jnp.log(jnp.where(nonempty, l, 1.0)),
+                    NEG_INF)
+    return out, lse
+
+
 def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
                    acc_ref, m_ref, l_ref, *, block_s, n_s, scale,
                    soft_cap=0.0, window=0, n_tok=1, use_qlens=False):
@@ -181,9 +239,7 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
 
     @pl.when(s == 0)
     def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        _softmax_state_init(acc_ref, m_ref, l_ref)
 
     llen, wlen, qlen = _read_lens(lens_ref, b, window=window,
                                   use_qlens=use_qlens)
@@ -199,48 +255,18 @@ def _decode_kernel(lens_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
 
     @pl.when(live)
     def _():
-        # K/V stay in their storage dtype: the MXU multiplies bf16 natively
-        # with f32 accumulation, and skipping the per-chunk [bs, D] VPU
-        # casts is worth ~10% at S=8192 (the cast traffic used to rival
-        # the exp math).  P is cast DOWN to the V dtype for the PV matmul
-        # — the standard flash-attention practice, and what keeps both
-        # matmuls on the MXU's double-rate path.
         q = q_ref[0, 0]                              # [R, D], R = n_tok*G
-        k = k_ref[0, 0]                              # [bs, D]
-        v = v_ref[0, 0]                              # [bs, D]
-
-        logits = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale        # [R, bs]
-        logits = apply_soft_cap(logits, soft_cap)
         pos = s * block_s + jax.lax.broadcasted_iota(
-            jnp.int32, logits.shape, 1)
+            jnp.int32, (q.shape[0], block_s), 1)
         valid = _chunk_valid(pos, llen, wlen, qlen, window=window,
                              group=q.shape[0] // n_tok)
-        logits = jnp.where(valid, logits, NEG_INF)
-
-        m_cur = m_ref[:]                                        # [R, 128]
-        row_max = jnp.max(logits, axis=-1, keepdims=True)       # [R, 1]
-        m_new = jnp.maximum(m_cur, row_max)                     # [R, 128]
-        alpha = jnp.exp(m_cur[:, :1] - m_new[:, :1])            # [R, 1]
-        p = jnp.where(valid, jnp.exp(logits - m_new[:, :1]), 0.0)
-        m_ref[:] = m_new
-        l_ref[:] = l_ref[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[:] = acc_ref[:] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+        _online_softmax_step(q, k_ref[0, 0], v_ref[0, 0], valid, acc_ref,
+                             m_ref, l_ref, scale=scale, soft_cap=soft_cap)
 
     @pl.when(s == n_s - 1)
     def _():
-        l = l_ref[:]                                            # [R, 128]
-        nonempty = l > 0.0  # rank's shard may be wholly past kv_len
-        out_ref[0, 0] = jnp.where(nonempty[:, :1], acc_ref[:] / jnp.where(
-            nonempty[:, :1], l[:, :1], 1.0), 0.0)
-        # lse rides a full-lane [R, 128] buffer (every lane the same value):
-        # Mosaic requires output block lane dims of 128 or the full array dim.
-        lse_ref[0, 0] = jnp.where(
-            nonempty, m_ref[:] + jnp.log(jnp.where(nonempty, l, 1.0)),
-            NEG_INF)
+        out_ref[0, 0], lse_ref[0, 0] = _softmax_state_emit(acc_ref, m_ref,
+                                                           l_ref)
 
 
 def _decode_kernel_i8(lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
@@ -262,9 +288,7 @@ def _decode_kernel_i8(lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     @pl.when(s == 0)
     def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        _softmax_state_init(acc_ref, m_ref, l_ref)
 
     llen, wlen, qlen = _read_lens(lens_ref, b, window=window,
                                   use_qlens=use_qlens)
@@ -310,13 +334,8 @@ def _decode_kernel_i8(lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
 
     @pl.when(s == n_s - 1)
     def _():
-        l = l_ref[:]
-        nonempty = l > 0.0
-        out_ref[0, 0] = jnp.where(nonempty[:, :1], acc_ref[:] / jnp.where(
-            nonempty[:, :1], l[:, :1], 1.0), 0.0)
-        lse_ref[0, 0] = jnp.where(
-            nonempty, m_ref[:] + jnp.log(jnp.where(nonempty, l, 1.0)),
-            NEG_INF)
+        out_ref[0, 0], lse_ref[0, 0] = _softmax_state_emit(acc_ref, m_ref,
+                                                           l_ref)
 
 
 def _local_decode_xla(q, k, v, local_lens, *, scale, k_scale=None,
@@ -636,12 +655,26 @@ def gqa_decode_shard(q, k, v, local_lens, *, block_s=None, impl="auto",
 # Reference analog: the decode layer's ``block_table`` argument
 # (sp_flash_decode_layer.py:78-103 — its kernel reads the KV cache through
 # a page table).  TPU-native design: the page table rides as a SECOND
-# scalar-prefetch operand and the KV pool's BlockSpec index_map reads the
-# physical page id from it — the kernel body is _decode_kernel verbatim
-# (the logical position base is still ``page * page_size``; only the HBM
-# address of each page block changes).  Dead table entries (pages past a
-# sequence's length) must hold any in-range pool index — their compute is
-# skipped by the length mask, but their DMA still streams.
+# scalar-prefetch operand and the pools stay in HBM (``memory_space=ANY``);
+# the kernel walks a batch row's LIVE pages itself and copies each page's
+# block in through the table, double-buffered (the structure of
+# ``jax.experimental.pallas.ops.tpu.paged_attention``).
+#
+# * Blocking: the pool is ``[N_pages, Hkv, page, D]``, so one page of
+#   EVERY local KV head is one contiguous block.  A loop step carries
+#   ``Hh`` heads of a page — the largest divisor of the local ``Hkv`` whose
+#   double-buffered K+V blocks fit ``PAGED_VMEM_BUDGET``
+#   (:func:`paged_heads_per_step`; ``Hh = Hkv`` at llama/Mistral widths,
+#   whole or head-sharded) — and the grid is ``(B, Hkv // Hh)``.
+# * Only ``ceil(len / page)`` pages of a row are walked (from the window's
+#   first page under a sliding window): a dead table entry costs neither a
+#   DMA nor a step, and a row with ``len == 0`` only writes its empty
+#   partials.  Dead entries must still hold an in-range pool index.
+# * The call has no name of its own: it reaches a device trace under the
+#   name of the scope around it, ``closed_call`` in the fused horizon's
+#   scan and ``_unknown_`` in the single-step program — the two names
+#   ``benchmarks/layer_metrics/paged_attn_roofline.json`` sums
+#   (docs/paged_decode.md).
 
 
 def _paged_gather(pool, table):
@@ -661,6 +694,45 @@ def _paged_gather_scale(scale_pool, table):
     return g.transpose(0, 2, 1, 3).reshape(B, Hkv, n * Pg)
 
 
+# Scoped VMEM the paged kernel may plan for its double-buffered K+V page
+# blocks, of Mosaic's 16 MiB (the q / partial blocks and the softmax
+# state are KiB beside them).
+PAGED_VMEM_BUDGET = 12 * 2 ** 20
+
+
+def _paged_block_bytes(heads: int, page: int, head_dim: int,
+                       itemsize: int) -> int:
+    """VMEM of the double-buffered K+V blocks at ``heads`` heads a step."""
+    return 4 * heads * page * head_dim * itemsize
+
+
+def paged_heads_per_step(hkv: int, page: int, head_dim: int,
+                         itemsize: int) -> int:
+    """KV heads of a page one step of the paged kernel carries: the
+    largest divisor of the LOCAL ``hkv`` whose double-buffered K+V blocks
+    stay inside ``PAGED_VMEM_BUDGET``; 0 when not even one head does
+    (:func:`paged_kernel_gap` then names the reroute).  Chosen from the
+    shapes alone — the same rule for a whole model and a head-sharded
+    rank."""
+    return max((h for h in range(1, hkv + 1) if hkv % h == 0
+                and _paged_block_bytes(h, page, head_dim, itemsize)
+                <= PAGED_VMEM_BUDGET), default=0)
+
+
+def paged_kernel_blocking(hkv: int, page: int, head_dim: int,
+                          itemsize: int, *, batch: int) -> dict:
+    """How the paged decode call is blocked at this geometry (static: it
+    is decided where the program is built): the heads a step carries, the
+    grid steps of one call — the page walk inside a step is as long as
+    the row's live context, hence ``"dynamic"`` — and the VMEM its K/V
+    blocks take."""
+    hh = paged_heads_per_step(hkv, page, head_dim, itemsize)
+    return {"heads_per_step": hh,
+            "steps_per_call": batch * (hkv // hh) if hh else 0,
+            "pages_per_step": "dynamic",
+            "vmem_bytes": _paged_block_bytes(hh, page, head_dim, itemsize)}
+
+
 def paged_kernel_gap(page: int, head_dim: int, itemsize: int, *,
                      quantized: bool = False) -> str | None:
     """Why :func:`gqa_decode_paged_shard` would NOT run its Pallas
@@ -676,9 +748,9 @@ def paged_kernel_gap(page: int, head_dim: int, itemsize: int, *,
         return (f"(page={page}, D={head_dim}) needs "
                 f"page%128 == D%128 == 0")
     # A page is the kernel's KV block — it cannot shrink (it IS the cache
-    # layout), so an over-budget page must reroute/raise, not reach
-    # Mosaic's opaque VMEM failure.
-    if 4 * page * head_dim * itemsize > 12 * 2 ** 20:
+    # layout), so a page of which not even ONE head fits must
+    # reroute/raise, not reach Mosaic's opaque VMEM failure.
+    if not paged_heads_per_step(1, page, head_dim, itemsize):
         return (f"(page={page}, D={head_dim}): double-buffered K+V page "
                 f"blocks exceed 12 MiB VMEM")
     return None
@@ -693,15 +765,17 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
     q [B, Hq, D]; k/v_pool [N_pages, Hkv, page, D] (the physical page
     pool); block_table [B, n_pages] int32 — logical page i of batch b
     lives at pool row ``block_table[b, i]``; local_lens [B] valid rows.
-    Returns float32 partials (out [B, Hq, D], lse [B, Hq]).
+    Returns float32 partials (out [B, Hq, D], lse [B, Hq]).  Only a
+    row's live pages are read, as many KV heads of a page a step as
+    :func:`paged_heads_per_step` allows (the comment above).
 
     INT8 POOLS: ``k_scale``/``v_scale`` [N_pages, Hkv, page] float32
     per-position scale pools dequantize int8 k/v pools (the paged twin
     of :func:`gqa_decode_shard`'s contiguous int8 path — scales ride
     the same page indirection as their pages).  The quantized paged
     attend runs the fused-dequant XLA path: the dedicated Pallas
-    paged-int8 kernel (lane-packed scale planes through the table
-    index_map) is a recorded debt — on a 128-aligned-page TPU layout
+    paged-int8 kernel (lane-packed scale planes copied in through the
+    table) is a recorded debt — on a 128-aligned-page TPU layout
     the float kernel's gate would apply unchanged.
 
     MULTI-TOKEN (r5, same contract as :func:`gqa_decode_shard`): q may
@@ -731,7 +805,8 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
             soft_cap=soft_cap, window=window, window_lens=window_lens,
             q_lens=q_lens)
 
-    gap = paged_kernel_gap(Pg, D, jnp.dtype(k_pool.dtype).itemsize)
+    itemsize = jnp.dtype(k_pool.dtype).itemsize
+    gap = paged_kernel_gap(Pg, D, itemsize)
     if use_fallback(raw_impl, impl, gap is None, "paged_decode",
                     gap or ""):
         return _local_decode_xla(q, _paged_gather(k_pool, block_table),
@@ -744,37 +819,38 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
                                          n_tok=n_tok, window=window)
     rows = n_tok * g
     qg = _fold_q_rows(q, n_tok, Hkv)
-    grid = (B, Hkv, n_pages)
-    kern = functools.partial(_decode_kernel_paged, block_s=Pg,
-                             n_s=n_pages, scale=scale, soft_cap=soft_cap,
-                             window=window, n_tok=n_tok,
+    hh = paged_heads_per_step(Hkv, Pg, D, itemsize)
+    kern = functools.partial(_paged_decode_kernel, page=Pg, hh=hh,
+                             n_pages=n_pages, scale=scale,
+                             soft_cap=soft_cap, window=window, n_tok=n_tok,
                              use_qlens=use_qlens)
     out, lse = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # (lens, block_table)
-            grid=grid,
+            grid=(B, Hkv // hh),
             in_specs=[
-                pl.BlockSpec((1, 1, rows, D),
-                             lambda b, h, s, lens, tab: (b, h, 0, 0)),
-                # THE paging trick: the pool block's leading index comes
-                # from the prefetched table — logical page s of batch b
-                # streams from physical pool row tab[b, s].
-                pl.BlockSpec((1, 1, Pg, D),
-                             lambda b, h, s, lens, tab: (tab[b, s], h, 0, 0)),
-                pl.BlockSpec((1, 1, Pg, D),
-                             lambda b, h, s, lens, tab: (tab[b, s], h, 0, 0)),
+                pl.BlockSpec((1, hh, rows, D),
+                             lambda b, h, lens, tab: (b, h, 0, 0)),
+                # THE paging trick: the pools stay in HBM and the body
+                # copies logical page i of batch b in from physical pool
+                # row tab[b, i] — live pages only.
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=[
-                pl.BlockSpec((1, 1, rows, D),
-                             lambda b, h, s, lens, tab: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, rows, 128),
-                             lambda b, h, s, lens, tab: (b, h, 0, 0)),
+                pl.BlockSpec((1, hh, rows, D),
+                             lambda b, h, lens, tab: (b, h, 0, 0)),
+                pl.BlockSpec((1, hh, rows, 128),
+                             lambda b, h, lens, tab: (b, h, 0, 0)),
             ],
             scratch_shapes=[
-                pltpu.VMEM((rows, D), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
-                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((2, hh, Pg, D), k_pool.dtype),
+                pltpu.VMEM((2, hh, Pg, D), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),      # (k | v, slot)
+                pltpu.VMEM((hh, rows, D), jnp.float32),
+                pltpu.VMEM((hh, rows, 128), jnp.float32),
+                pltpu.VMEM((hh, rows, 128), jnp.float32),
             ],
         ),
         out_shape=[
@@ -782,24 +858,79 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
             jax.ShapeDtypeStruct((B, Hkv, rows, 128), jnp.float32),
         ],
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel")),
         interpret=maybe_interpret(interpret),
     )(lens_arg, block_table, qg, k_pool, v_pool)
     return _unfold_out(out, lse, multi, n_tok, Hq)
 
 
-def _decode_kernel_paged(lens_ref, table_ref, q_ref, k_ref, v_ref, out_ref,
-                         lse_ref, acc_ref, m_ref, l_ref, *, block_s, n_s,
-                         scale, soft_cap=0.0, window=0, n_tok=1,
-                         use_qlens=False):
-    """Thin shim: the paged kernel IS :func:`_decode_kernel` — paging
-    lives entirely in the BlockSpec index maps; ``table_ref`` is consumed
-    there, not in the body."""
-    del table_ref
-    return _decode_kernel(lens_ref, q_ref, k_ref, v_ref, out_ref, lse_ref,
-                          acc_ref, m_ref, l_ref, block_s=block_s, n_s=n_s,
-                          scale=scale, soft_cap=soft_cap, window=window,
-                          n_tok=n_tok, use_qlens=use_qlens)
+def _live_pages(llen, wlen, qlen, *, page, n_pages, window, n_tok):
+    """[lo, hi): the logical pages of a row that hold a position some
+    live query may see.  ``hi`` covers the valid length; under a sliding
+    window ``lo`` is the page of the earliest query's window start
+    (conservative for multi-token: it reaches back ``n_tok - 1`` more
+    rows).  A row with no live query (q_lens mode, ``qlen == 0``) or no
+    length walks nothing."""
+    hi = jnp.minimum((llen + page - 1) // page, n_pages)
+    if qlen is not None:
+        hi = jnp.where(qlen > 0, hi, 0)
+    if not window:
+        return 0, hi
+    lo = jnp.maximum(wlen - (n_tok - 1) - window, 0) // page
+    return jnp.minimum(lo, hi), hi
+
+
+def _paged_decode_kernel(lens_ref, table_ref, q_ref, k_hbm, v_hbm, out_ref,
+                         lse_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref,
+                         *, page, hh, n_pages, scale, soft_cap=0.0,
+                         window=0, n_tok=1, use_qlens=False):
+    """Grid (B, Hkv // hh); one step is one batch row under ``hh`` KV
+    heads, and walks the row's live pages in order with the online
+    softmax of :func:`_decode_kernel` batched over the head axis (same
+    page order, same f32 state, same masking rule).  Page i + 1 streams
+    into the other buffer slot while page i is multiplied."""
+    b = pl.program_id(0)
+    h0 = pl.program_id(1) * hh
+    llen, wlen, qlen = _read_lens(lens_ref, b, window=window,
+                                  use_qlens=use_qlens)
+    lo, hi = _live_pages(llen, wlen, qlen, page=page, n_pages=n_pages,
+                         window=window, n_tok=n_tok)
+    rows = q_ref.shape[2]
+
+    def page_copies(i, slot):
+        row = table_ref[b, i]
+        return (pltpu.make_async_copy(k_hbm.at[row, pl.ds(h0, hh)],
+                                      k_buf.at[slot], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[row, pl.ds(h0, hh)],
+                                      v_buf.at[slot], sem.at[1, slot]))
+
+    @pl.when(lo < hi)
+    def _():
+        for c in page_copies(lo, 0):
+            c.start()
+
+    _softmax_state_init(acc_ref, m_ref, l_ref)
+
+    def page_step(i, _):
+        slot = jax.lax.rem(i - lo, 2)
+
+        @pl.when(i + 1 < hi)
+        def _():
+            for c in page_copies(i + 1, 1 - slot):
+                c.start()
+
+        for c in page_copies(i, slot):
+            c.wait()
+        pos = i * page + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page), 1)
+        valid = _chunk_valid(pos, llen, wlen, qlen, window=window,
+                             group=rows // n_tok)
+        _online_softmax_step(q_ref[0], k_buf[slot], v_buf[slot], valid,
+                             acc_ref, m_ref, l_ref, scale=scale,
+                             soft_cap=soft_cap)
+
+    jax.lax.fori_loop(lo, hi, page_step, None)
+    out_ref[0], lse_ref[0] = _softmax_state_emit(acc_ref, m_ref, l_ref)
 
 
 def sp_gqa_decode_paged_shard(q, k_pool, v_pool, block_table, kv_lens, *,

@@ -115,6 +115,7 @@ import numpy as np
 from triton_dist_tpu.kernels.flash_decode import (
     decode_kernel_gap,
     gqa_decode_paged_shard,
+    paged_kernel_blocking,
     paged_kernel_gap,
     quantize_kv,
 )
@@ -1164,10 +1165,24 @@ class ServeEngine:
             kv_quant=self.kv_quant, impl=impl, interpret=interpret,
             sp_world=self.sp_world)
         self.metrics.kernel_gaps = self.kernel_gaps
-        if self.kernel_gaps and topology.is_tpu():
+        # How the paged decode call is blocked (static, decided where the
+        # programs are built — kernels/flash_decode.py): the KV heads a
+        # step carries of the heads THIS rank holds, the grid steps of a
+        # call.  Empty when the call runs as XLA.
+        self.paged_attn_blocking = (
+            {} if "paged_decode" in self.kernel_gaps
+            else paged_kernel_blocking(
+                cfg.n_kv_heads // (self.mesh_world // self.sp_world),
+                page_size, cfg.head_dim, jnp.dtype(cfg.dtype).itemsize,
+                batch=max_batch))
+        self.metrics.paged_attn_blocking = self.paged_attn_blocking
+        if topology.is_tpu():
             for prog, why in self.kernel_gaps.items():
                 print(f"[serve] {prog} attention will run as XLA, not "
                       f"the Pallas kernel — {why}", file=sys.stderr)
+            if self.paged_attn_blocking:
+                print(f"[serve] paged decode attention: "
+                      f"{self.paged_attn_blocking}", file=sys.stderr)
         # w8a8 swaps the weight tree ONCE, host-side, before any program
         # captures it; the hooks ride the same ffn=/out_proj= seams the
         # mesh TP bodies use, so every program below stays one copy.

@@ -383,6 +383,10 @@ class ServeMetrics:
     # with the reason (engine.attention_kernel_gaps; stamped once at
     # engine construction)
     kernel_gaps: dict = field(default_factory=dict, repr=False)
+    # blocking of the paged decode attention call (heads_per_step,
+    # steps_per_call, ...: flash_decode.paged_kernel_blocking; stamped
+    # once at engine construction, empty when the call runs as XLA)
+    paged_attn_blocking: dict = field(default_factory=dict, repr=False)
     # per-step gauges as STREAMING aggregates (last / peak / running
     # sums) — never per-step lists, so a long-lived engine's metrics
     # stay O(1) regardless of how many steps it has served
@@ -921,6 +925,7 @@ class ServeMetrics:
             "prefix_cache": self.prefix_stats(),
             "compilation": self.compile_stats(),
             "kernel_gaps": dict(self.kernel_gaps),
+            "paged_attn_blocking": dict(self.paged_attn_blocking),
             "requests": {rid: m.to_dict()
                          for rid, m in self.requests.items()},
         }
@@ -1039,6 +1044,14 @@ class ServeMetrics:
               "pools shrink")
         gauge("serve_journal_bytes", self.journal_bytes)
         gauge("serve_compile_misses", self.compile_misses)
+        gauge("serve_paged_attn_heads_per_step",
+              self.paged_attn_blocking.get("heads_per_step", 0),
+              "KV heads of a page one step of the paged decode "
+              "attention kernel carries (0: the call runs as XLA)")
+        gauge("serve_paged_attn_steps_per_call",
+              self.paged_attn_blocking.get("steps_per_call", 0),
+              "grid steps of one paged decode attention call; each "
+              "walks only its row's live pages")
         if self.recorder is not None:
             counter("serve_trace_events_total", self.recorder.emitted,
                     "flight-recorder events emitted")
