@@ -199,6 +199,14 @@ _FAST_GATE_MODULES = {
     # bar, and the rule-registry/waiver units; only the lint_dist.py
     # subprocess CLI round-trips carry explicit @pytest.mark.slow.
     "test_analysis",
+    # latent attention + expert-share serving (ISSUE 26): the engine's
+    # logits against the plain float32 reference (and bf16 failing the
+    # same tolerance), absorbed == expanded attention, the latent paged
+    # kernel against its jnp oracle, the router's hand-worked cases, the
+    # shares of an expert layer adding up to the uncut layer, prefix hit /
+    # preemption / cow on latent pools, the MoE counters, and the named
+    # refusals (the whole file is the fast tier).
+    "test_mla_moe",
 }
 
 # Heavy tests inside core modules whose coverage is duplicated by a

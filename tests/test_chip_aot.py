@@ -294,3 +294,97 @@ def test_world4_programs_compile(v5e, as_tpu, kv_shard, shape, per_layer):
     chunk = xla["prefill_chunk"]._maker(512)
     assert _compile(chunk._prog(()),
                     *on_mesh(chunk, args + (n_valid,))) == 0
+
+
+# ---------------------------------------------------------------------------
+# The latent-attention + expert-share cell (ISSUE 26), at published widths
+# ---------------------------------------------------------------------------
+
+HBM_GIB = 15.75     # what the v5e's compiler allows one program
+
+
+def _mla_moe_cell():
+    """The benchmark cell's configuration as its builder reads it."""
+    import json
+    import os
+
+    from benchmarks import builders_mla_moe
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks/configs/gigachat3.1-702b-ep16-l5.json")) as f:
+        config = json.load(f)
+    return config, builders_mla_moe.model_config(config)
+
+
+def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu):
+    """Every program kind of ``gc3_ep16_l5_reason_sat`` — single-step
+    decode, the fused horizon (greedy and mixed), prefill chunks at the
+    shortest, a middle and the cap extent — at the file's widths and
+    engine sizes: each holds ONE latent attention call a layer and one
+    gate-up + one down grouped GEMM an expert layer, under their trace
+    names (the benchmark's roofline readers match them), and fits the
+    chip beside nothing else."""
+    import re
+    from collections import Counter
+
+    from triton_dist_tpu.models import mla_moe as M
+    from triton_dist_tpu.runtime.jit_cache import named
+
+    config, cfg = _mla_moe_cell()
+    eng = config["engine"]
+    batch, page, max_seq = eng["max_batch"], eng["page_size"], eng["max_seq"]
+    put = functools.partial(_on, sharding=SingleDeviceSharding(v5e.devices[0]))
+    s = jax.ShapeDtypeStruct
+    params = jax.eval_shape(functools.partial(M.init_params, cfg),
+                            jax.random.key(0))
+    gen = M.MlaMoeGenerator(cfg, max_seq=max_seq)
+    hooks = gen.serve_hooks()
+    assert gen.kernel_gaps(page_size=page) == {}
+    pool = s((eng["num_blocks"], 1, page, cfg.head_dim), cfg.dtype)
+    pools = [(pool,)] * cfg.n_layers
+    keys = jax.eval_shape(lambda: jnp.stack([jax.random.key(0)] * batch))
+    vec = lambda dt: s((batch,), dt)  # noqa: E731
+    d_args = (params, pools, s((batch, max_seq // page), I32), vec(I32),
+              vec(I32), vec(bool))
+    h_args = d_args + (vec(bool), vec(I32), vec(I32), keys,
+                       vec(jnp.float32), vec(I32), vec(jnp.float32),
+                       vec(bool), vec(I32))
+    kw = dict(cfg=cfg, page=page, impl="auto", interpret=False)
+    n_moe = cfg.n_layers - cfg.first_k_dense
+    want = {fd_name: n for fd_name, n in (
+        ("mla_paged_decode", cfg.n_layers), (M.GATE_UP_CALL, n_moe),
+        (M.DOWN_CALL, n_moe))}
+
+    def check(name, jitted, *args, **statics):
+        compiled = jitted.lower(*put(args), **statics).compile()
+        text = compiled.as_text()
+        assert text.split(",", 1)[0] == f"HloModule jit_{name}"
+        calls = Counter(n.split(".")[0] for n in re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*custom-call\([^\n]*"
+            + MOSAIC_CALL, text, re.M))
+        assert calls == want, (name, statics, calls)
+        ma = compiled.memory_analysis()
+        total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+                 + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+        assert total < HBM_GIB * 2 ** 30, (name, total / 2 ** 30)
+
+    check("paged_decode", jax.jit(named(
+        gen.wrap_program(E._paged_decode_step), "paged_decode", **kw,
+        **hooks), donate_argnums=(1,)), *d_args)
+    horizon = jax.jit(named(
+        E._paged_decode_horizon, "decode_horizon", **kw,
+        decode_fwd=gen.wrap_program(functools.partial(
+            E._paged_decode_forward, **kw, **hooks))),
+        static_argnames=("H", "all_greedy"), donate_argnums=(1,))
+    for all_greedy in (True, False):
+        check("decode_horizon", horizon, *h_args, H=eng["horizon"],
+              all_greedy=all_greedy)
+    ladder = E.build_bucket_ladder(max(page, eng["prefill_chunk"]), max_seq,
+                                   page)
+    for extent in (ladder[0], 2048, max_seq):
+        sc = s((1, 1, extent, cfg.head_dim), cfg.dtype)
+        check("prefill_chunk", gen._chunk_jit, params,
+              s((1, eng["prefill_chunk"]), I32), [(sc,)] * cfg.n_layers,
+              s((), I32), quantized=False, extent=extent,
+              n_valid=s((), I32))

@@ -130,5 +130,5 @@ def test_config_presets_match_reference_shapes():
         assert (cfg.dim, cfg.ffn_dim) == (k, n), name
         assert cfg.dim % cfg.n_heads == 0 and cfg.n_heads % cfg.n_kv_heads == 0
 
-    ds = MoEConfig.deepseek_moe()
-    assert (ds.dim, ds.n_experts, ds.topk) == (7168, 128, 8)
+    a2a = MoEConfig.a2a_e128_k8_d7168()     # a shape preset, not a model
+    assert (a2a.dim, a2a.n_experts, a2a.topk) == (7168, 128, 8)

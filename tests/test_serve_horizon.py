@@ -208,6 +208,23 @@ def test_horizon_sampled_streams_match_host_and_reproduce():
     assert all(0 <= t < cfg.vocab for t in o8["s"].token_ids)
 
 
+@pytest.mark.parametrize("seeds", [
+    [None, 5, 2 ** 31 - 1, 2 ** 31 + 7, 0],     # either side of int32
+    [None] * 4,                                 # an all-greedy batch
+])
+def test_key_batch_is_the_stack_of_the_host_path_keys(seeds):
+    """The horizon's base keys go to the device in one transfer; element
+    for element they are ``jax.random.key(seed)``, the call the host
+    sampler makes, so a stream may cross between the two paths."""
+    from triton_dist_tpu.serve.engine import _key_batch
+
+    got = _key_batch(seeds)
+    want = jnp.stack([jax.random.key(0 if s is None else s) for s in seeds])
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(jax.random.key_data(got),
+                                  jax.random.key_data(want))
+
+
 def test_horizon_dispatch_economics_and_itl():
     """ISSUE acceptance: a steady decode-only batch at H=8 pays
     dispatches/token <= 0.15 (vs 1.0 per-token), with ITL attributed from

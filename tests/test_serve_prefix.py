@@ -678,15 +678,24 @@ def test_bench_prefix_warm_ttft_collapses():
     The ratio is a host clock on a shared CPU: ten readings over PR 21
     spread 0.23-0.46 around the old 0.35 bound, which failed the gate
     three runs in six at the seed commit.  0.5 covers that spread and is
-    still a collapse; the count beside it is the exact part."""
+    still a collapse; the count beside it is the exact part.  Under the
+    tier-1 command's six workers a neighbour's compile can land inside
+    one of the four timed requests (the driver's run of PR 26), so the
+    clock gets three tries and the counts are held in every one."""
     from scripts.bench_serve import bench_prefix
 
-    r = bench_prefix(batch=2, prompt_len=128, suffix_len=8, new_tokens=4,
-                     n_cold=2, n_warm=2, dim=16, n_layers=1, vocab=64,
-                     page_size=8, prefill_chunk=16, seed=0, warmup=True)
-    assert r["warm_requests"] == 2 and r["cold_requests"] == 3
-    assert r["hit_rate"] > 0
-    assert r["ttft_warm_over_cold"] <= 0.5, r
-    # each warm prompt is 128 shared + 8 own tokens; the shared part is
-    # page- and chunk-aligned, so ALL of it is skipped
-    assert r["prefix_skipped_tokens"] == 2 * 128, r
+    ratios = []
+    for _ in range(3):
+        r = bench_prefix(batch=2, prompt_len=128, suffix_len=8,
+                         new_tokens=4, n_cold=2, n_warm=2, dim=16,
+                         n_layers=1, vocab=64, page_size=8,
+                         prefill_chunk=16, seed=0, warmup=True)
+        assert r["warm_requests"] == 2 and r["cold_requests"] == 3
+        assert r["hit_rate"] > 0
+        # each warm prompt is 128 shared + 8 own tokens; the shared part
+        # is page- and chunk-aligned, so ALL of it is skipped
+        assert r["prefix_skipped_tokens"] == 2 * 128, r
+        ratios.append(r["ttft_warm_over_cold"])
+        if ratios[-1] <= 0.5:
+            break
+    assert min(ratios) <= 0.5, ratios

@@ -933,6 +933,196 @@ def _paged_decode_kernel(lens_ref, table_ref, q_ref, k_hbm, v_hbm, out_ref,
     out_ref[0], lse_ref[0] = _softmax_state_emit(acc_ref, m_ref, l_ref)
 
 
+# ---------------------------------------------------------------------------
+# Paged decode over a LATENT cache (multi-head latent attention, absorbed)
+# ---------------------------------------------------------------------------
+#
+# The cache holds ONE row a token a layer: ``[c_kv | k_rope]``, ``rank +
+# rope`` wide (512 + 64 at the DeepSeek-V3 widths), padded with zeros to
+# the next lane tile by whoever writes it (640: Mosaic cannot cut a page
+# out of a 576-wide plane, which the chip lays out 640 wide anyway; the
+# query's pad columns are zero too, so they add nothing to a score).  In
+# the absorbed form
+# every query head scores against the whole row and sums the first ``rank``
+# columns of the same row as its value, so a page is copied in once and
+# serves as key and as value for all heads: 64 heads x T tokens are the
+# rows of one [R, rank + rope] x [rank + rope, page] product and one
+# [R, page] x [page, rank] product a page.  Same walk as the GQA body above
+# (a row's live pages only, page i + 1 streaming under page i's products),
+# same masking rule and online softmax through the shared helpers.
+
+# Query rows (tokens x heads) one step of the latent kernel carries: a
+# decode row is 64, a prefill chunk of 128 tokens is cut into tiles of this
+# many rows and each tile walks only the pages its last token may see.
+MLA_Q_ROWS = 512
+MLA_CALL_NAME = "mla_paged_decode"
+# Pages in flight (VMEM: 160 KiB a buffer).  On the chip (PR 26: 5 calls,
+# B 64, 88.8k live tokens) one page ahead read 2.38 ms and two or more 2.20
+# (28% of the call's roofline): copy latency is a small part — at 64 query
+# rows a page the step is bound by loading each page into the MXU for two
+# small products, ~0.6 us a page, not by the page's bytes.
+MLA_PAGES_IN_FLIGHT = 3
+
+
+def mla_kernel_gap(page: int, rank: int, rope: int) -> str | None:
+    """Why :func:`mla_decode_paged_shard` would NOT run its Pallas kernel
+    over a latent pool of this geometry (``None``: it would)."""
+    if page % 128 or rank % 128 or (rank + rope) % 128:
+        return (f"(page={page}, rank={rank}, row={rank + rope}) needs "
+                f"page%128 == rank%128 == row%128 == 0 (a row narrower "
+                f"than its lane tile is padded by the caller: the chip "
+                f"stores a 576-wide row as 640 either way)")
+    return None
+
+
+def _mla_decode_xla(q, pool, block_table, local_lens, *, rank, scale,
+                    q_lens=None):
+    """Dense form of the latent paged attend (the interpreter-free
+    fallback and the kernel's oracle): gather the row's pages, score all
+    heads against the whole row, sum the first ``rank`` columns."""
+    lat = pool[block_table].astype(jnp.float32)        # [B, n, page, W]
+    B, n, Pg, W = lat.shape
+    lat = lat.reshape(B, n * Pg, W)
+    T = q.shape[1]
+    logits = jnp.einsum("bthw,bsw->bths", q.astype(jnp.float32),
+                        lat) * scale
+    ql = (jnp.full((B,), T, jnp.int32) if q_lens is None
+          else q_lens.astype(jnp.int32))
+    pos = jnp.arange(n * Pg)[None, None, :]
+    d = ql[:, None] - 1 - jnp.arange(T)[None, :]                # [B, T]
+    valid = ((d[..., None] >= 0)
+             & (pos < (local_lens[:, None] - d)[..., None]))    # [B, T, S]
+    valid = valid[:, :, None, :]
+    logits = jnp.where(valid, logits, NEG_INF)
+    p = jnp.where(valid, jnp.exp(logits - jnp.max(logits, -1,
+                                                  keepdims=True)), 0.0)
+    l = jnp.sum(p, -1, keepdims=True)
+    p = p / jnp.where(l > 0, l, 1.0)
+    return jnp.einsum("bths,bsr->bthr", p, lat[..., :rank])
+
+
+def mla_decode_paged_shard(q, pool, block_table, local_lens, *, rank: int,
+                           scale: float, q_lens=None, impl="auto",
+                           interpret=False):
+    """Absorbed latent attention over a PAGED latent cache.
+
+    q [B, T, H, rank + rope] (the absorbed query ``[q_nope W_UK | q_rope]``;
+    T == 1 is a decode step, T > 1 a verify row or a prefill chunk: query t
+    of row b sits at position ``local_lens[b] - (T or q_lens[b]) + t``);
+    pool [N, page, rank + rope]; block_table [B, n_pages] int32;
+    local_lens [B] valid rows INCLUDING the queries' own.  Returns float32
+    ``[B, T, H, rank]``: softmax(q . row * scale) @ row[:rank], still in
+    the latent space (the caller applies W_UV).  Rows with no live query
+    or no length give zeros.
+    """
+    B, T, H, W = q.shape
+    N, Pg, W2 = pool.shape
+    assert W == W2 and rank < W, (q.shape, pool.shape, rank)
+    n_pages = block_table.shape[1]
+    raw_impl = impl
+    impl = resolve_impl(impl, interpret)
+    gap = mla_kernel_gap(Pg, rank, W - rank)
+    if use_fallback(raw_impl, impl, gap is None or interpret,
+                    MLA_CALL_NAME, gap or ""):
+        return _mla_decode_xla(q, pool, block_table, local_lens, rank=rank,
+                               scale=scale, q_lens=q_lens)
+
+    # the [3, B] lens layout whenever a row may hold dead queries
+    lens_arg, use_qlens = _pack_lens_arg(local_lens, None, q_lens,
+                                         n_tok=T, window=0)
+    tq = max(1, min(T, MLA_Q_ROWS // H))               # tokens a q tile
+    while T % tq:
+        tq -= 1
+    rows = tq * H
+    qr = q.reshape(B, T * H, W)
+    depth = max(2, MLA_PAGES_IN_FLIGHT)
+    kern = functools.partial(_mla_paged_kernel, page=Pg, rank=rank,
+                             n_pages=n_pages, scale=scale, n_tok=T, tq=tq,
+                             heads=H, use_qlens=use_qlens, depth=depth)
+    out = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # (lens, block_table)
+            grid=(B, T // tq),
+            in_specs=[
+                pl.BlockSpec((1, rows, W),
+                             lambda b, j, lens, tab: (b, j, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),    # the pool stays in HBM
+            ],
+            out_specs=pl.BlockSpec((1, rows, rank),
+                                   lambda b, j, lens, tab: (b, j, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((depth, Pg, W), pool.dtype),
+                pltpu.SemaphoreType.DMA((depth,)),
+                pltpu.VMEM((rows, rank), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+                pltpu.VMEM((rows, 128), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, T * H, rank), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=maybe_interpret(interpret),
+        name=MLA_CALL_NAME,
+    )(lens_arg, block_table, qr, pool)
+    return out.reshape(B, T, H, rank)
+
+
+def _mla_paged_kernel(lens_ref, table_ref, q_ref, pool_hbm, out_ref, buf,
+                      sem, acc_ref, m_ref, l_ref, *, page, rank, n_pages,
+                      scale, n_tok, tq, heads, use_qlens, depth):
+    """Grid (B, T // tq); one step is ``tq`` query tokens of one batch row
+    under every head, walking the row's live pages up to the last one its
+    own last token may see, ``depth - 1`` pages copied ahead of the one
+    being multiplied."""
+    b = pl.program_id(0)
+    t0 = pl.program_id(1) * tq
+    llen, wlen, qlen = _read_lens(lens_ref, b, window=0,
+                                  use_qlens=use_qlens)
+    if qlen is None:
+        qlen_t, seen = None, llen
+    else:
+        # this tile's tokens are t0 .. t0 + tq - 1 of the row's qlen:
+        # shift the count so ``_chunk_valid``'s t = r // heads is local,
+        # and stop the walk at what the tile's last token sees
+        qlen_t = qlen - t0
+        seen = llen - jnp.maximum(qlen_t - tq, 0)
+    _, hi = _live_pages(seen, seen, qlen_t, page=page, n_pages=n_pages,
+                        window=0, n_tok=n_tok)
+    rows = q_ref.shape[1]
+
+    def page_copy(i, slot):
+        return pltpu.make_async_copy(pool_hbm.at[table_ref[b, i]],
+                                     buf.at[slot], sem.at[slot])
+
+    for j in range(depth - 1):
+        @pl.when(j < hi)
+        def _():
+            page_copy(j, j).start()
+
+    _softmax_state_init(acc_ref, m_ref, l_ref)
+
+    def page_step(i, _):
+        slot = jax.lax.rem(i, depth)
+        ahead = i + depth - 1
+
+        @pl.when(ahead < hi)
+        def _():
+            page_copy(ahead, jax.lax.rem(ahead, depth)).start()
+
+        page_copy(i, slot).wait()
+        pos = i * page + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, page), 1)
+        valid = _chunk_valid(pos, llen, wlen, qlen_t, window=0,
+                             group=heads)
+        row = buf[slot]                                 # [page, rank + rope]
+        _online_softmax_step(q_ref[0], row, row[:, :rank], valid, acc_ref,
+                             m_ref, l_ref, scale=scale, soft_cap=0.0)
+
+    jax.lax.fori_loop(0, hi, page_step, None)
+    out_ref[0], _ = _softmax_state_emit(acc_ref, m_ref, l_ref)
+
+
 def sp_gqa_decode_paged_shard(q, k_pool, v_pool, block_table, kv_lens, *,
                               axis, impl="auto", interpret=False,
                               soft_cap=0.0, window=0, q_lens=None,

@@ -164,8 +164,28 @@ def _group_gemm_vjp_bwd(block_m, bn, bk, out_dtype, impl, interpret,
 _group_gemm_core.defvjp(_group_gemm_vjp_fwd, _group_gemm_vjp_bwd)
 
 
+def group_gemm_live(x_sorted, w_stack, tile_expert, n_live, *, block_m: int,
+                    bn: int = 2048, bk: int = 1024, out_dtype=None,
+                    impl: str = "auto", interpret: bool = False,
+                    name: str | None = None):
+    """:func:`group_gemm` for the few-rows-an-expert regime of serving
+    (decode steps, prefill chunks): only the first ``n_live`` row tiles
+    hold assignments (``moe_utils.sort_align_held`` puts the held
+    experts' tiles first), and the grid skips the rest — a dead tile
+    multiplies nothing and, its blocks pinned to the last live step's,
+    copies nothing.  Output rows of dead tiles are NOT written: mask them
+    (``valid_rows``) before use.  Each live tile streams its expert's
+    whole slab once, so the call is bound by the weights of the experts
+    hit; the wide default blocks keep the steps few.  ``name`` is the
+    Mosaic call's name in a device trace.  Forward only."""
+    return _group_gemm_fwd_impl(x_sorted, w_stack, tile_expert, block_m,
+                                bn, bk, out_dtype, impl, interpret,
+                                n_live=n_live, name=name)
+
+
 def _group_gemm_fwd_impl(x_sorted, w_stack, tile_expert, block_m, bn, bk,
-                         out_dtype, impl, interpret):
+                         out_dtype, impl, interpret, n_live=None,
+                         name=None):
     m_pad, k_dim = x_sorted.shape
     n_experts, k2, n_dim = w_stack.shape
     assert k_dim == k2, (x_sorted.shape, w_stack.shape)
@@ -190,29 +210,66 @@ def _group_gemm_fwd_impl(x_sorted, w_stack, tile_expert, block_m, bn, bk,
     bk = largest_divisor_block(k_dim, bk, 128)
     n_tiles, n_n, n_k = m_pad // block_m, n_dim // bn, k_dim // bk
 
+    if n_live is None:
+        prefetch = (tile_expert,)
+        x_map = lambda i, j, k, te: (i, k)                    # noqa: E731
+        w_map = lambda i, j, k, te: (te[i], k, j)             # noqa: E731
+        o_map = lambda i, j, k, te: (i, j)                    # noqa: E731
+
+        def _kernel(te_ref, x_ref, w_ref, out_ref, acc_ref):
+            group_gemm_pipeline_body(x_ref, w_ref, out_ref, acc_ref,
+                                     n_k=n_k, out_dtype=out_dtype)
+    else:
+        # Dead tiles (i >= n_live) keep every block index at the LAST
+        # live step's: the pipeline sees no index change, so it copies
+        # nothing in and writes nothing back until the grid ends.
+        prefetch = (tile_expert, jnp.reshape(n_live, (1,)).astype(jnp.int32))
+
+        def _pin(i, nl, live, dead):
+            return jnp.where(i < nl[0], live, dead)
+
+        def x_map(i, j, k, te, nl):
+            last = jnp.maximum(nl[0] - 1, 0)
+            return _pin(i, nl, i, last), _pin(i, nl, k, n_k - 1)
+
+        def w_map(i, j, k, te, nl):
+            last = jnp.maximum(nl[0] - 1, 0)
+            return (te[jnp.minimum(i, last)], _pin(i, nl, k, n_k - 1),
+                    _pin(i, nl, j, n_n - 1))
+
+        def o_map(i, j, k, te, nl):
+            last = jnp.maximum(nl[0] - 1, 0)
+            return _pin(i, nl, i, last), _pin(i, nl, j, n_n - 1)
+
+        def _kernel(te_ref, nl_ref, x_ref, w_ref, out_ref, acc_ref):
+            @pl.when(pl.program_id(0) < nl_ref[0])
+            def _():
+                group_gemm_pipeline_body(x_ref, w_ref, out_ref, acc_ref,
+                                         n_k=n_k, out_dtype=out_dtype)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
+        num_scalar_prefetch=len(prefetch),
         grid=(n_tiles, n_n, n_k),
         in_specs=[
-            pl.BlockSpec((block_m, bk), lambda i, j, k, te: (i, k)),
-            pl.BlockSpec((1, bk, bn), lambda i, j, k, te: (te[i], k, j)),
+            pl.BlockSpec((block_m, bk), x_map),
+            pl.BlockSpec((1, bk, bn), w_map),
         ],
-        out_specs=pl.BlockSpec((block_m, bn), lambda i, j, k, te: (i, j)),
+        out_specs=pl.BlockSpec((block_m, bn), o_map),
         scratch_shapes=[pltpu.VMEM((block_m, bn), acc_dtype)],
     )
 
-    def _kernel(te_ref, x_ref, w_ref, out_ref, acc_ref):
-        group_gemm_pipeline_body(x_ref, w_ref, out_ref, acc_ref,
-                                 n_k=n_k, out_dtype=out_dtype)
-
     return pl.pallas_call(
         _kernel,
+        name=name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, n_dim), out_dtype),
         # Row tiles and n-blocks are independent; only k accumulates.
         # Same knob as the dense matmul's 96%-MXU config (gemm.py).
+        # (the live form revisits the last live output block from its dead
+        # tiles, which only an in-order grid may do)
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "parallel", "arbitrary")
+            if n_live is None else ("arbitrary",) * 3),
         cost_estimate=pl.CostEstimate(
             flops=2 * m_pad * n_dim * k_dim,
             bytes_accessed=(m_pad * k_dim + n_experts * k_dim * n_dim)
@@ -221,7 +278,7 @@ def _group_gemm_fwd_impl(x_sorted, w_stack, tile_expert, block_m, bn, bk,
             transcendentals=0,
         ),
         interpret=maybe_interpret(interpret),
-    )(tile_expert, x_sorted, w_stack)
+    )(*prefetch, x_sorted, w_stack)
 
 
 def moe_ffn_sorted(x_sorted, w_gate, w_up, w_down, tile_expert, *,

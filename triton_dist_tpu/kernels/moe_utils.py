@@ -106,6 +106,50 @@ def sort_align(experts, n_experts: int, block_m: int):
             "valid_rows": valid, "m_pad": m_pad}
 
 
+def sort_align_held(experts, n_held: int, block_m: int, offset=0):
+    """:func:`sort_align` for a layer that holds ``n_held`` of the experts
+    its router chooses among (expert parallelism: ids ``offset .. offset
+    + n_held - 1`` live here, the rest on other chips).  Assignments to
+    held experts are sorted and aligned as there, FIRST in the buffer;
+    every other assignment has no row.  Same contract plus:
+
+      local        [T*topk] bool — the assignment's expert is held here
+      dest         [T*topk] row in the sorted buffer (``m_pad`` — out of
+                   range, so a scatter drops it — where not ``local``)
+      src_token    [M_pad] token of each buffer row (0 on padding rows)
+      n_live_tiles scalar int32: the tiles that hold any row
+      counts       [n_held] assignments an expert got
+
+    A decode step gives a held expert a handful of rows, so this is dense
+    compare-and-sum arithmetic over ``[T*topk, n_held]``, with no sort
+    and no scatter (both serialise on the chip)."""
+    T, topk = experts.shape
+    n = T * topk
+    flat = experts.reshape(-1).astype(jnp.int32) - offset
+    local = (flat >= 0) & (flat < n_held)
+    onehot = (flat[:, None] == jnp.arange(n_held, dtype=jnp.int32)[None])
+    before = jnp.cumsum(onehot.astype(jnp.int32), axis=0)      # inclusive
+    counts = before[-1]
+    rank = jnp.sum(jnp.where(onehot, before - 1, 0), axis=1)
+    padded = round_up(counts, block_m)
+    ends = jnp.cumsum(padded)
+    m_pad = padded_rows(n, n_held, block_m)
+    start = jnp.sum(jnp.where(onehot, (ends - padded)[None], 0), axis=1)
+    dest = jnp.where(local, start + rank, m_pad).astype(jnp.int32)
+    n_tiles = m_pad // block_m
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(ends, jnp.arange(n_tiles) * block_m,
+                         side="right"), n_held - 1).astype(jnp.int32)
+    hit = dest[None, :] == jnp.arange(m_pad, dtype=jnp.int32)[:, None]
+    valid = jnp.any(hit, axis=1)
+    src = jnp.argmax(hit, axis=1).astype(jnp.int32) // topk
+    return {"dest": dest, "tile_expert": tile_expert, "valid_rows": valid,
+            "m_pad": m_pad, "local": local,
+            "src_token": jnp.where(valid, src, 0),
+            "n_live_tiles": (ends[-1] // block_m).astype(jnp.int32),
+            "counts": counts}
+
+
 def gather_sorted(x, dest, m_pad: int):
     """Scatter token rows into the expert-sorted padded buffer.
 

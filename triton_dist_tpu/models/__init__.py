@@ -4,7 +4,9 @@ Reference analog: the reference ships no trainer — its model story is the
 LLaMA-shape test configs (test_ag_gemm.py ``--shape_id``) and inference
 layers.  The TPU build provides actual models: a Llama-style dense
 transformer (``llama.py``) and a Mixtral-style MoE (``moe.py``), both
-running forward AND backward through the overlapped kernels.
+running forward AND backward through the overlapped kernels; and, for
+serving, the latent-attention + sigmoid-routed expert block as one chip's
+share of an expert-parallel deployment (``mla_moe.py``).
 """
 
 from triton_dist_tpu.models.llama import (  # noqa: F401
@@ -41,6 +43,11 @@ from triton_dist_tpu.models.generate import (  # noqa: F401
 from triton_dist_tpu.models.generate_moe import (  # noqa: F401
     MoEGenerator,
     place_params_serving,
+)
+from triton_dist_tpu.models.mla_moe import (  # noqa: F401
+    LatentPoolUnsupported,
+    MlaMoeConfig,
+    MlaMoeGenerator,
 )
 from triton_dist_tpu.models.sampling import (  # noqa: F401
     make_sampler,

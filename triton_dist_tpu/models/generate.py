@@ -338,7 +338,7 @@ def _default_out_proj(o2, layer):
 
 
 def _token_forward(params, caches, token, pos, *, cfg: LlamaConfig,
-                   write_kv, attend, ffn=None, out_proj=None):
+                   write_kv, attend, ffn=None, out_proj=None, project=None):
     """ONE copy of the single-token decode layer math, parameterized by
     the cache addressing (ROADMAP: the shared (write_kv, attend) pair):
 
@@ -356,7 +356,14 @@ def _token_forward(params, caches, token, pos, *, cfg: LlamaConfig,
     ``out_proj(o2, layer) -> [B, D]`` swaps the attention output
     projection (with ``ffn``, the two seams a tensor-parallel
     instantiation must reduce across ranks — serve/mesh.py passes
-    row-parallel matmul + psum hooks and a head-local ``cfg``)."""
+    row-parallel matmul + psum hooks and a head-local ``cfg``).
+
+    ``project(h [B, T, D], layer, pos [B, T]) -> (q, k, v)`` swaps the
+    attention's FRONT half (the seam of all four forwards of this file):
+    the default is ``wq / wk / wv`` + RoPE, written out below; latent
+    attention (models/mla_moe.py) projects through its low-rank pairs and
+    returns ONE cache row a token as ``k`` and ``v = None`` — ``write_kv``
+    / ``attend`` / ``out_proj`` of such a caller know what that means."""
     if ffn is None:
         ffn = _dense_prompt_ffn
     if out_proj is None:
@@ -365,11 +372,15 @@ def _token_forward(params, caches, token, pos, *, cfg: LlamaConfig,
     x = params["embed"][token]  # [B, D]
     for li, layer in enumerate(params["layers"]):
         h = _rms_norm(x[:, None], layer["attn_norm"], cfg.norm_eps)[:, 0]
-        q = (h @ layer["wq"]).reshape(-1, cfg.n_heads, cfg.head_dim)
-        k = (h @ layer["wk"]).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ layer["wv"]).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
-        q = _rope_at(q, pos, cfg.rope_theta)
-        k = _rope_at(k, pos, cfg.rope_theta)
+        if project is None:
+            q = (h @ layer["wq"]).reshape(-1, cfg.n_heads, cfg.head_dim)
+            k = (h @ layer["wk"]).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
+            v = (h @ layer["wv"]).reshape(-1, cfg.n_kv_heads, cfg.head_dim)
+            q = _rope_at(q, pos, cfg.rope_theta)
+            k = _rope_at(k, pos, cfg.rope_theta)
+        else:
+            q, k, v = (None if t is None else t[:, 0]
+                       for t in project(h[:, None], layer, pos[:, None]))
         cache = write_kv(li, caches[li], k, v)
         o = attend(li, q, cache)  # [B, Hq, hd]
         x = x + out_proj(o.reshape(o.shape[0], -1).astype(cfg.dtype),
@@ -384,7 +395,8 @@ def _token_forward(params, caches, token, pos, *, cfg: LlamaConfig,
 
 
 def _multitoken_forward(params, caches, chunk, pos, *, cfg: LlamaConfig,
-                        write_kv, attend, ffn=None, out_proj=None):
+                        write_kv, attend, ffn=None, out_proj=None,
+                        project=None):
     """ONE copy of the multi-token (speculative-verify) layer math,
     parameterized like :func:`_token_forward`:
 
@@ -396,8 +408,9 @@ def _multitoken_forward(params, caches, chunk, pos, *, cfg: LlamaConfig,
     ``_verify_forward`` (contiguous per-row writes) and
     ``serve.engine._paged_verify_forward`` (block-table addressing)
     share it.  ``pos`` [B, T] int32: global position of query t of row
-    b (``kv_lens[b] + t``).  ``out_proj`` as in :func:`_token_forward`
-    (the tensor-parallel reduction seam)."""
+    b (``kv_lens[b] + t``).  ``out_proj`` and ``project`` as in
+    :func:`_token_forward` (the tensor-parallel reduction seam; the
+    attention's front half)."""
     if ffn is None:
         ffn = _dense_prompt_ffn
     if out_proj is None:
@@ -408,15 +421,18 @@ def _multitoken_forward(params, caches, chunk, pos, *, cfg: LlamaConfig,
     new_caches = []
     for li, layer in enumerate(params["layers"]):
         h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        h2 = h.reshape(B * T, cfg.dim)
-        q = (h2 @ layer["wq"]).reshape(B, T, cfg.n_heads, hd)
-        k = (h2 @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, hd)
-        v = (h2 @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, hd)
-        q = _rope_rows(q, pos, cfg.rope_theta)
-        k = _rope_rows(k, pos, cfg.rope_theta)
+        if project is None:
+            h2 = h.reshape(B * T, cfg.dim)
+            q = (h2 @ layer["wq"]).reshape(B, T, cfg.n_heads, hd)
+            k = (h2 @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, hd)
+            v = (h2 @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, hd)
+            q = _rope_rows(q, pos, cfg.rope_theta)
+            k = _rope_rows(k, pos, cfg.rope_theta)
+        else:
+            q, k, v = project(h, layer, pos)
         cache = write_kv(li, caches[li], k, v)
         o = attend(li, q, cache)                      # [B, T, Hq, hd]
-        o = o.reshape(B * T, cfg.n_heads * hd).astype(cfg.dtype)
+        o = o.reshape(B * T, -1).astype(cfg.dtype)
         x = x + out_proj(o, layer).reshape(B, T, cfg.dim)
         h2 = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps).reshape(
             B * T, cfg.dim)
@@ -604,7 +620,7 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg: LlamaConfig,
                    quantized: bool, ffn=None, out_proj=None,
                    extent: int | None = None,
                    n_valid=None, impl: str = "auto", interpret: bool = False,
-                   mesh=None, axis=None, attend=None):
+                   mesh=None, axis=None, attend=None, project=None):
     """One prompt chunk [B, c] against the cached prefix; returns
     (new_caches, logits [B, c, V] — position i predicts the token after
     chunk[:, i]).  The chunk's own K/V are written to the cache first
@@ -639,7 +655,11 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg: LlamaConfig,
     ``quantized``).  serve/mesh.py's sequence-sharded chunk prefill
     supplies one that slices the rank-local span out of the views and
     LSE-combines across ranks — the K/V write above it stays whole, so
-    cache contents never depend on the layout."""
+    cache contents never depend on the layout.
+
+    ``project`` as in :func:`_token_forward`.  A layer's cache is a tuple
+    of PLANES, (K, V) here; a ``project`` that returns ``v = None`` writes
+    one plane, and ``attend`` is then called with that one view."""
     if ffn is None:
         ffn = _dense_prompt_ffn
     if out_proj is None:
@@ -658,33 +678,37 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg: LlamaConfig,
                                                            None])
     new_caches = []
     for li, layer in enumerate(params["layers"]):
-        k_c, v_c = caches[li]
         h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        h2 = h.reshape(B * c, cfg.dim)
-        q = (h2 @ layer["wq"]).reshape(B, c, cfg.n_heads, hd)
-        k = (h2 @ layer["wk"]).reshape(B, c, cfg.n_kv_heads, hd)
-        v = (h2 @ layer["wv"]).reshape(B, c, cfg.n_kv_heads, hd)
-        q = _rope(q.transpose(1, 0, 2, 3), positions,
-                  cfg.rope_theta).transpose(1, 0, 2, 3)
-        k = _rope(k.transpose(1, 0, 2, 3), positions,
-                  cfg.rope_theta).transpose(1, 0, 2, 3)
+        if project is None:
+            h2 = h.reshape(B * c, cfg.dim)
+            q = (h2 @ layer["wq"]).reshape(B, c, cfg.n_heads, hd)
+            k = (h2 @ layer["wk"]).reshape(B, c, cfg.n_kv_heads, hd)
+            v = (h2 @ layer["wv"]).reshape(B, c, cfg.n_kv_heads, hd)
+            q = _rope(q.transpose(1, 0, 2, 3), positions,
+                      cfg.rope_theta).transpose(1, 0, 2, 3)
+            k = _rope(k.transpose(1, 0, 2, 3), positions,
+                      cfg.rope_theta).transpose(1, 0, 2, 3)
+        else:
+            q, k, v = project(h, layer, positions[None, :])
+        rows = (k,) if v is None else (k, v)
         if pad_mask is not None:
-            k = jnp.where(pad_mask, k, jnp.zeros((), k.dtype))
-            v = jnp.where(pad_mask, v, jnp.zeros((), v.dtype))
-        k_c = _write_chunk(k_c, k.transpose(0, 2, 1, 3), prefix_len,
-                           quantized)
-        v_c = _write_chunk(v_c, v.transpose(0, 2, 1, 3), prefix_len,
-                           quantized)
-        new_caches.append((k_c, v_c))
-        ext = extent or (k_c["q"] if quantized else k_c).shape[2]
+            rows = tuple(jnp.where(pad_mask, t, jnp.zeros((), t.dtype))
+                         for t in rows)
+        planes = tuple(_write_chunk(p, t.transpose(0, 2, 1, 3), prefix_len,
+                                    quantized)
+                       for p, t in zip(caches[li], rows))
+        new_caches.append(planes)
+        ext = extent or (planes[0]["q"] if quantized
+                         else planes[0]).shape[2]
         if quantized:
+            k_c, v_c = planes
             o = attend(q, k_c["q"][:, :, :ext], v_c["q"][:, :, :ext],
                        prefix_len, k_scale=k_c["s"][:, :, :ext],
                        v_scale=v_c["s"][:, :, :ext])
         else:
-            o = attend(q, k_c[:, :, :ext], v_c[:, :, :ext], prefix_len,
+            o = attend(q, *(p[:, :, :ext] for p in planes), prefix_len,
                        k_scale=None, v_scale=None)
-        o = o.reshape(B * c, cfg.n_heads * hd).astype(cfg.dtype)
+        o = o.reshape(B * c, -1).astype(cfg.dtype)
         x = x + out_proj(o, layer).reshape(B, c, cfg.dim)
         h2 = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps).reshape(
             B * c, cfg.dim)
@@ -771,17 +795,24 @@ def _dense_prompt_ffn(h2, layer):
 
 
 def _prompt_forward(params, tokens, *, cfg: LlamaConfig, ffn=None,
-                    impl: str = "auto", interpret: bool = False):
+                    impl: str = "auto", interpret: bool = False,
+                    out_proj=None, project=None, attend=None):
     """Full-sequence forward on replicated weights that also returns the
     per-layer K/V (post-RoPE, cache layout [B, Hkv, S, hd]) and logits.
 
     ``ffn(h2, layer) -> [B*S, D]`` swaps the MLP — the MoE family
     (generate_moe.py) reuses the whole attention/cache body this way.
+    ``project`` / ``out_proj`` as in :func:`_token_forward`; with a
+    ``project``, ``attend(q [B, S, Hq, .], k [B, S, Hkv, .]) -> [B, S,
+    Hq, .]`` is the causal attention over what it returned, and a
+    layer's cache rows come back as a one-plane tuple.
     """
     from triton_dist_tpu.kernels.flash_attention import flash_gqa_attention
 
     if ffn is None:
         ffn = _dense_prompt_ffn
+    if out_proj is None:
+        out_proj = _default_out_proj
     B, S = tokens.shape
     hd = cfg.head_dim
     x = params["embed"][tokens]          # [B, S, D]
@@ -789,23 +820,28 @@ def _prompt_forward(params, tokens, *, cfg: LlamaConfig, ffn=None,
     kvs = []
     for layer in params["layers"]:
         h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        h2 = h.reshape(B * S, cfg.dim)
-        q = (h2 @ layer["wq"]).reshape(B, S, cfg.n_heads, hd)
-        k = (h2 @ layer["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
-        v = (h2 @ layer["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
-        # _rope expects [S, B, H, hd] (seq-major).
-        q = _rope(q.transpose(1, 0, 2, 3), positions, cfg.rope_theta)
-        k = _rope(k.transpose(1, 0, 2, 3), positions, cfg.rope_theta)
-        v = v.transpose(1, 0, 2, 3)
-        kvs.append((k.transpose(1, 2, 0, 3), v.transpose(1, 2, 0, 3)))
-        o = flash_gqa_attention(q, k, v, causal=True,
-                                scale=1.0 / np.sqrt(hd),
-                                impl="xla" if impl == "xla" else "auto",
-                                interpret=interpret,
-                                window=cfg.attn_window,
-                                soft_cap=cfg.attn_soft_cap)
-        o = o.transpose(1, 0, 2, 3).reshape(B * S, cfg.n_heads * hd)
-        x = x + (o @ layer["wo"]).reshape(B, S, cfg.dim)
+        if project is None:
+            h2 = h.reshape(B * S, cfg.dim)
+            q = (h2 @ layer["wq"]).reshape(B, S, cfg.n_heads, hd)
+            k = (h2 @ layer["wk"]).reshape(B, S, cfg.n_kv_heads, hd)
+            v = (h2 @ layer["wv"]).reshape(B, S, cfg.n_kv_heads, hd)
+            # _rope expects [S, B, H, hd] (seq-major).
+            q = _rope(q.transpose(1, 0, 2, 3), positions, cfg.rope_theta)
+            k = _rope(k.transpose(1, 0, 2, 3), positions, cfg.rope_theta)
+            v = v.transpose(1, 0, 2, 3)
+            kvs.append((k.transpose(1, 2, 0, 3), v.transpose(1, 2, 0, 3)))
+            o = flash_gqa_attention(q, k, v, causal=True,
+                                    scale=1.0 / np.sqrt(hd),
+                                    impl="xla" if impl == "xla" else "auto",
+                                    interpret=interpret,
+                                    window=cfg.attn_window,
+                                    soft_cap=cfg.attn_soft_cap)
+            o = o.transpose(1, 0, 2, 3).reshape(B * S, cfg.n_heads * hd)
+        else:
+            q, k, _ = project(h, layer, positions[None, :])
+            kvs.append((k.transpose(0, 2, 1, 3),))
+            o = attend(q, k).reshape(B * S, -1).astype(cfg.dtype)
+        x = x + out_proj(o, layer).reshape(B, S, cfg.dim)
         h2 = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps).reshape(
             B * S, cfg.dim)
         x = x + ffn(h2, layer).reshape(B, S, cfg.dim)

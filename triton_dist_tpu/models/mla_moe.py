@@ -1,0 +1,633 @@
+"""Latent attention + sigmoid-routed expert layers (the DeepSeek-V3 block)
+for the serving engine, as ONE CHIP'S SHARE of an expert-parallel
+deployment.
+
+The block, per layer: ``x += W_o . MLA(rms(x))``, ``x += FFN(rms(x))``.
+
+* **Latent attention (MLA).**  Queries go through a low-rank pair
+  (``wq_a`` -> RMSNorm -> ``wq_b``) to ``n_heads x (nope | rope)``; keys
+  and values come from ONE latent row a token, ``[c_kv | k_rope]`` (``wkv_a``
+  -> RMSNorm on the ``c_kv`` part, RoPE on the ``k_rope`` part, which every
+  head shares).  The cache holds that row and nothing else.  Attention
+  runs in the ABSORBED form: ``q~_h = q_nope_h W_UK,h^T`` scores against
+  the whole row, the value is the row's ``c_kv`` part, and ``W_UV,h`` is
+  applied to the result (``out_proj``) — the expanded K and V (``c_kv
+  W_kvb``) are never formed.  RoPE is YaRN-scaled (:func:`yarn_inv_freq`).
+* **Expert layer.**  ``noaux_tc`` routing: sigmoid scores, a bias that
+  enters the SELECTION only, group-limited top-k, weights renormalised
+  and scaled; a shared expert beside the routed ones.  The layer is told
+  which experts it HOLDS (``experts_held`` from ``expert_offset``): it
+  routes over all ``n_experts``, computes its own experts' part for the
+  rows routed to them (``moe_utils.sort_align_held`` + the grouped GEMM),
+  and leaves the rest out — what the absent experts would add is absent,
+  nothing stands in for the other chips.  Leading layers
+  (``first_k_dense``) carry a dense SwiGLU MLP instead.
+
+Everything enters the engine's programs through the seams of
+``models/generate.py`` (``project`` / ``write_kv`` / ``attend`` /
+``out_proj`` / ``ffn``), so the forwards stay one copy each.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+import types
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from triton_dist_tpu.kernels import moe_utils
+from triton_dist_tpu.kernels.flash_decode import (
+    mla_decode_paged_shard,
+    mla_kernel_gap,
+)
+from triton_dist_tpu.kernels.gemm import resolve_impl
+from triton_dist_tpu.kernels.group_gemm import group_gemm_live
+from triton_dist_tpu.models.generate import (
+    _chunk_forward,
+    _dense_prompt_ffn,
+    _prompt_forward,
+)
+from triton_dist_tpu.models.llama import _rms_norm
+from triton_dist_tpu.runtime.jit_cache import named
+
+LANES = 128
+GATE_UP_CALL, DOWN_CALL = "moe_gate_up", "moe_down"
+
+
+class LatentPoolUnsupported(NotImplementedError):
+    """A serving feature that has not been carried over to latent (MLA)
+    pools was asked for: raised where the engine or generator is built,
+    or where the entry point is called — never a quiet fallback."""
+
+
+@dataclasses.dataclass(frozen=True)
+class MlaMoeConfig:
+    vocab: int                  # rows of the vocabulary held here
+    dim: int
+    n_layers: int
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    ffn_dim: int                # the dense layers' MLP
+    moe_ffn_dim: int            # one expert (routed or shared)
+    n_experts: int              # the router's width
+    experts_held: int           # routed experts this chip holds ...
+    expert_offset: int = 0      # ... ids offset .. offset + held - 1
+    n_shared_experts: int = 1
+    first_k_dense: int = 1
+    n_group: int = 1
+    topk_group: int = 1
+    top_k: int = 8
+    routed_scaling: float = 1.0
+    norm_topk_prob: bool = True
+    rope_theta: float = 10000.0
+    # YaRN: (factor, original_max_position_embeddings, beta_fast,
+    # beta_slow, mscale, mscale_all_dim), or None for plain RoPE
+    yarn: tuple | None = None
+    norm_eps: float = 1e-6
+    max_seq: int = 2048
+    dtype: object = jnp.float32
+    moe_block_m: int = 32       # grouped-GEMM row tile (see routed_experts)
+
+    # -- what the serving engine reads of a model config -------------------
+    @property
+    def latent_width(self) -> int:
+        """Numbers the cache holds a token a layer."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def head_dim(self) -> int:
+        """Width of a cache row AS STORED: the latent row padded to whole
+        lane tiles (576 -> 640; see kernels/flash_decode.py)."""
+        return -(-self.latent_width // LANES) * LANES
+
+    @property
+    def n_kv_heads(self) -> int:
+        return 1                # one latent row serves every head
+
+    @property
+    def softmax_scale(self) -> float:
+        s = (self.qk_nope_head_dim + self.qk_rope_head_dim) ** -0.5
+        if self.yarn is not None:
+            factor, _, _, _, _, all_dim = self.yarn
+            s *= _yarn_mscale(factor, all_dim) ** 2
+        return s
+
+    def is_moe_layer(self, li: int) -> bool:
+        return li >= self.first_k_dense
+
+    @staticmethod
+    def from_hf(c: dict, *, max_seq: int, dtype=jnp.bfloat16,
+                experts_total: int | None = None, expert_offset: int = 0,
+                **over) -> "MlaMoeConfig":
+        """From the keys of a ``deepseek_v3`` ``config.json``.  In a
+        share's file ``n_routed_experts`` counts the experts HELD and
+        ``vocab_size`` the rows held; ``experts_total`` is the router's
+        published width."""
+        rs = c.get("rope_scaling")
+        yarn = None
+        if rs:
+            if rs.get("rope_type", rs.get("type")) != "yarn":
+                raise ValueError(f"rope_scaling {rs!r}: only yarn is served")
+            yarn = (float(rs["factor"]),
+                    int(rs["original_max_position_embeddings"]),
+                    float(rs["beta_fast"]), float(rs["beta_slow"]),
+                    float(rs.get("mscale", 1.0)),
+                    float(rs.get("mscale_all_dim", 0.0)))
+        for key, want in (("scoring_func", "sigmoid"),
+                          ("topk_method", "noaux_tc"),
+                          ("hidden_act", "silu")):
+            if c.get(key, want) != want:
+                raise ValueError(f"{key} {c[key]!r}: only {want!r} is served")
+        if c.get("moe_layer_freq", 1) != 1:
+            raise ValueError("moe_layer_freq != 1 is not served")
+        return MlaMoeConfig(
+            vocab=c["vocab_size"], dim=c["hidden_size"],
+            n_layers=c["num_hidden_layers"],
+            n_heads=c["num_attention_heads"],
+            q_lora_rank=c["q_lora_rank"], kv_lora_rank=c["kv_lora_rank"],
+            qk_nope_head_dim=c["qk_nope_head_dim"],
+            qk_rope_head_dim=c["qk_rope_head_dim"],
+            v_head_dim=c["v_head_dim"], ffn_dim=c["intermediate_size"],
+            moe_ffn_dim=c["moe_intermediate_size"],
+            n_experts=experts_total or c["n_routed_experts"],
+            experts_held=c["n_routed_experts"], expert_offset=expert_offset,
+            n_shared_experts=c.get("n_shared_experts") or 0,
+            first_k_dense=c["first_k_dense_replace"],
+            n_group=c["n_group"], topk_group=c["topk_group"],
+            top_k=c["num_experts_per_tok"],
+            routed_scaling=float(c["routed_scaling_factor"]),
+            norm_topk_prob=bool(c["norm_topk_prob"]),
+            rope_theta=float(c["rope_theta"]), yarn=yarn,
+            norm_eps=float(c["rms_norm_eps"]), max_seq=max_seq,
+            dtype=dtype, **over)
+
+    @staticmethod
+    def tiny(dtype=jnp.float32, **over) -> "MlaMoeConfig":
+        """CPU test size: every mechanism of the block, kernel-legal
+        shapes (rank and row a whole number of lane tiles)."""
+        kw = dict(vocab=256, dim=128, n_layers=3, n_heads=4,
+                  q_lora_rank=64, kv_lora_rank=128, qk_nope_head_dim=32,
+                  qk_rope_head_dim=32, v_head_dim=48, ffn_dim=256,
+                  moe_ffn_dim=128, n_experts=16, experts_held=4,
+                  expert_offset=4, n_shared_experts=1, first_k_dense=1,
+                  n_group=4, topk_group=2, top_k=4, routed_scaling=2.5,
+                  rope_theta=1e5, yarn=(64.0, 64, 32.0, 1.0, 1.0, 1.0),
+                  max_seq=512, dtype=dtype, moe_block_m=8)
+        kw.update(over)
+        return MlaMoeConfig(**kw)
+
+
+# ---------------------------------------------------------------------------
+# RoPE (YaRN)
+# ---------------------------------------------------------------------------
+
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_inv_freq(cfg: MlaMoeConfig) -> np.ndarray:
+    """Inverse frequencies of the ``qk_rope_head_dim / 2`` rotary pairs:
+    plain RoPE's, or YaRN's blend of the interpolated (``/ factor``) and
+    the extrapolated ones along a linear ramp between the pairs that
+    turn ``beta_fast`` and ``beta_slow`` times over the original context."""
+    d = cfg.qk_rope_head_dim
+    extra = 1.0 / (cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
+    if cfg.yarn is None:
+        return extra.astype(np.float32)
+    factor, orig, beta_fast, beta_slow, _, _ = cfg.yarn
+
+    def corr(n_rot):
+        return (d * math.log(orig / (n_rot * 2 * math.pi))
+                / (2 * math.log(cfg.rope_theta)))
+
+    low = max(math.floor(corr(beta_fast)), 0)
+    high = min(math.ceil(corr(beta_slow)), d - 1)
+    ramp = np.clip((np.arange(d // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    return (extra / factor * ramp + extra * (1.0 - ramp)).astype(np.float32)
+
+
+def yarn_cos_sin_scale(cfg: MlaMoeConfig) -> float:
+    if cfg.yarn is None:
+        return 1.0
+    factor, _, _, _, mscale, all_dim = cfg.yarn
+    return _yarn_mscale(factor, mscale) / _yarn_mscale(factor, all_dim)
+
+
+def _rope(x, pos, inv_freq, scale):
+    """x [B, T, H, d] at positions pos [B, T] (or [1, T]); rotate-half."""
+    ang = pos[..., None].astype(jnp.float32) * inv_freq       # [B, T, d/2]
+    cos = (jnp.cos(ang) * scale)[:, :, None, :]
+    sin = (jnp.sin(ang) * scale)[:, :, None, :]
+    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                           axis=-1).astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+# name -> (subkey index, fan_in, shape) of a layer's matrices; subkeys are
+# split(layer_key, 16).  The recipe (normal / sqrt(fan_in), norms 1, the
+# router's bias normal / 100, rounded once to the serving dtype) is stated
+# by the benchmark's configuration file and drawn again, independently, by
+# its reference.  A routed expert's matrices derive from its GLOBAL id
+# (fold_in), so the 16 shares of a layer tile the uncut layer.
+
+
+def _attn_matrices(c: MlaMoeConfig) -> dict:
+    H, D = c.n_heads, c.dim
+    return {
+        "wq_a": (0, D, (D, c.q_lora_rank)),
+        "wq_b": (1, c.q_lora_rank,
+                 (c.q_lora_rank, H * (c.qk_nope_head_dim
+                                      + c.qk_rope_head_dim))),
+        "wkv_a": (2, D, (D, c.latent_width)),
+        "wkv_b": (3, c.kv_lora_rank,
+                  (c.kv_lora_rank, H * (c.qk_nope_head_dim + c.v_head_dim))),
+        "wo": (4, H * c.v_head_dim, (H * c.v_head_dim, D)),
+    }
+
+
+def _mlp_matrices(D: int, F: int, base: int) -> dict:
+    return {"wgate": (base, D, (D, F)), "wup": (base + 1, D, (D, F)),
+            "wdown": (base + 2, F, (F, D))}
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _draw(key, denom, *, shape, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) / denom).astype(dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _draw_experts(key, ids, denom, *, shape, dtype):
+    def one(e):
+        return jax.random.normal(jax.random.fold_in(key, e), shape,
+                                 jnp.float32) / denom
+    return jax.vmap(one)(ids).astype(dtype)
+
+
+def init_params(cfg: MlaMoeConfig, key) -> dict:
+    """Seeded weights, drawn on the default device leaf by leaf.
+
+    ``wkv_b`` is drawn at its published shape and stored split per head
+    as the absorbed form uses it: ``w_uk`` [H, nope, rank] (into the
+    query) and ``w_uv`` [H, rank, v] (out of the latent result).  Gate
+    and up of the routed experts are stored side by side (``w_gate_up``
+    [held, D, 2F]): one grouped GEMM serves both."""
+    c, dt = cfg, cfg.dtype
+
+    def dense(k, fan_in, shape):
+        return _draw(k, jnp.float32(math.sqrt(fan_in)), shape=shape, dtype=dt)
+
+    def mats(lk, table):
+        return {n: dense(lk[j], fi, sh) for n, (j, fi, sh) in table.items()}
+
+    keys = jax.random.split(key, 2 + c.n_layers)
+    params = {
+        "embed": dense(keys[0], 1, (c.vocab, c.dim)),
+        "lm_head": dense(keys[1], c.dim, (c.dim, c.vocab)),
+        "final_norm": jnp.ones((c.dim,), dt),
+        "layers": [],
+    }
+    H, R = c.n_heads, c.kv_lora_rank
+    held = jnp.arange(c.expert_offset, c.expert_offset + c.experts_held)
+    for li in range(c.n_layers):
+        lk = jax.random.split(keys[2 + li], 16)
+        layer = mats(lk, _attn_matrices(c))
+        kvb = layer.pop("wkv_b").reshape(
+            R, H, c.qk_nope_head_dim + c.v_head_dim)
+        layer["w_uk"] = kvb[:, :, :c.qk_nope_head_dim].transpose(1, 2, 0)
+        layer["w_uv"] = kvb[:, :, c.qk_nope_head_dim:].transpose(1, 0, 2)
+        layer.update(attn_norm=jnp.ones((c.dim,), dt),
+                     mlp_norm=jnp.ones((c.dim,), dt),
+                     q_norm=jnp.ones((c.q_lora_rank,), dt),
+                     kv_norm=jnp.ones((R,), dt))
+        if not c.is_moe_layer(li):
+            layer.update(mats(lk, _mlp_matrices(c.dim, c.ffn_dim, 5)))
+        else:
+            F = c.moe_ffn_dim
+            layer["router"] = dense(lk[8], c.dim, (c.dim, c.n_experts))
+            layer["router_bias"] = _draw(lk[9], jnp.float32(100.0),
+                                         shape=(c.n_experts,), dtype=dt)
+            if c.n_shared_experts:
+                layer["shared"] = mats(lk, _mlp_matrices(
+                    c.dim, F * c.n_shared_experts, 5))
+
+            def experts(j, fan_in, shape):
+                return _draw_experts(lk[j], held,
+                                     jnp.float32(math.sqrt(fan_in)),
+                                     shape=shape, dtype=dt)
+
+            layer["w_gate_up"] = jnp.concatenate(
+                [experts(10, c.dim, (c.dim, F)),
+                 experts(11, c.dim, (c.dim, F))], axis=-1)
+            layer["w_down"] = experts(12, F, (F, c.dim))
+        params["layers"].append(layer)
+    return params
+
+
+# ---------------------------------------------------------------------------
+# The seams: project / out_proj / ffn
+# ---------------------------------------------------------------------------
+
+
+def project(h, layer, pos, *, cfg: MlaMoeConfig):
+    """The attention's front half.  h [B, T, D], pos [B, T] (or [1, T])
+    -> (q [B, T, H, W] absorbed ``[q_nope W_UK | q_rope | 0]``, latent
+    [B, T, 1, W] ``[rms(c_kv) | rope(k_r) | 0]``, None): there is no V —
+    the value is the first ``kv_lora_rank`` columns of the same row."""
+    c = cfg
+    B, T, D = h.shape
+    H, R, dn, dr = (c.n_heads, c.kv_lora_rank, c.qk_nope_head_dim,
+                    c.qk_rope_head_dim)
+    inv_freq, cs = yarn_inv_freq(c), yarn_cos_sin_scale(c)
+    h2 = h.reshape(B * T, D)
+    cq = _rms_norm(h2 @ layer["wq_a"], layer["q_norm"], c.norm_eps)
+    q = (cq @ layer["wq_b"]).reshape(B, T, H, dn + dr)
+    ckv = h2 @ layer["wkv_a"]                          # [B*T, R + dr]
+    c_kv = _rms_norm(ckv[:, :R], layer["kv_norm"], c.norm_eps)
+    k_r = _rope(ckv[:, R:].reshape(B, T, 1, dr), pos, inv_freq, cs)
+    q_r = _rope(q[..., dn:], pos, inv_freq, cs)
+    q_abs = jnp.einsum("bthn,hnr->bthr", q[..., :dn], layer["w_uk"])
+    pad = c.head_dim - c.latent_width
+    zq = [jnp.zeros((B, T, H, pad), q.dtype)] if pad else []
+    zk = [jnp.zeros((B, T, 1, pad), q.dtype)] if pad else []
+    return (jnp.concatenate([q_abs, q_r] + zq, axis=-1),
+            jnp.concatenate([c_kv.reshape(B, T, 1, R), k_r] + zk, axis=-1),
+            None)
+
+
+def out_proj(o2, layer, *, cfg: MlaMoeConfig):
+    """o2 [rows, H * rank] (the latent-space attention result) ->
+    [rows, D]: ``W_UV`` per head, then ``W_o``."""
+    rows = o2.shape[0]
+    o = jnp.einsum("rhc,hcv->rhv",
+                   o2.reshape(rows, cfg.n_heads, cfg.kv_lora_rank),
+                   layer["w_uv"])
+    return o.reshape(rows, cfg.n_heads * cfg.v_head_dim) @ layer["wo"]
+
+
+def _top_k(x, k: int):
+    """``lax.top_k`` over the last axis for a handful of picks among a few
+    hundred columns: ``k`` rounds of argmax-and-mask (ties go to the lower
+    index, as there).  ``lax.top_k`` lowers to a full sort on the chip —
+    three a layer, 1.7 ms of a 12 ms decode step (chip trace, PR 26)."""
+    cols = jnp.arange(x.shape[-1], dtype=jnp.int32)
+    vals, ids = [], []
+    for _ in range(k):
+        i = jnp.argmax(x, axis=-1).astype(jnp.int32)
+        vals.append(jnp.max(x, axis=-1))
+        ids.append(i)
+        x = jnp.where(cols == i[..., None], -jnp.inf, x)
+    return jnp.stack(vals, axis=-1), jnp.stack(ids, axis=-1)
+
+
+def route(h2, layer, cfg: MlaMoeConfig):
+    """``noaux_tc``: h2 [T, D] -> (ids [T, top_k] int32 over all
+    ``n_experts``, weights [T, top_k] float32).  Scores in float32; the
+    bias moves the choice, never the weight."""
+    c = cfg
+    T = h2.shape[0]
+    s = jax.nn.sigmoid(jnp.dot(h2, layer["router"],
+                               preferred_element_type=jnp.float32))
+    sb = s + layer["router_bias"].astype(jnp.float32)
+    per = c.n_experts // c.n_group
+    best2 = _top_k(sb.reshape(T, c.n_group, per), min(2, per))[0]
+    _, groups = _top_k(best2.sum(-1), c.topk_group)           # [T, tg]
+    keep = jnp.any(groups[:, :, None]
+                   == jnp.arange(c.n_group)[None, None, :], axis=1)
+    sb = jnp.where(jnp.repeat(keep, per, axis=1), sb, -jnp.inf)
+    _, ids = _top_k(sb, c.top_k)
+    w = jnp.take_along_axis(s, ids, axis=1)
+    if c.norm_topk_prob:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), w * c.routed_scaling
+
+
+def routed_experts(h2, layer, cfg: MlaMoeConfig, *, impl="auto",
+                   interpret=False):
+    """The held experts' part of the routed sum for rows h2 [T, D] ->
+    (float32 [T, D], stats int32 [4]).
+
+    Rows routed here are gathered expert by expert into row tiles of
+    ``moe_block_m`` (a decode step of 64 rows gives a held expert ~2, a
+    prefill chunk ~4; a tile of 32 holds an expert's rows in one piece,
+    so its weights stream once, and costs the MXU what any tile under 128
+    rows costs), then two grouped GEMMs: gate and up side by side, and
+    down.  ``stats``: assignments routed, those that landed here, pad rows
+    of the live tiles, experts hit."""
+    c = cfg
+    T, D = h2.shape
+    F = c.moe_ffn_dim
+    ids, w = route(h2, layer, c)
+    plan = moe_utils.sort_align_held(ids, c.experts_held, c.moe_block_m,
+                                     c.expert_offset)
+    live = plan["valid_rows"][:, None]
+    x_sorted = jnp.where(live, h2[plan["src_token"]], jnp.zeros((), h2.dtype))
+    gg = functools.partial(group_gemm_live, tile_expert=plan["tile_expert"],
+                           n_live=plan["n_live_tiles"],
+                           block_m=c.moe_block_m, impl=impl,
+                           interpret=interpret)
+    gu = gg(x_sorted, layer["w_gate_up"], name=GATE_UP_CALL)
+    # dead tiles are not written: whatever they hold stays out of the sum
+    act = jnp.where(live, (jax.nn.silu(gu[:, :F].astype(jnp.float32))
+                           .astype(h2.dtype) * gu[:, F:]),
+                    jnp.zeros((), h2.dtype))
+    y = gg(act, layer["w_down"], name=DOWN_CALL)
+    local = plan["local"].reshape(T, c.top_k)
+    rows = jnp.minimum(plan["dest"], plan["m_pad"] - 1).reshape(T, c.top_k)
+    picked = jnp.where(local[..., None], y[rows].astype(jnp.float32), 0.0)
+    out = jnp.einsum("tk,tkd->td", jnp.where(local, w, 0.0), picked)
+    n_local = jnp.sum(plan["local"].astype(jnp.int32))
+    stats = jnp.stack([jnp.int32(T * c.top_k), n_local,
+                       plan["n_live_tiles"] * c.moe_block_m - n_local,
+                       jnp.sum((plan["counts"] > 0).astype(jnp.int32))])
+    return out, stats
+
+
+class MoeTally:
+    """Trace-time collector of the expert layers' ``stats`` of ONE
+    program: the ``ffn`` seam returns activations only, so each expert
+    layer leaves its counts here and :func:`with_moe_stats` hands their
+    sum out as the program's last output."""
+
+    def __init__(self):
+        self.rows: list = []
+
+    def drain(self):
+        rows, self.rows = self.rows, []
+        return sum(rows) if rows else jnp.zeros((4,), jnp.int32)
+
+
+def with_moe_stats(fwd, tally: MoeTally):
+    """``fwd`` with the tally's sum appended to its outputs."""
+    @functools.wraps(fwd)
+    def run(*args, **kwargs):
+        tally.rows.clear()      # a trace that raised may have left some
+        out = fwd(*args, **kwargs)
+        return (*out, tally.drain())
+    return run
+
+
+def ffn(h2, layer, *, cfg: MlaMoeConfig, tally: MoeTally | None = None,
+        impl="auto", interpret=False):
+    """Dense SwiGLU on the leading layers; shared expert + the held
+    routed experts on the rest."""
+    if "router" not in layer:
+        return _dense_prompt_ffn(h2, layer)
+    routed, stats = routed_experts(h2, layer, cfg, impl=impl,
+                                   interpret=interpret)
+    if tally is not None:
+        tally.rows.append(stats)
+    if "shared" in layer:
+        routed = routed + _dense_prompt_ffn(
+            h2, layer["shared"]).astype(jnp.float32)
+    return routed.astype(h2.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Attention over latent caches
+# ---------------------------------------------------------------------------
+
+
+def paged_attend(q, pool, tables, lens, *, cfg: MlaMoeConfig, impl,
+                 interpret, q_lens=None):
+    """The engine's paged attend over ONE layer's latent pool
+    ``(plane [N, 1, page, W],)``: q [B, (T,) H, W] -> [B, (T,) H, rank]."""
+    plane = pool[0]
+    N, _, page, W = plane.shape
+    single = q.ndim == 3
+    out = mla_decode_paged_shard(
+        q[:, None] if single else q, plane.reshape(N, page, W), tables,
+        lens, rank=cfg.kv_lora_rank, scale=cfg.softmax_scale,
+        q_lens=q_lens, impl=impl, interpret=interpret)
+    return out[:, 0] if single else out
+
+
+def _scratch_block(ext: int) -> int:
+    return next(b for b in (128, 64, 32, 16, 8, 4, 2, 1) if ext % b == 0)
+
+
+def attend_prefix(q, lat, prefix_len, *, cfg: MlaMoeConfig, impl, interpret,
+                  k_scale=None, v_scale=None):
+    """Chunk attention of ``generate._chunk_forward`` over a CONTIGUOUS
+    latent scratch lat [B, 1, S, W] (the chunk's rows already written at
+    ``prefix_len``): the scratch read as pages under an identity table,
+    through the same kernel in its multi-token form."""
+    B, c = q.shape[0], q.shape[1]
+    S, W = lat.shape[2], lat.shape[3]
+    page = _scratch_block(S)
+    n = S // page
+    tables = (jnp.arange(B, dtype=jnp.int32)[:, None] * n
+              + jnp.arange(n, dtype=jnp.int32)[None, :])
+    lens = jnp.full((B,), c, jnp.int32) + prefix_len
+    return mla_decode_paged_shard(
+        q, lat.reshape(B * n, page, W), tables, lens,
+        rank=cfg.kv_lora_rank, scale=cfg.softmax_scale, impl=impl,
+        interpret=interpret)
+
+
+def attend_prompt(q, lat, *, cfg: MlaMoeConfig, impl, interpret):
+    """Whole-prompt causal attention of ``generate._prompt_forward``:
+    q [B, S, H, W] over its own latent rows lat [B, S, 1, W]."""
+    return attend_prefix(q, lat.transpose(0, 2, 1, 3), jnp.int32(0),
+                         cfg=cfg, impl=impl, interpret=interpret)
+
+
+# ---------------------------------------------------------------------------
+# The generator the engine is built over
+# ---------------------------------------------------------------------------
+
+
+class MlaMoeGenerator:
+    """What ``ServeEngine`` needs of a model: its config, the latent
+    cache's planes, the seam hooks of its block, and the chunked-prefill
+    program.  The contiguous-cache decode loop of
+    :class:`~triton_dist_tpu.models.generate.Generator` is not provided:
+    this family decodes through the engine's paged pools."""
+
+    def __init__(self, cfg: MlaMoeConfig, mesh=None, *, axis: str = "sp",
+                 max_seq: int | None = None, impl: str = "auto",
+                 interpret: bool = False, kv_dtype=None):
+        if kv_dtype is not None:
+            raise LatentPoolUnsupported(
+                f"kv_dtype={kv_dtype}: latent pools are served in the "
+                f"model's dtype only (no int8 latent rows yet)")
+        if mesh is not None and math.prod(mesh.shape.values()) != 1:
+            raise LatentPoolUnsupported(
+                "latent pools are served on one chip: no sequence- or "
+                "head-sharded latent cache yet")
+        self.cfg, self.mesh, self.axis = cfg, mesh, axis
+        self.max_seq = max_seq or cfg.max_seq
+        self.attn = types.SimpleNamespace(
+            world=1, quantized=False,
+            ctx=types.SimpleNamespace(impl=impl, interpret=interpret))
+        self.tally = MoeTally()
+        kw = dict(cfg=cfg, impl=impl, interpret=interpret)
+        self._hooks = {
+            "project": functools.partial(project, cfg=cfg),
+            "out_proj": functools.partial(out_proj, cfg=cfg),
+            "ffn": functools.partial(ffn, tally=self.tally, **kw),
+        }
+        self._chunk_jit = jax.jit(
+            named(self.wrap_program(functools.partial(
+                _chunk_forward, **kw, **self._hooks,
+                attend=functools.partial(attend_prefix, **kw))),
+                "prefill_chunk"),
+            static_argnames=("quantized", "extent"), donate_argnums=(2,))
+        self._prompt_jit = jax.jit(self.wrap_program(functools.partial(
+            _prompt_forward, **kw, **self._hooks,
+            attend=functools.partial(attend_prompt, **kw))))
+
+    # -- the engine's view --------------------------------------------------
+
+    @property
+    def kv_planes(self) -> list:
+        """(heads, width) of each plane of a layer's cache: one latent
+        plane, where the dense family has a K and a V plane."""
+        return [(1, self.cfg.head_dim)]
+
+    #: trailing outputs every program of this family adds (the MoE tally)
+    aux_outputs = 1
+
+    def serve_hooks(self) -> dict:
+        """Keyword seams for the engine's paged forwards."""
+        ctx = self.attn.ctx
+        return dict(self._hooks, paged_attend=functools.partial(
+            paged_attend, cfg=self.cfg, impl=ctx.impl,
+            interpret=ctx.interpret))
+
+    def wrap_program(self, fwd):
+        return with_moe_stats(fwd, self.tally)
+
+    def kernel_gaps(self, *, page_size: int) -> dict:
+        """Attention paths that will NOT reach the latent Pallas kernel
+        (``engine.attention_kernel_gaps`` for this family): prefill chunks
+        and decode share one kernel, so they share one answer."""
+        ctx = self.attn.ctx
+        if resolve_impl(ctx.impl, ctx.interpret) == "xla":
+            why = ("impl='xla' was asked for" if ctx.impl == "xla" else
+                   "impl='auto' resolves to XLA off a TPU (no interpreter)")
+            return {"paged_decode": why, "prefill_chunk": why}
+        c = self.cfg
+        gap = None if ctx.interpret else mla_kernel_gap(
+            page_size, c.kv_lora_rank, c.head_dim - c.kv_lora_rank)
+        return {} if gap is None else {"paged_decode": gap,
+                                       "prefill_chunk": gap}
+
+    def forward_logits(self, params, tokens):
+        """Logits [B, S, V] of whole prompts in one pass (no cache kept):
+        the absorbed forward the tests hold against the reference."""
+        _, logits, _ = self._prompt_jit(params, tokens)
+        return logits

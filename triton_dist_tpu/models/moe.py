@@ -96,10 +96,13 @@ class MoEConfig:
                          expert_ffn_dim=14336, dtype=jnp.bfloat16)
 
     @staticmethod
-    def deepseek_moe() -> "MoEConfig":
-        """The reference's low-latency AllToAll benchmark config
-        (README.md:87 / test_all_to_all.py: 128 experts, topk 8,
-        hidden 7168 — the DeepSeek-V3 serving point)."""
+    def a2a_e128_k8_d7168() -> "MoEConfig":
+        """A SHAPE preset, not a model: the reference's low-latency
+        AllToAll benchmark point (README.md:87 / test_all_to_all.py: 128
+        experts, topk 8, hidden 7168) on this file's block — GQA attention,
+        softmax top-k router, no shared expert.  The DeepSeek-V3 block
+        itself (latent attention, sigmoid group-limited routing, a shared
+        expert) is ``models/mla_moe.py``, built from its ``config.json``."""
         return MoEConfig(vocab=129280, dim=7168, n_layers=61, n_heads=128,
                          n_kv_heads=128, n_experts=128, topk=8,
                          expert_ffn_dim=2048, dtype=jnp.bfloat16)

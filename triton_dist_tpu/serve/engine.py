@@ -217,6 +217,11 @@ def _scatter_kv(pool, k, v, pool_row, in_page):
     ``quantize_kv``'s per-(head, position) absmax over D, the identical
     recipe the contiguous quantized cache uses — so a row's int8 bytes
     and its scale land together and never drift apart."""
+    if v is None:
+        # a latent pool (models/mla_moe.py): ONE plane, one row a token
+        plane, = pool
+        return (plane.at[pool_row, :, in_page, :].set(
+            k.astype(plane.dtype)),)
     k_pool, v_pool = pool
     if isinstance(k_pool, dict):
         kq, ks = quantize_kv(k)
@@ -242,7 +247,8 @@ def _pool_views(pool):
 
 def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
                           cfg, page, impl, interpret, fwd_cfg=None,
-                          ffn=None, out_proj=None):
+                          ffn=None, out_proj=None, project=None,
+                          paged_attend=None):
     """One decode token for every batch row over the paged pools.
 
     ``generate._token_forward`` (the same math as ``_step_impl`` — the
@@ -255,7 +261,13 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
     local-head shard view) with row-parallel psum hooks, while the page
     addressing and the attention kernel's soft-cap/window stay on the
     global ``cfg`` — ONE copy of the block-table addressing serves the
-    world-1 engine and every head-sharded rank."""
+    world-1 engine and every head-sharded rank.
+
+    ``project`` / ``paged_attend`` are the model-family seams
+    (``Generator.serve_hooks``): the attention's front half
+    (``generate._token_forward``) and ``paged_attend(q, pool, tables,
+    lens)`` over one layer's pool in that family's layout — a latent
+    pool is one plane, read by its own kernel."""
     inc = active.astype(kv_lens.dtype)
     pool_row, in_page = _page_slots(tables, kv_lens, active, page=page)
 
@@ -263,6 +275,8 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
         return _scatter_kv(pool, k, v, pool_row, in_page)
 
     def attend(li, q, pool):
+        if paged_attend is not None:
+            return paged_attend(q, pool, tables, kv_lens + inc)
         kq, vq, ks, vs = _pool_views(pool)
         o, _ = gqa_decode_paged_shard(
             q, kq, vq, tables, kv_lens + inc, impl=impl,
@@ -272,7 +286,8 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
 
     return _token_forward(params, pools, token, kv_lens,
                           cfg=fwd_cfg or cfg, write_kv=write_kv,
-                          attend=attend, ffn=ffn, out_proj=out_proj)
+                          attend=attend, ffn=ffn, out_proj=out_proj,
+                          project=project)
 
 
 def _paged_decode_step(*args, **kwargs):
@@ -293,14 +308,16 @@ def _paged_decode_step(*args, **kwargs):
 
 def _paged_verify_forward(params, pools, tables, kv_lens, chunk, active, *,
                           cfg, page, impl, interpret, fwd_cfg=None,
-                          ffn=None, out_proj=None):
+                          ffn=None, out_proj=None, project=None,
+                          paged_attend=None):
     """Score ``chunk`` [B, T] draft tokens per row at PER-ROW lengths over
     the paged pools — ``generate._multitoken_forward`` (the same math as
     ``_verify_forward``) re-addressed through block tables (K/V rows
     scatter into each request's pages, the multi-token decode kernel
     reads them back through the table).  Returns (new_pools,
     logits [B, T, V]).  ``fwd_cfg``/``ffn``/``out_proj`` as in
-    :func:`_paged_decode_forward` — the TP seams."""
+    :func:`_paged_decode_forward` — the TP seams — and ``project`` /
+    ``paged_attend``, the model-family ones."""
     T = chunk.shape[1]
     n_pages = tables.shape[1]
     pos = kv_lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None]  # [B, T]
@@ -314,6 +331,8 @@ def _paged_verify_forward(params, pools, tables, kv_lens, chunk, active, *,
         return _scatter_kv(pool, k, v, pool_row, in_page)
 
     def attend(li, q, pool):
+        if paged_attend is not None:
+            return paged_attend(q, pool, tables, kv_lens + T)
         kq, vq, ks, vs = _pool_views(pool)
         o, _ = gqa_decode_paged_shard(
             q, kq, vq, tables, kv_lens + T, impl=impl,
@@ -324,7 +343,7 @@ def _paged_verify_forward(params, pools, tables, kv_lens, chunk, active, *,
     return _multitoken_forward(params, pools, chunk, pos,
                                cfg=fwd_cfg or cfg, write_kv=write_kv,
                                attend=attend, ffn=ffn,
-                               out_proj=out_proj)
+                               out_proj=out_proj, project=project)
 
 
 def _paged_decode_horizon(params, pools, tables, kv_lens, token, active,
@@ -360,7 +379,10 @@ def _paged_decode_horizon(params, pools, tables, kv_lens, token, active,
 
     Returns ``(pools, tokens [B, H], emitted [B, H] bool, kv_lens,
     last_token, eos_done, counts)`` — the trailing carries re-enter the
-    next chained dispatch without touching the host.
+    next chained dispatch without touching the host.  A ``decode_fwd``
+    that returns more than ``(pools, logits)`` (a model family's counters:
+    ``Generator.wrap_program``) has each extra output summed over the
+    steps and appended.
     """
     # ``base_keys`` are HOST-built per-row typed keys (the engine stacks
     # jax.random.key(p.seed) — the exact call `_choose_token` makes, so
@@ -380,8 +402,8 @@ def _paged_decode_horizon(params, pools, tables, kv_lens, token, active,
     def step(carry, t):
         pools, kv_lens, token, eos_done, counts = carry
         live = active & ~eos_done & (t < limits)
-        pools, logits = decode_fwd(params, pools, tables, kv_lens,
-                                   token, live)
+        pools, logits, *aux = decode_fwd(params, pools, tables, kv_lens,
+                                         token, live)
         kv_lens = kv_lens + live.astype(kv_lens.dtype)
         if all_greedy:
             nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -393,12 +415,31 @@ def _paged_decode_horizon(params, pools, tables, kv_lens, token, active,
         nxt = jnp.where(live, nxt, token)
         counts = counts + live.astype(counts.dtype)
         eos_done = eos_done | (live & has_eos & (nxt == eos_ids))
-        return (pools, kv_lens, nxt, eos_done, counts), (nxt, live)
+        return (pools, kv_lens, nxt, eos_done, counts), (nxt, live, *aux)
 
     carry = (pools, kv_lens, token, eos_done, counts)
-    (pools, kv_lens, token, eos_done, counts), (toks, mask) = jax.lax.scan(
+    ((pools, kv_lens, token, eos_done, counts),
+     (toks, mask, *aux)) = jax.lax.scan(
         step, carry, jnp.arange(H, dtype=jnp.int32))
-    return (pools, toks.T, mask.T, kv_lens, token, eos_done, counts)
+    return (pools, toks.T, mask.T, kv_lens, token, eos_done, counts,
+            *(a.sum(0) for a in aux))
+
+
+@functools.lru_cache(maxsize=8192)
+def _key_words(seed: int) -> np.ndarray:
+    """The raw words of ``jax.random.key(seed)``."""
+    return np.asarray(jax.random.key_data(jax.random.key(seed)))
+
+
+def _key_batch(seeds: list):
+    """Typed base keys ``[B]`` for per-row seeds (``None``: ``key(0)``) —
+    element for element what ``jnp.stack([jax.random.key(s), ...])``
+    gives, so a stream matches :meth:`ServeEngine._choose_token`'s at any
+    seed (>= 2**31 included) — in ONE transfer: stacking B key arrays is
+    B + 1 small dispatches on the step path before the horizon's first
+    link goes out (29 ms a step at 64 rows, host profile, PR 26)."""
+    words = np.stack([_key_words(0 if s is None else int(s)) for s in seeds])
+    return jax.random.wrap_key_data(jnp.asarray(words))
 
 
 def _sample_token(logits_row, base_key, count, temperature, top_k, top_p):
@@ -585,9 +626,17 @@ def _spec_round_fused(params, draft_params, pools, dcaches, tables,
             dlast_logits, counts, limits, done)
 
 
+def _plane_pages(c, n, page):
+    """Contiguous scratch plane [1, Hkv, n*page(, D)] -> pool pages
+    [n, Hkv, page(, D)] (a 3-D plane is an int8 pool's scale plane)."""
+    Hkv = c.shape[1]
+    pages = c[0].reshape(Hkv, n, page, *c.shape[3:])
+    return jnp.moveaxis(pages, 0, 1)
+
+
 def _gather_pool_pages(pools, block_ids, *, page):
     """Inverse of :func:`_fill_pool_pages`: assemble contiguous scratch
-    caches ([1, Hkv, n*page, D] per layer) from pool pages.
+    caches ([1, Hkv, n*page, D] per plane per layer) from pool pages.
 
     The warm-prefix prefill path (docs/serving.md "Prefix caching")
     reads the request's SHARED prefix blocks back into its prefill
@@ -597,81 +646,44 @@ def _gather_pool_pages(pools, block_ids, *, page):
     stream cannot differ from a cold prefill.  ``block_ids`` covers
     every scratch page (trace keyed by the s_ext bucket): entries past
     the cached prefix hold the null block, whose junk rows are all
-    overwritten by the residual chunks or causally masked."""
+    overwritten by the residual chunks or causally masked.
+
+    Every plane of a layer moves the same way, whatever the layout: the
+    K and V planes of the dense family, an int8 pool's quant and scale
+    planes (bytes + scales, never a dequant/requant round trip —
+    quantization isn't idempotent), the one latent plane of an MLA pool."""
     n = block_ids.shape[0]
-    out = []
-    for k_pool, v_pool in pools:
-        def as_scratch(p):
-            pages = p[block_ids]                    # [n, Hkv, page, D]
-            Hkv, D = pages.shape[1], pages.shape[3]
-            return (pages.transpose(1, 0, 2, 3)
-                    .reshape(1, Hkv, n * page, D))
 
-        def as_scratch_s(sp):
-            pages = sp[block_ids]                   # [n, Hkv, page]
-            Hkv = pages.shape[1]
-            return pages.transpose(1, 0, 2).reshape(1, Hkv, n * page)
+    def as_scratch(p):
+        pages = jnp.moveaxis(p[block_ids], 1, 0)     # [Hkv, n, page(, D)]
+        return pages.reshape(1, pages.shape[0], n * page, *pages.shape[3:])
 
-        if isinstance(k_pool, dict):
-            # int8 pages travel as bytes + their scale plane — never a
-            # dequant/requant round trip (quantization isn't idempotent)
-            out.append(({"q": as_scratch(k_pool["q"]),
-                         "s": as_scratch_s(k_pool["s"])},
-                        {"q": as_scratch(v_pool["q"]),
-                         "s": as_scratch_s(v_pool["s"])}))
-        else:
-            out.append((as_scratch(k_pool), as_scratch(v_pool)))
-    return out
+    return jax.tree.map(as_scratch, pools)
 
 
 def _copy_pool_block(pools, src, dst):
-    """Copy one pool page ``src`` → ``dst`` across every layer's K and V
-    — the device half of a copy-on-write split (``BlockManager.cow``
+    """Copy one pool page ``src`` → ``dst`` across every plane of every
+    layer — the device half of a copy-on-write split (``BlockManager.cow``
     swaps the table entry; this lands the bytes before any write)."""
-    def copy(p):
-        if isinstance(p, dict):
-            return {"q": p["q"].at[dst].set(p["q"][src]),
-                    "s": p["s"].at[dst].set(p["s"][src])}
-        return p.at[dst].set(p[src])
-
-    return [(copy(k_pool), copy(v_pool)) for k_pool, v_pool in pools]
+    return jax.tree.map(lambda p: p.at[dst].set(p[src]), pools)
 
 
 def _fill_pool_pages(pools, scratch, block_ids, *, page):
-    """Scatter a completed prefill's K/V (contiguous scratch caches
-    [1, Hkv, n*page, D] per layer) into the request's pool pages.
+    """Scatter a completed prefill's cache rows (contiguous scratch
+    planes [1, Hkv, n*page, D] per layer) into the request's pool pages.
 
     ``block_ids`` covers EVERY scratch page (n = s_ext // page): entries
     past the prompt's allocation hold the null block, so a bucketed
     scratch scatters its zero-masked padding pages into block 0 (written
     by every inactive row anyway) instead of forcing one trace per
-    prompt-page count — the trace is keyed by the s_ext bucket alone."""
+    prompt-page count — the trace is keyed by the s_ext bucket alone.
+    A quantized scratch's int8 bytes + scales scatter AS-IS: the pool
+    rows are bit-identical to the scratch rows, so a warm-prefix
+    gather-back reproduces the cold prefill exactly."""
     n = block_ids.shape[0]
-    new_pools = []
-    for (k_pool, v_pool), (kc, vc) in zip(pools, scratch):
-        def as_pages(c):
-            Hkv, S_ext, D = c.shape[1:]
-            return c[0].reshape(Hkv, n, page, D).transpose(1, 0, 2, 3)
-
-        def as_pages_s(s):
-            Hkv = s.shape[1]
-            return s[0].reshape(Hkv, n, page).transpose(1, 0, 2)
-
-        if isinstance(k_pool, dict):
-            # the quantized scratch's int8 bytes + scales scatter AS-IS:
-            # the pool rows are bit-identical to the scratch rows, so a
-            # warm-prefix gather-back reproduces the cold prefill exactly
-            k_pool = {"q": k_pool["q"].at[block_ids].set(as_pages(kc["q"])),
-                      "s": k_pool["s"].at[block_ids].set(as_pages_s(kc["s"]))}
-            v_pool = {"q": v_pool["q"].at[block_ids].set(as_pages(vc["q"])),
-                      "s": v_pool["s"].at[block_ids].set(as_pages_s(vc["s"]))}
-        else:
-            k_pool = k_pool.at[block_ids].set(
-                as_pages(kc).astype(k_pool.dtype))
-            v_pool = v_pool.at[block_ids].set(
-                as_pages(vc).astype(v_pool.dtype))
-        new_pools.append((k_pool, v_pool))
-    return new_pools
+    return jax.tree.map(
+        lambda p, c: p.at[block_ids].set(
+            _plane_pages(c, n, page).astype(p.dtype)), pools, scratch)
 
 
 def _splice_draft_rows(bcaches, blens, blogits, tcaches, slot, s0, last):
@@ -690,6 +702,16 @@ def _splice_draft_rows(bcaches, blens, blogits, tcaches, slot, s0, last):
         vb = vb.at[slot, :, :w, :].set(vt[0, :, :w, :].astype(vb.dtype))
         out.append((kb, vb))
     return out, blens.at[slot].set(s0), blogits.at[slot].set(last)
+
+
+def _refuse_latent(latent: bool, what: str) -> None:
+    """What has not been carried over to latent (MLA) pools refuses by
+    name, where the engine is built or the entry point is called."""
+    if latent:
+        from triton_dist_tpu.models.mla_moe import LatentPoolUnsupported
+
+        raise LatentPoolUnsupported(
+            f"{what}: not served on latent (MLA) pools yet")
 
 
 def build_bucket_ladder(base: int, cap: int, page: int) -> list[int]:
@@ -838,6 +860,23 @@ class ServeEngine:
         # NOT the fp stream, so speculative decode (whose accept chain
         # assumes the target's own fp logits) is a recorded follow-up.
         self.kv_quant = bool(gen.attn.quantized)
+        # A model family with a cache layout of its own (latent attention:
+        # ONE plane a layer, models/mla_moe.py) says so on its generator:
+        # the planes of a layer's cache, the seams of its block, and the
+        # counters its programs hand out.  What has not been carried over
+        # to such pools refuses HERE, by name — never a quiet fallback.
+        self.kv_planes = list(getattr(gen, "kv_planes", None) or
+                              [(gen.cfg.n_kv_heads, gen.cfg.head_dim)] * 2)
+        self.latent = len(self.kv_planes) == 1
+        for what, asked in (
+                ("a mesh (sharded latent pools)", mesh is not None),
+                ("int8 pools", self.kv_quant),
+                ("w8a8 weights", w8a8),
+                ("speculative rounds (spec_k / draft)",
+                 bool(spec_k) or draft is not None),
+                ("snapshot_dir (journal, snapshot / restore)",
+                 snapshot_dir is not None)):
+            _refuse_latent(self.latent and asked, what)
         if self.kv_quant and spec_k:
             raise ValueError(
                 "int8 KV pools cannot drive speculative decoding yet "
@@ -1158,19 +1197,21 @@ class ServeEngine:
         # kernels, visible in metrics.summary()["kernel_gaps"] and, on a
         # TPU — where a silent XLA reroute is a lost kernel, not a test
         # convenience — said once at construction.
-        self.kernel_gaps = attention_kernel_gaps(
-            head_dim=cfg.head_dim, page_size=page_size,
-            prefill_chunk=prefill_chunk, ladder=self.ladder,
-            kv_itemsize=jnp.dtype(cfg.dtype).itemsize,
-            kv_quant=self.kv_quant, impl=impl, interpret=interpret,
-            sp_world=self.sp_world)
+        self.kernel_gaps = (
+            gen.kernel_gaps(page_size=page_size) if self.latent
+            else attention_kernel_gaps(
+                head_dim=cfg.head_dim, page_size=page_size,
+                prefill_chunk=prefill_chunk, ladder=self.ladder,
+                kv_itemsize=jnp.dtype(cfg.dtype).itemsize,
+                kv_quant=self.kv_quant, impl=impl, interpret=interpret,
+                sp_world=self.sp_world))
         self.metrics.kernel_gaps = self.kernel_gaps
         # How the paged decode call is blocked (static, decided where the
         # programs are built — kernels/flash_decode.py): the KV heads a
         # step carries of the heads THIS rank holds, the grid steps of a
         # call.  Empty when the call runs as XLA.
         self.paged_attn_blocking = (
-            {} if "paged_decode" in self.kernel_gaps
+            {} if "paged_decode" in self.kernel_gaps or self.latent
             else paged_kernel_blocking(
                 cfg.n_kv_heads // (self.mesh_world // self.sp_world),
                 page_size, cfg.head_dim, jnp.dtype(cfg.dtype).itemsize,
@@ -1186,7 +1227,12 @@ class ServeEngine:
         # w8a8 swaps the weight tree ONCE, host-side, before any program
         # captures it; the hooks ride the same ffn=/out_proj= seams the
         # mesh TP bodies use, so every program below stays one copy.
-        w8a8_hooks = {}
+        hooks = dict(getattr(gen, "serve_hooks", dict)())
+        # a family's programs may end in counters of their own (the MoE
+        # tally): ``_note_aux`` takes them off every program's outputs
+        wrap = getattr(gen, "wrap_program", lambda fwd: fwd)
+        self._aux_pending: list = []
+        self._aux_folded = 0    # counter outputs folded so far
         if self.w8a8:
             from triton_dist_tpu.models import llama_w8a8
 
@@ -1194,7 +1240,7 @@ class ServeEngine:
                 params, cfg,
                 world=self.mesh_world if mesh is not None else 1)
             self.params = params
-            w8a8_hooks = {
+            hooks = {
                 "ffn": functools.partial(
                     llama_w8a8.w8a8_serve_ffn, impl=impl,
                     interpret=interpret),
@@ -1255,24 +1301,24 @@ class ServeEngine:
                                          "prefill_chunk")
         else:
             self._decode_fn = CountingJit(jax.jit(named(
-                _paged_decode_step, "paged_decode", cfg=cfg,
+                wrap(_paged_decode_step), "paged_decode", cfg=cfg,
                 page=page_size, impl=impl, interpret=interpret,
-                **w8a8_hooks), donate_argnums=(1,)), "paged_decode")
+                **hooks), donate_argnums=(1,)), "paged_decode")
             self._verify_fn = CountingJit(jax.jit(named(
-                _paged_verify_forward, "paged_verify", cfg=cfg,
+                wrap(_paged_verify_forward), "paged_verify", cfg=cfg,
                 page=page_size, impl=impl, interpret=interpret,
-                **w8a8_hooks), donate_argnums=(1,)), "paged_verify")
+                **hooks), donate_argnums=(1,)), "paged_verify")
             if self.horizon > 1:
                 # One program per (horizon rung, greedy-or-mixed): the
                 # scan length is static, so the ladder bounds the trace
                 # count and warmup() sweeps every rung (the
                 # prompt-extent ladder's twin for the decode side).
                 horizon_kw = {}
-                if self.w8a8:
+                if hooks:
                     # the scan's per-step forward must carry the hooks
-                    horizon_kw["decode_fwd"] = functools.partial(
+                    horizon_kw["decode_fwd"] = wrap(functools.partial(
                         _paged_decode_forward, cfg=cfg, page=page_size,
-                        impl=impl, interpret=interpret, **w8a8_hooks)
+                        impl=impl, interpret=interpret, **hooks))
                 self._horizon_fn = CountingJit(jax.jit(
                     named(
                         _paged_decode_horizon, "decode_horizon", cfg=cfg,
@@ -1309,7 +1355,7 @@ class ServeEngine:
                     named(
                         _chunk_forward, "prefill_chunk", cfg=cfg,
                         impl=impl, interpret=interpret, mesh=gen.mesh,
-                        axis=gen.axis, **w8a8_hooks),
+                        axis=gen.axis, **hooks),
                     static_argnames=("quantized", "extent"),
                     donate_argnums=(2,)), "prefill_chunk")
             else:
@@ -1333,7 +1379,8 @@ class ServeEngine:
             def zpool():
                 return jnp.zeros(page_shape, cfg.dtype,
                                  device=self._pool_sharding)
-        self._pools = [(zpool(), zpool()) for _ in range(cfg.n_layers)]
+        self._pools = [tuple(zpool() for _ in self.kv_planes)
+                       for _ in range(cfg.n_layers)]
         self._sample_fn = CountingJit(
             jax.jit(named(_sample_token, "sample_token")), "sample_token")
         for c in (self._chunk_fn, self._fill_fn, self._decode_fn,
@@ -1354,7 +1401,13 @@ class ServeEngine:
             pool_bytes=sum(int(x.size) * x.dtype.itemsize
                            for x in jax.tree_util.tree_leaves(self._pools)),
             token_slots=num_blocks * page_size,
-            quantized=self.kv_quant)
+            quantized=self.kv_quant,
+            row=({"latent_row_width": cfg.latent_width,
+                  "stored_row_width": cfg.head_dim,
+                  "latent_bytes_per_token":
+                      cfg.latent_width * cfg.n_layers
+                      * jnp.dtype(cfg.dtype).itemsize}
+                 if self.latent else None))
         # cache-tier reclaims happen inside the allocator; the hook puts
         # them on the flight-recorder timeline (an eviction storm under
         # allocation pressure is a classic tail-latency culprit)
@@ -1714,6 +1767,7 @@ class ServeEngine:
         argument; docs/serving.md "Crash recovery" for the recipe."""
         from triton_dist_tpu.serve import recovery
 
+        _refuse_latent(self.latent, "snapshot()")
         d = directory or self.snapshot_dir
         if d is None:
             raise ValueError("snapshot() needs a directory: pass one or "
@@ -1831,6 +1885,7 @@ class ServeEngine:
         geometry overrides)."""
         from triton_dist_tpu.serve import recovery
 
+        _refuse_latent(len(getattr(gen, "kv_planes", ())) == 1, "restore()")
         return recovery.restore_engine(directory, gen, params, **kwargs)
 
     # -- live migration ---------------------------------------------------
@@ -1868,6 +1923,9 @@ class ServeEngine:
         tier hand-offs and failure migrations stay separately
         observable."""
         from triton_dist_tpu.serve.recovery import MANIFEST_FORMAT
+
+        _refuse_latent(self.latent, "push_out() of latent pages" if push
+                            else "drain() / migrate-out")
 
         if rids is None:
             rids = self.unfinished_rids()
@@ -2036,6 +2094,8 @@ class ServeEngine:
             _shift,
         )
 
+        _refuse_latent(self.latent, "admit_pushed() of latent pages" if push
+                            else "migrate_in()")
         if manifest.get("format") != MANIFEST_FORMAT:
             raise ValueError(
                 f"migration manifest format {manifest.get('format')}; "
@@ -2676,7 +2736,7 @@ class ServeEngine:
             jnp.zeros((B, self.n_pages_max), jnp.int32), z32, zb, zb,
             self._last_logits, sd.last_logits, z32, z32,
             jnp.ones((B,), jnp.int32),
-            jnp.stack([jax.random.key(0)] * B),
+            _key_batch([None] * B),
             jnp.ones((B,), jnp.float32), z32,
             jnp.ones((B,), jnp.float32), jnp.ones((B,), bool),
             jnp.full((B,), -1, jnp.int32), K=int(rung),
@@ -2749,19 +2809,15 @@ class ServeEngine:
             # quantizes each chunk's rows as it writes them (the
             # generate._write_chunk convention), so fill_pages moves
             # finished bytes + scales into the pool verbatim.
-            def _zs():
-                return {"q": jnp.zeros((1, cfg.n_kv_heads, s_ext,
-                                        cfg.head_dim), jnp.int8),
-                        "s": jnp.zeros((1, cfg.n_kv_heads, s_ext),
-                                       jnp.float32)}
-            rs.scratch = [(_zs(), _zs()) for _ in range(cfg.n_layers)]
+            def _zs(h, d):
+                return {"q": jnp.zeros((1, h, s_ext, d), jnp.int8),
+                        "s": jnp.zeros((1, h, s_ext), jnp.float32)}
         else:
-            rs.scratch = [
-                (jnp.zeros((1, cfg.n_kv_heads, s_ext, cfg.head_dim),
-                           cfg.dtype),
-                 jnp.zeros((1, cfg.n_kv_heads, s_ext, cfg.head_dim),
-                           cfg.dtype))
-                for _ in range(cfg.n_layers)]
+            def _zs(h, d):
+                return jnp.zeros((1, h, s_ext, d), cfg.dtype)
+        # one scratch plane per pool plane (K and V; or the latent row)
+        rs.scratch = [tuple(_zs(h, d) for h, d in self.kv_planes)
+                      for _ in range(cfg.n_layers)]
 
     def _run_prefill(self, rs: ReqState, n_tokens: int,
                      now: float) -> Optional[RequestOutput]:
@@ -2782,10 +2838,11 @@ class ServeEngine:
                 buf[0, :c] = prompt[rs.prefill_pos:rs.prefill_pos + c]
                 buf_d = jnp.asarray(buf)
                 pos_d, valid_d = jnp.int32(rs.prefill_pos), jnp.int32(c)
-            rs.scratch, logits = self._device_call(
+            rs.scratch, logits, *aux = self._device_call(
                 "prefill_chunk", (rs.req.request_id,), self._chunk_fn,
                 self.params, buf_d, rs.scratch, pos_d,
                 quantized=self.kv_quant, extent=rs.s_ext, n_valid=valid_d)
+            self._note_aux(aux)
             rs.prefill_pos += c
             n_last = c
             self.metrics.prefill_tokens += c
@@ -2831,6 +2888,7 @@ class ServeEngine:
                 # the host blocks here until the chunk has run
                 row = np.asarray(last[0], np.float32)
         with span("prefill.commit"):
+            self._fold_aux(self._note_aux(()))
             if spec:
                 self._last_logits = \
                     self._last_logits.at[rs.slot].set(last[0])
@@ -3393,6 +3451,27 @@ class ServeEngine:
                         " blocks) is too small for this request")
                 self._preempt(victim)
 
+    def _note_aux(self, aux: list) -> int:
+        """Keep a program's trailing counter outputs (device arrays, not
+        waited on) until a commit point folds them.  Returns how many
+        have been noted in all: the device runs ONE stream in order, so
+        once a later result of the same program has reached the host,
+        everything noted up to here is ready."""
+        self._aux_pending.extend(aux)
+        return self._aux_folded + len(self._aux_pending)
+
+    def _fold_aux(self, upto: int) -> None:
+        """Fold the counter arrays noted up to the ``upto``-th (all ready:
+        see :meth:`_note_aux`) into the metrics — the MoE tally of a
+        family with expert layers (``ServeMetrics.observe_moe``)."""
+        n = upto - self._aux_folded
+        if n > 0:
+            done = self._aux_pending[:n]
+            del self._aux_pending[:n]
+            self._aux_folded = upto
+            self.metrics.observe_moe(
+                np.sum(jax.device_get(done), axis=0))
+
     def _preempt(self, victim: ReqState) -> None:
         self.trace.emit("preempt", victim.req.request_id,
                         kv_len=victim.kv_len,
@@ -3536,12 +3615,13 @@ class ServeEngine:
                                                  self.n_pages_max)
             operands = (jnp.asarray(tables), jnp.asarray(lens),
                         jnp.asarray(tokens), jnp.asarray(active))
-        pools, logits = self._device_call(
+        pools, logits, *aux = self._device_call(
             "paged_decode", tuple(r.req.request_id for r in rows),
             self._decode_fn, self.params, self._pools, *operands)
         with span("decode.wait"):
             logits_np = np.asarray(logits)  # sync BEFORE committing pools
         self._pools = pools
+        self._fold_aux(self._note_aux(aux))
         self.metrics.decode_steps += 1
         self.metrics.host_syncs += 1
         toks0 = self.metrics.decode_tokens
@@ -3664,34 +3744,35 @@ class ServeEngine:
             # Host-built per-row base keys — the SAME jax.random.key(p.seed)
             # call `_choose_token` makes, so seeds the int32 array route
             # would overflow (>= 2**31) stream identically at every H.
-            key_rows = [jax.random.key(0)] * B
+            seeds = [None] * B
             if not all_greedy:
                 for rs in rows:
                     if not rs.req.params.greedy:
-                        key_rows[rs.slot] = jax.random.key(rs.req.params.seed)
-            samp = (jnp.stack(key_rows), jnp.asarray(temps),
+                        seeds[rs.slot] = rs.req.params.seed
+            samp = (_key_batch(seeds), jnp.asarray(temps),
                     jnp.asarray(top_ks), jnp.asarray(top_ps),
                     jnp.asarray(greedy), jnp.asarray(eos_ids))
         outs = []
         t_prev = self._clock()
         for j, (h_link, lim) in enumerate(budgets):
             (pools, toks, mask, kv_d, tok_d, done_d,
-             cnt_d) = self._device_call(
+             cnt_d, *aux) = self._device_call(
                 "decode_horizon", rids, self._horizon_fn, self.params,
                 self._pools, tables_d, kv_d, tok_d, active_d, done_d,
                 lim, cnt_d, *samp, H=int(h_link),
                 all_greedy=all_greedy, fire_injector=(j == 0))
             self._pools = pools
-            outs.append((toks, mask))
+            outs.append((toks, mask, self._note_aux(aux)))
 
         # Drain in order: committing link j's burst overlaps the device
         # executing links > j (nothing here forces their results).
         committed = False
         try:
-            for toks, mask in outs:
+            for toks, mask, n_aux in outs:
                 with span("decode.wait"):
                     toks_np, mask_np = jax.device_get((toks, mask))
                 self.metrics.host_syncs += 1
+                self._fold_aux(n_aux)
                 now = self._clock()
                 steps = int(mask_np.any(axis=0).sum())
                 self.metrics.decode_steps += steps
@@ -3819,7 +3900,7 @@ class ServeEngine:
             top_ps = np.ones((B,), np.float32)
             greedy = np.ones((B,), bool)
             eos_ids = np.full((B,), -1, np.int32)
-            key_rows = [jax.random.key(0)] * B
+            seeds = [None] * B
             for rs in live:
                 b = rs.slot
                 p = rs.req.params
@@ -3846,7 +3927,7 @@ class ServeEngine:
                     # Host-built typed keys, like the horizon: any seed the
                     # host path accepts (>= 2**31 included) streams
                     # identically on device.
-                    key_rows[b] = jax.random.key(p.seed)
+                    seeds[b] = p.seed
             all_greedy = bool(greedy[active].all())
             k_rung = bucket_down(self._k_ladder, int(k_rows[active].max()))
             chain_k = {rs.slot: min(int(k_rows[rs.slot]), k_rung)
@@ -3863,7 +3944,7 @@ class ServeEngine:
             cnt_d = jnp.asarray(counts)
             lim_d = jnp.asarray(limits)
             k_rows_d = jnp.asarray(k_rows)
-            samp = (jnp.stack(key_rows), jnp.asarray(temps),
+            samp = (_key_batch(seeds), jnp.asarray(temps),
                     jnp.asarray(top_ks), jnp.asarray(top_ps),
                     jnp.asarray(greedy), jnp.asarray(eos_ids))
         # The PRE-CHAIN round-opening logits: every live row's next

@@ -73,6 +73,8 @@ MERGE_COUNTERS = (
     "migrated_tokens", "pushed_out", "pushed_in",
     "prefix_hits", "prefix_hit_tokens",
     "prefix_skipped_tokens", "running_sum", "kv_util_sum",
+    "moe_assignments", "moe_local_assignments", "moe_pad_rows",
+    "moe_experts_hit",
     "net_requests", "net_dup_hits", "net_redelivered_tokens",
     "brownout_transitions",
     "journal_corrupt", "manifest_corrupt",
@@ -262,6 +264,16 @@ class ServeMetrics:
     # dispatch + one sync; the fused horizon amortizes both — the
     # dispatches_per_token quotient is THE metric the horizon exists to
     # shrink.
+    # expert layers of a share of an expert-parallel deployment
+    # (models/mla_moe.py): every (row, expert) choice the routers made
+    # for the rows the programs computed, those whose expert is held
+    # here, the pad rows of the grouped GEMM's live tiles, and the
+    # (layer, step) x held-expert pairs that got any row.  Folded in at
+    # commit from a small output of each program (observe_moe).
+    moe_assignments: int = 0
+    moe_local_assignments: int = 0
+    moe_pad_rows: int = 0
+    moe_experts_hit: int = 0
     decode_tokens: int = 0        # tokens committed by the decode loop
     dispatches: int = 0           # decode-path device dispatches
     host_syncs: int = 0           # decode-path host sync points
@@ -406,6 +418,7 @@ class ServeMetrics:
     kv_pool_bytes: int = 0        # device bytes pinned by the KV pools
     kv_token_slots: int = 0       # num_blocks * page_size token capacity
     kv_quant: bool = False        # pools hold int8 pages + f32 scales
+    kv_row: dict = field(default_factory=dict)  # latent pools: row widths
     # SLO latency histograms (serve/trace.LogHistogram): log-bucketed,
     # bounded, p50/p95/p99 in summary()["latency"] and the Prometheus
     # exposition.  TTFT/ITL/queue on the ENGINE clock; step/snapshot on
@@ -458,7 +471,7 @@ class ServeMetrics:
     # -- KV pool capacity --------------------------------------------------
 
     def set_kv_capacity(self, *, pool_bytes: int, token_slots: int,
-                        quantized: bool) -> None:
+                        quantized: bool, row: dict | None = None) -> None:
         """Stamp the engine's KV pool geometry (the engine calls this at
         construction, right after allocating pools): resident device
         bytes across every pool leaf (int8 pages AND their f32 scales
@@ -470,6 +483,26 @@ class ServeMetrics:
         self.kv_pool_bytes = int(pool_bytes)
         self.kv_token_slots = int(token_slots)
         self.kv_quant = bool(quantized)
+        self.kv_row = dict(row or {})
+
+    MOE_COUNTERS = ("moe_assignments", "moe_local_assignments",
+                    "moe_pad_rows", "moe_experts_hit")
+
+    def observe_moe(self, stats) -> None:
+        """Add one or more programs' expert-layer counts (int[4], the
+        order of ``MOE_COUNTERS``)."""
+        for name, v in zip(self.MOE_COUNTERS, stats):
+            setattr(self, name, getattr(self, name) + int(v))
+
+    def moe_stats(self) -> dict:
+        """summary()["moe"]: the four counters and the share of routed
+        assignments that landed on the experts held here (1 / chips that
+        share a layer, under even routing)."""
+        out = {k[4:]: getattr(self, k) for k in self.MOE_COUNTERS}
+        out["local_share"] = (self.moe_local_assignments
+                              / self.moe_assignments
+                              if self.moe_assignments else 0.0)
+        return out
 
     def kv_stats(self) -> dict:
         """KV pool capacity (summary()["kv"]): pool bytes, token slots,
@@ -481,6 +514,9 @@ class ServeMetrics:
             "bytes_per_token": (self.kv_pool_bytes / self.kv_token_slots
                                 if self.kv_token_slots else 0.0),
             "quantized": self.kv_quant,
+            # a latent (MLA) pool: the numbers a token's row holds a
+            # layer, and the width it is stored at (whole lane tiles)
+            **self.kv_row,
         }
 
     # -- per-program wall-time attribution --------------------------------
@@ -916,6 +952,7 @@ class ServeMetrics:
             "phases": self.phase_stats(),
             "decode": self.decode_stats(),
             "kv": self.kv_stats(),
+            "moe": self.moe_stats(),
             "spec": self.spec_stats(),
             "slo": self.slo_stats(),
             "failures": self.failure_stats(),
@@ -964,6 +1001,8 @@ class ServeMetrics:
         counter("serve_completed_total", self.completed,
                 "requests retired (any reason)")
         counter("serve_preemptions_total", self.preemptions)
+        for name in self.MOE_COUNTERS:
+            counter(f"serve_{name}_total", getattr(self, name))
         counter("serve_shed_total", self.shed)
         counter("serve_deadline_expired_total", self.deadline_expired)
         counter("serve_quarantined_total", self.quarantined)
