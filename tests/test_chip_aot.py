@@ -12,6 +12,10 @@ widths (depth cut to 2 layers — a compile check, not a run):
   compile for ``heads``, ``seq`` and ``heads+seq`` (2x2), with one Mosaic
   call per layer under ``heads`` and two (attention kernel + SP combine)
   under the seq layouts;
+- at the cells' pool geometry no decode-path program (bf16, int8, a
+  ``heads`` rank of 4, the latent plane) copies a whole pool plane or
+  holds a temporary that grows with the pool: the paged K/V write lands
+  in place;
 - at the CPU-demo geometry (page 16, chunk 64) the same programs hold NO
   Mosaic call, and the engine's construction-time kernel-reach report
   (``attention_kernel_gaps``) says so in words.
@@ -94,14 +98,18 @@ def _compile(jitted, *args, **kw) -> int:
     return lowered.as_text().count(MOSAIC_CALL)
 
 
-def _decode_args(cfg, page, num_blocks=257, batch=B, max_seq=MAX_SEQ):
+def _decode_args(cfg, page, num_blocks=257, batch=B, max_seq=MAX_SEQ,
+                 params=None, pools=None):
     """Abstract argument tuples of ``paged_decode`` and
-    ``decode_horizon`` (no sharding yet — callers place them)."""
+    ``decode_horizon`` (no sharding yet — callers place them); the dense
+    family's bf16 pools and parameters unless others are passed."""
     s = jax.ShapeDtypeStruct
-    pool = s((num_blocks, cfg.n_kv_heads, page, cfg.head_dim), cfg.dtype)
+    if pools is None:
+        pool = s((num_blocks, cfg.n_kv_heads, page, cfg.head_dim), cfg.dtype)
+        pools = [(pool, pool)] * cfg.n_layers
     keys = jax.eval_shape(lambda: jnp.stack([jax.random.key(0)] * batch))
     vec = lambda dt: s((batch,), dt)  # noqa: E731
-    decode = (_abstract_params(cfg), [(pool, pool)] * cfg.n_layers,
+    decode = (params or _abstract_params(cfg), pools,
               s((batch, max_seq // page), I32), vec(I32), vec(I32),
               vec(bool))
     horizon = decode + (vec(bool), vec(I32), vec(I32), keys,
@@ -297,6 +305,115 @@ def test_world4_programs_compile(v5e, as_tpu, kv_shard, shape, per_layer):
 
 
 # ---------------------------------------------------------------------------
+# The paged K/V write lands in place (ISSUE 27)
+# ---------------------------------------------------------------------------
+
+
+def _write_program(kind, program, v5e, num_blocks):
+    """One decode-path program of one pool family as the engine builds
+    it, compiled for the described chip(s) at the benchmark cells'
+    geometry with a pool of ``num_blocks`` -> ``(compiled, the planes of
+    a layer's K as one device holds them)``."""
+    from triton_dist_tpu.runtime.jit_cache import named
+
+    s = jax.ShapeDtypeStruct
+    params, hooks, wrap, ranks = None, {}, lambda f: f, 1
+    if kind == "latent":
+        from triton_dist_tpu.models import mla_moe as M
+
+        config, cfg = _mla_moe_cell()
+        eng = config["engine"]
+        cfg = dataclasses.replace(cfg, n_layers=LAYERS)
+        batch, page, max_seq = (eng["max_batch"], eng["page_size"],
+                                eng["max_seq"])
+        params = jax.eval_shape(functools.partial(M.init_params, cfg),
+                                jax.random.key(0))
+        gen = M.MlaMoeGenerator(cfg, max_seq=max_seq)
+        hooks, wrap = gen.serve_hooks(), gen.wrap_program
+        layer = (s((num_blocks, 1, page, cfg.head_dim), cfg.dtype),)
+    else:
+        # Mistral-7B's widths: llama3-8B's with its own vocabulary
+        cfg = dataclasses.replace(_cfg(), max_seq=8192, vocab=32000)
+        batch, page, max_seq = 32, 128, 8192
+        plane = (num_blocks, cfg.n_kv_heads, page, cfg.head_dim)
+        k = s(plane, cfg.dtype)
+        if kind == "int8":
+            k = {"q": s(plane, jnp.int8), "s": s(plane[:3], jnp.float32)}
+        layer = (k, k)
+    d_args, h_args = _decode_args(cfg, page, batch=batch, max_seq=max_seq,
+                                  params=params, pools=[layer] * LAYERS)
+    args = {"paged_decode": d_args, "decode_horizon": h_args,
+            "paged_verify": d_args[:4] + (s((batch, 5), I32), d_args[5])}
+    name, _, sampler = program.partition("-")
+    statics = {"H": 8, "all_greedy": sampler == "greedy"} if sampler else {}
+    if kind == "heads4":
+        ranks = 4
+        prog = serve_mesh.build_programs(
+            mesh=Mesh(np.array(v5e.devices), ("tp",)), tp_axis="tp",
+            kv_shard="heads", cfg=cfg, params=d_args[0], page_size=page,
+            num_blocks=num_blocks, n_pages_max=max_seq // page, impl="auto",
+            interpret=False, horizon=8)[name]
+        lowered = prog._prog(tuple(sorted(statics.items()))).lower(
+            *(_on(a, p) for a, p in zip(args[name], prog._placements)))
+    else:
+        kw = dict(cfg=cfg, page=page, impl="auto", interpret=False)
+        if name == "decode_horizon":
+            fwd = {"decode_fwd": wrap(functools.partial(
+                E._paged_decode_forward, **kw, **hooks))} if hooks else {}
+            jitted = jax.jit(named(E._paged_decode_horizon, name, **kw, **fwd),
+                             static_argnames=("H", "all_greedy"),
+                             donate_argnums=(1,))
+        else:
+            body = {"paged_decode": E._paged_decode_step,
+                    "paged_verify": E._paged_verify_forward}[name]
+            jitted = jax.jit(named(wrap(body), name, **kw, **hooks),
+                             donate_argnums=(1,))
+        lowered = jitted.lower(
+            *_on(args[name], SingleDeviceSharding(v5e.devices[0])),
+            **statics)
+    return lowered.compile(), [
+        s((p.shape[0], p.shape[1] // ranks) + p.shape[2:], p.dtype)
+        for p in jax.tree.leaves(layer[0])]
+
+
+@pytest.mark.parametrize("program", [
+    "paged_decode", "decode_horizon-greedy", "decode_horizon-sampled",
+    "paged_verify"])
+@pytest.mark.parametrize("kind,num_blocks", [
+    ("bf16", 449), ("int8", 449), ("heads4", 449), ("latent", 1216)])
+def test_the_paged_write_lands_in_place(v5e, as_tpu, kind, num_blocks,
+                                        program):
+    """No decode-path program re-lays a pool plane out around its K/V
+    write: compiled for the v5e at the cells' pool geometry, the program
+    holds no ``copy`` / ``transpose`` whose result is a whole plane (in
+    its own shape or its merged block-and-head view), and no temporary
+    that grows with the pool — its temp bytes at the cell's pool and at
+    a pool of 65 blocks differ by under a quarter of a plane.  (Written
+    as ``plane.at[row, :, in_page, :]`` the scatter's two indexed
+    dimensions have the heads between them, and the chip copies every
+    plane out to a layout where they are adjacent and back: two
+    whole-plane copies a plane a step and one plane of temp a write,
+    every plane a second time under the horizon's scan.)"""
+    import re
+
+    compiled, planes = _write_program(kind, program, v5e, num_blocks)
+    shapes = {",".join(map(str, dims))
+              for nb, hk, *rest in (p.shape for p in planes)
+              for dims in ((nb, hk, *rest), (nb * hk, *rest))}
+    moved = re.findall(
+        r"^\s*(?:ROOT )?%?([\w.\-]+) = \w+\[(?:" + "|".join(shapes)
+        + r")\]\S* (?:copy|transpose)\(", compiled.as_text(), re.M)
+    assert moved == [], (kind, program, moved)
+    small, _ = _write_program(kind, program, v5e, 65)
+    temp, temp_small = (c.memory_analysis().temp_size_in_bytes
+                        for c in (compiled, small))
+    plane_bytes = max(int(np.prod(p.shape)) * p.dtype.itemsize
+                      for p in planes)
+    assert temp - temp_small < plane_bytes // 4, (
+        kind, program, temp, temp_small, plane_bytes)
+
+
+# ---------------------------------------------------------------------------
 # The latent-attention + expert-share cell (ISSUE 26), at published widths
 # ---------------------------------------------------------------------------
 
@@ -342,14 +459,9 @@ def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu):
     hooks = gen.serve_hooks()
     assert gen.kernel_gaps(page_size=page) == {}
     pool = s((eng["num_blocks"], 1, page, cfg.head_dim), cfg.dtype)
-    pools = [(pool,)] * cfg.n_layers
-    keys = jax.eval_shape(lambda: jnp.stack([jax.random.key(0)] * batch))
-    vec = lambda dt: s((batch,), dt)  # noqa: E731
-    d_args = (params, pools, s((batch, max_seq // page), I32), vec(I32),
-              vec(I32), vec(bool))
-    h_args = d_args + (vec(bool), vec(I32), vec(I32), keys,
-                       vec(jnp.float32), vec(I32), vec(jnp.float32),
-                       vec(bool), vec(I32))
+    d_args, h_args = _decode_args(cfg, page, batch=batch, max_seq=max_seq,
+                                  params=params,
+                                  pools=[(pool,)] * cfg.n_layers)
     kw = dict(cfg=cfg, page=page, impl="auto", interpret=False)
     n_moe = cfg.n_layers - cfg.first_k_dense
     want = {fd_name: n for fd_name, n in (

@@ -524,6 +524,85 @@ def test_write_rows_skips_overflowing_rows():
     assert (out[1] == np.asarray(cache)[1]).all()
 
 
+@pytest.mark.parametrize("redirect", [False, True],
+                         ids=["all_active", "some_to_null"])
+@pytest.mark.parametrize("n_tok", [None, 3], ids=["B", "BT"])
+@pytest.mark.parametrize("kind", ["bf16", "int8", "latent"])
+def test_scatter_kv_equals_a_row_by_row_write(kind, n_tok, redirect):
+    """``_scatter_kv`` writes through each plane's merged block-and-head
+    view; what lands is, bit for bit, what a plain numpy loop over
+    (row, head) puts at ``plane[pool_row, head, in_page]`` — decode
+    ``[B]`` and verify ``[B, T]`` indices (a verify chunk crossing a page
+    boundary), float, int8 (bytes and scales) and one-head latent
+    planes, with and without rows redirected to the null block — and
+    every other slot of the pool keeps its bytes."""
+    from triton_dist_tpu.kernels.flash_decode import quantize_kv
+    from triton_dist_tpu.serve.engine import _scatter_kv
+
+    NB, page, D, B = 11, 4, 16, 4
+    hk = 1 if kind == "latent" else 3
+    rng = np.random.default_rng(7)
+
+    def plane(dtype, *tail):
+        x = rng.integers(-100, 100, (NB, hk, page) + tail)
+        return jnp.asarray(x, dtype)
+
+    def layer():
+        if kind == "int8":
+            return {"q": plane(jnp.int8, D), "s": plane(jnp.float32)}
+        return plane(jnp.bfloat16, D)
+
+    pool = (layer(),) if kind == "latent" else (layer(), layer())
+    # row b owns blocks 1+2b, 2+2b and stands at position 2 + b % 3 of
+    # its first page, so a 3-token chunk crosses into the second
+    T = n_tok or 1
+    pos = (2 + np.arange(B) % 3)[:, None] + np.arange(T)[None]
+    pool_row = 1 + 2 * np.arange(B)[:, None] + pos // page
+    in_page = pos % page
+    active = np.array([True, False, True, False]) if redirect \
+        else np.ones(B, bool)
+    pool_row = np.where(active[:, None], pool_row, 0)
+    in_page = np.where(active[:, None], in_page, 0)
+    if n_tok is None:
+        pool_row, in_page = pool_row[:, 0], in_page[:, 0]
+    lead = pool_row.shape
+    k = jnp.asarray(rng.standard_normal(lead + (hk, D)), jnp.float32)
+    v = None if kind == "latent" else jnp.asarray(
+        rng.standard_normal(lead + (hk, D)), jnp.float32)
+
+    # op by op, as the reference's quantize_kv below runs: a fused
+    # absmax / 127 may round the scale's last bit another way
+    got = _scatter_kv(pool, k, v, jnp.asarray(pool_row, jnp.int32),
+                      jnp.asarray(in_page, jnp.int32))
+
+    def rows_of(x):
+        # the planes of one K or V layer and the rows each receives
+        if kind == "int8":
+            q, s = quantize_kv(x)
+            return {"q": q, "s": s}
+        return x.astype(jnp.bfloat16)
+
+    want = jax.tree.map(lambda p: np.array(p), pool)
+    new = tuple(rows_of(x) for x in (k, v) if x is not None)
+    for w, n in zip(jax.tree.leaves(want), jax.tree.leaves(new)):
+        n = np.asarray(n)
+        for i in np.ndindex(*lead):
+            for h in range(hk):
+                w[pool_row[i], h, in_page[i]] = n[i][h]
+    assert jax.tree.structure(got) == jax.tree.structure(pool)
+    for g, w, p in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                       jax.tree.leaves(pool)):
+        g, p = np.array(g), np.asarray(p)
+        assert g.dtype == w.dtype and g.shape == w.shape
+        # several rows land on the null slot: which one stays is not the
+        # write's to say (nothing reads block 0)
+        g[0, :, 0], w[0, :, 0] = 0, 0
+        assert g.tobytes() == w.tobytes()
+        untouched = np.setdiff1d(np.arange(1, NB), pool_row)
+        assert untouched.size
+        assert g[untouched].tobytes() == p[untouched].tobytes()
+
+
 def test_sp_paged_decode_accepts_multi_token_q(mesh2):
     """The paged SP decode now honours the 4D-q / q_lens contract
     (ISSUE-19 debt (a)): [B, T, Hq, D] partials combine as a B*T batch.

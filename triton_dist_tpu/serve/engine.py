@@ -82,7 +82,10 @@ KV pools are float by default, or INT8 with per-page scale planes
 ``Generator`` with ``kv_dtype=jnp.int8`` and every pool layer becomes a
 ``{"q": int8 [NB, Hkv, page, D], "s": f32 [NB, Hkv, page]}`` pair —
 ``_scatter_kv`` quantizes rows as they land (``flash_decode.quantize_kv``,
-the contiguous cache's recipe), the scale plane moves WITH its page
+the contiguous cache's recipe) and writes every plane, float or int8,
+through its ``[NB * Hkv, page, ...]`` view: the indexed dimensions of a
+scatter must be adjacent, or the chip re-lays the whole plane out and
+back around each write.  The scale plane moves WITH its page
 through fill/gather/COW/snapshot/migration (never a dequant/requant
 round trip — quantization is not idempotent, so bit-reproducibility
 demands the bytes move as bytes), and attention dequantizes inside
@@ -207,31 +210,47 @@ def _page_slots(tables, kv_lens, active, *, page):
             jnp.where(active, in_page, 0))
 
 
+def _write_rows_paged(plane, rows, pool_row, in_page):
+    """``rows`` ([..., Hk, D]; [..., Hk] for a scale plane) into ``plane``
+    ([NB, Hk, page, D]; [NB, Hk, page]) at ``(pool_row, in_page)`` [...],
+    through the plane's ``[NB * Hk, page, ...]`` view.  A scatter's
+    indexed dimensions must be adjacent: with the heads between block and
+    in-page row the chip copies the whole plane to a layout where they
+    are, writes, and copies it back — two whole-plane copies a write, and
+    under the horizon's scan a second copy of every pool (PERF.md §6, PR
+    27).  Block and head are both major to the (page, D) tile, so the
+    view costs nothing and the write lands in place."""
+    nb, hk = plane.shape[:2]
+    flat = plane.reshape(nb * hk, *plane.shape[2:])
+    row = pool_row[..., None] * hk + jnp.arange(hk, dtype=pool_row.dtype)
+    return flat.at[row, in_page[..., None]].set(
+        rows.astype(plane.dtype)).reshape(plane.shape)
+
+
 def _scatter_kv(pool, k, v, pool_row, in_page):
     """The ONE paged K/V write: scatter new rows into pool pages at
     (pool_row, in_page) — [B] indices for a decode token, [B, T] for a
     verify chunk.  Both paged forwards use it, so the write can never
-    diverge between decode and verify.
+    diverge between decode and verify.  A layer's pool is its planes — K
+    and V, or the ONE plane of a latent pool (models/mla_moe.py: a row a
+    token as ``k``, ``v`` None) — and each is written through its merged
+    block-and-head view (:func:`_write_rows_paged`).
 
-    Quantized pools (``{"q", "s"}`` dicts) quantize each new row HERE —
+    Quantized planes (``{"q", "s"}`` dicts) quantize each new row HERE —
     ``quantize_kv``'s per-(head, position) absmax over D, the identical
     recipe the contiguous quantized cache uses — so a row's int8 bytes
     and its scale land together and never drift apart."""
-    if v is None:
-        # a latent pool (models/mla_moe.py): ONE plane, one row a token
-        plane, = pool
-        return (plane.at[pool_row, :, in_page, :].set(
-            k.astype(plane.dtype)),)
-    k_pool, v_pool = pool
-    if isinstance(k_pool, dict):
-        kq, ks = quantize_kv(k)
-        vq, vs = quantize_kv(v)
-        return ({"q": k_pool["q"].at[pool_row, :, in_page, :].set(kq),
-                 "s": k_pool["s"].at[pool_row, :, in_page].set(ks)},
-                {"q": v_pool["q"].at[pool_row, :, in_page, :].set(vq),
-                 "s": v_pool["s"].at[pool_row, :, in_page].set(vs)})
-    return (k_pool.at[pool_row, :, in_page, :].set(k.astype(k_pool.dtype)),
-            v_pool.at[pool_row, :, in_page, :].set(v.astype(v_pool.dtype)))
+    put = functools.partial(_write_rows_paged, pool_row=pool_row,
+                            in_page=in_page)
+    out = []
+    new = (k,) if v is None else (k, v)
+    for plane, rows in zip(pool, new, strict=True):
+        if isinstance(plane, dict):
+            q, s = quantize_kv(rows)
+            out.append({"q": put(plane["q"], q), "s": put(plane["s"], s)})
+        else:
+            out.append(put(plane, rows))
+    return tuple(out)
 
 
 def _pool_views(pool):
