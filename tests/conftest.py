@@ -206,6 +206,11 @@ _FAST_GATE_MODULES = {
     # preemption / cow on latent pools, the MoE counters, and the named
     # refusals (the whole file is the fast tier).
     "test_mla_moe",
+    # the sampler every decode step of a sampled batch runs (ISSUE 29):
+    # kept set and token against the two-sort form it replaced over the
+    # top-k x top-p x ties grid at both cells' vocabularies, host path
+    # == device path, and the filters' edge cases (~30 s, one worker).
+    "test_sampling",
 }
 
 # Heavy tests inside core modules whose coverage is duplicated by a

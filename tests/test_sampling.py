@@ -3,6 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from triton_dist_tpu.models.sampling import make_sampler, sample_logits
 
@@ -95,7 +96,6 @@ def test_top_p_zero_is_greedy(key):
 def test_zero_temperature_guard(key):
     """filtered_probs(temperature=0) must raise, not return NaN silently
     (sample_logits special-cases greedy before the divide)."""
-    import pytest
     from triton_dist_tpu.models.sampling import filtered_probs
     logits = _logits(key)
     with pytest.raises(ValueError, match="temperature"):
@@ -164,3 +164,157 @@ def test_rowwise_sampler_filters_respected(key):
                                     greedy=greedy)
         assert int(tok[0]) in allowed
         assert int(tok[1]) in nucleus
+
+
+# ---------------------------------------------------------------------------
+# The windowed sampler against the two-sort form it replaced (PR 29).  The
+# oracle below IS that form, kept here verbatim: one sort of the vocabulary
+# for the k-th largest value, a second inside the nucleus rule.
+# ---------------------------------------------------------------------------
+
+_NEG_INF = np.float32(-1e30)
+
+
+def _oracle_top_p(logits, top_p):
+    sorted_logits = jnp.sort(logits, axis=-1)[..., ::-1]
+    probs = jax.nn.softmax(sorted_logits, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    cut = cum - probs >= top_p
+    idx = jax.lax.broadcasted_iota(jnp.int32, cut.shape, cut.ndim - 1)
+    cut = cut & (idx > 0)
+    cutoff = jnp.where(cut, jnp.float32(jnp.inf), sorted_logits).min(
+        axis=-1, keepdims=True)
+    return jnp.where(logits < cutoff, _NEG_INF, logits)
+
+
+def _oracle_rowwise(logits, keys, *, temperature, top_k, top_p, greedy):
+    """-> (filtered logits [B, V], token [B])."""
+    gr = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    V = logits.shape[-1]
+    t = jnp.where(greedy, jnp.float32(1.0), temperature.astype(jnp.float32))
+    x = logits.astype(jnp.float32) / t[:, None]
+    k = jnp.clip(jnp.where(top_k > 0, top_k, V), 1, V)
+    srt = jnp.sort(x, axis=-1)[..., ::-1]
+    kth = jnp.take_along_axis(srt, (k - 1)[:, None], axis=-1)
+    x = jnp.where(((top_k > 0) & (top_k < V))[:, None],
+                  jnp.where(x < kth, _NEG_INF, x), x)
+    x = jnp.where((top_p < 1.0)[:, None],
+                  _oracle_top_p(x, top_p[:, None].astype(jnp.float32)), x)
+    drawn = jax.vmap(
+        lambda kk, row: jax.random.categorical(kk, row[None], axis=-1)[0]
+    )(keys, x).astype(jnp.int32)
+    return x, jnp.where(greedy, gr, drawn)
+
+
+_TOP_KS = (1, 5, 64, 128, 129, 0)
+_TOP_PS = (1.0, 0.95, 0.5, 0.0)
+_VOCABS = (32000, 16032)
+_KINDS = ("f32", "bf16_ties")
+#: rank whose value the ``bf16_ties`` logits repeat, and how often: the
+#: ties start inside the 64 largest values and run far past the 128th
+_TIE_RANK, _TIE_COPIES = 40, 200
+
+
+def _oracle_logits(kind, V):
+    x = np.asarray(jax.random.normal(jax.random.key(V), (4, V),
+                                     jnp.float32)) * 3.0
+    if kind == "bf16_ties":
+        x = np.asarray(jnp.asarray(x).astype(jnp.bfloat16)
+                       .astype(jnp.float32)).copy()
+        rng = np.random.default_rng(V)
+        for row in x:
+            tie = np.sort(row)[-_TIE_RANK]
+            low = np.argsort(row)[:V // 2]
+            row[rng.choice(low, _TIE_COPIES, replace=False)] = tie
+    return jnp.asarray(x)
+
+
+@pytest.fixture(scope="module")
+def sampler_and_oracle():
+    """Both forms jitted once a vocabulary (every parameter an array)."""
+    from triton_dist_tpu.models import sampling
+
+    def new(logits, keys, **kw):
+        return (sampling._filtered_logits_rowwise(logits, **kw),
+                sampling.sample_logits_rowwise(logits, keys, **kw))
+
+    return jax.jit(new), jax.jit(_oracle_rowwise)
+
+
+@pytest.mark.parametrize("V", _VOCABS)
+@pytest.mark.parametrize("kind", _KINDS)
+@pytest.mark.parametrize("top_p", _TOP_PS)
+@pytest.mark.parametrize("top_k", _TOP_KS)
+def test_sampler_matches_two_sort_oracle(sampler_and_oracle, top_k, top_p,
+                                         kind, V):
+    """Kept set and drawn token equal the two-sort form's, row for row,
+    in a batch that mixes a greedy row with three sampled ones — float32
+    logits, and bf16-rounded ones in which 200 more values tie with the
+    40th largest (a cut at the 64th or 128th value keeps every one of
+    them; the mass they carry moves the nucleus)."""
+    new, oracle = sampler_and_oracle
+    logits = _oracle_logits(kind, V)
+    keys = jax.vmap(jax.random.fold_in)(
+        jax.vmap(jax.random.key)(jnp.array([3, 2 ** 31 - 5, 7, 11])),
+        jnp.array([0, 4, 9, 31]))
+    kw = dict(temperature=jnp.array([0.0, 0.8, 1.0, 1.7], jnp.float32),
+              top_k=jnp.full((4,), top_k, jnp.int32),
+              top_p=jnp.full((4,), top_p, jnp.float32),
+              greedy=jnp.array([True, False, False, False]))
+    x_new, tok_new = new(logits, keys, **kw)
+    x_old, tok_old = oracle(logits, keys, **kw)
+    kept_new = np.asarray(x_new)[1:] > _NEG_INF / 2
+    kept_old = np.asarray(x_old)[1:] > _NEG_INF / 2
+    np.testing.assert_array_equal(kept_new, kept_old)
+    np.testing.assert_array_equal(np.asarray(x_new)[1:][kept_new],
+                                  np.asarray(x_old)[1:][kept_old])
+    np.testing.assert_array_equal(np.asarray(tok_new), np.asarray(tok_old))
+    assert int(tok_new[0]) == int(np.argmax(np.asarray(logits[0])))
+    if 0 < top_k < V:
+        ties = _TIE_COPIES if kind == "bf16_ties" and top_k > _TIE_RANK else 0
+        assert (kept_new.sum(axis=1) <= top_k + ties + 8).all()
+        if top_p == 1.0:
+            assert (kept_new.sum(axis=1) >= top_k).all()
+
+
+def test_ordered_bits_keep_the_floats_order():
+    """The bisection runs on a float32's bits re-ordered so that unsigned
+    comparison is the floats' own; the map is its own way back."""
+    from triton_dist_tpu.models.sampling import (
+        _from_ordered_bits,
+        _ordered_bits,
+    )
+
+    x = jnp.asarray(np.asarray(
+        [-np.inf, -3e38, -1.5, -1e-45, -0.0, 0.0, 1e-45, 1.5, 3e38, np.inf],
+        np.float32))
+    bits = np.asarray(_ordered_bits(x))
+    assert bits.dtype == np.uint32
+    assert (np.diff(bits.astype(np.int64)) > 0).all()
+    back = np.asarray(_from_ordered_bits(jnp.asarray(bits)))
+    np.testing.assert_array_equal(back.view(np.uint32),
+                                  np.asarray(x).view(np.uint32))
+    # a threshold that held nowhere stays 0: a NaN, which cuts nothing
+    assert np.isnan(np.asarray(_from_ordered_bits(jnp.uint32(0))))
+
+
+def test_sampler_sorts_nothing(key):
+    """Neither sampler surface holds a sort or a top-k any more: both
+    cut-offs come from reduces."""
+    from triton_dist_tpu.models.sampling import (
+        sample_logits,
+        sample_positions_rowwise,
+    )
+
+    logits = _logits(key, B=3, V=300)
+    hlo = sample_logits.lower(logits, key, temperature=0.8, top_k=64,
+                              top_p=0.95).compile().as_text()
+    assert " sort(" not in hlo and "topk" not in hlo.lower()
+    base = jax.vmap(jax.random.key)(jnp.arange(3))
+    kw = dict(temperature=jnp.full((3,), 0.8), top_k=jnp.array([64, 0, 5]),
+              top_p=jnp.array([0.95, 0.9, 1.0]),
+              greedy=jnp.array([False, False, True]))
+    hlo = jax.jit(lambda lg: sample_positions_rowwise(
+        lg[:, None], base, jnp.zeros((3,), jnp.int32), **kw)).lower(
+        logits).compile().as_text()
+    assert " sort(" not in hlo and "topk" not in hlo.lower()

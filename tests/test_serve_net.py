@@ -512,7 +512,7 @@ def test_dead_serve_loop_reads_as_down(tiny, tmp_path):
 
 
 def _net_fleet(gen, params, root, *, n=3, client_inj=None,
-               step_sleep_s=0.02, max_restarts=0):
+               step_sleep_s=0.02, max_restarts=0, dead_after_s=1.5):
     procs: dict = {}
     clients: dict = {}
 
@@ -529,7 +529,7 @@ def _net_fleet(gen, params, root, *, n=3, client_inj=None,
         return rr.wait_ready(30)
 
     fc = FleetController(factory, n, root=str(root),
-                         suspect_after_s=0.6, dead_after_s=1.5,
+                         suspect_after_s=0.6, dead_after_s=dead_after_s,
                          backoff_base_s=0.05, backoff_cap_s=0.1,
                          max_restarts=max_restarts)
     return fc, procs, clients
@@ -614,8 +614,11 @@ def test_net_fleet_partition_heals_to_healthy(tiny, tmp_path):
     reqs = _mixed_reqs(cfg, 4, new_tokens=24)
     oracle = _oracle(gen, params, reqs)
     client_inj = FaultInjector(seed=5)
+    # SUSPECT must be SEEN between two controller passes: at the other
+    # tests' 1.5 s one slow pass (a first compile beside five other
+    # workers) carries a replica from HEALTHY straight to DEAD
     fc, procs, _ = _net_fleet(gen, params, tmp_path / "healfleet", n=2,
-                              client_inj=client_inj)
+                              client_inj=client_inj, dead_after_s=15.0)
     for r in reqs:
         fc.submit(Request(r.request_id, r.prompt, r.params))
     part_name = fc.placement[reqs[0].request_id]
