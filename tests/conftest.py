@@ -206,6 +206,16 @@ _FAST_GATE_MODULES = {
     # preemption / cow on latent pools, the MoE counters, and the named
     # refusals (the whole file is the fast tier).
     "test_mla_moe",
+    # the same block read through learned sparse attention (ISSUE 30, the
+    # glm_moe_dsa keys): prefill chunks + paged decode through latent AND
+    # index-key planes against the float32 reference's full forward (and
+    # bf16 failing the tolerance), index scores and the selected set
+    # against its plain sort, contexts under index_topk == the plain
+    # latent path, prefix hit / preemption / cow with two planes of
+    # different widths, the dsa_* counters, a whole layer's shares adding
+    # up, the index and masked-walk kernels against their oracles, and the
+    # named refusals (the whole file is the fast tier, ~3 min).
+    "test_mla_dsa",
     # the sampler every decode step of a sampled batch runs (ISSUE 29):
     # kept set and token against the two-sort form it replaced over the
     # top-k x top-p x ties grid at both cells' vocabularies, host path

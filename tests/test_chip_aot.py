@@ -438,35 +438,43 @@ def test_the_paged_write_lands_in_place(v5e, as_tpu, kind, num_blocks,
 HBM_GIB = 15.75     # what the v5e's compiler allows one program
 
 
-def _mla_moe_cell():
-    """The benchmark cell's configuration as its builder reads it."""
+def _mla_moe_cell(name="gigachat3.1-702b-ep16-l5"):
+    """A latent-family benchmark cell's configuration as its builder
+    reads it."""
     import json
     import os
 
     from benchmarks import builders_mla_moe
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(
-            root, "benchmarks/configs/gigachat3.1-702b-ep16-l5.json")) as f:
+    with open(os.path.join(root, f"benchmarks/configs/{name}.json")) as f:
         config = json.load(f)
     return config, builders_mla_moe.model_config(config)
 
 
-def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu):
-    """Every program kind of ``gc3_ep16_l5_reason_sat`` — single-step
-    decode, the fused horizon (greedy and mixed), prefill chunks at the
-    shortest, a middle and the cap extent — at the file's widths and
-    engine sizes: each holds ONE latent attention call a layer and one
-    gate-up + one down grouped GEMM an expert layer, under their trace
-    names (the benchmark's roofline readers match them), and fits the
-    chip beside nothing else."""
+@pytest.mark.parametrize("name,extents", [
+    ("gigachat3.1-702b-ep16-l5", (512, 2048, 8192)),
+    # the rungs longctx_sat's prompts (4,186-16,033) reach, and the cap
+    ("glm-5-ep16-l5", (8192, 16384, 18432)),
+])
+def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu, name,
+                                                      extents):
+    """Every program kind of ``gc3_ep16_l5_reason_sat`` and of
+    ``glm5_ep16_l5_longctx_sat`` — single-step decode, the fused horizon
+    (greedy and mixed), prefill chunks at the shortest, a middle and the
+    cap extent — at the file's widths and engine sizes: each holds ONE
+    latent attention call a layer (and, with an indexer, ONE index-score
+    call a layer beside it) and one gate-up + one down grouped GEMM an
+    expert layer, under their trace names (the benchmark's roofline
+    readers match them), and fits the chip beside nothing else."""
     import re
     from collections import Counter
 
+    from triton_dist_tpu.kernels import flash_decode as fd
     from triton_dist_tpu.models import mla_moe as M
     from triton_dist_tpu.runtime.jit_cache import named
 
-    config, cfg = _mla_moe_cell()
+    config, cfg = _mla_moe_cell(name)
     eng = config["engine"]
     batch, page, max_seq = eng["max_batch"], eng["page_size"], eng["max_seq"]
     put = functools.partial(_on, sharding=SingleDeviceSharding(v5e.devices[0]))
@@ -475,17 +483,22 @@ def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu):
                             jax.random.key(0))
     gen = M.MlaMoeGenerator(cfg, max_seq=max_seq)
     assert gen.kernel_gaps(page_size=page) == {}
-    pool = s((eng["num_blocks"], 1, page, cfg.head_dim), cfg.dtype)
+    assert gen.kv_planes == [(1, 640)] + [(1, 128)] * cfg.sparse
+    pool = tuple(s((eng["num_blocks"], h, page, d), cfg.dtype)
+                 for h, d in gen.kv_planes)
     d_args, h_args = _decode_args(cfg, page, batch=batch, max_seq=max_seq,
                                   params=params,
-                                  pools=[(pool,)] * cfg.n_layers)
+                                  pools=[pool] * cfg.n_layers)
     kw = dict(cfg=cfg, page=page, **gen.serve_hooks())
     n_moe = cfg.n_layers - cfg.first_k_dense
     want = {fd_name: n for fd_name, n in (
-        ("mla_paged_decode", cfg.n_layers), (M.GATE_UP_CALL, n_moe),
-        (M.DOWN_CALL, n_moe))}
+        (fd.MLA_CALL_NAME, cfg.n_layers),
+        (fd.DSA_INDEX_CALL_NAME, cfg.n_layers * cfg.sparse),
+        (M.GATE_UP_CALL, n_moe), (M.DOWN_CALL, n_moe)) if n}
+    assert (fd.MLA_CALL_NAME, fd.DSA_INDEX_CALL_NAME) == (
+        "mla_paged_decode", "dsa_index_scores")     # the readers' patterns
 
-    def check(name, jitted, *args, **statics):
+    def check(name, jitted, *args, want=want, **statics):
         compiled = jitted.lower(*put(args), **statics).compile()
         text = compiled.as_text()
         assert text.split(",", 1)[0] == f"HloModule jit_{name}"
@@ -511,12 +524,22 @@ def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu):
               all_greedy=all_greedy)
     ladder = E.build_bucket_ladder(max(page, eng["prefill_chunk"]), max_seq,
                                    page)
-    for extent in (ladder[0], 2048, max_seq):
-        sc = s((1, 1, extent, cfg.head_dim), cfg.dtype)
+    assert set(extents) <= set(ladder) and extents[-1] == max_seq
+    # a sparse block's chunk of this size attends in the expanded form:
+    # the flash call over expanded rows in the absorbed page walk's place
+    chunk_want = dict(want)
+    if cfg.expands(eng["prefill_chunk"]):
+        assert fd.MLA_PREFILL_CALL_NAME == "mla_expanded_prefill"
+        chunk_want[fd.MLA_PREFILL_CALL_NAME] = chunk_want.pop(
+            fd.MLA_CALL_NAME)
+        assert gen.kernel_gaps(page_size=page, ladder=ladder,
+                               prefill_chunk=eng["prefill_chunk"]) == {}
+    for extent in extents:
+        sc = tuple(s((1, h, extent, d), cfg.dtype) for h, d in gen.kv_planes)
         check("prefill_chunk", gen._chunk_jit, params,
-              s((1, eng["prefill_chunk"]), I32), [(sc,)] * cfg.n_layers,
+              s((1, eng["prefill_chunk"]), I32), [sc] * cfg.n_layers,
               s((), I32), quantized=False, extent=extent,
-              n_valid=s((), I32))
+              n_valid=s((), I32), want=chunk_want)
 
 
 # ---------------------------------------------------------------------------

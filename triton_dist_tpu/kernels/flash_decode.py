@@ -975,8 +975,19 @@ def mla_kernel_gap(page: int, rank: int, rope: int) -> str | None:
     return None
 
 
+def _visible(B, T, n, Pg, local_lens, q_lens):
+    """[B, T, n * Pg] bool: the multi-token visibility rule of
+    :func:`_chunk_valid` in dense form (the XLA fallbacks' mask)."""
+    ql = (jnp.full((B,), T, jnp.int32) if q_lens is None
+          else q_lens.astype(jnp.int32))
+    pos = jnp.arange(n * Pg)[None, None, :]
+    d = ql[:, None] - 1 - jnp.arange(T)[None, :]                # [B, T]
+    return ((d[..., None] >= 0)
+            & (pos < (local_lens[:, None] - d)[..., None]))     # [B, T, S]
+
+
 def _mla_decode_xla(q, pool, block_table, local_lens, *, rank, scale,
-                    q_lens=None):
+                    q_lens=None, sel=None):
     """Dense form of the latent paged attend (the interpreter-free
     fallback and the kernel's oracle): gather the row's pages, score all
     heads against the whole row, sum the first ``rank`` columns."""
@@ -986,12 +997,9 @@ def _mla_decode_xla(q, pool, block_table, local_lens, *, rank, scale,
     T = q.shape[1]
     logits = jnp.einsum("bthw,bsw->bths", q.astype(jnp.float32),
                         lat) * scale
-    ql = (jnp.full((B,), T, jnp.int32) if q_lens is None
-          else q_lens.astype(jnp.int32))
-    pos = jnp.arange(n * Pg)[None, None, :]
-    d = ql[:, None] - 1 - jnp.arange(T)[None, :]                # [B, T]
-    valid = ((d[..., None] >= 0)
-             & (pos < (local_lens[:, None] - d)[..., None]))    # [B, T, S]
+    valid = _visible(B, T, n, Pg, local_lens, q_lens)
+    if sel is not None:
+        valid = valid & (_sel_rows(sel, T).reshape(B, T, n * Pg) >= 0)
     valid = valid[:, :, None, :]
     logits = jnp.where(valid, logits, NEG_INF)
     p = jnp.where(valid, jnp.exp(logits - jnp.max(logits, -1,
@@ -1001,9 +1009,45 @@ def _mla_decode_xla(q, pool, block_table, local_lens, *, rank, scale,
     return jnp.einsum("bths,bsr->bthr", p, lat[..., :rank])
 
 
+def _sel_rows(sel, T):
+    """A selection operand in its call layout ([B, n, page] at T == 1,
+    [B, n, T, page] above) -> [B, T, n, page]."""
+    return sel[:, None] if T == 1 else sel.transpose(0, 2, 1, 3)
+
+
+def _tokens_per_tile(T: int, heads: int, row_cap: int,
+                     blocked: bool = False) -> int:
+    """Query tokens one grid step of a latent-family call carries: at most
+    ``row_cap`` rows (tokens x heads), a divisor of ``T``; ``blocked``
+    (an operand blocked by token: its tile must be 8-aligned, or whole)
+    also a multiple of 8 unless it is all of ``T``."""
+    tq = max(1, min(T, row_cap // heads))
+    while T % tq or (blocked and 1 < tq < T and tq % 8):
+        tq -= 1
+    return tq
+
+
+def _tile_walk(lens_ref, b, t0, *, tq, page, n_pages, n_tok, use_qlens):
+    """What a (batch row, query tile) step of a latent-family kernel
+    walks: ``(llen, wlen, qlen_t, hi)`` — the lens, the row's live query
+    count shifted so that ``_chunk_valid``'s token index is local to the
+    tile starting at token ``t0`` (None without q_lens), and the pages up
+    to the last one the tile's own last token may see."""
+    llen, wlen, qlen = _read_lens(lens_ref, b, window=0,
+                                  use_qlens=use_qlens)
+    if qlen is None:
+        qlen_t, seen = None, llen
+    else:
+        qlen_t = qlen - t0
+        seen = llen - jnp.maximum(qlen_t - tq, 0)
+    _, hi = _live_pages(seen, seen, qlen_t, page=page, n_pages=n_pages,
+                        window=0, n_tok=n_tok)
+    return llen, wlen, qlen_t, hi
+
+
 def mla_decode_paged_shard(q, pool, block_table, local_lens, *, rank: int,
-                           scale: float, q_lens=None, impl="auto",
-                           interpret=False):
+                           scale: float, q_lens=None, sel=None,
+                           impl="auto", interpret=False):
     """Absorbed latent attention over a PAGED latent cache.
 
     q [B, T, H, rank + rope] (the absorbed query ``[q_nope W_UK | q_rope]``;
@@ -1014,6 +1058,12 @@ def mla_decode_paged_shard(q, pool, block_table, local_lens, *, rank: int,
     ``[B, T, H, rank]``: softmax(q . row * scale) @ row[:rank], still in
     the latent space (the caller applies W_UV).  Rows with no live query
     or no length give zeros.
+
+    ``sel`` (learned sparse attention: :func:`dsa_index_scores` less each
+    query's cut-off, in that call's layout — float32 [B, n_pages, page] at
+    T == 1, [B, n_pages, T, page] above) keeps of the visible rows those
+    with ``sel >= 0``: the same page walk with a selection mask, dense
+    bytes.
     """
     B, T, H, W = q.shape
     N, Pg, W2 = pool.shape
@@ -1025,20 +1075,27 @@ def mla_decode_paged_shard(q, pool, block_table, local_lens, *, rank: int,
     if use_fallback(raw_impl, impl, gap is None or interpret,
                     MLA_CALL_NAME, gap or ""):
         return _mla_decode_xla(q, pool, block_table, local_lens, rank=rank,
-                               scale=scale, q_lens=q_lens)
+                               scale=scale, q_lens=q_lens, sel=sel)
 
     # the [3, B] lens layout whenever a row may hold dead queries
     lens_arg, use_qlens = _pack_lens_arg(local_lens, None, q_lens,
                                          n_tok=T, window=0)
-    tq = max(1, min(T, MLA_Q_ROWS // H))               # tokens a q tile
-    while T % tq:
-        tq -= 1
+    tq = _tokens_per_tile(T, H, MLA_Q_ROWS, blocked=sel is not None)
     rows = tq * H
     qr = q.reshape(B, T * H, W)
     depth = max(2, MLA_PAGES_IN_FLIGHT)
     kern = functools.partial(_mla_paged_kernel, page=Pg, rank=rank,
                              n_pages=n_pages, scale=scale, n_tok=T, tq=tq,
-                             heads=H, use_qlens=use_qlens, depth=depth)
+                             heads=H, use_qlens=use_qlens, depth=depth,
+                             has_sel=sel is not None)
+    sel_spec, sel_arg = [], []
+    if sel is not None:
+        sel_arg = [sel]
+        sel_spec = [pl.BlockSpec((1, n_pages, Pg),
+                                 lambda b, j, lens, tab: (b, 0, 0))
+                    if T == 1 else
+                    pl.BlockSpec((1, n_pages, tq, Pg),
+                                 lambda b, j, lens, tab: (b, 0, j, 0))]
     out = pl.pallas_call(
         kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -1048,6 +1105,7 @@ def mla_decode_paged_shard(q, pool, block_table, local_lens, *, rank: int,
                 pl.BlockSpec((1, rows, W),
                              lambda b, j, lens, tab: (b, j, 0)),
                 pl.BlockSpec(memory_space=pl.ANY),    # the pool stays in HBM
+                *sel_spec,
             ],
             out_specs=pl.BlockSpec((1, rows, rank),
                                    lambda b, j, lens, tab: (b, j, 0)),
@@ -1064,31 +1122,24 @@ def mla_decode_paged_shard(q, pool, block_table, local_lens, *, rank: int,
             dimension_semantics=("parallel", "parallel")),
         interpret=maybe_interpret(interpret),
         name=MLA_CALL_NAME,
-    )(lens_arg, block_table, qr, pool)
+    )(lens_arg, block_table, qr, pool, *sel_arg)
     return out.reshape(B, T, H, rank)
 
 
-def _mla_paged_kernel(lens_ref, table_ref, q_ref, pool_hbm, out_ref, buf,
-                      sem, acc_ref, m_ref, l_ref, *, page, rank, n_pages,
-                      scale, n_tok, tq, heads, use_qlens, depth):
+def _mla_paged_kernel(lens_ref, table_ref, q_ref, pool_hbm, *refs, page,
+                      rank, n_pages, scale, n_tok, tq, heads, use_qlens,
+                      depth, has_sel=False):
     """Grid (B, T // tq); one step is ``tq`` query tokens of one batch row
     under every head, walking the row's live pages up to the last one its
     own last token may see, ``depth - 1`` pages copied ahead of the one
-    being multiplied."""
+    being multiplied.  With a selection operand (``has_sel``) a page's
+    rows count only where the tile's token kept them."""
+    sel_ref = refs[0] if has_sel else None
+    out_ref, buf, sem, acc_ref, m_ref, l_ref = refs[int(has_sel):]
     b = pl.program_id(0)
-    t0 = pl.program_id(1) * tq
-    llen, wlen, qlen = _read_lens(lens_ref, b, window=0,
-                                  use_qlens=use_qlens)
-    if qlen is None:
-        qlen_t, seen = None, llen
-    else:
-        # this tile's tokens are t0 .. t0 + tq - 1 of the row's qlen:
-        # shift the count so ``_chunk_valid``'s t = r // heads is local,
-        # and stop the walk at what the tile's last token sees
-        qlen_t = qlen - t0
-        seen = llen - jnp.maximum(qlen_t - tq, 0)
-    _, hi = _live_pages(seen, seen, qlen_t, page=page, n_pages=n_pages,
-                        window=0, n_tok=n_tok)
+    llen, wlen, qlen_t, hi = _tile_walk(
+        lens_ref, b, pl.program_id(1) * tq, tq=tq, page=page,
+        n_pages=n_pages, n_tok=n_tok, use_qlens=use_qlens)
     rows = q_ref.shape[1]
 
     def page_copy(i, slot):
@@ -1115,12 +1166,376 @@ def _mla_paged_kernel(lens_ref, table_ref, q_ref, pool_hbm, out_ref, buf,
             jnp.int32, (rows, page), 1)
         valid = _chunk_valid(pos, llen, wlen, qlen_t, window=0,
                              group=heads)
+        if sel_ref is not None and n_tok == 1:
+            valid = valid & (sel_ref[0, pl.ds(i, 1), :] >= 0.0)
+        elif sel_ref is not None:
+            kept = sel_ref[0, i]                        # [tq, page]
+            valid = valid & (jnp.broadcast_to(
+                kept[:, None, :], (tq, heads, page)).reshape(rows, page)
+                >= 0.0)
         row = buf[slot]                                 # [page, rank + rope]
         _online_softmax_step(q_ref[0], row, row[:, :rank], valid, acc_ref,
                              m_ref, l_ref, scale=scale, soft_cap=0.0)
 
     jax.lax.fori_loop(0, hi, page_step, None)
     out_ref[0], _ = _softmax_state_emit(acc_ref, m_ref, l_ref)
+
+
+# ---------------------------------------------------------------------------
+# A prefill chunk over a latent scratch, in the EXPANDED form
+# ---------------------------------------------------------------------------
+#
+# The absorbed form above suits a decode step: 64 query rows a page, and a
+# page's bytes are what it costs.  A prefill chunk is bound by the products,
+# and there the absorbed form pays ``2 x (rank + rope + rank)`` operations a
+# query-key pair a head (2,176 at the published widths) where the expanded
+# form pays ``2 x (nope + rope + v)`` (1,024): the caller expands the
+# contiguous scratch once a chunk (one product of its rows with ``[W_UK |
+# I | W_UV]``: a head's key beside its value, 42 M operations a cached row
+# against a chunk's ``T x 65.5 k``) and this call is flash attention over
+# it, a head's own keys and values, with the indexer's selection as a mask
+# the heads share.  Everything stays in the layout a matmul leaves it in
+# ([tokens, heads x width]): a step cuts its heads out by lanes.  On the
+# chip (PERF.md §6, PR 30; one layer, 2,048 queries over 16,384 visible
+# rows): the absorbed walk 62.9 ms, this call 12 ms at 86% of the MXU's peak.
+
+MLA_PREFILL_CALL_NAME = "mla_expanded_prefill"
+# (query rows, key rows, heads) of one grid step.
+MLA_PREFILL_BLOCK = (512, 1024, 4)
+MLA_PREFILL_VMEM = 64 * 2 ** 20
+
+
+def _prefill_blocks(T, S, H):
+    bq, bk, hb = MLA_PREFILL_BLOCK
+    return math.gcd(bq, T), math.gcd(bk, S), math.gcd(hb, H)
+
+
+def mla_prefill_gap(T: int, S: int, d_qk: int, d_v: int,
+                    page: int = 128) -> str | None:
+    """Why :func:`mla_expanded_prefill` would NOT run its Pallas kernel on
+    a chunk of ``T`` queries over ``S`` scratch rows (``None``: it would)."""
+    bq, bk, _ = _prefill_blocks(T, S, 1)
+    if d_qk % 128 or d_v % 128 or page % 128 or bq % 8 or bk % page:
+        return (f"(chunk={T}, extent={S}, qk={d_qk}, v={d_v}, page={page}) "
+                f"needs qk%128 == v%128 == page%128 == 0 and whole blocks "
+                f"of queries (a multiple of 8: {bq}) and of rows (whole "
+                f"pages: {bk})")
+    return None
+
+
+def _mla_prefill_xla(q, kv, sel, prefix_len, *, heads, d_qk, scale):
+    """Dense form of :func:`mla_expanded_prefill` (the interpreter-free
+    fallback and the kernel's oracle)."""
+    B, T, _ = q.shape
+    S = kv.shape[1]
+    q = q.reshape(B, T, heads, d_qk).astype(jnp.float32)
+    kv = kv.reshape(B, S, heads, -1).astype(jnp.float32)
+    logits = jnp.einsum("bthd,bshd->bhts", q, kv[..., :d_qk]) * scale
+    keep = sel.transpose(0, 2, 1, 3).reshape(B, T, S) >= 0.0
+    keep = keep & (jnp.arange(S)[None, None, :]
+                   <= prefix_len + jnp.arange(T)[None, :, None])
+    keep = keep[:, None]
+    logits = jnp.where(keep, logits, NEG_INF)
+    p = jnp.where(keep, jnp.exp(logits - jnp.max(logits, -1, keepdims=True)),
+                  0.0)
+    l = jnp.sum(p, -1, keepdims=True)
+    p = p / jnp.where(l > 0, l, 1.0)
+    return jnp.einsum("bhts,bshd->bthd", p,
+                      kv[..., d_qk:]).reshape(B, T, -1)
+
+
+def mla_expanded_prefill(q, kv, sel, prefix_len, *, heads: int, d_qk: int,
+                         scale: float, impl="auto", interpret=False):
+    """Causal attention of a prefill chunk over EXPANDED keys and values,
+    under a selection the heads share.
+
+    q [B, T, H * Dk] (query t sits at position ``prefix_len + t``), kv [B,
+    S, H * (Dk + Dv)] (row s at position s, a head's key beside its value;
+    rows past the chunk's end are never read), sel float32 [B, S // page,
+    T, page] in :func:`dsa_index_scores`' layout (a row counts where ``sel
+    >= 0`` AND the query may see it), prefix_len int32 scalar.  Returns
+    [B, T, H * Dv] in q's dtype; a query with nothing kept gives zeros.
+    """
+    B, T, HD = q.shape
+    S = kv.shape[1]
+    d_v = kv.shape[2] // heads - d_qk
+    n_pages, page = sel.shape[1], sel.shape[3]
+    assert HD == heads * d_qk and d_v > 0 and kv.shape == (
+        B, S, heads * (d_qk + d_v)), (q.shape, kv.shape, heads, d_qk)
+    assert sel.shape == (B, n_pages, T, page) and n_pages * page == S, (
+        sel.shape, S)
+    raw_impl = impl
+    impl = resolve_impl(impl, interpret)
+    gap = mla_prefill_gap(T, S, d_qk, d_v, page)
+    if use_fallback(raw_impl, impl, gap is None or interpret,
+                    MLA_PREFILL_CALL_NAME, gap or ""):
+        return _mla_prefill_xla(q, kv, sel, prefix_len, heads=heads,
+                                d_qk=d_qk, scale=scale).astype(q.dtype)
+    bq, bk, hb = _prefill_blocks(T, S, heads)
+    assert bk % page == 0, (bk, page)
+    n_k = S // bk
+    plen = jnp.asarray(prefix_len, jnp.int32).reshape(1)
+
+    def last_live(i, plen_ref):
+        # the last key block query block i may see
+        return jnp.minimum((plen_ref[0] + (i + 1) * bq - 1) // bk, n_k - 1)
+
+    kern = functools.partial(_mla_prefill_kernel, bq=bq, bk=bk, hb=hb,
+                             n_k=n_k, page=page, d_qk=d_qk, d_v=d_v,
+                             scale=scale)
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,  # prefix_len
+            grid=(B, heads // hb, T // bq, n_k),
+            in_specs=[
+                pl.BlockSpec((None, bq, hb * d_qk),
+                             lambda b, h, i, j, plen_ref: (b, i, h)),
+                pl.BlockSpec((None, bk, hb * (d_qk + d_v)),
+                             lambda b, h, i, j, plen_ref: (
+                                 b, jnp.minimum(j, last_live(i, plen_ref)),
+                                 h)),
+                pl.BlockSpec((None, bk // page, bq, page),
+                             lambda b, h, i, j, plen_ref: (
+                                 b, jnp.minimum(j, last_live(i, plen_ref)),
+                                 i, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, bq, hb * d_v),
+                                   lambda b, h, i, j, plen_ref: (b, i, h)),
+            scratch_shapes=[
+                pltpu.VMEM((hb, bq, d_v), jnp.float32),
+                pltpu.VMEM((hb, bq, 128), jnp.float32),
+                pltpu.VMEM((hb, bq, 128), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((B, T, heads * d_v), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "parallel",
+                                 "arbitrary"),
+            vmem_limit_bytes=MLA_PREFILL_VMEM),
+        interpret=maybe_interpret(interpret),
+        name=MLA_PREFILL_CALL_NAME,
+    )(plen, q, kv, sel)
+
+
+def _mla_prefill_kernel(plen_ref, q_ref, kv_ref, sel_ref, out_ref, acc_ref,
+                        m_ref, l_ref, *, bq, bk, hb, n_k, page, d_qk, d_v,
+                        scale):
+    """Grid (B, H // hb, T // bq, S // bk), the key axis innermost and
+    sequential: one step folds a block of ``bk`` expanded rows into the
+    online softmax of ``bq`` queries, ``hb`` heads one after the other
+    (each its own lanes of the blocks, the mask built once).  Key blocks
+    wholly past the query block's last position are skipped (their index
+    maps point at the last live block, so nothing is copied for them)."""
+    i, j = pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _():
+        _softmax_state_init(acc_ref, m_ref, l_ref)
+
+    q0 = plen_ref[0] + i * bq            # position of the block's first query
+    k0 = j * bk
+
+    @pl.when(k0 <= q0 + bq - 1)
+    def _():
+        kept = jnp.concatenate(
+            [sel_ref[g] for g in range(bk // page)], axis=-1)   # [bq, bk]
+        qpos = q0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        kpos = k0 + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        valid = (kept >= 0.0) & (kpos <= qpos)
+        w = d_qk + d_v
+        for h in range(hb):
+            _online_softmax_step(
+                q_ref[:, h * d_qk:(h + 1) * d_qk],
+                kv_ref[:, h * w:h * w + d_qk],
+                kv_ref[:, h * w + d_qk:(h + 1) * w], valid,
+                acc_ref.at[h], m_ref.at[h], l_ref.at[h], scale=scale,
+                soft_cap=0.0)
+
+    @pl.when(j == n_k - 1)
+    def _():
+        for h in range(hb):
+            out, _ = _softmax_state_emit(acc_ref.at[h], m_ref.at[h],
+                                         l_ref.at[h])
+            out_ref[:, h * d_v:(h + 1) * d_v] = out.astype(out_ref.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Learned sparse attention over a latent cache: the indexer's scores
+# ---------------------------------------------------------------------------
+#
+# Beside the latent row the cache holds one INDEX KEY a token a layer (128
+# wide at the published widths).  A query scores every cached token with a
+# few small heads, ``I(t, s) = sum_j w[t, j] * relu(qI[t, j] . kI[s])``,
+# and attends only to the ``index_topk`` best.  This call computes the
+# scores over the paged index-key plane: the same live-page walk as the
+# latent kernel, but a page is 32 KiB and one [heads, 128] x [128, page]
+# product, so ``DSA_PAGES_PER_STEP`` pages are multiplied at once and the
+# next group streams in under them.  The cut-off and what is read of the
+# latent plane afterwards are the caller's (models/mla_moe.py).
+
+DSA_INDEX_CALL_NAME = "dsa_index_scores"
+DSA_PAGES_PER_STEP = 8
+DSA_GROUPS_IN_FLIGHT = 3
+# Query rows (tokens x index heads) of one step: a decode row is 32, a
+# prefill chunk is cut into tiles of 16 tokens.
+DSA_Q_ROWS = 512
+
+
+def dsa_index_gap(page: int, dim: int) -> str | None:
+    """Why :func:`dsa_index_scores` would NOT run its Pallas kernel over
+    an index-key plane of this geometry (``None``: it would)."""
+    if page % 128 or dim % 128:
+        return (f"(page={page}, index_head_dim={dim}) needs page%128 == "
+                f"index_head_dim%128 == 0")
+    return None
+
+
+def _dsa_index_xla(qi, w, pool, block_table, local_lens, *, q_lens=None):
+    """Dense form of the index scores (the interpreter-free fallback and
+    the kernel's oracle) -> [B, T, n_pages, page]."""
+    keys = pool[block_table].astype(jnp.float32)       # [B, n, page, Di]
+    B, n, Pg, _ = keys.shape
+    T = qi.shape[1]
+    dots = jnp.einsum("bthd,bnpd->bthnp", qi.astype(jnp.float32), keys)
+    s = jnp.einsum("bth,bthnp->btnp", w.astype(jnp.float32),
+                   jnp.maximum(dots, 0.0))
+    valid = _visible(B, T, n, Pg, local_lens, q_lens).reshape(B, T, n, Pg)
+    return jnp.where(valid, s, NEG_INF)
+
+
+def dsa_index_scores(qi, w, pool, block_table, local_lens, *, q_lens=None,
+                     impl="auto", interpret=False):
+    """The indexer's scores of every cached token, over a PAGED index-key
+    plane.
+
+    qi [B, T, Hi, Di] (the index queries, RoPE applied), w [B, T, Hi]
+    float32 (the head weights), pool [N, page, Di] (index keys),
+    block_table [B, n_pages], local_lens [B] valid rows INCLUDING the
+    queries' own (query t of row b sits at ``local_lens[b] - (T or
+    q_lens[b]) + t``, as in :func:`mla_decode_paged_shard`).  Returns
+    float32 ``sum_j w[.., j] * relu(qi[.., j] . key)`` per cached token,
+    ``NEG_INF`` where the query may not see it, laid out by page as the
+    latent call's ``sel`` wants it: [B, n_pages, page] at T == 1,
+    [B, n_pages, T, page] above.
+    """
+    B, T, Hi, Di = qi.shape
+    N, Pg, Di2 = pool.shape
+    assert Di == Di2 and w.shape == (B, T, Hi), (qi.shape, w.shape,
+                                                 pool.shape)
+    n_pages = block_table.shape[1]
+    raw_impl = impl
+    impl = resolve_impl(impl, interpret)
+    gap = dsa_index_gap(Pg, Di)
+    if use_fallback(raw_impl, impl, gap is None or interpret,
+                    DSA_INDEX_CALL_NAME, gap or ""):
+        s = _dsa_index_xla(qi, w, pool, block_table, local_lens,
+                           q_lens=q_lens)
+        return s[:, 0] if T == 1 else s.transpose(0, 2, 1, 3)
+
+    lens_arg, use_qlens = _pack_lens_arg(local_lens, None, q_lens,
+                                         n_tok=T, window=0)
+    tq = _tokens_per_tile(T, Hi, DSA_Q_ROWS, blocked=True)
+    rows = tq * Hi
+    G = next(g for g in range(min(DSA_PAGES_PER_STEP, n_pages), 0, -1)
+             if n_pages % g == 0)
+    depth = max(2, DSA_GROUPS_IN_FLIGHT)
+    kern = functools.partial(_dsa_index_kernel, page=Pg, n_pages=n_pages,
+                             n_tok=T, tq=tq, heads=Hi, use_qlens=use_qlens,
+                             G=G, depth=depth)
+    single = T == 1
+    return pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # (lens, block_table)
+            grid=(B, T // tq),
+            in_specs=[
+                pl.BlockSpec((1, rows, Di),
+                             lambda b, j, lens, tab: (b, j, 0)),
+                pl.BlockSpec((1, rows, 1),
+                             lambda b, j, lens, tab: (b, j, 0)),
+                pl.BlockSpec(memory_space=pl.ANY),    # the plane stays in HBM
+            ],
+            out_specs=(pl.BlockSpec((1, n_pages, Pg),
+                                    lambda b, j, lens, tab: (b, 0, 0))
+                       if single else
+                       pl.BlockSpec((1, n_pages, tq, Pg),
+                                    lambda b, j, lens, tab: (b, 0, j, 0))),
+            scratch_shapes=[
+                pltpu.VMEM((depth, G * Pg, Di), pool.dtype),
+                pltpu.SemaphoreType.DMA((depth, G)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (B, n_pages, Pg) if single else (B, n_pages, T, Pg),
+            jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=maybe_interpret(interpret),
+        name=DSA_INDEX_CALL_NAME,
+    )(lens_arg, block_table, qi.reshape(B, T * Hi, Di),
+      w.astype(jnp.float32).reshape(B, T * Hi, 1), pool)
+
+
+def _dsa_index_kernel(lens_ref, table_ref, q_ref, w_ref, pool_hbm, out_ref,
+                      buf, sem, *, page, n_pages, n_tok, tq, heads,
+                      use_qlens, G, depth):
+    """Grid (B, T // tq); one step is ``tq`` query tokens of one batch row
+    under every index head, walking the row's live pages ``G`` at a time
+    (``G`` divides ``n_pages``; a group's dead pages are copied too — the
+    table pads with the null block — and masked), ``depth - 1`` groups
+    copied ahead of the one being multiplied."""
+    b = pl.program_id(0)
+    llen, wlen, qlen_t, hi = _tile_walk(
+        lens_ref, b, pl.program_id(1) * tq, tq=tq, page=page,
+        n_pages=n_pages, n_tok=n_tok, use_qlens=use_qlens)
+    n_groups = (hi + G - 1) // G
+
+    def copies(g, slot):
+        return [pltpu.make_async_copy(
+            pool_hbm.at[table_ref[b, g * G + j]],
+            buf.at[slot, pl.ds(j * page, page)], sem.at[slot, j])
+            for j in range(G)]
+
+    for d in range(depth - 1):
+        @pl.when(d < n_groups)
+        def _():
+            for c in copies(d, d):
+                c.start()
+
+    out_ref[...] = jnp.full(out_ref.shape, NEG_INF, out_ref.dtype)
+
+    def group_step(g, _):
+        slot = jax.lax.rem(g, depth)
+        ahead = g + depth - 1
+
+        @pl.when(ahead < n_groups)
+        def _():
+            for c in copies(ahead, jax.lax.rem(ahead, depth)):
+                c.start()
+
+        for c in copies(g, slot):
+            c.wait()
+        dots = jax.lax.dot_general(
+            q_ref[0], buf[slot], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [rows, G * page]
+        s = jnp.maximum(dots, 0.0) * w_ref[0]
+        if tq == 1:
+            s = jnp.sum(s, axis=0, keepdims=True)
+        else:
+            s = jnp.sum(s.reshape(tq, heads, G * page), axis=1)
+        pos = g * (G * page) + jax.lax.broadcasted_iota(
+            jnp.int32, (tq, G * page), 1)
+        valid = _chunk_valid(pos, llen, wlen, qlen_t, window=0, group=1)
+        s = jnp.where(valid, s, NEG_INF)
+        for j in range(G):
+            part = s[:, j * page:(j + 1) * page]
+            if n_tok == 1:
+                out_ref[0, pl.ds(g * G + j, 1), :] = part
+            else:
+                out_ref[0, g * G + j] = part
+
+    jax.lax.fori_loop(0, n_groups, group_step, None)
 
 
 def sp_gqa_decode_paged_shard(q, k_pool, v_pool, block_table, kv_lens, *,

@@ -105,7 +105,9 @@ class Generator:
         # one compiled scan per distinct call signature.
         self._onchip_cache: dict = {}
 
-    # -- the engine's view (``MlaMoeGenerator`` has the same four) ---------
+    # -- the engine's view (``MlaMoeGenerator`` has the same five) ---------
+
+    latent = False  # K and V planes (``MlaMoeGenerator``: latent pools)
 
     @property
     def kv_planes(self) -> list:

@@ -12,7 +12,8 @@ The block, per layer: ``x += W_o . MLA(rms(x))``, ``x += FFN(rms(x))``.
   runs in the ABSORBED form: ``q~_h = q_nope_h W_UK,h^T`` scores against
   the whole row, the value is the row's ``c_kv`` part, and ``W_UV,h`` is
   applied to the result (``out_proj``) — the expanded K and V (``c_kv
-  W_kvb``) are never formed.  RoPE is YaRN-scaled (:func:`yarn_inv_freq`).
+  W_kvb``) are never cached, and formed only for a sparse block's long
+  prefill chunk (below).  RoPE is YaRN-scaled (:func:`yarn_inv_freq`).
 * **Expert layer.**  ``noaux_tc`` routing: sigmoid scores, a bias that
   enters the SELECTION only, group-limited top-k, weights renormalised
   and scaled; a shared expert beside the routed ones.  The layer is told
@@ -22,6 +23,22 @@ The block, per layer: ``x += W_o . MLA(rms(x))``, ``x += FFN(rms(x))``.
   and leaves the rest out — what the absent experts would add is absent,
   nothing stands in for the other chips.  Leading layers
   (``first_k_dense``) carry a dense SwiGLU MLP instead.
+
+* **Learned sparse attention** (``index_topk > 0``: the ``glm_moe_dsa``
+  block).  Beside the latent row a layer caches one INDEX KEY a token
+  (``LayerNorm(W_ik x)``, RoPE on its leading columns); a query scores
+  every cached token with a few small heads off the same query latent,
+  ``I(t, s) = sum_j w[t, j] relu(qI[t, j] . kI[s])``, and attends to the
+  ``index_topk`` best only (all of them while fewer are cached).  The
+  cache is then TWO planes of different widths a layer, written by the one
+  ``write_kv`` seam; the scores are a paged call of their own
+  (``dsa_index_scores``), the cut-off is the k-th value by bisection on
+  counts (as ``models/sampling.py``), and the latent call walks the row's
+  pages with the selection as a mask (:func:`paged_attend`).  A prefill
+  chunk of ``PREFILL_EXPAND_MIN`` queries or more is bound by its products,
+  not by the cache's bytes, and attends in the EXPANDED form instead
+  (:func:`attend_prefix`: the scratch through ``W_UK`` / ``W_UV`` once a
+  chunk, flash attention a head under the same selection).
 
 Everything enters the engine's programs through the seams of
 ``models/generate.py`` (``project`` / ``write_kv`` / ``attend`` /
@@ -41,8 +58,12 @@ import numpy as np
 
 from triton_dist_tpu.kernels import moe_utils
 from triton_dist_tpu.kernels.flash_decode import (
+    dsa_index_gap,
+    dsa_index_scores,
     mla_decode_paged_shard,
+    mla_expanded_prefill,
     mla_kernel_gap,
+    mla_prefill_gap,
 )
 from triton_dist_tpu.kernels.gemm import resolve_impl
 from triton_dist_tpu.kernels.group_gemm import group_gemm_live
@@ -52,16 +73,35 @@ from triton_dist_tpu.models.generate import (
     _prompt_forward,
 )
 from triton_dist_tpu.models.llama import _rms_norm
+from triton_dist_tpu.models.sampling import (
+    _from_ordered_bits,
+    _largest_threshold,
+    _ordered_bits,
+)
 from triton_dist_tpu.runtime.jit_cache import named
 
 LANES = 128
 GATE_UP_CALL, DOWN_CALL = "moe_gate_up", "moe_down"
+# A sparse block's prefill chunk of at least this many queries attends in
+# the EXPANDED form (:func:`attend_prefix`): under it the expansion of the
+# whole scratch, once a chunk, outweighs what the cheaper pairs save (29.4 M
+# operations a cached row against 74 k a query saved), and verify rows stay
+# on the decode path's kernel.
+PREFILL_EXPAND_MIN = 256
 
 
 class LatentPoolUnsupported(NotImplementedError):
     """A serving feature that has not been carried over to latent (MLA)
     pools was asked for: raised where the engine or generator is built,
     or where the entry point is called — never a quiet fallback."""
+
+
+# model_type -> the indexer keys its config.json may carry
+_MODEL_TYPES = {
+    "deepseek_v3": frozenset(),
+    "glm_moe_dsa": frozenset({"index_n_heads", "index_head_dim",
+                              "index_topk", "indexer_rope_interleave"}),
+}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,6 +135,12 @@ class MlaMoeConfig:
     max_seq: int = 2048
     dtype: object = jnp.float32
     moe_block_m: int = 32       # grouped-GEMM row tile (see routed_experts)
+    rope_interleave: bool = False   # rotary pairs (2i, 2i + 1), else halves
+    # learned sparse attention (0 heads / 0 rows: none)
+    index_n_heads: int = 0
+    index_head_dim: int = 128
+    index_topk: int = 0
+    index_norm_eps: float = 1e-6    # the index key's LayerNorm
 
     # -- what the serving engine reads of a model config -------------------
     @property
@@ -120,6 +166,20 @@ class MlaMoeConfig:
             s *= _yarn_mscale(factor, all_dim) ** 2
         return s
 
+    @property
+    def sparse(self) -> bool:
+        """Learned sparse attention: an indexer beside every layer's
+        latent attention and an index-key plane in its cache."""
+        return self.index_topk > 0
+
+    def expands(self, n_queries: int) -> bool:
+        """Whether a sparse block's ``n_queries`` of one row attend in the
+        EXPANDED form (:func:`attend_prefix`).  Never where a head's value
+        is as wide as the latent row: ``out_proj`` tells the two results
+        apart by their width."""
+        return (self.sparse and n_queries >= PREFILL_EXPAND_MIN
+                and self.v_head_dim != self.kv_lora_rank)
+
     def is_moe_layer(self, li: int) -> bool:
         return li >= self.first_k_dense
 
@@ -127,10 +187,21 @@ class MlaMoeConfig:
     def from_hf(c: dict, *, max_seq: int, dtype=jnp.bfloat16,
                 experts_total: int | None = None, expert_offset: int = 0,
                 **over) -> "MlaMoeConfig":
-        """From the keys of a ``deepseek_v3`` ``config.json``.  In a
-        share's file ``n_routed_experts`` counts the experts HELD and
-        ``vocab_size`` the rows held; ``experts_total`` is the router's
-        published width."""
+        """From the keys of a ``deepseek_v3`` or a ``glm_moe_dsa``
+        ``config.json`` (docs/serving.md lists them).  In a share's file
+        ``n_routed_experts`` counts the experts HELD and ``vocab_size``
+        the rows held; ``experts_total`` is the router's published width.
+        An unknown ``model_type`` or ``index*`` key is refused by name."""
+        kind = c.get("model_type", "deepseek_v3")
+        if kind not in _MODEL_TYPES:
+            raise ValueError(f"model_type {kind!r}: served are "
+                             f"{sorted(_MODEL_TYPES)}")
+        unknown = sorted(k for k in c if k.startswith("index")
+                         and k not in _MODEL_TYPES[kind])
+        if unknown:
+            raise ValueError(
+                f"{unknown}: not an indexer key this {kind} block serves "
+                f"({sorted(_MODEL_TYPES[kind]) or 'it has no indexer'})")
         rs = c.get("rope_scaling")
         yarn = None
         if rs:
@@ -141,6 +212,19 @@ class MlaMoeConfig:
                     float(rs["beta_fast"]), float(rs["beta_slow"]),
                     float(rs.get("mscale", 1.0)),
                     float(rs.get("mscale_all_dim", 0.0)))
+        rp = c.get("rope_parameters") or {}
+        if rp.get("rope_type", "default") != "default":
+            raise ValueError(f"rope_parameters {rp!r}: only the default "
+                             f"(plain) RoPE is served there")
+        if kind == "glm_moe_dsa":
+            inter = bool(c.get("rope_interleave", False))
+            if bool(c.get("indexer_rope_interleave", inter)) != inter:
+                raise ValueError("indexer_rope_interleave must equal "
+                                 "rope_interleave: one pairing is served")
+            over = dict(rope_interleave=inter,
+                        index_n_heads=c["index_n_heads"],
+                        index_head_dim=c["index_head_dim"],
+                        index_topk=c["index_topk"], **over)
         for key, want in (("scoring_func", "sigmoid"),
                           ("topk_method", "noaux_tc"),
                           ("hidden_act", "silu")):
@@ -165,7 +249,8 @@ class MlaMoeConfig:
             top_k=c["num_experts_per_tok"],
             routed_scaling=float(c["routed_scaling_factor"]),
             norm_topk_prob=bool(c["norm_topk_prob"]),
-            rope_theta=float(c["rope_theta"]), yarn=yarn,
+            rope_theta=float(rp.get("rope_theta", c.get("rope_theta"))),
+            yarn=yarn,
             norm_eps=float(c["rms_norm_eps"]), max_seq=max_seq,
             dtype=dtype, **over)
 
@@ -183,6 +268,14 @@ class MlaMoeConfig:
                   max_seq=512, dtype=dtype, moe_block_m=8)
         kw.update(over)
         return MlaMoeConfig(**kw)
+
+    @staticmethod
+    def tiny_sparse(dtype=jnp.float32, **over) -> "MlaMoeConfig":
+        """:meth:`tiny` with an indexer whose ``index_topk`` lies well
+        under the test contexts, plain interleaved RoPE."""
+        return MlaMoeConfig.tiny(dtype, **{**dict(
+            yarn=None, rope_theta=1e4, rope_interleave=True,
+            index_n_heads=16, index_head_dim=128, index_topk=48), **over})
 
 
 # ---------------------------------------------------------------------------
@@ -223,12 +316,18 @@ def yarn_cos_sin_scale(cfg: MlaMoeConfig) -> float:
     return _yarn_mscale(factor, mscale) / _yarn_mscale(factor, all_dim)
 
 
-def _rope(x, pos, inv_freq, scale):
-    """x [B, T, H, d] at positions pos [B, T] (or [1, T]); rotate-half."""
+def _rope(x, pos, inv_freq, scale, interleave=False):
+    """x [B, T, H, d] at positions pos [B, T] (or [1, T]).  Pair i is the
+    columns (i, i + d/2) (rotate-half) or, ``interleave``, (2i, 2i + 1) as
+    ``glm_moe_dsa`` publishes them; the result is laid out by halves
+    either way (q and k alike, so every dot product is the published
+    one)."""
     ang = pos[..., None].astype(jnp.float32) * inv_freq       # [B, T, d/2]
     cos = (jnp.cos(ang) * scale)[:, :, None, :]
     sin = (jnp.sin(ang) * scale)[:, :, None, :]
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    xf = x.astype(jnp.float32)
+    x1, x2 = ((xf[..., 0::2], xf[..., 1::2]) if interleave
+              else jnp.split(xf, 2, axis=-1))
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
                            axis=-1).astype(x.dtype)
 
@@ -256,6 +355,17 @@ def _attn_matrices(c: MlaMoeConfig) -> dict:
         "wkv_b": (3, c.kv_lora_rank,
                   (c.kv_lora_rank, H * (c.qk_nope_head_dim + c.v_head_dim))),
         "wo": (4, H * c.v_head_dim, (H * c.v_head_dim, D)),
+    }
+
+
+def _index_matrices(c: MlaMoeConfig) -> dict:
+    """The indexer's: its heads' queries off the query latent, the one
+    key a token and the head weights off the layer's input."""
+    return {
+        "idx_wq": (13, c.q_lora_rank,
+                   (c.q_lora_rank, c.index_n_heads * c.index_head_dim)),
+        "idx_wk": (14, c.dim, (c.dim, c.index_head_dim)),
+        "idx_ww": (15, c.dim, (c.dim, c.index_n_heads)),
     }
 
 
@@ -313,6 +423,10 @@ def init_params(cfg: MlaMoeConfig, key) -> dict:
                      mlp_norm=jnp.ones((c.dim,), dt),
                      q_norm=jnp.ones((c.q_lora_rank,), dt),
                      kv_norm=jnp.ones((R,), dt))
+        if c.sparse:
+            layer.update(mats(lk, _index_matrices(c)),
+                         idx_k_norm=jnp.ones((c.index_head_dim,), dt),
+                         idx_k_bias=jnp.zeros((c.index_head_dim,), dt))
         if not c.is_moe_layer(li):
             layer.update(mats(lk, _mlp_matrices(c.dim, c.ffn_dim, 5)))
         else:
@@ -346,7 +460,14 @@ def project(h, layer, pos, *, cfg: MlaMoeConfig):
     """The attention's front half.  h [B, T, D], pos [B, T] (or [1, T])
     -> (q [B, T, H, W] absorbed ``[q_nope W_UK | q_rope | 0]``, latent
     [B, T, 1, W] ``[rms(c_kv) | rope(k_r) | 0]``, None): there is no V —
-    the value is the first ``kv_lora_rank`` columns of the same row."""
+    the value is the first ``kv_lora_rank`` columns of the same row.
+
+    With an indexer (``cfg.sparse``) the query is the triple ``(q, qI
+    [B, T, Hi, Di], w [B, T, Hi] float32)`` and the third result is the
+    token's index key [B, T, 1, Di], the cache's second plane.  A chunk of
+    ``PREFILL_EXPAND_MIN`` queries or more carries a fourth member,
+    what the expanded form needs of the layer: ``(q [B, T, H, nope + rope]
+    un-absorbed, W_UK, W_UV)``."""
     c = cfg
     B, T, D = h.shape
     H, R, dn, dr = (c.n_heads, c.kv_lora_rank, c.qk_nope_head_dim,
@@ -357,21 +478,47 @@ def project(h, layer, pos, *, cfg: MlaMoeConfig):
     q = (cq @ layer["wq_b"]).reshape(B, T, H, dn + dr)
     ckv = h2 @ layer["wkv_a"]                          # [B*T, R + dr]
     c_kv = _rms_norm(ckv[:, :R], layer["kv_norm"], c.norm_eps)
-    k_r = _rope(ckv[:, R:].reshape(B, T, 1, dr), pos, inv_freq, cs)
-    q_r = _rope(q[..., dn:], pos, inv_freq, cs)
+    rope = functools.partial(_rope, pos=pos, inv_freq=inv_freq, scale=cs,
+                             interleave=c.rope_interleave)
+    k_r = rope(ckv[:, R:].reshape(B, T, 1, dr))
+    q_r = rope(q[..., dn:])
     q_abs = jnp.einsum("bthn,hnr->bthr", q[..., :dn], layer["w_uk"])
     pad = c.head_dim - c.latent_width
     zq = [jnp.zeros((B, T, H, pad), q.dtype)] if pad else []
     zk = [jnp.zeros((B, T, 1, pad), q.dtype)] if pad else []
-    return (jnp.concatenate([q_abs, q_r] + zq, axis=-1),
-            jnp.concatenate([c_kv.reshape(B, T, 1, R), k_r] + zk, axis=-1),
-            None)
+    q_abs = jnp.concatenate([q_abs, q_r] + zq, axis=-1)
+    latent = jnp.concatenate([c_kv.reshape(B, T, 1, R), k_r] + zk, axis=-1)
+    if not c.sparse:
+        return q_abs, latent, None
+    Hi, Di = c.index_n_heads, c.index_head_dim
+    qi = (cq @ layer["idx_wq"]).reshape(B, T, Hi, Di)
+    qi = jnp.concatenate([rope(qi[..., :dr]), qi[..., dr:]], axis=-1)
+    ki = jnp.dot(h2, layer["idx_wk"], preferred_element_type=jnp.float32)
+    mu = jnp.mean(ki, -1, keepdims=True)
+    ki = ((ki - mu) * jax.lax.rsqrt(
+        jnp.mean(jnp.square(ki - mu), -1, keepdims=True) + c.index_norm_eps)
+        * layer["idx_k_norm"].astype(jnp.float32)
+        + layer["idx_k_bias"].astype(jnp.float32)).reshape(B, T, 1, Di)
+    ki = jnp.concatenate([rope(ki[..., :dr]), ki[..., dr:]],
+                         axis=-1).astype(h.dtype)
+    w = jnp.dot(h2, layer["idx_ww"],
+                preferred_element_type=jnp.float32) * Hi ** -0.5
+    query = (q_abs, qi, w.reshape(B, T, Hi))
+    if c.expands(T):
+        query += ((jnp.concatenate([q[..., :dn], q_r], axis=-1),
+                   layer["w_uk"], layer["w_uv"]),)
+    return query, latent, ki
 
 
 def out_proj(o2, layer, *, cfg: MlaMoeConfig):
     """o2 [rows, H * rank] (the latent-space attention result) ->
-    [rows, D]: ``W_UV`` per head, then ``W_o``."""
+    [rows, D]: ``W_UV`` per head, then ``W_o``.  The expanded form's
+    result [rows, H * v] has been through ``W_UV`` already (the two widths
+    differ wherever :meth:`MlaMoeConfig.expands`)."""
     rows = o2.shape[0]
+    if (o2.shape[1] == cfg.n_heads * cfg.v_head_dim
+            and cfg.v_head_dim != cfg.kv_lora_rank):
+        return o2 @ layer["wo"]
     o = jnp.einsum("rhc,hcv->rhv",
                    o2.reshape(rows, cfg.n_heads, cfg.kv_lora_rank),
                    layer["w_uv"])
@@ -457,24 +604,34 @@ def routed_experts(h2, layer, cfg: MlaMoeConfig, *, impl="auto",
 
 
 class MoeTally:
-    """Trace-time collector of the expert layers' ``stats`` of ONE
-    program: the ``ffn`` seam returns activations only, so each expert
-    layer leaves its counts here and :func:`with_moe_stats` hands their
-    sum out as the program's last output."""
+    """Trace-time collector of the counts the layers of ONE program
+    leave behind: the seams return activations only, so each expert layer
+    leaves its ``stats`` here (``rows``), each sparse attention call its
+    own (``dsa``, when the block has an indexer), and
+    :func:`with_moe_stats` hands their sums out as the program's last
+    output — int32 [4], or [8] with the indexer's four behind."""
 
-    def __init__(self):
+    def __init__(self, sparse: bool = False):
+        self.sparse = sparse
         self.rows: list = []
+        self.dsa: list = []
+
+    def clear(self):
+        self.rows.clear()
+        self.dsa.clear()
 
     def drain(self):
-        rows, self.rows = self.rows, []
-        return sum(rows) if rows else jnp.zeros((4,), jnp.int32)
+        out = [sum(got) if got else jnp.zeros((4,), jnp.int32)
+               for got in (self.rows, self.dsa)[:1 + self.sparse]]
+        self.clear()
+        return jnp.concatenate(out)
 
 
 def with_moe_stats(fwd, tally: MoeTally):
-    """``fwd`` with the tally's sum appended to its outputs."""
+    """``fwd`` with the tally's sums appended to its outputs."""
     @functools.wraps(fwd)
     def run(*args, **kwargs):
-        tally.rows.clear()      # a trace that raised may have left some
+        tally.clear()           # a trace that raised may have left some
         out = fwd(*args, **kwargs)
         return (*out, tally.drain())
     return run
@@ -501,17 +658,81 @@ def ffn(h2, layer, *, cfg: MlaMoeConfig, tally: MoeTally | None = None,
 # ---------------------------------------------------------------------------
 
 
+def _kth_largest(x, k: int, axes: tuple):
+    """The k-th largest value of float32 ``x`` over ``axes`` (kept as
+    ones): the largest ``t`` with ``count(x >= t) >= k``, by the sampler's
+    bisection over a float32's 32 bits — 32 compare-and-count passes,
+    nothing sorted (``models/sampling.py`` has the measurement).  Fewer
+    than ``k`` finite members give a value at or under the masked ones'."""
+    shape = tuple(1 if a in axes else d for a, d in enumerate(x.shape))
+    return _from_ordered_bits(_largest_threshold(
+        _ordered_bits(x),
+        lambda kept: kept.sum(axes, keepdims=True) >= k, shape))
+
+
+def _dsa_stats(visible, k: int, rows: bool):
+    """int32 [4] of one sparse attention call, ``visible`` the cached
+    tokens each query may see: tokens the indexer scored, rows the
+    attention read, and (``rows``: decode programs) the queries whose
+    context lies past / at or under ``index_topk``."""
+    v = visible.astype(jnp.int32).reshape(-1)
+    past = jnp.sum((v > k).astype(jnp.int32))
+    return jnp.stack([jnp.sum(v), jnp.sum(jnp.minimum(v, k)),
+                      past if rows else jnp.int32(0),
+                      jnp.sum((v > 0).astype(jnp.int32)) - past if rows
+                      else jnp.int32(0)])
+
+
+def _attend_pages(q, planes, tables, lens, *, cfg: MlaMoeConfig, impl,
+                  interpret, q_lens=None):
+    """Latent attention of q [B, T, ...] over paged ``planes`` ([N, page,
+    W] latent rows; with an indexer q is ``project``'s triple and a
+    second plane [N, page, Di] holds the index keys) -> [B, T, H, rank].
+
+    Sparse: the indexer's scores of every visible row (one paged call),
+    each query's cut-off at its ``index_topk``-th score, then the latent
+    call's page walk with ``score - cut-off`` as its selection mask — the
+    same mathematics as reading the selected rows alone, dense bytes.  A
+    table that cannot hold more than ``index_topk`` rows takes the dense
+    call as it is (every visible row is selected)."""
+    kw = dict(rank=cfg.kv_lora_rank, scale=cfg.softmax_scale, q_lens=q_lens,
+              impl=impl, interpret=interpret)
+    if not cfg.sparse:
+        return mla_decode_paged_shard(q, planes[0], tables, lens, **kw)
+    q, qi, w = q[:3]
+    T = q.shape[1]
+    if tables.shape[1] * planes[0].shape[1] <= cfg.index_topk:
+        return mla_decode_paged_shard(q, planes[0], tables, lens, **kw)
+    scores = dsa_index_scores(qi, w, planes[1], tables, lens, q_lens=q_lens,
+                              impl=impl, interpret=interpret)
+    if T == 1:      # [B, n_pages, page]: one flat row a query
+        cut = _kth_largest(scores.reshape(scores.shape[0], -1),
+                           cfg.index_topk, (1,))[:, :, None]
+    else:           # [B, n_pages, T, page]
+        cut = _kth_largest(scores, cfg.index_topk, (1, 3))
+    return mla_decode_paged_shard(q, planes[0], tables, lens,
+                                  sel=scores - cut, **kw)
+
+
 def paged_attend(q, pool, tables, lens, *, cfg: MlaMoeConfig, impl,
-                 interpret, q_lens=None):
-    """The engine's paged attend over ONE layer's latent pool
-    ``(plane [N, 1, page, W],)``: q [B, (T,) H, W] -> [B, (T,) H, rank]."""
-    plane = pool[0]
-    N, _, page, W = plane.shape
-    single = q.ndim == 3
-    out = mla_decode_paged_shard(
-        q[:, None] if single else q, plane.reshape(N, page, W), tables,
-        lens, rank=cfg.kv_lora_rank, scale=cfg.softmax_scale,
-        q_lens=q_lens, impl=impl, interpret=interpret)
+                 interpret, q_lens=None, tally: MoeTally | None = None):
+    """The engine's paged attend over ONE layer's latent pool (planes [N,
+    1, page, .]: the latent rows, and the index keys of a sparse block):
+    q [B, (T,) H, W] — ``project``'s triple when sparse — -> [B, (T,) H,
+    rank]."""
+    single = jax.tree.leaves(q)[0].ndim == 3
+    if single:
+        q = jax.tree.map(lambda t: t[:, None], q)
+    T = jax.tree.leaves(q)[0].shape[1]
+    if cfg.sparse and tally is not None:
+        first = lens - (T if q_lens is None else q_lens)
+        tally.dsa.append(_dsa_stats(
+            jnp.where(lens[:, None] > 0,
+                      first[:, None] + 1 + jnp.arange(T), 0),
+            cfg.index_topk, rows=True))
+    out = _attend_pages(
+        q, [p.reshape(p.shape[0], *p.shape[2:]) for p in pool], tables,
+        lens, cfg=cfg, impl=impl, interpret=interpret, q_lens=q_lens)
     return out[:, 0] if single else out
 
 
@@ -519,30 +740,90 @@ def _scratch_block(ext: int) -> int:
     return next(b for b in (128, 64, 32, 16, 8, 4, 2, 1) if ext % b == 0)
 
 
-def attend_prefix(q, lat, prefix_len, *, cfg: MlaMoeConfig, impl, interpret,
-                  k_scale=None, v_scale=None):
-    """Chunk attention of ``generate._chunk_forward`` over a CONTIGUOUS
-    latent scratch lat [B, 1, S, W] (the chunk's rows already written at
-    ``prefix_len``): the scratch read as pages under an identity table,
-    through the same kernel in its multi-token form."""
-    B, c = q.shape[0], q.shape[1]
-    S, W = lat.shape[2], lat.shape[3]
+def attend_prefix(q, *views_and_len, cfg: MlaMoeConfig, impl, interpret,
+                  k_scale=None, v_scale=None, tally: MoeTally | None = None):
+    """Chunk attention of ``generate._chunk_forward`` over CONTIGUOUS
+    scratch planes [B, 1, S, .] (the latent rows, and the index keys of a
+    sparse block; the chunk's rows already written at ``prefix_len``, the
+    last positional): the scratch read as pages under an identity table,
+    through the same calls in their multi-token form.
+
+    A sparse block's chunk that carries ``project``'s fourth member (and a
+    scratch longer than ``index_topk``) attends in the EXPANDED form: the
+    scratch's rows through ``W_UK`` / ``W_UV`` once a chunk, then flash
+    attention a head over its own keys and values under the indexer's
+    selection — 1,024 operations a query-key pair a head where the
+    absorbed page walk pays 2,176 (``kernels/flash_decode.py``).  Its
+    result is in VALUE space, [B, T, H, v]: ``out_proj`` tells by width."""
+    *views, prefix_len = views_and_len
+    B, c = jax.tree.leaves(q)[0].shape[:2]
+    S = views[0].shape[2]
     page = _scratch_block(S)
     n = S // page
     tables = (jnp.arange(B, dtype=jnp.int32)[:, None] * n
               + jnp.arange(n, dtype=jnp.int32)[None, :])
     lens = jnp.full((B,), c, jnp.int32) + prefix_len
-    return mla_decode_paged_shard(
-        q, lat.reshape(B * n, page, W), tables, lens,
-        rank=cfg.kv_lora_rank, scale=cfg.softmax_scale, impl=impl,
-        interpret=interpret)
+    if cfg.sparse and tally is not None:
+        tally.dsa.append(_dsa_stats(
+            jnp.broadcast_to(prefix_len + 1 + jnp.arange(c), (B, c)),
+            cfg.index_topk, rows=False))
+    planes = [v.reshape(B * n, page, v.shape[3]) for v in views]
+    if not (cfg.sparse and len(q) == 4 and S > cfg.index_topk):
+        return _attend_pages(q, planes, tables, lens, cfg=cfg, impl=impl,
+                             interpret=interpret)
+    _, qi, w, (q_raw, w_uk, w_uv) = q
+    kw = dict(impl=impl, interpret=interpret)
+    scores = dsa_index_scores(qi, w, planes[1], tables, lens, **kw)
+    cut = _cutoffs(scores, cfg.index_topk)
+    H, dk = cfg.n_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+    kv = views[0][:, 0] @ _expansion(w_uk, w_uv, cfg.qk_rope_head_dim,
+                                     views[0].shape[3])
+    return mla_expanded_prefill(
+        q_raw.reshape(B, c, H * dk), kv, scores - cut, prefix_len, heads=H,
+        d_qk=dk, scale=cfg.softmax_scale, **kw).reshape(B, c, H, -1)
 
 
-def attend_prompt(q, lat, *, cfg: MlaMoeConfig, impl, interpret):
+def _expansion(w_uk, w_uv, dr: int, width: int):
+    """[width, H * (nope + rope + v)]: what takes a cache row ``[c_kv |
+    k_rope | 0]`` to every head's key ``[W_UK c_kv | k_rope]`` beside its
+    value ``W_UV c_kv`` in one product."""
+    H, dn, R = w_uk.shape
+    eye = jnp.broadcast_to(jnp.eye(dr, dtype=w_uk.dtype)[:, None], (dr, H, dr))
+    top = jnp.concatenate([w_uk.transpose(2, 0, 1),
+                           jnp.zeros((R, H, dr), w_uk.dtype),
+                           w_uv.transpose(1, 0, 2)], axis=-1)
+    mid = jnp.concatenate([jnp.zeros((dr, H, dn), w_uk.dtype), eye,
+                           jnp.zeros((dr, H, w_uv.shape[2]), w_uk.dtype)],
+                          axis=-1)
+    rest = jnp.zeros((width - R - dr, H, top.shape[2]), w_uk.dtype)
+    return jnp.concatenate([top, mid, rest], axis=0).reshape(width, -1)
+
+
+# The cut-off's 32 passes read the scores 32 times: a block that stays in
+# VMEM between them (the chip has 128 MiB) costs a tenth of one that does
+# not (PERF.md §6, PR 30: 0.63 ms over 67 MB, 6.2 ms over 134 MB).
+CUT_BLOCK_BYTES = 72 * 2 ** 20
+
+
+def _cutoffs(scores, k: int):
+    """Each query's k-th largest score: [B, n_pages, T, page] -> [B, 1, T,
+    1], the queries taken ``CUT_BLOCK_BYTES`` of scores at a time."""
+    B, n, T, page = scores.shape
+    tb = next(t for t in range(T, 0, -1) if T % t == 0
+              and (t == 1 or B * n * t * page * 4 <= CUT_BLOCK_BYTES))
+    if tb == T:
+        return _kth_largest(scores, k, (1, 3))
+    blocks = scores.reshape(B, n, T // tb, tb, page).transpose(2, 0, 1, 3, 4)
+    cuts = jax.lax.map(lambda s: _kth_largest(s, k, (1, 3)), blocks)
+    return cuts.transpose(1, 2, 0, 3, 4).reshape(B, 1, T, 1)
+
+
+def attend_prompt(q, *rows, cfg: MlaMoeConfig, impl, interpret):
     """Whole-prompt causal attention of ``generate._prompt_forward``:
-    q [B, S, H, W] over its own latent rows lat [B, S, 1, W]."""
-    return attend_prefix(q, lat.transpose(0, 2, 1, 3), jnp.int32(0),
-                         cfg=cfg, impl=impl, interpret=interpret)
+    q [B, S, H, W] over its own cache rows [B, S, 1, .] a plane."""
+    return attend_prefix(q, *(r.transpose(0, 2, 1, 3) for r in rows),
+                         jnp.int32(0), cfg=cfg, impl=impl,
+                         interpret=interpret)
 
 
 # ---------------------------------------------------------------------------
@@ -563,17 +844,20 @@ class MlaMoeGenerator:
         if kv_dtype is not None:
             raise LatentPoolUnsupported(
                 f"kv_dtype={kv_dtype}: latent pools are served in the "
-                f"model's dtype only (no int8 latent rows yet)")
+                f"model's dtype only (no int8 latent rows yet"
+                + (", nor int8 index keys)" if cfg.sparse else ")"))
         if mesh is not None and math.prod(mesh.shape.values()) != 1:
             raise LatentPoolUnsupported(
                 "latent pools are served on one chip: no sequence- or "
-                "head-sharded latent cache yet")
+                "head-sharded latent cache yet"
+                + (", and no selection over a sharded index-key plane"
+                   if cfg.sparse else ""))
         self.cfg, self.mesh, self.axis = cfg, mesh, axis
         self.max_seq = max_seq or cfg.max_seq
         self.attn = types.SimpleNamespace(
             world=1, quantized=False,
             ctx=types.SimpleNamespace(impl=impl, interpret=interpret))
-        self.tally = MoeTally()
+        self.tally = MoeTally(cfg.sparse)
         kw = dict(cfg=cfg, impl=impl, interpret=interpret)
         self._hooks = {
             "project": functools.partial(project, cfg=cfg),
@@ -583,7 +867,8 @@ class MlaMoeGenerator:
         self._chunk_jit = jax.jit(
             named(self.wrap_program(functools.partial(
                 _chunk_forward, cfg=cfg, **self._hooks,
-                attend=functools.partial(attend_prefix, **kw))),
+                attend=functools.partial(attend_prefix, tally=self.tally,
+                                         **kw))),
                 "prefill_chunk"),
             static_argnames=("quantized", "extent"), donate_argnums=(2,))
         self._prompt_jit = jax.jit(self.wrap_program(functools.partial(
@@ -592,37 +877,54 @@ class MlaMoeGenerator:
 
     # -- the engine's view --------------------------------------------------
 
+    latent = True   # the engine's name for this family's pools
+
     @property
     def kv_planes(self) -> list:
         """(heads, width) of each plane of a layer's cache: one latent
-        plane, where the dense family has a K and a V plane."""
-        return [(1, self.cfg.head_dim)]
+        plane, where the dense family has a K and a V plane — and, with
+        an indexer, the index keys' plane of ITS width beside it."""
+        c = self.cfg
+        return [(1, c.head_dim)] + [(1, c.index_head_dim)] * c.sparse
 
     def serve_hooks(self) -> dict:
         """Keyword seams for the engine's paged forwards."""
         ctx = self.attn.ctx
         return dict(self._hooks, paged_attend=functools.partial(
             paged_attend, cfg=self.cfg, impl=ctx.impl,
-            interpret=ctx.interpret))
+            interpret=ctx.interpret, tally=self.tally))
 
     def wrap_program(self, fwd):
         return with_moe_stats(fwd, self.tally)
 
     def kernel_gaps(self, *, page_size: int, **_prefill_geometry) -> dict:
         """Attention paths that will NOT reach the latent Pallas kernel
-        (``generate.attention_kernel_gaps`` for this family): prefill
-        chunks and decode share one kernel, so they share one answer
-        whatever the chunk and its extents."""
+        (``generate.attention_kernel_gaps`` for this family): decode and
+        the absorbed prefill chunk share one kernel and one answer; a
+        sparse block's EXPANDED chunk (``prefill_chunk`` queries over each
+        rung of ``ladder``) has a call and an answer of its own."""
         ctx = self.attn.ctx
         if resolve_impl(ctx.impl, ctx.interpret) == "xla":
             why = ("impl='xla' was asked for" if ctx.impl == "xla" else
                    "impl='auto' resolves to XLA off a TPU (no interpreter)")
             return {"paged_decode": why, "prefill_chunk": why}
         c = self.cfg
-        gap = None if ctx.interpret else mla_kernel_gap(
-            page_size, c.kv_lora_rank, c.head_dim - c.kv_lora_rank)
-        return {} if gap is None else {"paged_decode": gap,
+        gap = None if ctx.interpret else (
+            mla_kernel_gap(page_size, c.kv_lora_rank,
+                           c.head_dim - c.kv_lora_rank)
+            or (c.sparse and dsa_index_gap(page_size, c.index_head_dim))
+            or None)
+        gaps = {} if gap is None else {"paged_decode": gap,
                                        "prefill_chunk": gap}
+        chunk = _prefill_geometry.get("prefill_chunk")
+        if gap is None and not ctx.interpret and chunk and c.expands(chunk):
+            for ext in _prefill_geometry.get("ladder") or ():
+                why = ext > c.index_topk and mla_prefill_gap(
+                    chunk, ext, c.qk_nope_head_dim + c.qk_rope_head_dim,
+                    c.v_head_dim, _scratch_block(ext))
+                if why:
+                    gaps["prefill_chunk"] = why
+        return gaps
 
     def forward_logits(self, params, tokens):
         """Logits [B, S, V] of whole prompts in one pass (no cache kept):

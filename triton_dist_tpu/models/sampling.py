@@ -43,19 +43,20 @@ def _from_ordered_bits(b):
     return jax.lax.bitcast_convert_type(u, jnp.float32)
 
 
-def _largest_threshold(bits, reaches):
-    """The largest uint32 ``t`` ``[..., 1]`` at which ``reaches(bits >=
-    t)`` is true, for a test that never turns true again once false as
-    ``t`` grows (the mask only loses members): built from the top bit
-    down, one evaluation a bit.  0 where it holds nowhere — the ordered
-    bits of a NaN, below which no float compares, so such a row is cut
-    nowhere."""
+def _largest_threshold(bits, reaches, shape=None):
+    """The largest uint32 ``t`` ``[..., 1]`` (or of ``shape``, what
+    ``reaches`` reduces ``bits`` to) at which ``reaches(bits >= t)`` is
+    true, for a test that never turns true again once false as ``t``
+    grows (the mask only loses members): built from the top bit down, one
+    evaluation a bit.  0 where it holds nowhere — the ordered bits of a
+    NaN, below which no float compares, so such a row is cut nowhere."""
     def try_bit(i, t):
         trial = t | (jnp.uint32(1 << 31) >> i.astype(jnp.uint32))
         return jnp.where(reaches(bits >= trial), trial, t)
 
     return jax.lax.fori_loop(
-        0, 32, try_bit, jnp.zeros(bits.shape[:-1] + (1,), jnp.uint32))
+        0, 32, try_bit,
+        jnp.zeros(shape or bits.shape[:-1] + (1,), jnp.uint32))
 
 
 def _apply_top_k(logits, top_k):

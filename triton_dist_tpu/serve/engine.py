@@ -194,13 +194,16 @@ _FATAL = (WatchdogTimeout, KeyboardInterrupt, SystemExit)
 
 
 def _refuse_latent(latent: bool, what: str) -> None:
-    """What has not been carried over to latent (MLA) pools refuses by
-    name, where the engine is built or the entry point is called."""
+    """What has not been carried over to latent (MLA) pools — the latent
+    rows' plane, and the index keys' beside it under learned sparse
+    attention — refuses by name, where the engine is built or the entry
+    point is called."""
     if latent:
         from triton_dist_tpu.models.mla_moe import LatentPoolUnsupported
 
         raise LatentPoolUnsupported(
-            f"{what}: not served on latent (MLA) pools yet")
+            f"{what}: not served on latent (MLA) pools yet (neither the "
+            f"latent rows' plane nor an indexer's key plane carries it)")
 
 
 def build_bucket_ladder(base: int, cap: int, page: int) -> list[int]:
@@ -312,7 +315,7 @@ class ServeEngine:
         # been carried over to one-plane (latent) pools refuses HERE, by
         # name — never a quiet fallback.
         self.kv_planes = list(gen.kv_planes)
-        self.latent = len(self.kv_planes) == 1
+        self.latent = gen.latent
         for what, asked in (
                 ("a mesh (sharded latent pools)", mesh is not None),
                 ("int8 pools", self.kv_quant),
@@ -771,22 +774,24 @@ class ServeEngine:
         # Zeroed pools, born on their mesh layout (None off-mesh): a mesh
         # engine's pools never exist whole on one device — at real widths
         # they would not fit beside its weight shard.
-        page_shape = (num_blocks, cfg.n_kv_heads, page_size, cfg.head_dim)
+        # A plane is [blocks, heads, page, width] at ITS heads and width
+        # (K and V alike; a latent row beside a narrower index key).
         if self.kv_quant:
             # int8 pools: the quant plane plus its per-(head, row) scale
             # plane — one scale per (block, head, in-page row), the exact
             # shape _scatter_kv's quantize_kv emits, living in the SAME
             # pool tuple so pages and scales can never travel separately.
-            def zpool():
-                return {"q": jnp.zeros(page_shape, jnp.int8,
-                                       device=self._pool_sharding),
-                        "s": jnp.zeros(page_shape[:3], jnp.float32,
+            def zpool(h, d):
+                return {"q": jnp.zeros((num_blocks, h, page_size, d),
+                                       jnp.int8, device=self._pool_sharding),
+                        "s": jnp.zeros((num_blocks, h, page_size),
+                                       jnp.float32,
                                        device=self._pool_sharding)}
         else:
-            def zpool():
-                return jnp.zeros(page_shape, cfg.dtype,
+            def zpool(h, d):
+                return jnp.zeros((num_blocks, h, page_size, d), cfg.dtype,
                                  device=self._pool_sharding)
-        self._pools = [tuple(zpool() for _ in self.kv_planes)
+        self._pools = [tuple(zpool(h, d) for h, d in self.kv_planes)
                        for _ in range(cfg.n_layers)]
         self._sample_fn = CountingJit(
             jax.jit(named(_sample_token, "sample_token")), "sample_token")
@@ -803,6 +808,7 @@ class ServeEngine:
         # capacity"): pool bytes are THE capacity currency — stamp the
         # real allocated footprint (quant + scale planes both) and the
         # token-slot count so bytes/token and fleet-wide sums fall out.
+        index_width = sum(d for _, d in self.kv_planes[1:])   # latent pools
         self.metrics.set_kv_capacity(
             pool_bytes=sum(int(x.size) * x.dtype.itemsize
                            for x in jax.tree_util.tree_leaves(self._pools)),
@@ -810,8 +816,9 @@ class ServeEngine:
             quantized=self.kv_quant,
             row=({"latent_row_width": cfg.latent_width,
                   "stored_row_width": cfg.head_dim,
+                  "index_key_width": index_width,
                   "latent_bytes_per_token":
-                      cfg.latent_width * cfg.n_layers
+                      (cfg.latent_width + index_width) * cfg.n_layers
                       * jnp.dtype(cfg.dtype).itemsize}
                  if self.latent else None))
         # cache-tier reclaims happen inside the allocator; the hook puts
@@ -1276,7 +1283,7 @@ class ServeEngine:
         geometry overrides)."""
         from triton_dist_tpu.serve import recovery
 
-        _refuse_latent(len(gen.kv_planes) == 1, "restore()")
+        _refuse_latent(gen.latent, "restore()")
         return recovery.restore_engine(directory, gen, params, **kwargs)
 
     # -- live migration ---------------------------------------------------
@@ -2847,14 +2854,15 @@ class ServeEngine:
 
     def _fold_aux(self, upto: int) -> None:
         """Fold the counter arrays noted up to the ``upto``-th (all ready:
-        see :meth:`_note_aux`) into the metrics — the MoE tally of a
-        family with expert layers (``ServeMetrics.observe_moe``)."""
+        see :meth:`_note_aux`) into the metrics — the tally of a family
+        with expert layers, and an indexer's behind it
+        (``ServeMetrics.observe_family``)."""
         n = upto - self._aux_folded
         if n > 0:
             done = self._aux_pending[:n]
             del self._aux_pending[:n]
             self._aux_folded = upto
-            self.metrics.observe_moe(
+            self.metrics.observe_family(
                 np.sum(jax.device_get(done), axis=0))
 
     def _preempt(self, victim: ReqState) -> None:

@@ -75,6 +75,8 @@ MERGE_COUNTERS = (
     "prefix_skipped_tokens", "running_sum", "kv_util_sum",
     "moe_assignments", "moe_local_assignments", "moe_pad_rows",
     "moe_experts_hit",
+    "dsa_indexed_tokens", "dsa_selected_rows", "dsa_rows_sparse",
+    "dsa_rows_dense",
     "net_requests", "net_dup_hits", "net_redelivered_tokens",
     "brownout_transitions",
     "journal_corrupt", "manifest_corrupt",
@@ -269,11 +271,21 @@ class ServeMetrics:
     # for the rows the programs computed, those whose expert is held
     # here, the pad rows of the grouped GEMM's live tiles, and the
     # (layer, step) x held-expert pairs that got any row.  Folded in at
-    # commit from a small output of each program (observe_moe).
+    # commit from a small output of each program (observe_family).
     moe_assignments: int = 0
     moe_local_assignments: int = 0
     moe_pad_rows: int = 0
     moe_experts_hit: int = 0
+    # learned sparse attention (models/mla_moe.py, a block with an
+    # indexer): cached tokens the indexer scored and latent rows the
+    # attention kept, over every query the programs computed (prefill
+    # chunks included), and the decode queries whose context lay past /
+    # at or under ``index_topk`` (the sparse read / every visible row).
+    # Folded in with the expert counts (observe_family).
+    dsa_indexed_tokens: int = 0
+    dsa_selected_rows: int = 0
+    dsa_rows_sparse: int = 0
+    dsa_rows_dense: int = 0
     decode_tokens: int = 0        # tokens committed by the decode loop
     dispatches: int = 0           # decode-path device dispatches
     host_syncs: int = 0           # decode-path host sync points
@@ -488,11 +500,26 @@ class ServeMetrics:
     MOE_COUNTERS = ("moe_assignments", "moe_local_assignments",
                     "moe_pad_rows", "moe_experts_hit")
 
-    def observe_moe(self, stats) -> None:
-        """Add one or more programs' expert-layer counts (int[4], the
-        order of ``MOE_COUNTERS``)."""
-        for name, v in zip(self.MOE_COUNTERS, stats):
+    DSA_COUNTERS = ("dsa_indexed_tokens", "dsa_selected_rows",
+                    "dsa_rows_sparse", "dsa_rows_dense")
+    FAMILY_COUNTERS = MOE_COUNTERS + DSA_COUNTERS   # a program's tally
+
+    def observe_family(self, stats) -> None:
+        """Add one or more programs' trailing counts: the expert layers'
+        int[4] in the order of ``MOE_COUNTERS`` and, from a block with an
+        indexer, ``DSA_COUNTERS``' four behind them."""
+        for name, v in zip(self.FAMILY_COUNTERS, stats):
             setattr(self, name, getattr(self, name) + int(v))
+
+    def dsa_stats(self) -> dict:
+        """summary()["dsa"]: the four counters and the share of the
+        scored tokens that the attention read (``index_topk`` over the
+        mean context, where contexts lie past it)."""
+        out = {k[4:]: getattr(self, k) for k in self.DSA_COUNTERS}
+        out["selected_share"] = (self.dsa_selected_rows
+                                 / self.dsa_indexed_tokens
+                                 if self.dsa_indexed_tokens else 0.0)
+        return out
 
     def moe_stats(self) -> dict:
         """summary()["moe"]: the four counters and the share of routed
@@ -953,6 +980,7 @@ class ServeMetrics:
             "decode": self.decode_stats(),
             "kv": self.kv_stats(),
             "moe": self.moe_stats(),
+            "dsa": self.dsa_stats(),
             "spec": self.spec_stats(),
             "slo": self.slo_stats(),
             "failures": self.failure_stats(),
@@ -1001,7 +1029,7 @@ class ServeMetrics:
         counter("serve_completed_total", self.completed,
                 "requests retired (any reason)")
         counter("serve_preemptions_total", self.preemptions)
-        for name in self.MOE_COUNTERS:
+        for name in self.FAMILY_COUNTERS:
             counter(f"serve_{name}_total", getattr(self, name))
         counter("serve_shed_total", self.shed)
         counter("serve_deadline_expired_total", self.deadline_expired)

@@ -117,7 +117,10 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
                            pool_row, in_page)
 
     def attend(li, q, pool):
-        return paged_attend(q[:, 0], pool, tables, kv_lens + inc)[:, None]
+        # q: an array, or whatever tree of [B, 1, ...] arrays the family's
+        # ``project`` hands its ``paged_attend`` (an indexer's beside it)
+        return paged_attend(jax.tree.map(lambda t: t[:, 0], q), pool,
+                            tables, kv_lens + inc)[:, None]
 
     pools, logits = _layer_stack(
         params, token[:, None], kv_lens[:, None], pools, cfg=cfg,
