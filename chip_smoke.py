@@ -13,8 +13,9 @@ what came out by the repo's own means:
   count of its programs, and by jax's count of every executable the
   process asked XLA for while the requests were served;
 - the programs the engine SERVED WITH, re-lowered from the signatures it
-  was called with, hold one Mosaic custom call per layer for paged decode,
-  the fused decode horizon and chunked prefill — and the engine's own
+  was called with, hold one Mosaic custom call per layer for the decode
+  horizon (every rung, the one-step link of a clamped step included) and
+  chunked prefill — and the engine's own
   construction-time kernel-reach report is empty.  A run that "works" on
   XLA fallbacks has exercised no kernel of this repo and fails here;
 - for one short prompt, the prefill-last-position and first-decode logits
@@ -295,8 +296,10 @@ def no_fresh_compiles(engine, misses0: int, leg: Leg, when: str) -> None:
 def engine_logits(engine, prompt, first_token=None):
     """(prefill-last-position logits, first-decode logits, first token) of
     ``prompt``, computed by calling — in the order the step loop does — the
-    very programs ``engine`` serves with: chunked prefill into a scratch,
-    the page scatter, one paged decode of the next token."""
+    very programs ``engine`` prefills with (chunked prefill into a scratch,
+    the page scatter) and then ``paged_decode`` on the next token: the one
+    program that hands back a decode step's logits, over the same forward
+    the horizon's links scan."""
     import jax.numpy as jnp
 
     cfg, page = engine.cfg, engine.page
@@ -339,8 +342,10 @@ def engine_logits(engine, prompt, first_token=None):
     return prefill_last, np.asarray(dec[0], np.float32), first_token
 
 
-# The engine programs that attend (docs/serving.md "Kernel reach").
-ATTENTION_PROGRAMS = ("paged_decode", "decode_horizon", "prefill_chunk")
+# The programs this engine serves with that attend (docs/serving.md
+# "Kernel reach"): an engine with a horizon decodes through its links
+# alone, so ``paged_decode`` is not among them.
+ATTENTION_PROGRAMS = ("decode_horizon", "prefill_chunk")
 
 
 def check_mosaic(engine, leg: Leg, *, interpret: bool) -> dict:
@@ -385,10 +390,14 @@ def check_reference(engine, leg: Leg, base_cfg, seed: int, *,
     n = min(2 * 128 + 37, leg.max_seq - 2)      # 2 full chunks + a residual
     prompt = rng.integers(0, cfg.vocab, size=n).astype(np.int32)
     misses0 = engine.metrics.compile_misses
+    decode0 = engine._decode_fn.misses
     got_p, got_d, tok = engine_logits(engine, prompt)
-    # the calls above are the step loop's own, so they hit its programs
-    no_fresh_compiles(engine, misses0, leg, "replaying the step loop's "
-                      "calls for the reference check")
+    # the prefill calls above are the step loop's own, so they hit its
+    # programs; the single-step decode is not (the horizon's links hand
+    # back tokens, never logits): the forward they scan compiles here
+    no_fresh_compiles(engine, misses0 + engine._decode_fn.misses - decode0,
+                      leg, "replaying the step loop's calls for the "
+                      "reference check")
     # built on the engine's own (mesh-placed) weights: shared buffers,
     # not a second copy
     twin = build_engine(leg, base_cfg, seed, interpret=False, impl="xla",

@@ -6,8 +6,8 @@ installed libtpu provides on a CPU host.  What this pins, at llama3-8B
 widths (depth cut to 2 layers — a compile check, not a run):
 
 - world-1 ``paged_decode``, ``decode_horizon[H=8]`` (greedy and sampled) and
-  ``prefill_chunk[c=128]`` compile for the v5e and hold exactly one Mosaic
-  custom call per layer;
+  ``[H=1]`` (sampled) and ``prefill_chunk[c=128]`` compile for the v5e and
+  hold exactly one Mosaic custom call per layer;
 - the world-4 decode and chunked-prefill programs of ``serve/mesh.py``
   compile for ``heads``, ``seq`` and ``heads+seq`` (2x2), with one Mosaic
   call per layer under ``heads`` and two (attention kernel + SP combine)
@@ -153,8 +153,8 @@ def test_world1_programs_compile_with_one_mosaic_call_per_layer(v5e, as_tpu):
     decode, horizon, chunk = _world1_programs(cfg, page=128)
     d_args, h_args = put(_decode_args(cfg, 128))
     assert _compile(decode, *d_args) == LAYERS
-    for all_greedy in (True, False):
-        assert _compile(horizon, *h_args, H=8,
+    for H, all_greedy in ((8, True), (8, False), (1, False)):
+        assert _compile(horizon, *h_args, H=H,
                         all_greedy=all_greedy) == LAYERS
     for extent in (128, MAX_SEQ):
         args, n_valid = put(_chunk_args(cfg, 128, extent))
@@ -180,10 +180,12 @@ def test_named_programs_keep_the_operation_names_the_benchmark_reads(
     paged attention call — which XLA names after the scope around it —
     still reads ``_unknown_`` in the single-step program
     (``_paged_decode_step`` keeps it there) and ``closed_call`` in the
-    horizon's scan: the two names ``benchmarks/layer_metrics/
-    paged_attn_roofline.json`` sums.  Exactly ONE Mosaic call a layer in
-    both: the paged kernel walks a row's live pages and carries every KV
-    head inside one call (no second call for a merge)."""
+    horizon's scan, at H = 8 and at the one-step link's H = 1 (the
+    mixed-sampler variant, the one the engine builds there): the two
+    names ``benchmarks/layer_metrics/paged_attn_roofline.json`` sums.
+    Exactly ONE Mosaic call a layer in both: the paged kernel walks a
+    row's live pages and carries every KV head inside one call (no second
+    call for a merge)."""
     import re
 
     from triton_dist_tpu.runtime.jit_cache import named
@@ -206,13 +208,17 @@ def test_named_programs_keep_the_operation_names_the_benchmark_reads(
         *d_args)
     assert module == "HloModule jit_paged_decode"
     assert names == ["_unknown_"] * LAYERS
-    module, names = calls(jax.jit(named(
+    horizon = jax.jit(named(
         PR._paged_decode_horizon, "decode_horizon",
         decode_fwd=functools.partial(PR._paged_decode_forward, **kw)),
-        static_argnames=("H", "all_greedy"), donate_argnums=(1,)),
-        *h_args, H=8, all_greedy=True)
-    assert module == "HloModule jit_decode_horizon"
-    assert names == ["closed_call"] * LAYERS
+        static_argnames=("H", "all_greedy"), donate_argnums=(1,))
+    # H = 1 is the link of a clamped step (a slot mid-prefill): XLA drops
+    # its one-trip loop, and the call still reads ``closed_call`` — it
+    # stays inside the pattern, at one decode step a link
+    for H, all_greedy in ((8, True), (1, False)):
+        module, names = calls(horizon, *h_args, H=H, all_greedy=all_greedy)
+        assert module == "HloModule jit_decode_horizon"
+        assert names == ["closed_call"] * LAYERS, (H, all_greedy)
 
 
 @pytest.mark.parametrize("hkv", [8, 2, 1])
@@ -461,12 +467,13 @@ def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu, name,
                                                       extents):
     """Every program kind of ``gc3_ep16_l5_reason_sat`` and of
     ``glm5_ep16_l5_longctx_sat`` — single-step decode, the fused horizon
-    (greedy and mixed), prefill chunks at the shortest, a middle and the
-    cap extent — at the file's widths and engine sizes: each holds ONE
-    latent attention call a layer (and, with an indexer, ONE index-score
-    call a layer beside it) and one gate-up + one down grouped GEMM an
-    expert layer, under their trace names (the benchmark's roofline
-    readers match them), and fits the chip beside nothing else."""
+    (greedy and mixed) and its one-step link, prefill chunks at the
+    shortest, a middle and the cap extent — at the file's widths and
+    engine sizes: each holds ONE latent attention call a layer (and, with
+    an indexer, ONE index-score call a layer beside it) and one gate-up +
+    one down grouped GEMM an expert layer, under their trace names (the
+    benchmark's roofline readers match them), and fits the chip beside
+    nothing else."""
     import re
     from collections import Counter
 
@@ -522,6 +529,8 @@ def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu, name,
     for all_greedy in (True, False):
         check("decode_horizon", horizon, *h_args, H=eng["horizon"],
               all_greedy=all_greedy)
+    # the link of a clamped step: rung 1 has the mixed variant alone
+    check("decode_horizon", horizon, *h_args, H=1, all_greedy=False)
     ladder = E.build_bucket_ladder(max(page, eng["prefill_chunk"]), max_seq,
                                    page)
     assert set(extents) <= set(ladder) and extents[-1] == max_seq

@@ -169,6 +169,27 @@ def test_fused_horizon_streams_equal_single_step(tiny, undisturbed):
                   seed=11) == sampled
 
 
+def test_clamped_steps_are_one_step_links_on_latent_pools(tiny, undisturbed):
+    """A prompt is two to four engine steps of prefill here (budget one
+    chunk of 16), so the rows already running decode under the
+    scheduler's clamp: one ``decode_horizon`` link at H = 1 a step, the
+    sampler on the device, the same streams — and neither the
+    single-step program nor the host sampler serves a decode token."""
+    cfg, params, gen = tiny
+    prompts, greedy, sampled = undisturbed
+    for want, sp in ((greedy, {}), (sampled, dict(
+            temperature=0.8, top_k=16, top_p=0.9, seed=11))):
+        eng = _engine(gen, params, horizon=4, pipeline=2, max_batch=3,
+                      prefill_chunk=16, prefill_budget=16,
+                      prefix_cache=False, trace_level=1)
+        assert _serve(eng, prompts, 12, **sp) == want
+        progs = eng.metrics.summary()["programs"]
+        assert progs["decode_horizon[H=1]"]["count"] >= 4, sorted(progs)
+        assert "paged_decode" not in progs
+        assert eng.metrics.host_choices == len(prompts)
+        assert ("sample_token" in progs) == bool(sp)
+
+
 def test_prefix_hit_on_latent_pools(tiny, undisturbed):
     cfg, params, gen = tiny
     prompts, greedy, _ = undisturbed

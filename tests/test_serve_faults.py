@@ -351,6 +351,69 @@ def test_poison_forward_bisected_and_quarantined(tiny):
     assert all(s is None for s in eng.slots)
 
 
+def test_one_step_horizon_link_is_contained_like_the_single_step(tiny):
+    """A ``horizon=8`` engine beside a mid-prefill slot decodes through
+    ONE ``decode_horizon`` link at H = 1 a step, and nothing host-side
+    mutates before that link drains — so a transient ``forward`` fault on
+    it is absorbed by the retry, a poison row bisects to quarantine, the
+    slot-mates' streams are a fault-free run's, and an aborted attempt
+    (it never reached the device) counts no dispatch."""
+    cfg, params, gen = tiny
+    rng = np.random.default_rng(17)
+    prompts = {"p0": 5, "p1": 6, "long": 30}   # "long": 8 steps of prefill
+    prompts = {rid: rng.integers(0, cfg.vocab, size=n).astype(np.int32)
+               for rid, n in prompts.items()}
+
+    def drive(faults):
+        eng = _engine(gen, params, max_batch=3, prefill_budget=4,
+                      horizon=8, pipeline=2, faults=faults,
+                      fault_retries=1, clock=_Tick())
+        faulted, call = [], eng._device_call
+
+        def device_call(op, rids, fn, *a, **kw):
+            try:
+                return call(op, rids, fn, *a, **kw)
+            except RuntimeError:
+                faulted.append((op, kw.get("H")))
+                raise
+
+        eng._device_call = device_call
+        for rid, p in prompts.items():
+            eng.submit(Request(rid, p, SamplingParams(
+                max_new_tokens=3 if rid == "long" else 8)))
+        return eng, eng.run(), faulted
+
+    inj = FaultInjector(seed=0)
+    inj.inject("forward", op="decode_horizon", error="transient",
+               max_fires=1)
+    inj.inject("forward", rid="p1", op="decode_horizon", error="bad row")
+    eng, outs, faulted = drive(inj)
+    _, clean, none = drive(None)
+    assert not none
+
+    assert outs["p1"].finish_reason is FinishReason.ERROR
+    assert "bad row" in outs["p1"].error
+    assert len(outs["p1"].token_ids) == 1   # prefill token, then poison
+    for rid in ("p0", "long"):
+        assert outs[rid].finish_reason is FinishReason.LENGTH
+        assert outs[rid].token_ids == clean[rid].token_ids
+        assert outs[rid].token_ids == _oracle(
+            gen, params, prompts[rid], len(clean[rid].token_ids))
+    # p0 decoded beside p1's prefill, p1 beside long's: every faulted
+    # attempt was a one-step link (the transient, then p1's pair twice)
+    assert len(faulted) >= 4
+    assert set(faulted) == {("decode_horizon", 1)}, faulted
+    f = eng.metrics.summary()["failures"]
+    assert f["quarantined"] == 1
+    assert f["forward_retries"] >= 2 and f["forward_bisections"] >= 1
+    # a dispatch is a link that reached the device
+    assert eng.metrics.dispatches == (eng._horizon_fn.hits
+                                      + eng._horizon_fn.misses)
+    assert eng._decode_fn.hits + eng._decode_fn.misses == 0
+    assert eng.bm.num_free == eng.bm.num_allocatable
+    assert all(s is None for s in eng.slots)
+
+
 def test_block_alloc_fault_quarantines_grower(tiny):
     cfg, params, gen = tiny
     rng = np.random.default_rng(4)

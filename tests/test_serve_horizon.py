@@ -342,6 +342,139 @@ def test_horizon_abort_from_callback_no_tokens_past_retire():
 
 
 # ---------------------------------------------------------------------------
+# fast tier: a clamped step is ONE one-step horizon link
+# ---------------------------------------------------------------------------
+
+
+def _tap(eng):
+    """Count what the one-step link must account for: the ``H`` of every
+    ``decode_horizon`` dispatch, and every completed prefill (the only
+    place such an engine still chooses a token on the host) with whether
+    its request is sampled."""
+    links, prefills = [], []
+    call, finish = eng._device_call, eng._finish_prefill
+
+    def device_call(op, rids, fn, *a, **kw):
+        if op == "decode_horizon":
+            links.append(kw["H"])
+        return call(op, rids, fn, *a, **kw)
+
+    def finish_prefill(rs, *a, **kw):
+        prefills.append(not rs.req.params.greedy)
+        return finish(rs, *a, **kw)
+
+    eng._device_call, eng._finish_prefill = device_call, finish_prefill
+    return links, prefills
+
+
+_SAMPLED = dict(temperature=0.8, top_k=16, top_p=0.9)
+
+
+def _clamped_case(case, cfg, oracle):
+    """-> (requests, engine keywords, what else must hold).  Every case
+    prefills in chunks of 4 on a budget of 4 a step, so a prompt of n
+    tokens keeps its slot mid-prefill for n / 4 engine steps while its
+    slot-mates decode: those steps are clamped."""
+    rng = np.random.default_rng(31)
+
+    def prompt(n):
+        return rng.integers(0, cfg.vocab, size=n).astype(np.int32)
+
+    kw = dict(num_blocks=40, max_batch=3)
+    if case == "greedy":
+        reqs = [Request(f"r{i}", prompt(n), SamplingParams(max_new_tokens=12))
+                for i, n in enumerate((6, 19, 11, 23))]
+    elif case == "sampled":
+        reqs = [Request(f"r{i}", prompt(n), SamplingParams(
+                    max_new_tokens=12, seed=40 + i,
+                    **(_SAMPLED if i % 2 else dict(temperature=1.3))))
+                for i, n in enumerate((6, 19, 11, 23))]
+    elif case == "seed_over_int32":
+        reqs = [Request("r0", prompt(7), SamplingParams(max_new_tokens=12)),
+                Request("r1", prompt(17), SamplingParams(
+                    max_new_tokens=12, seed=2 ** 31 + 11, **_SAMPLED)),
+                Request("r2", prompt(21), SamplingParams(
+                    max_new_tokens=12, seed=2 ** 32 - 1, **_SAMPLED))]
+    elif case == "eos_on_the_link":
+        # "e" prefills behind r1 and decodes beside r2's nine steps of
+        # prefill, so its EOS can only come out of a one-step link
+        p = prompt(5)
+        want = oracle(p, 14)
+        j = next(i for i in range(2, 8) if want[i] not in want[:i])
+        reqs = [Request("r1", prompt(40), SamplingParams(max_new_tokens=4)),
+                Request("e", p, SamplingParams(max_new_tokens=14,
+                                               eos_id=want[j])),
+                Request("r2", prompt(36), SamplingParams(max_new_tokens=4))]
+    elif case == "preempted_across_it":
+        # 9 blocks of 4 hold neither the three rows nor two of them to
+        # their ends: the youngest is evicted while a slot-mate decodes,
+        # and recomputes (mid-prefill again: more clamped steps)
+        kw = dict(num_blocks=9, max_batch=3)
+        reqs = [Request("r0", prompt(9), SamplingParams(max_new_tokens=10)),
+                Request("r1", prompt(7), SamplingParams(
+                    max_new_tokens=10, seed=5, **_SAMPLED)),
+                Request("r2", prompt(6), SamplingParams(max_new_tokens=8))]
+    return reqs, kw
+
+
+@pytest.mark.parametrize("case", [
+    "greedy", "sampled", "seed_over_int32", "eos_on_the_link",
+    "preempted_across_it"])
+def test_clamped_step_is_one_horizon_link_with_the_same_stream(case):
+    """A ``horizon=8, pipeline=2`` engine whose steps are clamped (a slot
+    is mid-prefill) dispatches ONE ``decode_horizon`` link at ``H = 1``
+    for each, and emits token for token what a ``horizon=1`` engine (host
+    sampler) and ``Generator.generate`` emit.  Its decode never reaches
+    the single-step program or ``_choose_token``: the host chooses one
+    token a completed prefill, no more."""
+    cfg, params, gen = _tiny_model()
+    reqs, kw = _clamped_case(
+        case, cfg, lambda p, n: _oracle(gen, params, p, n))
+
+    def run(h):
+        eng = ServeEngine(gen, params, page_size=4, prefill_chunk=4,
+                          prefill_budget=4, horizon=h, pipeline=2,
+                          clock=_Tick(), **kw)
+        links, prefills = _tap(eng)
+        # the H of the newest link at each token's delivery
+        by = {r.request_id: [] for r in reqs}
+        outs = _drive(eng, [
+            Request(r.request_id, r.prompt, r.params,
+                    on_token=lambda rid, tok: by[rid].append(links[-1:]))
+            for r in reqs], stagger=1)
+        assert eng.bm.num_free == eng.bm.num_allocatable
+        return eng, outs, links, prefills, by
+
+    e1, o1, _, pre1, _ = run(1)
+    e8, o8, links, prefills, by = run(8)
+    for r in reqs:
+        rid = r.request_id
+        assert o8[rid].token_ids == o1[rid].token_ids, (case, rid)
+        assert o8[rid].finish_reason is o1[rid].finish_reason
+        if r.params.greedy and r.params.eos_id is None:
+            assert o8[rid].token_ids == _oracle(
+                gen, params, r.prompt, r.params.max_new_tokens)
+    # clamped steps happened, each ONE link of one step ...
+    assert links.count(1) >= 4, links
+    d = e8.metrics.summary()["decode"]
+    assert d["dispatches"] == d["host_syncs"] == len(links)
+    # ... the single-step program and the host sampler served no decode
+    assert e8._decode_fn.hits + e8._decode_fn.misses == 0
+    assert e8.metrics.host_choices == len(prefills)
+    assert e8._sample_fn.hits + e8._sample_fn.misses == sum(prefills)
+    # (the horizon=1 engine chooses every token there)
+    assert e1.metrics.host_choices == len(pre1) + e1.metrics.decode_tokens
+    if case == "eos_on_the_link":
+        assert o8["e"].finish_reason is FinishReason.EOS
+        assert len(by["e"]) == len(o8["e"].token_ids) >= 3
+        # every decode token of "e", its last included: a one-step link
+        assert by["e"][1:] == [[1]] * (len(by["e"]) - 1), by["e"]
+    if case == "preempted_across_it":
+        assert e8.metrics.preemptions >= 1
+        assert len(prefills) > len(reqs)      # a recompute prefilled again
+
+
+# ---------------------------------------------------------------------------
 # fast tier: horizon x fault injection
 # ---------------------------------------------------------------------------
 
@@ -412,19 +545,22 @@ def test_horizon_transient_fault_absorbed_by_retry():
 
 
 def test_horizon_warmup_leaves_miss_counter_flat():
-    """warmup() sweeps the horizon ladder (greedy AND sampled variants,
-    serially per rung) — mixed-length, mixed-sampler traffic then never
-    compiles, horizon programs included."""
+    """warmup() sweeps the WHOLE horizon ladder (greedy AND sampled
+    variants, serially per rung; rung 1 is the link of a clamped step) —
+    mixed-length, mixed-sampler traffic then never compiles, horizon
+    programs included, and the single-step program is never built."""
     cfg, params, gen = _tiny_model()
     eng = ServeEngine(gen, params, num_blocks=40, page_size=4,
-                      max_batch=2, prefill_chunk=4, horizon=8,
-                      pipeline=2, clock=_Tick())
+                      max_batch=2, prefill_chunk=4, prefill_budget=4,
+                      horizon=8, pipeline=2, trace_level=1, clock=_Tick())
     w = eng.warmup()
     assert w["programs"] > 0
     hz_misses = eng._horizon_fn.misses
-    # every rung above 1 compiles a greedy and a mixed-sampler program
-    assert hz_misses == 2 * len([r for r in eng.h_ladder if r > 1]), (
-        eng._horizon_fn.stats())
+    # every rung compiles a greedy and a mixed-sampler program, rung 1
+    # the mixed one alone (it serves a greedy-only batch too)
+    assert eng.h_ladder == [1, 2, 4, 8]
+    assert hz_misses == 2 * len(eng.h_ladder) - 1, eng._horizon_fn.stats()
+    assert eng._decode_fn.misses == eng._decode_fn.hits == 0
     flat = eng.metrics.compile_misses
     rng = np.random.default_rng(15)
     reqs = []
@@ -442,6 +578,13 @@ def test_horizon_warmup_leaves_miss_counter_flat():
         "horizon serving compiled after warmup: "
         f"{eng.metrics.summary()['compilation']}")
     assert eng._horizon_fn.misses == hz_misses
+    # the staggered arrivals prefill beside running rows (chunks of 4,
+    # prompts up to 23): those steps are clamped, and each was ONE
+    # one-step link of the warmed rung — never the single-step program
+    progs = eng.metrics.summary()["programs"]
+    assert progs["decode_horizon[H=1]"]["count"] >= 6, sorted(progs)
+    assert "paged_decode" not in progs
+    assert eng._decode_fn.misses == eng._decode_fn.hits == 0
     # (at most: jit caches by function, so an earlier engine of this
     # process at the same vocabulary already holds it)
     assert eng._sample_fn.misses <= 1 and eng._sample_fn.hits >= 3

@@ -311,6 +311,28 @@ def test_mesh_tp_oracle_h8_flat_misses(model, oracle, mesh4):
         eng.metrics.summary()["compilation"])
 
 
+def test_mesh_tp_clamped_steps_are_one_step_links(model, oracle, mesh4):
+    """The shard_map horizon takes ``H`` as the world-1 one does: on the
+    ``heads`` mesh a step clamped by a mid-prefill slot (a budget of two
+    chunks a step) is ONE ``decode_horizon`` link at H = 1, streams equal
+    to the world-1 single-step oracle, nothing compiled after warmup and
+    no decode token from ``paged_decode`` or the host sampler."""
+    cfg, params, gen = model
+    eng = _build(gen, params, mesh=mesh4, kv_shard="heads", horizon=8,
+                 pipeline=2, trace_level=1)
+    eng.warmup()
+    flat = eng.metrics.compile_misses
+    reqs = _requests(cfg)
+    assert _serve(eng, reqs) == oracle
+    assert eng.metrics.compile_misses == flat, (
+        eng.metrics.summary()["compilation"])
+    progs = eng.metrics.summary()["programs"]
+    assert progs["decode_horizon[H=1]"]["count"] >= 2, sorted(progs)
+    assert "paged_decode" not in progs
+    assert eng._decode_fn.hits + eng._decode_fn.misses == 0
+    assert eng.metrics.host_choices == len(reqs)
+
+
 def test_mesh_programs_lower_under_their_own_names(model, mesh4):
     """Every program of the ``heads`` mesh engine lowers to the HLO
     module ``jit_<its name>``: a device trace then tells the shard_map
@@ -320,16 +342,21 @@ def test_mesh_programs_lower_under_their_own_names(model, mesh4):
     from triton_dist_tpu.analysis.jaxpr_audit import lowered_module_names
 
     cfg, params, gen = model
-    eng = _build(gen, params, mesh=mesh4, kv_shard="heads", horizon=4)
-    eng.warmup()
-    names = lowered_module_names(eng)
-    assert {"paged_decode", "decode_horizon", "prefill_chunk",
-            "fill_pages", "load_pages", "cow_copy",
-            "sample_token"} <= set(names)
-    for prog, mods in names.items():
-        if prog == "paged_verify" and not mods:
-            continue        # no draft: never called
-        assert mods == {f"jit_{prog}"}, (prog, mods)
+    for horizon in (1, 4):
+        eng = _build(gen, params, mesh=mesh4, kv_shard="heads",
+                     horizon=horizon)
+        eng.warmup()
+        names = lowered_module_names(eng)
+        assert {"paged_decode", "prefill_chunk", "fill_pages",
+                "load_pages", "cow_copy", "sample_token"} <= set(names)
+        if horizon > 1:
+            # its decode is horizon links alone, the one-step link too
+            assert names.pop("paged_decode") == set()
+            assert "decode_horizon" in names
+        for prog, mods in names.items():
+            if prog == "paged_verify" and not mods:
+                continue        # no draft: never called
+            assert mods == {f"jit_{prog}"}, (prog, mods)
     # and the spans of a step on the mesh engine nest like the one-chip
     # engine's: nothing under ``step`` is lost or counted twice
     _serve(eng, _requests(cfg))

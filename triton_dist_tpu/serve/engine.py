@@ -16,8 +16,9 @@ iteration-level scheduling):
    arrays under this module and ``serve/mesh.py`` alike: a
    traced scan with on-device sampling and KV commit, pipelined so the
    host commits horizon N's token burst while the device runs horizon
-   N+1 — docs/serving.md "Decode horizon"); at H=1, one batched forward
-   per step through
+   N+1 — docs/serving.md "Decode horizon"; a step the scheduler clamps
+   to one decode step is ONE link of that program at H = 1); on an
+   engine without a horizon, one batched forward per step through
    ``kernels/flash_decode.gqa_decode_paged_shard`` — per-row lengths,
    per-row block tables, the r5 ``active`` mask semantics (retired/free
    rows freeze; their dummy K/V writes redirect to the reserved null
@@ -255,8 +256,10 @@ class ServeEngine:
     carry — the host drains token bursts instead of paying a round trip
     per token.  Streams are bit-identical at every H (docs/serving.md
     "Decode horizon"); the scheduler clamps fused decode back to
-    single-step whenever prefill interleaving, waiting-queue deadlines,
-    or speculative rounds need iteration-level scheduling.
+    one step whenever prefill interleaving, waiting-queue deadlines,
+    or speculative rounds need iteration-level scheduling, and the
+    engine runs that step as one link at H = 1 (no option: it has the
+    program and runs no speculative rounds, or it does not).
 
     **Shape bucketing** (docs/serving.md): prefill always runs the ONE
     fixed ``prefill_chunk`` shape (the final residual pads, its K/V
@@ -1883,11 +1886,13 @@ class ServeEngine:
         only when no admissible request can reach it (shorter prompts
         and max_new=1 are tried before giving up) — then production
         cannot hit it either.  With a decode ``horizon`` the sweep also
-        drains one dummy per HORIZON rung (greedy and sampled variants,
-        serially — co-scheduled rung dummies would all bucket to the
+        drains one dummy per HORIZON rung, rung 1 included (greedy and
+        sampled variants — at rung 1 the sampled one serves both —
+        serially: co-scheduled rung dummies would all bucket to the
         largest limit), so fused decode never compiles under traffic
-        either.  Spec mode: the draft prefills through
-        its own padded chunk + extent ladder (``draft_prefill`` /
+        either; such an engine's decode never reaches ``paged_decode``,
+        so nothing here compiles it.  Spec mode: the draft prefills
+        through its own padded chunk + extent ladder (``draft_prefill`` /
         ``draft_join`` counters), and warmup sweeps THAT ladder too —
         spec-mode admission is fully compile-free after warmup.  An
         attached ``FaultInjector`` is disabled for the duration (dummy
@@ -1982,9 +1987,10 @@ class ServeEngine:
                         # would all bucket to the largest limit in the
                         # batch and leave the smaller rungs cold for the
                         # tail of every request's generation.
+                        # Rung 1 is the link of a clamped step (a slot
+                        # mid-prefill) and of a one-step tail: ONE
+                        # program, the mixed one, whatever the samplers.
                         for r in self.h_ladder:
-                            if r <= 1:
-                                continue
                             for ti, temp in enumerate((0.0, 1.0)):
                                 self._warmup_horizon_try(
                                     f"wh{round_}_{r}_{ti}", r, temp)
@@ -2390,13 +2396,16 @@ class ServeEngine:
     # -- token choice / emission -----------------------------------------
 
     def _choose_token(self, rs: ReqState, logits_row) -> int:
-        """HOST-side token choice — the prefill-first-token and
-        single-step (H=1 / spec-verify fallback) path only; the fused
-        decode horizon samples ON DEVICE.  Both draw through
+        """HOST-side token choice (counted: ``metrics.host_choices``) —
+        the first token after a prefill on every engine, and each decode
+        token of :meth:`_decode_rows` (``horizon=1`` engines, the
+        speculative tail and bail-out); the decode horizon, its one-step
+        link included, samples ON DEVICE.  Both draw through
         ``sampling.sample_logits_rowwise`` (same filter math, same
         ``fold_in(key(seed), emission_index)`` stream), so a stream may
-        cross between the two mid-request (preemption, horizon clamps)
-        without a token ever differing."""
+        cross between the two mid-request (prefill to decode,
+        preemption) without a token ever differing."""
+        self.metrics.host_choices += 1
         p = rs.req.params
         row = np.asarray(logits_row, np.float32)
         if p.greedy:
@@ -2930,12 +2939,18 @@ class ServeEngine:
 
     def _decode_once(self,
                      running: list[ReqState]) -> list[RequestOutput]:
-        """One decode pass for the running rows: a single per-token step
-        (the PR-1 path) or, with ``horizon > 1`` and the scheduler's
-        blessing, a fused multi-step horizon dispatch (pipelined when
-        ``pipeline > 1``).  Capacity for the WHOLE planned horizon is
+        """One decode pass for the running rows.  An engine that has a
+        horizon program and runs no speculative rounds (``horizon > 1``,
+        no ``spec_k``) dispatches horizon links whatever the scheduler
+        plans: a fused multi-step chain (pipelined when ``pipeline > 1``)
+        with its blessing, ONE link of ``H = 1`` when it clamps the step
+        (a slot mid-prefill, a waiting deadline) — the token is chosen on
+        the device either way and the host drains a ``[B, H]`` burst.
+        Every other engine (``horizon=1``; a speculative engine's
+        bail-out) takes the per-token step with the host sampler,
+        :meth:`_decode_rows`.  Capacity for the WHOLE planned horizon is
         reserved up front — a row that cannot grow quarantines here, per
-        row, exactly like the single-step path."""
+        row, on either path."""
         finished: list[RequestOutput] = []
         with self.trace.span("decode.plan"):
             h_plan = self.scheduler.plan_horizon(
@@ -2974,21 +2989,24 @@ class ServeEngine:
                 h_eff = bucket_down(
                     self.h_ladder,
                     min(h_plan, max(r.remaining_new for r in live)))
-        if h_eff <= 1:
-            self._forward_contained(
-                live, lambda rows: self._decode_rows(rows, finished),
-                "decode", finished)
-        else:
+        if self.horizon > 1 and not self.spec_k:
             self._forward_contained(
                 live,
                 lambda rows: self._decode_horizon_rows(rows, h_eff,
                                                        finished),
                 "decode horizon", finished)
+        else:
+            self._forward_contained(
+                live, lambda rows: self._decode_rows(rows, finished),
+                "decode", finished)
         return finished
 
     def _decode_rows(self, rows: list[ReqState], finished: list) -> None:
-        """ONE batched decode for ``rows`` (other slots inactive — their
-        writes redirect to the null block) + per-row commits.  All
+        """The per-token step of an engine WITHOUT horizon links
+        (``horizon=1``; a speculative engine's bail-out): ONE batched
+        decode for ``rows`` (other slots inactive — their writes redirect
+        to the null block), the logits to the host, a host-side token
+        choice and commit per row.  All
         engine-state mutation happens after the logits sync, so a
         failed dispatch leaves nothing committed and
         :meth:`_forward_contained` can retry or bisect safely."""
@@ -3043,8 +3061,9 @@ class ServeEngine:
 
     def _decode_horizon_rows(self, rows: list[ReqState], h: int,
                              finished: list) -> None:
-        """Fused multi-step decode for ``rows``: up to ``pipeline``
-        chained ``_paged_decode_horizon`` dispatches of ``h`` steps each,
+        """Fused decode for ``rows``: up to ``pipeline`` chained
+        ``_paged_decode_horizon`` dispatches of ``h`` steps each — or, at
+        ``h == 1`` (a step the scheduler clamped), one link of one step —
         then an in-order drain committing each link's token burst.
 
         The async pipeline is the point of the chaining: every link's
@@ -3110,16 +3129,15 @@ class ServeEngine:
             # max budget — a tail link covering a 2-step residual runs the
             # warmed H=2 program, not h-2 dead full-batch forwards on the
             # H=h one (every rung is warmup-swept, so no new traces).
+            # A step the scheduler clamps (h == 1) is ONE link of one step,
+            # whatever the rows could still run: the mid-prefill row it was
+            # clamped for is owed its chunk budget next engine step.  A
+            # one-step residual behind longer links chains like any other.
             budgets = []
             left = rem.copy()
-            for _ in range(max(self.pipeline, 1)):
+            for _ in range(self.pipeline if h > 1 else 1):
                 need = int(left[active].max()) if active.any() else 0
-                if need <= 1:
-                    # A 1-step residual is NOT worth a link: warmup never
-                    # compiles the H=1 horizon variant (the planner routes
-                    # single steps to the legacy `_decode_rows` program), so
-                    # the next iteration picks it up there — same dispatch
-                    # count, no cold trace under traffic.
+                if need < 1:
                     break
                 h_link = bucket_down(self.h_ladder, min(h, need))
                 lim = np.minimum(left, h_link).astype(np.int32)
@@ -3153,7 +3171,13 @@ class ServeEngine:
                 "decode_horizon", rids, self._horizon_fn, self.params,
                 self._pools, tables_d, kv_d, tok_d, active_d, done_d,
                 lim, cnt_d, *samp, H=int(h_link),
-                all_greedy=all_greedy, fire_injector=(j == 0))
+                # Rung 1 has the mixed-sampler variant alone: a greedy
+                # row takes its argmax there as beside any sampled
+                # slot-mate, at the sampler's ~0.3 ms a step, where a
+                # second program would be 3.4 s of every start (16
+                # layers, v5e: PERF.md §6, PR 31).
+                all_greedy=all_greedy and h_link > 1,
+                fire_injector=(j == 0))
             self._pools = pools
             outs.append((toks, mask, self._note_aux(aux)))
 

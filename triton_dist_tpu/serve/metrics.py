@@ -62,7 +62,7 @@ REQUESTS_RETAIN = 4096
 MERGE_COUNTERS = (
     "steps", "decode_steps", "verify_rounds", "prefill_tokens",
     "preemptions", "completed", "decode_tokens", "dispatches",
-    "host_syncs", "shed", "deadline_expired", "quarantined",
+    "host_syncs", "host_choices", "shed", "deadline_expired", "quarantined",
     "callback_errors", "forward_retries", "forward_bisections",
     "watchdog_trips", "spec_bailouts", "spec_rounds", "spec_proposed",
     "spec_accepted", "spec_tokens", "spec_dispatches",
@@ -289,6 +289,11 @@ class ServeMetrics:
     decode_tokens: int = 0        # tokens committed by the decode loop
     dispatches: int = 0           # decode-path device dispatches
     host_syncs: int = 0           # decode-path host sync points
+    # tokens chosen ON THE HOST (engine._choose_token: a logits row
+    # brought over, argmax or one `sample_token` launch): one a completed
+    # prefill on an engine whose decode runs horizon links, one a token
+    # on a `horizon=1` engine
+    host_choices: int = 0
     # failure-containment counters (docs/serving.md "Failure
     # containment"): every non-healthy retirement and every recovery
     # action is a counter, so overload and poison traffic are visible
@@ -854,12 +859,15 @@ class ServeMetrics:
         on a steady fused-horizon batch — the horizon amortizes steps,
         the batch amortizes rows, and only the former is the decode
         horizon's doing; ``host_syncs`` counts the blocking device→host
-        fetches the loop paid."""
+        fetches the loop paid, ``host_choices`` the tokens the host chose
+        from a logits row (first tokens after a prefill, and every token
+        of the per-token ``_decode_rows`` path)."""
         return {
             "decode_steps": self.decode_steps,
             "decode_tokens": self.decode_tokens,
             "dispatches": self.dispatches,
             "host_syncs": self.host_syncs,
+            "host_choices": self.host_choices,
             "tokens_per_dispatch": (self.decode_tokens / self.dispatches
                                     if self.dispatches else 0.0),
             "dispatches_per_token": (self.dispatches / self.decode_tokens
@@ -1026,6 +1034,8 @@ class ServeMetrics:
         counter("serve_dispatches_total", self.dispatches,
                 "decode-path device dispatches")
         counter("serve_host_syncs_total", self.host_syncs)
+        counter("serve_host_choices_total", self.host_choices,
+                "tokens chosen on the host from a logits row")
         counter("serve_completed_total", self.completed,
                 "requests retired (any reason)")
         counter("serve_preemptions_total", self.preemptions)

@@ -295,12 +295,17 @@ class FCFSScheduler:
 
         Fusing trades scheduling granularity for dispatch economy, so the
         plan clamps back to ITERATION-LEVEL decode (1) whenever a fused
-        horizon would break a per-step contract:
+        horizon would break a per-step contract.  How the one step is
+        dispatched is the engine's: an engine with a horizon program
+        runs it as ONE link of that program at ``H = 1`` (token choice
+        stays on the device), every other engine as the per-token
+        ``paged_decode`` step with the host sampler.
 
         - ``spec``: speculative rounds are already multi-token per
           dispatch and share device state across rows; they keep their
-          own round machinery (this also keeps a post-bailout engine on
-          the warmed single-step program).
+          own round machinery (a post-bailout engine serves single steps
+          on ``paged_decode``, the program its warm-up compiled: it
+          warms no horizon rung).
         - ``prefilling``: mid-prefill rows are owed chunk budget every
           iteration — a fused horizon would freeze their TTFT for its
           whole duration.
