@@ -221,6 +221,18 @@ _FAST_GATE_MODULES = {
     # top-k x top-p x ties grid at both cells' vocabularies, host path
     # == device path, and the filters' edge cases (~30 s, one worker).
     "test_sampling",
+    # window and global layers over softmax-routed experts (ISSUE 32, the
+    # mellum keys): prefill chunks + paged decode through BOTH cache
+    # groups against the float32 reference's full forward (and bf16
+    # failing the tolerance), the fused horizon == single steps, the
+    # softmax router and its tie, four shares adding up, the window
+    # group bounded while the full group grows and both free lists whole
+    # after a drain, preemption-and-recompute, the allocator's units,
+    # every refusal over cache groups by name, from_hf and its refusals,
+    # RoPE by kind, and the paged kernel under a per-layer window and
+    # name over a table with released pages (the whole file is the fast
+    # tier, ~3 min).
+    "test_swa_moe",
 }
 
 # Heavy tests inside core modules whose coverage is duplicated by a

@@ -552,6 +552,116 @@ def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu, name,
 
 
 # ---------------------------------------------------------------------------
+# Window and global layers over softmax-routed experts (ISSUE 32), at
+# published widths
+# ---------------------------------------------------------------------------
+
+
+def test_swa_moe_programs_compile_at_published_widths(v5e, as_tpu):
+    """Every program of ``mellum2_l8_mixedctx_sat`` — single-step decode,
+    the fused horizon (greedy and mixed) and its one-step link, prefill
+    chunks at every rung its prompts reach and the cap, the page fill — at
+    the file's widths and engine sizes, over BOTH cache groups (the full
+    group's 5,120 blocks on 2 layers, the window group's derived 641 on 6,
+    one table a group): the paged call carries its layer kind's name (6
+    ``gqa_paged_window`` + 2 ``gqa_paged_full`` a decode step: the
+    readers' patterns), every layer one gate-up + one down grouped GEMM,
+    and each program fits the chip beside nothing else.  The dense
+    family's call keeps no name (the test above)."""
+    import json
+    import os
+    import re
+    from collections import Counter
+
+    from benchmarks import builders_swa_moe
+    from triton_dist_tpu.models import mla_moe as M
+    from triton_dist_tpu.models import swa_moe as S
+    from triton_dist_tpu.runtime.jit_cache import named
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks/configs/mellum2-12b-a2.5b-l8.json")) as f:
+        config = json.load(f)
+    cfg = builders_swa_moe.model_config(config)
+    eng = config["engine"]
+    batch, page, max_seq = eng["max_batch"], eng["page_size"], eng["max_seq"]
+    put = functools.partial(_on, sharding=SingleDeviceSharding(v5e.devices[0]))
+    s = jax.ShapeDtypeStruct
+    params = jax.eval_shape(functools.partial(S.init_params, cfg),
+                            jax.random.key(0))
+    gen = S.SwaMoeGenerator(cfg, max_seq=max_seq)
+    ladder = E.build_bucket_ladder(max(page, eng["prefill_chunk"]), max_seq,
+                                   page)
+    assert ladder == [2048, 4096, 8192, 16384, 20480]
+    assert gen.kernel_gaps(page_size=page, ladder=ladder,
+                           prefill_chunk=eng["prefill_chunk"]) == {}
+    assert gen.kv_planes == [(4, 128)] * 2
+    # the window group's count as the engine derives it
+    ahead = eng["horizon"] * eng["pipeline"]
+    blocks = [eng["num_blocks"],
+              1 + batch * ((cfg.sliding_window + ahead - 2) // page + 2)]
+    assert blocks == [5120, 641]
+    kinds = cfg.kinds
+    pools = [tuple(s((blocks[k.group], h, page, d), cfg.dtype)
+                   for h, d in gen.kv_planes) for k in kinds]
+    d_args, h_args = _decode_args(cfg, page, batch=batch, max_seq=max_seq,
+                                  params=params, pools=pools)
+    tables = s((2, batch, max_seq // page), I32)
+    d_args = d_args[:2] + (tables,) + d_args[3:]
+    h_args = h_args[:2] + (tables,) + h_args[3:]
+    kw = dict(cfg=cfg, page=page, **gen.serve_hooks())
+    want = {"gqa_paged_window": 6, "gqa_paged_full": 2,
+            M.GATE_UP_CALL: 8, M.DOWN_CALL: 8}
+    worst = {}
+
+    def check(name, jitted, *args, want=want, **statics):
+        compiled = jitted.lower(*put(args), **statics).compile()
+        text = compiled.as_text()
+        assert text.split(",", 1)[0] == f"HloModule jit_{name}"
+        calls = Counter(n.split(".")[0] for n in re.findall(
+            r"^\s*(?:ROOT )?%?([\w.\-]+) = [^\n]*custom-call\([^\n]*"
+            + MOSAIC_CALL, text, re.M))
+        if want is not None:
+            assert calls == want, (name, statics, calls)
+        ma = compiled.memory_analysis()
+        total = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+                 + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+        assert total < HBM_GIB * 2 ** 30, (name, statics, total / 2 ** 30)
+        worst[name] = max(worst.get(name, 0), total / 2 ** 30)
+        return calls
+
+    check("paged_decode", jax.jit(named(
+        gen.wrap_program(PR._paged_decode_step), "paged_decode", **kw),
+        donate_argnums=(1,)), *d_args)
+    horizon = jax.jit(named(
+        PR._paged_decode_horizon, "decode_horizon",
+        decode_fwd=gen.wrap_program(functools.partial(
+            PR._paged_decode_forward, **kw))),
+        static_argnames=("H", "all_greedy"), donate_argnums=(1,))
+    for all_greedy in (True, False):
+        check("decode_horizon", horizon, *h_args, H=eng["horizon"],
+              all_greedy=all_greedy)
+    check("decode_horizon", horizon, *h_args, H=1, all_greedy=False)
+    # a prefill chunk: flash attention a layer (the window as a block
+    # skip), the grouped GEMMs at the chunk's own row tile
+    assert cfg.row_tile(eng["prefill_chunk"]) == 256 and \
+        cfg.row_tile(batch) == 32
+    for extent in ladder:
+        sc = tuple(s((1, h, extent, d), cfg.dtype) for h, d in gen.kv_planes)
+        calls = check("prefill_chunk", gen._chunk_jit, params,
+                      s((1, eng["prefill_chunk"]), I32),
+                      [sc] * cfg.n_layers, s((), I32), quantized=False,
+                      extent=extent, n_valid=s((), I32), want=None)
+        assert calls[M.GATE_UP_CALL] == calls[M.DOWN_CALL] == 8
+        assert sum(calls.values()) == 24, calls        # + 8 flash calls
+        fill = jax.jit(named(PR._fill_pool_pages, "fill_pages", page=page,
+                             kinds=kinds), donate_argnums=(0,))
+        check("fill_pages", fill, pools, [sc] * cfg.n_layers,
+              s((2, extent // page), I32), want={})
+    print("GiB a program:", {k: round(v, 2) for k, v in worst.items()})
+
+
+# ---------------------------------------------------------------------------
 # The sampler sorts nothing (ISSUE 29)
 # ---------------------------------------------------------------------------
 

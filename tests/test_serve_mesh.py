@@ -283,6 +283,20 @@ def test_mesh_block_manager_partitions():
     tab = bm2.table("x")
     assert bm2.part_of_block(tab[2]) == 1
     bm2.free("x")
+    # a hit whose chain reaches into the LAST partition asked about is
+    # claimed from the cache tier as the table's head: the table starts
+    # with the shared blocks themselves, at full length
+    hit = bm2.match_prefix([1, 2, 3, 4, 5, 6, 7, 8])
+    assert hit == tab[:3]
+    two = BlockManager(8, 2, shards=2, pages_per_shard=2, prefix_cache=True)
+    old = two.allocate("x", 8)
+    for logical, toks in enumerate(([1, 2], [3, 4], [5, 6])):
+        two.commit_block("x", logical, toks)
+    two.free("x")
+    shared = two.match_prefix([1, 2, 3, 4, 5, 6, 7, 8])
+    assert two.part_of_block(shared[2]) == 1     # cached, last partition
+    t2 = two.allocate("y", 8, shared)
+    assert t2[:3] == old[:3] and len(t2) == 4 and two.placement_ok(t2)
     bm3 = BlockManager(16, 2, shards=4, pages_per_shard=2,
                        prefix_cache=True)
     # same content, committed under world-1-style placement (all in

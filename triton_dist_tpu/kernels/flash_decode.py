@@ -670,10 +670,14 @@ def gqa_decode_shard(q, k, v, local_lens, *, block_s=None, impl="auto",
 #   first page under a sliding window): a dead table entry costs neither a
 #   DMA nor a step, and a row with ``len == 0`` only writes its empty
 #   partials.  Dead entries must still hold an in-range pool index.
-# * The call has no name of its own: it reaches a device trace under the
-#   name of the scope around it, ``closed_call`` in the fused horizon's
-#   scan and ``_unknown_`` in the single-step program — the two names
-#   ``benchmarks/layer_metrics/paged_attn_roofline.json`` sums
+# * The call carries the name its caller gives it (``name=``): a family
+#   whose layers differ in kind names the call by the layer's kind
+#   (``gqa_paged_window`` / ``gqa_paged_full``, models/swa_moe.py), so a
+#   device trace tells a window layer's walk from a full layer's.  Where no
+#   name is given — the dense family's call — it reaches a device trace
+#   under the name of the scope around it, ``closed_call`` in the fused
+#   horizon's scan and ``_unknown_`` in the single-step program: the two
+#   names ``benchmarks/layer_metrics/paged_attn_roofline.json`` sums
 #   (docs/paged_decode.md).
 
 
@@ -759,7 +763,8 @@ def paged_kernel_gap(page: int, head_dim: int, itemsize: int, *,
 def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
                            impl="auto", interpret=False, soft_cap=0.0,
                            window=0, window_lens=None, q_lens=None,
-                           k_scale=None, v_scale=None):
+                           k_scale=None, v_scale=None,
+                           name: str | None = None):
     """Single-shard GQA decode over a PAGED KV cache.
 
     q [B, Hq, D]; k/v_pool [N_pages, Hkv, page, D] (the physical page
@@ -782,6 +787,9 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
     be [B, T, Hq, D] with optional per-request ``q_lens`` [B] — the
     k-token verify over a PAGED cache (mixed decode/verify batches);
     returns (out [B, T, Hq, D], lse [B, T, Hq]).
+
+    ``name`` is the Mosaic call's name in a device trace (the comment
+    above the budget: none by default).
     """
     multi = q.ndim == 4
     n_tok = q.shape[1] if multi else 1
@@ -826,6 +834,7 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
                              use_qlens=use_qlens)
     out, lse = pl.pallas_call(
         kern,
+        name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # (lens, block_table)
             grid=(B, Hkv // hh),

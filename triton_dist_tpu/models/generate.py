@@ -32,6 +32,33 @@ from triton_dist_tpu.models.llama import LlamaConfig, _rms_norm
 from triton_dist_tpu.runtime.jit_cache import named
 
 
+@dataclass(frozen=True)
+class LayerKind:
+    """What ONE layer is, as a static the seams see at trace time: a model
+    whose layers differ in kind (``models/swa_moe.py``: sliding-window
+    layers beside full ones) hands :func:`_layer_stack` one of these a
+    layer (``kinds``), and ``project``, the attend pair and ``write_kv``
+    read ``kinds[li]``.  A model with one kind of layer passes none.
+
+    ``attn`` names the attention (``"full"`` | ``"window"``), ``window``
+    its reach in positions (0: the whole context), ``group`` the cache
+    group — block table and pool geometry — the layer reads and writes
+    (serve/block_manager.py ``KvGroups``)."""
+
+    attn: str = "full"
+    window: int = 0
+    group: int = 0
+
+    @property
+    def call_name(self) -> str:
+        """The paged attention call's name in a device trace."""
+        return f"gqa_paged_{self.attn}"
+
+
+def _kind_kw(kinds, li) -> dict:
+    return {} if kinds is None else {"kind": kinds[li]}
+
+
 @dataclass
 class GenerationState:
     """Per-layer sharded KV caches + global lengths."""
@@ -386,7 +413,7 @@ class Generator:
 
 
 def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
-                 ffn, write_kv, attend):
+                 ffn, write_kv, attend, kinds=None):
     """THE layer loop of serving and its oracles: tokens [B, T] at global
     positions pos [B, T] (a leading 1 broadcasts) through every layer of
     ``params`` -> (new caches, logits [B, T, V] float32).  Decode is this
@@ -415,6 +442,10 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     - ``attend(li, q, cache') -> [B, T, Hq, .]`` scores the queries
       against the updated cache.
 
+    ``kinds`` (a :class:`LayerKind` a layer, static) is given by a model
+    whose layers differ in kind: ``project`` then takes ``kind=kinds[li]``
+    (a RoPE per kind), and the pair, which has ``li``, looks its own up.
+
     The residual stream is ``[B, T, D]`` — and ``[B, D]`` at T = 1, by
     evidence, not taste (PERF.md §6, PR 28): the chip's compiler folds
     the ``[B, T, D] -> [B * T, D] -> [B, T, H, hd]`` reshapes around the
@@ -428,7 +459,8 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     new_caches = []
     for li, layer in enumerate(params["layers"]):
         h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q, k, v = project(h.reshape(B, T, -1), layer, pos)
+        q, k, v = project(h.reshape(B, T, -1), layer, pos,
+                          **_kind_kw(kinds, li))
         cache = write_kv(li, caches[li], k, v)
         o = attend(li, q, cache)                         # [B, T, Hq, .]
         o2 = o.reshape(B * T, -1).astype(cfg.dtype)
@@ -491,29 +523,36 @@ def _pool_views(pool):
     return k_pool, v_pool, None, None
 
 
-def paged_attend(q, pool, tables, lens, *, cfg, impl, interpret):
+def paged_attend(q, pool, tables, lens, *, cfg, impl, interpret,
+                 kind: LayerKind | None = None):
     """The engine's paged attend over ONE layer's ``(K, V)`` pool
     (``[NB, Hkv, page, hd]`` planes, float or int8 ``{"q", "s"}``):
     q [B, (T,) Hq, hd] -> [B, (T,) Hq, hd] through the block-table
-    kernel."""
+    kernel.  The window is the model's one (``cfg.attn_window``) or, with
+    a ``kind``, the layer's own — and the call then carries the kind's
+    name."""
     kq, vq, ks, vs = _pool_views(pool)
     o, _ = gqa_decode_paged_shard(
         q, kq, vq, tables, lens, impl=impl, interpret=interpret,
-        soft_cap=cfg.attn_soft_cap, window=cfg.attn_window, k_scale=ks,
-        v_scale=vs)
+        soft_cap=cfg.attn_soft_cap,
+        window=cfg.attn_window if kind is None else kind.window,
+        k_scale=ks, v_scale=vs,
+        name=None if kind is None else kind.call_name)
     return o
 
 
-def _attend_prompt(q, k, v, *, cfg, impl, interpret):
+def _attend_prompt(q, k, v, *, cfg, impl, interpret, kind=None):
     """Whole-prompt causal attention of :func:`_prompt_forward`:
-    q [B, S, Hq, hd] over its own rows k, v [B, S, Hkv, hd]."""
+    q [B, S, Hq, hd] over its own rows k, v [B, S, Hkv, hd] (under the
+    layer's own window where a ``kind`` is given)."""
     from triton_dist_tpu.kernels.flash_attention import flash_attention
 
     out = flash_attention(
         *(t.transpose(0, 2, 1, 3) for t in (q, k, v)), causal=True,
         scale=1.0 / np.sqrt(cfg.head_dim),
         impl="xla" if impl == "xla" else "auto", interpret=interpret,
-        window=cfg.attn_window, soft_cap=cfg.attn_soft_cap)
+        window=cfg.attn_window if kind is None else kind.window,
+        soft_cap=cfg.attn_soft_cap)
     return out.transpose(0, 2, 1, 3)
 
 
@@ -582,8 +621,10 @@ def prefill_kernel_gap(chunk: int, extent: int, head_dim: int) -> str | None:
 
 def _attend_prefix(q, k_all, v_all, prefix_len, *, k_scale=None,
                    v_scale=None, impl="auto", interpret=False,
-                   mesh=None, axis=None, window=0, soft_cap=0.0):
-    """Chunk attention against the cache prefix + itself.
+                   mesh=None, axis=None, window=0, soft_cap=0.0,
+                   kind=None):
+    """Chunk attention against the cache prefix + itself (under the
+    layer's own window where a ``kind`` is given).
 
     q [B, c, Hq, hd]; k/v_all [B, Hkv, S, hd] (the full cache, chunk rows
     already written at [prefix, prefix+c)); position j is visible to chunk
@@ -608,6 +649,8 @@ def _attend_prefix(q, k_all, v_all, prefix_len, *, k_scale=None,
     exercised by tests/test_flash_attention.py and the kernel-reach spy
     in tests/test_chunked_prefill.py.
     """
+    if kind is not None:
+        window = kind.window
     if impl != "xla":
         from triton_dist_tpu.kernels.flash_attention import (
             flash_attention,
@@ -734,7 +777,7 @@ def _write_chunk(cache, new, prefix_len, quantized):
 
 def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
                    project, out_proj, ffn, attend,
-                   extent: int | None = None, n_valid=None):
+                   extent: int | None = None, n_valid=None, kinds=None):
     """One prompt chunk [B, c] against the cached prefix; returns
     (new_caches, logits [B, c, V] — position i predicts the token after
     chunk[:, i]): :func:`_layer_stack` with the pair of a CONTIGUOUS
@@ -790,13 +833,14 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
             k_c, v_c = planes
             return attend(q, k_c["q"][:, :, :ext], v_c["q"][:, :, :ext],
                           prefix_len, k_scale=k_c["s"][:, :, :ext],
-                          v_scale=v_c["s"][:, :, :ext])
+                          v_scale=v_c["s"][:, :, :ext],
+                          **_kind_kw(kinds, li))
         return attend(q, *(p[:, :, :ext] for p in planes), prefix_len,
-                      k_scale=None, v_scale=None)
+                      k_scale=None, v_scale=None, **_kind_kw(kinds, li))
 
     return _layer_stack(params, chunk, positions[None], caches, cfg=cfg,
                         project=project, out_proj=out_proj, ffn=ffn,
-                        write_kv=write_kv, attend=attend_views)
+                        write_kv=write_kv, attend=attend_views, kinds=kinds)
 
 
 def _write_rows(cache, new, offs):
@@ -856,7 +900,8 @@ def _verify_forward(params, chunk, caches, kv_lens, *, cfg: LlamaConfig,
                         write_kv=write_kv, attend=attend)
 
 
-def _prompt_forward(params, tokens, *, cfg, project, out_proj, ffn, attend):
+def _prompt_forward(params, tokens, *, cfg, project, out_proj, ffn, attend,
+                    kinds=None):
     """Full-sequence forward on replicated weights that also returns the
     per-layer cache rows (post-RoPE, cache layout [B, Hkv, S, .], one per
     plane) and logits: :func:`_layer_stack` with a pair that keeps the
@@ -868,5 +913,6 @@ def _prompt_forward(params, tokens, *, cfg, project, out_proj, ffn, attend):
         [None] * len(params["layers"]), cfg=cfg, project=project,
         out_proj=out_proj, ffn=ffn,
         write_kv=lambda li, _, k, v: (k,) if v is None else (k, v),
-        attend=lambda li, q, kv: attend(q, *kv))
+        attend=lambda li, q, kv: attend(q, *kv, **_kind_kw(kinds, li)),
+        kinds=kinds)
     return [tuple(t.transpose(0, 2, 1, 3) for t in kv) for kv in rows], logits

@@ -135,6 +135,7 @@ class MlaMoeConfig:
     max_seq: int = 2048
     dtype: object = jnp.float32
     moe_block_m: int = 32       # grouped-GEMM row tile (see routed_experts)
+    router: str = "sigmoid_noaux"   # the router's kind (see route)
     rope_interleave: bool = False   # rotary pairs (2i, 2i + 1), else halves
     # learned sparse attention (0 heads / 0 rows: none)
     index_n_heads: int = 0
@@ -182,6 +183,12 @@ class MlaMoeConfig:
 
     def is_moe_layer(self, li: int) -> bool:
         return li >= self.first_k_dense
+
+    def row_tile(self, rows: int) -> int:
+        """The grouped GEMMs' row tile in a program of ``rows`` rows: this
+        family's is the one fixed option (``moe_block_m``), whatever the
+        program (:func:`row_tile` is the rule that follows the rows)."""
+        return self.moe_block_m
 
     @staticmethod
     def from_hf(c: dict, *, max_seq: int, dtype=jnp.bfloat16,
@@ -288,19 +295,26 @@ def _yarn_mscale(factor: float, mscale: float) -> float:
 
 
 def yarn_inv_freq(cfg: MlaMoeConfig) -> np.ndarray:
-    """Inverse frequencies of the ``qk_rope_head_dim / 2`` rotary pairs:
-    plain RoPE's, or YaRN's blend of the interpolated (``/ factor``) and
-    the extrapolated ones along a linear ramp between the pairs that
-    turn ``beta_fast`` and ``beta_slow`` times over the original context."""
-    d = cfg.qk_rope_head_dim
-    extra = 1.0 / (cfg.rope_theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
-    if cfg.yarn is None:
+    """:func:`rope_inv_freq` of this block's rotary columns."""
+    return rope_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta, cfg.yarn)
+
+
+def rope_inv_freq(d: int, theta: float, yarn: tuple | None) -> np.ndarray:
+    """Inverse frequencies of the ``d / 2`` rotary pairs: plain RoPE's, or
+    YaRN's blend (``yarn``: factor, original context, beta_fast,
+    beta_slow, ...) of the interpolated (``/ factor``) and the
+    extrapolated ones along a linear ramp between the pairs that turn
+    ``beta_fast`` and ``beta_slow`` times over the original context.  The
+    ONE copy: the latent block's rotary columns and a dense head's whole
+    width (models/swa_moe.py) both come here."""
+    extra = 1.0 / (theta ** (np.arange(0, d, 2, dtype=np.float64) / d))
+    if yarn is None:
         return extra.astype(np.float32)
-    factor, orig, beta_fast, beta_slow, _, _ = cfg.yarn
+    factor, orig, beta_fast, beta_slow = yarn[:4]
 
     def corr(n_rot):
         return (d * math.log(orig / (n_rot * 2 * math.pi))
-                / (2 * math.log(cfg.rope_theta)))
+                / (2 * math.log(theta)))
 
     low = max(math.floor(corr(beta_fast)), 0)
     high = min(math.ceil(corr(beta_slow)), d - 1)
@@ -540,14 +554,29 @@ def _top_k(x, k: int):
     return jnp.stack(vals, axis=-1), jnp.stack(ids, axis=-1)
 
 
-def route(h2, layer, cfg: MlaMoeConfig):
-    """``noaux_tc``: h2 [T, D] -> (ids [T, top_k] int32 over all
-    ``n_experts``, weights [T, top_k] float32).  Scores in float32; the
-    bias moves the choice, never the weight."""
+def route(h2, layer, cfg):
+    """h2 [T, D] -> (ids [T, top_k] int32 over all ``n_experts``, weights
+    [T, top_k] float32), scores in float32.  The router's KIND is data of
+    the one expert layer (``cfg.router``):
+
+    - ``"sigmoid_noaux"`` (``noaux_tc``): sigmoid scores, a bias that
+      moves the choice and never the weight, group-limited top-k, weights
+      renormalised and scaled;
+    - ``"softmax"``: a softmax over all experts, the ``top_k`` largest
+      (ties to the lower id), renormalised over the chosen
+      (``norm_topk_prob``): no bias, no groups, no scaling."""
     c = cfg
     T = h2.shape[0]
-    s = jax.nn.sigmoid(jnp.dot(h2, layer["router"],
-                               preferred_element_type=jnp.float32))
+    scores = jnp.dot(h2, layer["router"], preferred_element_type=jnp.float32)
+    if c.router == "softmax":
+        p = jax.nn.softmax(scores, axis=-1)
+        w, ids = _top_k(p, c.top_k)
+        if c.norm_topk_prob:
+            w = w / w.sum(-1, keepdims=True)
+        return ids.astype(jnp.int32), w
+    if c.router != "sigmoid_noaux":
+        raise ValueError(f"router {c.router!r}: 'sigmoid_noaux' or 'softmax'")
+    s = jax.nn.sigmoid(scores)
     sb = s + layer["router_bias"].astype(jnp.float32)
     per = c.n_experts // c.n_group
     best2 = _top_k(sb.reshape(T, c.n_group, per), min(2, per))[0]
@@ -562,29 +591,45 @@ def route(h2, layer, cfg: MlaMoeConfig):
     return ids.astype(jnp.int32), w * c.routed_scaling
 
 
-def routed_experts(h2, layer, cfg: MlaMoeConfig, *, impl="auto",
-                   interpret=False):
+ROW_TILES = (32, 64, 128, 256)
+
+
+def row_tile(rows: int, top_k: int, n_experts: int) -> int:
+    """The grouped GEMMs' row tile that FOLLOWS the rows an expert gets in
+    a program of ``rows`` rows under even routing (``rows . top_k /
+    n_experts``): the smallest of ``ROW_TILES`` that holds them in one
+    piece.  ``group_gemm_live`` streams an expert's slab once a row tile,
+    so 256 rows an expert at tile 32 read it 8 times; a tile under 128
+    rows costs the MXU what any such tile costs, so 32 is the floor."""
+    per_expert = -(-rows * top_k // n_experts)
+    return next((t for t in ROW_TILES if t >= per_expert), ROW_TILES[-1])
+
+
+def routed_experts(h2, layer, cfg, *, impl="auto", interpret=False):
     """The held experts' part of the routed sum for rows h2 [T, D] ->
     (float32 [T, D], stats int32 [4]).
 
     Rows routed here are gathered expert by expert into row tiles of
-    ``moe_block_m`` (a decode step of 64 rows gives a held expert ~2, a
-    prefill chunk ~4; a tile of 32 holds an expert's rows in one piece,
-    so its weights stream once, and costs the MXU what any tile under 128
-    rows costs), then two grouped GEMMs: gate and up side by side, and
+    ``cfg.row_tile(T)`` rows (the latent family: its fixed ``moe_block_m``
+    — a decode step of 64 rows gives a held expert ~2, a prefill chunk
+    ~4; a tile of 32 holds an expert's rows in one piece, so its weights
+    stream once, and costs the MXU what any tile under 128 rows costs;
+    ``models/swa_moe.py``: :func:`row_tile`, read off this program's
+    static shapes), then two grouped GEMMs: gate and up side by side, and
     down.  ``stats``: assignments routed, those that landed here, pad rows
     of the live tiles, experts hit."""
     c = cfg
     T, D = h2.shape
     F = c.moe_ffn_dim
+    block_m = c.row_tile(T)
     ids, w = route(h2, layer, c)
-    plan = moe_utils.sort_align_held(ids, c.experts_held, c.moe_block_m,
+    plan = moe_utils.sort_align_held(ids, c.experts_held, block_m,
                                      c.expert_offset)
     live = plan["valid_rows"][:, None]
     x_sorted = jnp.where(live, h2[plan["src_token"]], jnp.zeros((), h2.dtype))
     gg = functools.partial(group_gemm_live, tile_expert=plan["tile_expert"],
                            n_live=plan["n_live_tiles"],
-                           block_m=c.moe_block_m, impl=impl,
+                           block_m=block_m, impl=impl,
                            interpret=interpret)
     gu = gg(x_sorted, layer["w_gate_up"], name=GATE_UP_CALL)
     # dead tiles are not written: whatever they hold stays out of the sum
@@ -598,7 +643,7 @@ def routed_experts(h2, layer, cfg: MlaMoeConfig, *, impl="auto",
     out = jnp.einsum("tk,tkd->td", jnp.where(local, w, 0.0), picked)
     n_local = jnp.sum(plan["local"].astype(jnp.int32))
     stats = jnp.stack([jnp.int32(T * c.top_k), n_local,
-                       plan["n_live_tiles"] * c.moe_block_m - n_local,
+                       plan["n_live_tiles"] * block_m - n_local,
                        jnp.sum((plan["counts"] > 0).astype(jnp.int32))])
     return out, stats
 
@@ -637,8 +682,8 @@ def with_moe_stats(fwd, tally: MoeTally):
     return run
 
 
-def ffn(h2, layer, *, cfg: MlaMoeConfig, tally: MoeTally | None = None,
-        impl="auto", interpret=False):
+def ffn(h2, layer, *, cfg, tally: MoeTally | None = None, impl="auto",
+        interpret=False):
     """Dense SwiGLU on the leading layers; shared expert + the held
     routed experts on the rest."""
     if "router" not in layer:

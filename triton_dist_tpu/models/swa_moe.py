@@ -1,0 +1,378 @@
+"""Grouped-query attention whose KIND differs by layer — sliding-window
+layers beside full ones, a RoPE a kind — over softmax-routed small experts
+(the ``mellum`` block), for the serving engine.
+
+The block, per layer ``li`` of kind ``layer_types[li]``: ``x += W_o .
+GQA(rms(x))``, ``x += MoE(rms(x))``.
+
+* **Attention.**  Dense q / k / v products (``head_dim`` is a key of its
+  own, not ``hidden_size / heads``), an RMSNorm per head on q and on k
+  before RoPE, RoPE over the whole head (rotate-half), then causal GQA.  A
+  ``sliding_attention`` layer sees the last ``sliding_window`` positions
+  (key ``j`` for query ``i`` iff ``i - j < window``) under plain RoPE; a
+  ``full_attention`` layer sees everything under YaRN (the blend of
+  ``mla_moe.rope_inv_freq``, cos and sin times ``attention_factor``).  The
+  kind reaches every seam as a static (:class:`generate.LayerKind`):
+  ``project`` picks the RoPE, the attend pair the window and the paged
+  call's trace name (``gqa_paged_window`` / ``gqa_paged_full``).
+* **Cache groups.**  Layers fall into groups by kind, and a group has its
+  own block table and its own pool geometry (serve/block_manager.py
+  ``KvGroups``): the full group grows with the context, the window group
+  holds the pages a window layer can still see.  ``LayerKind.group`` says
+  which a layer reads.
+* **Expert layer.**  The one the latent family runs
+  (``mla_moe.routed_experts``: ``sort_align_held`` + ``group_gemm_live``,
+  ``MoeTally``), its router a plain softmax top-k, renormalised — no bias,
+  no groups, no scaling, no shared expert, no leading dense layer — and
+  its row tile read off each program's rows (``mla_moe.row_tile``).  It is
+  told which experts it holds (``experts_held`` from ``expert_offset``).
+
+Everything enters the engine's programs through the seams of
+``models/generate.py``; there is no layer loop and no forward here.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import math
+import types
+
+import jax
+import jax.numpy as jnp
+
+from triton_dist_tpu.models import mla_moe
+from triton_dist_tpu.models.generate import (
+    LayerKind,
+    _attend_prefix,
+    _attend_prompt,
+    _chunk_forward,
+    _dense_out_proj,
+    _prompt_forward,
+    attention_kernel_gaps,
+    paged_attend,
+)
+from triton_dist_tpu.models.llama import _rms_norm
+from triton_dist_tpu.runtime.jit_cache import named
+
+# config.json's names of the layer kinds -> LayerKind.attn
+ATTN_KINDS = {"full_attention": "full", "sliding_attention": "window"}
+
+
+@dataclasses.dataclass(frozen=True)
+class SwaMoeConfig:
+    vocab: int
+    dim: int
+    n_layers: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int               # a key of its own: not dim / n_heads
+    moe_ffn_dim: int            # one expert
+    n_experts: int              # the router's width
+    experts_held: int           # routed experts this chip holds ...
+    expert_offset: int = 0      # ... ids offset .. offset + held - 1
+    top_k: int = 8
+    norm_topk_prob: bool = True
+    layer_types: tuple = ()     # "window" | "full", one a layer
+    sliding_window: int = 0
+    rope_theta: float = 500000.0
+    # the full layers' YaRN: (factor, original_max_position_embeddings,
+    # beta_fast, beta_slow, attention_factor), or None for plain RoPE;
+    # window layers always rotate plainly
+    yarn: tuple | None = None
+    norm_eps: float = 1e-6
+    max_seq: int = 2048
+    dtype: object = jnp.float32
+    router: str = "softmax"     # mla_moe.route's kind
+    attn_soft_cap: float = 0.0
+
+    def __post_init__(self):
+        bad = sorted(set(self.layer_types) - set(ATTN_KINDS.values()))
+        if bad or len(self.layer_types) != self.n_layers:
+            raise ValueError(
+                f"layer_types {self.layer_types}: one of "
+                f"{sorted(ATTN_KINDS.values())} for each of the "
+                f"{self.n_layers} layers")
+        if "window" in self.layer_types and self.sliding_window < 1:
+            raise ValueError("window layers need sliding_window >= 1")
+
+    # -- the layers' kinds and the cache groups they fall into -------------
+    @property
+    def group_names(self) -> tuple:
+        """The cache groups, full first: a model of one kind has one."""
+        return tuple(k for k in ("full", "window") if k in self.layer_types)
+
+    @property
+    def kinds(self) -> tuple:
+        """One :class:`LayerKind` a layer."""
+        groups = self.group_names
+        return tuple(LayerKind(
+            attn=t, window=self.sliding_window if t == "window" else 0,
+            group=groups.index(t)) for t in self.layer_types)
+
+    def rope(self, attn: str) -> tuple:
+        """(inverse frequencies, cos / sin factor) of a layer kind."""
+        yarn = self.yarn if attn == "full" else None
+        return (mla_moe.rope_inv_freq(self.head_dim, self.rope_theta, yarn),
+                1.0 if yarn is None else float(yarn[4]))
+
+    def row_tile(self, rows: int) -> int:
+        """The grouped GEMMs' row tile follows the rows an expert gets in
+        THIS program (8 in a 64-row decode step, 256 in a 2,048-token
+        chunk at 64 experts, top-8): ``mla_moe.row_tile``."""
+        return mla_moe.row_tile(rows, self.top_k, self.n_experts)
+
+    @staticmethod
+    def from_hf(c: dict, *, max_seq: int, dtype=jnp.bfloat16,
+                experts_total: int | None = None, expert_offset: int = 0,
+                **over) -> "SwaMoeConfig":
+        """From the keys of a ``mellum`` ``config.json`` (docs/serving.md
+        lists them).  An unknown ``model_type``, an unknown layer kind, a
+        ``dense`` entry in ``mlp_layer_types`` and a RoPE type that is not
+        served are refused by name."""
+        kind = c.get("model_type")
+        if kind != "mellum":
+            raise ValueError(f"model_type {kind!r}: served here is 'mellum'")
+        unknown = sorted(set(c["layer_types"]) - set(ATTN_KINDS))
+        if unknown:
+            raise ValueError(f"layer_types {unknown}: served are "
+                             f"{sorted(ATTN_KINDS)}")
+        mlp = c.get("mlp_layer_types") or ["sparse"] * len(c["layer_types"])
+        if set(mlp) != {"sparse"}:
+            raise ValueError(
+                f"mlp_layer_types {sorted(set(mlp) - {'sparse'})}: every "
+                f"layer's MLP is the expert layer here ('sparse'); no "
+                f"dense MLP is served in this block")
+        n_layers = c["num_hidden_layers"]
+        if len(c["layer_types"]) != n_layers or len(mlp) != n_layers:
+            raise ValueError("layer_types / mlp_layer_types must have "
+                             "num_hidden_layers entries")
+        for key, want in (("hidden_act", "silu"), ("attention_bias", False)):
+            if c.get(key, want) != want:
+                raise ValueError(f"{key} {c[key]!r}: only {want!r} is served")
+        if "sliding_attention" in c["layer_types"] \
+                and not c.get("use_sliding_window", True):
+            raise ValueError("sliding_attention layers with "
+                             "use_sliding_window false")
+        rp = c["rope_parameters"]
+        full = rp["full_attention"]
+        sliding = rp.get("sliding_attention", {"rope_theta": full["rope_theta"]})
+        if sliding.get("rope_type", "default") != "default":
+            raise ValueError(f"rope_parameters.sliding_attention {sliding!r}: "
+                             f"only plain RoPE is served on window layers")
+        if float(sliding["rope_theta"]) != float(full["rope_theta"]):
+            raise ValueError("one rope_theta for both layer kinds is served")
+        yarn = None
+        if full.get("rope_type", "default") == "yarn":
+            yarn = (float(full["factor"]),
+                    int(full["original_max_position_embeddings"]),
+                    float(full["beta_fast"]), float(full["beta_slow"]),
+                    float(full.get("attention_factor")
+                          or 0.1 * math.log(float(full["factor"])) + 1.0))
+        elif full.get("rope_type", "default") != "default":
+            raise ValueError(f"rope_parameters.full_attention {full!r}: "
+                             f"served are 'default' and 'yarn'")
+        return SwaMoeConfig(
+            vocab=c["vocab_size"], dim=c["hidden_size"], n_layers=n_layers,
+            n_heads=c["num_attention_heads"],
+            n_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
+            moe_ffn_dim=c["moe_intermediate_size"],
+            n_experts=experts_total or c["num_experts"],
+            experts_held=c["num_experts"], expert_offset=expert_offset,
+            top_k=c["num_experts_per_tok"],
+            norm_topk_prob=bool(c["norm_topk_prob"]),
+            layer_types=tuple(ATTN_KINDS[t] for t in c["layer_types"]),
+            sliding_window=int(c.get("sliding_window") or 0),
+            rope_theta=float(full["rope_theta"]), yarn=yarn,
+            norm_eps=float(c["rms_norm_eps"]), max_seq=max_seq, dtype=dtype,
+            **over)
+
+    @staticmethod
+    def tiny(dtype=jnp.float32, **over) -> "SwaMoeConfig":
+        """CPU test size: periods of (window, window, window, full) over
+        ``n_layers`` (8: two of them) unless ``layer_types`` is given,
+        window 16, 8 experts top-2, kernel-legal head and expert widths."""
+        kw = dict(vocab=256, dim=128, n_layers=8, n_heads=4, n_kv_heads=2,
+                  head_dim=128, moe_ffn_dim=128, n_experts=8, experts_held=8,
+                  top_k=2, sliding_window=16, rope_theta=1e4,
+                  yarn=(4.0, 32, 32.0, 1.0, 1.1386), max_seq=256, dtype=dtype)
+        kw.update(over)
+        period = ("window",) * 3 + ("full",)
+        kw.setdefault("layer_types", tuple(
+            period[i % 4] for i in range(kw["n_layers"])))
+        return SwaMoeConfig(**kw)
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+# name -> (subkey index, fan_in, shape) of a layer's attention matrices;
+# subkeys are split(layer_key, 16), the router's is 8 and the experts' 10-12
+# (a routed expert's matrices derive from its GLOBAL id), as in
+# models/mla_moe.py.  The recipe (normal / sqrt(fan_in), norms 1, rounded
+# once to the serving dtype) is stated by the benchmark's configuration file
+# and drawn again, independently, by its reference.
+
+
+def _attn_matrices(c: SwaMoeConfig) -> dict:
+    D, q, kv = c.dim, c.n_heads * c.head_dim, c.n_kv_heads * c.head_dim
+    return {"wq": (0, D, (D, q)), "wk": (1, D, (D, kv)),
+            "wv": (2, D, (D, kv)), "wo": (3, q, (q, D))}
+
+
+def init_params(cfg: SwaMoeConfig, key) -> dict:
+    """Seeded weights, drawn on the default device leaf by leaf.  Gate and
+    up of the experts are stored side by side (``w_gate_up`` [held, D,
+    2F]): one grouped GEMM serves both."""
+    c, dt = cfg, cfg.dtype
+
+    def dense(k, fan_in, shape):
+        return mla_moe._draw(k, jnp.float32(math.sqrt(fan_in)), shape=shape,
+                             dtype=dt)
+
+    keys = jax.random.split(key, 2 + c.n_layers)
+    params = {
+        "embed": dense(keys[0], 1, (c.vocab, c.dim)),
+        "lm_head": dense(keys[1], c.dim, (c.dim, c.vocab)),
+        "final_norm": jnp.ones((c.dim,), dt),
+        "layers": [],
+    }
+    held = jnp.arange(c.expert_offset, c.expert_offset + c.experts_held)
+    F = c.moe_ffn_dim
+    for li in range(c.n_layers):
+        lk = jax.random.split(keys[2 + li], 16)
+        layer = {n: dense(lk[j], fi, sh)
+                 for n, (j, fi, sh) in _attn_matrices(c).items()}
+        layer.update(attn_norm=jnp.ones((c.dim,), dt),
+                     mlp_norm=jnp.ones((c.dim,), dt),
+                     q_norm=jnp.ones((c.head_dim,), dt),
+                     k_norm=jnp.ones((c.head_dim,), dt),
+                     router=dense(lk[8], c.dim, (c.dim, c.n_experts)))
+
+        def experts(j, fan_in, shape):
+            return mla_moe._draw_experts(
+                lk[j], held, jnp.float32(math.sqrt(fan_in)), shape=shape,
+                dtype=dt)
+
+        layer["w_gate_up"] = jnp.concatenate(
+            [experts(10, c.dim, (c.dim, F)), experts(11, c.dim, (c.dim, F))],
+            axis=-1)
+        layer["w_down"] = experts(12, F, (F, c.dim))
+        params["layers"].append(layer)
+    return params
+
+
+# ---------------------------------------------------------------------------
+# The seam this family brings: project (the rest are the dense family's
+# attention and the latent family's expert layer, told the layer's kind)
+# ---------------------------------------------------------------------------
+
+
+def project(h, layer, pos, *, cfg: SwaMoeConfig, kind: LayerKind):
+    """``wq / wk / wv``, an RMSNorm a head on q and k, RoPE of the layer's
+    kind: h [B, T, D] -> q [B, T, Hq, hd], k and v [B, T, Hkv, hd]."""
+    B, T, _ = h.shape
+    h2 = h.reshape(B * T, cfg.dim)
+    q = (h2 @ layer["wq"]).reshape(B, T, cfg.n_heads, cfg.head_dim)
+    k = (h2 @ layer["wk"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+    v = (h2 @ layer["wv"]).reshape(B, T, cfg.n_kv_heads, cfg.head_dim)
+    inv_freq, scale = cfg.rope(kind.attn)
+    rope = functools.partial(mla_moe._rope, pos=pos, inv_freq=inv_freq,
+                             scale=scale)
+    return (rope(_rms_norm(q, layer["q_norm"], cfg.norm_eps)),
+            rope(_rms_norm(k, layer["k_norm"], cfg.norm_eps)), v)
+
+
+# ---------------------------------------------------------------------------
+# The generator the engine is built over
+# ---------------------------------------------------------------------------
+
+
+class SwaMoeGenerator:
+    """What ``ServeEngine`` needs of a model (``MlaMoeGenerator`` has the
+    same view): its config, the planes and GROUPS of its cache, the seam
+    hooks of its block with the layers' kinds, and the chunked-prefill
+    program.  It decodes through the engine's paged pools only."""
+
+    latent = False
+
+    def __init__(self, cfg: SwaMoeConfig, mesh=None, *, axis: str = "sp",
+                 max_seq: int | None = None, impl: str = "auto",
+                 interpret: bool = False, kv_dtype=None):
+        if mesh is not None and math.prod(mesh.shape.values()) != 1:
+            raise ValueError("SwaMoeGenerator stays world-1 (the engine "
+                             "owns mesh placement)")
+        self.cfg, self.mesh, self.axis = cfg, mesh, axis
+        self.max_seq = max_seq or cfg.max_seq
+        # int8 pools are the ENGINE's to refuse by name where the cache
+        # has groups (a one-kind model would take the dense family's path)
+        self.attn = types.SimpleNamespace(
+            world=1, quantized=kv_dtype is not None,
+            ctx=types.SimpleNamespace(impl=impl, interpret=interpret))
+        self.tally = mla_moe.MoeTally()
+        kw = dict(cfg=cfg, impl=impl, interpret=interpret)
+        self._hooks = {
+            "project": functools.partial(project, cfg=cfg),
+            "out_proj": _dense_out_proj,
+            "ffn": functools.partial(mla_moe.ffn, tally=self.tally, **kw),
+            "kinds": cfg.kinds,
+        }
+        self._chunk_jit = jax.jit(
+            named(self.wrap_program(functools.partial(
+                _chunk_forward, cfg=cfg, **self._hooks,
+                attend=functools.partial(
+                    _attend_prefix, impl=impl, interpret=interpret,
+                    soft_cap=cfg.attn_soft_cap))), "prefill_chunk"),
+            static_argnames=("quantized", "extent"), donate_argnums=(2,))
+        self._prompt_jit = jax.jit(self.wrap_program(functools.partial(
+            _prompt_forward, cfg=cfg, **self._hooks,
+            attend=functools.partial(_attend_prompt, **kw))))
+
+    # -- the engine's view --------------------------------------------------
+
+    @property
+    def kv_planes(self) -> list:
+        """(heads, width) of each plane of a layer's cache: K and V."""
+        return [(self.cfg.n_kv_heads, self.cfg.head_dim)] * 2
+
+    @property
+    def kv_groups(self) -> list:
+        """The cache groups, in ``LayerKind.group`` order: each with its
+        name, the reach of its layers (0: the whole context) and the
+        layers in it.  One group: the engine it builds is the one-table
+        engine, whatever the kind."""
+        c = self.cfg
+        return [{"name": g,
+                 "window": c.sliding_window if g == "window" else 0,
+                 "layers": tuple(li for li, t in enumerate(c.layer_types)
+                                 if t == g)}
+                for g in c.group_names]
+
+    def serve_hooks(self) -> dict:
+        """Keyword seams for the engine's paged forwards."""
+        ctx = self.attn.ctx
+        return dict(self._hooks, paged_attend=functools.partial(
+            paged_attend, cfg=self.cfg, impl=ctx.impl,
+            interpret=ctx.interpret))
+
+    def wrap_program(self, fwd):
+        return mla_moe.with_moe_stats(fwd, self.tally)
+
+    def kernel_gaps(self, *, page_size: int, prefill_chunk: int,
+                    ladder: list, sp_world: int = 1) -> dict:
+        """Attention paths that will NOT reach a Pallas kernel: the dense
+        family's calls at this model's head width."""
+        ctx = self.attn.ctx
+        return attention_kernel_gaps(
+            head_dim=self.cfg.head_dim, page_size=page_size,
+            prefill_chunk=prefill_chunk, ladder=ladder,
+            kv_itemsize=jnp.dtype(self.cfg.dtype).itemsize,
+            kv_quant=bool(self.attn.quantized), impl=ctx.impl,
+            interpret=ctx.interpret, sp_world=sp_world)
+
+    def forward_logits(self, params, tokens):
+        """Logits [B, S, V] of whole prompts in one pass (no cache kept):
+        what the tests hold against the reference."""
+        _, logits, _ = self._prompt_jit(params, tokens)
+        return logits

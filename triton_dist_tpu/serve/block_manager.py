@@ -38,11 +38,23 @@ Hash-collision safety: the index buckets on :func:`_block_hash` but a
 lookup only matches after a FULL ``(parent, token ids)`` compare — a
 colliding hash can never alias two different prefixes (pinned by
 tests/test_serve_prefix.py with a deliberately degenerate hash).
+
+**Window and global layers (docs/serving.md).**  A model whose layers
+differ in reach has one allocator a GROUP of layers (:class:`KvGroups`):
+each group its own block-id space, its own pool geometry and one table a
+request.  A group whose layers see only the last ``window`` positions
+(``BlockManager(window=W)``) keeps a POSITIONAL table — entry ``i`` is
+logical page ``i`` — in which the pages no query can see any more hold the
+null block: they are never allocated at admission (``allocate`` skips
+them) and :meth:`BlockManager.release_unseen` gives them back while the
+request runs.
 """
 
 from __future__ import annotations
 
 from typing import Optional, Sequence
+
+import numpy as np
 
 _ROOT = 0  # parent sentinel for a request's first block (the null block
            # can never be committed, so the id is free to mean "no parent")
@@ -62,10 +74,17 @@ class BlockExhausted(Exception):
     queueing or preemption)."""
 
 
+class KvGroupsUnsupported(NotImplementedError):
+    """A serving feature that has not been carried over to a cache of
+    several layer GROUPS (window and global layers, :class:`KvGroups`) was
+    asked for: raised where the engine is built, or where the entry point
+    is called — never a quiet fallback."""
+
+
 class BlockManager:
     def __init__(self, num_blocks: int, page_size: int, *, faults=None,
                  prefix_cache: bool = False, shards: int = 1,
-                 pages_per_shard: Optional[int] = None):
+                 pages_per_shard: Optional[int] = None, window: int = 0):
         if num_blocks < 2:
             raise ValueError(
                 f"num_blocks must be >= 2 (block 0 is the reserved null "
@@ -96,8 +115,18 @@ class BlockManager:
         if shards > 1 and not pages_per_shard:
             raise ValueError("shards > 1 needs pages_per_shard (the "
                              "logical-page span each rank owns)")
+        if window and (prefix_cache or shards > 1):
+            raise KvGroupsUnsupported(
+                "a window group's table has released pages: neither a "
+                "prefix chain nor a sequence-sharded placement runs "
+                "through it")
         self.num_blocks = num_blocks
         self.page_size = page_size
+        # Layers that see only the last ``window`` positions (0: all of
+        # them): pages wholly behind every query's window are not held.
+        self.window = int(window)
+        self.released = 0         # pages given back while requests ran
+        self._first_held: dict[str, int] = {}   # rid -> first page it holds
         self.shards = shards
         self.pages_per_shard = pages_per_shard or num_blocks
         self._nb_loc = num_blocks // shards
@@ -222,9 +251,31 @@ class BlockManager:
         used = self.num_allocatable - self.num_free
         return used / self.num_allocatable
 
+    def group_stats(self) -> dict:
+        """Blocks in use by layer group, where the cache has several
+        (:meth:`KvGroups.group_stats`); one group has none to tell apart:
+        ``utilization`` says it all."""
+        return {}
+
     def blocks_for(self, n_tokens: int) -> int:
         """Pages needed to hold ``n_tokens`` cache rows."""
         return -(-n_tokens // self.page_size)
+
+    def first_seen_page(self, kv_len: int) -> int:
+        """The first logical page a query at position ``kv_len`` or later
+        can still see: key ``j`` is seen by query ``i`` iff ``i - j <
+        window``, so the earliest seen key is ``kv_len - window + 1`` (the
+        rule of ``flash_decode._live_pages``, whose walk starts there).
+        0 without a window."""
+        if not self.window:
+            return 0
+        return max(0, kv_len - self.window + 1) // self.page_size
+
+    def pages_held(self, n_tokens: int) -> int:
+        """Blocks an allocation for ``n_tokens`` rows takes (its LAST row
+        is the earliest query's position): all its pages, less those a
+        window leaves behind."""
+        return self.blocks_for(n_tokens) - self.first_seen_page(n_tokens - 1)
 
     def can_allocate(self, n_tokens: int,
                      shared: Sequence[int] = ()) -> bool:
@@ -236,7 +287,7 @@ class BlockManager:
         subtracted from both sides."""
         in_cache = sum(1 for b in shared if b in self._cached)
         avail = len(self._free) + len(self._cached) - in_cache
-        if self.blocks_for(n_tokens) - len(shared) > avail:
+        if self.pages_held(n_tokens) - len(shared) > avail:
             return False
         if self.shards > 1:
             need = self.blocks_for(n_tokens)
@@ -461,14 +512,19 @@ class BlockManager:
             raise ValueError(
                 f"{rid}: {len(shared)} shared blocks exceed the "
                 f"{need}-block allocation for {n_tokens} tokens")
+        # A window group: the pages behind the first query's window hold
+        # the null block from the start (``shared`` is empty there: no
+        # prefix chain runs through a window group).
+        behind = self.first_seen_page(n_tokens - 1)
         # Same availability math as can_allocate: shared blocks sitting
         # in the cache tier are about to be CLAIMED, so they cannot also
         # count as evictable supply for the fresh remainder.
         avail = self.num_free - sum(1 for b in shared if b in self._cached)
-        if need - len(shared) > avail:
+        if need - behind - len(shared) > avail:
             raise BlockExhausted(
-                f"{rid}: need {need - len(shared)} blocks for {n_tokens} "
-                f"tokens ({len(shared)} shared), only {avail} free")
+                f"{rid}: need {need - behind - len(shared)} blocks for "
+                f"{n_tokens} tokens ({len(shared)} shared), only {avail} "
+                f"free")
         if self.shards > 1:
             # Partitioned placement: every fresh page must come from its
             # logical position's partition, and the availability check
@@ -488,11 +544,13 @@ class BlockManager:
                         f"{rid}: need {need_p} blocks in partition {p} "
                         f"for {n_tokens} tokens, only "
                         f"{self._part_free(p, skip_cached=skip)} free")
-        table = []
+        table = [self.null_block] * behind
+        if self.window:
+            self._first_held[rid] = behind  # release_unseen's watermark
         for b in shared:
             self._claim_shared(b)
             table.append(b)
-        for i in range(len(shared), need):
+        for i in range(behind + len(shared), need):
             b = self._pop_free(self.part_of_page(i)
                                if self.shards > 1 else None)
             self._ref[b] = 1
@@ -650,13 +708,38 @@ class BlockManager:
         self._cached[block] = None
         return True
 
+    def release_unseen(self, rid: str, kv_len: int) -> int:
+        """Give back the pages of ``rid`` that no query at position
+        ``kv_len`` or later can see (a window group; a no-op without a
+        window): each goes to the free list and its entry to the null
+        block — the table stays positional.  ``kv_len`` is the position of
+        the EARLIEST query still to be issued for the row, so the caller
+        counts what is in flight (the engine calls between chains, with
+        nothing in flight: its committed length).  Returns the pages
+        released."""
+        if not self.window:
+            return 0
+        table = self._tables[rid]
+        lo = self._first_held[rid]      # everything below went already
+        hi = min(self.first_seen_page(kv_len), len(table))
+        for i in range(lo, hi):
+            b = table[i]
+            table[i] = self.null_block
+            del self._ref[b]
+            self._free.append(b)
+        if hi > lo:
+            self._first_held[rid] = hi
+            self.released += hi - lo
+        return max(hi - lo, 0)
+
     def free(self, rid: str) -> None:
         """Drop ``rid``'s claim on its blocks.  A block whose refcount
         reaches 0 returns to the free list — unless its contents are
         committed in the prefix index, in which case it enters the LRU
         cache tier instead (still counted by ``num_free``; reclaimed
         under allocation pressure)."""
-        for b in reversed(self._tables.pop(rid)):
+        first = self._first_held.pop(rid, 0)    # a window left those behind
+        for b in reversed(self._tables.pop(rid)[first:]):
             self._ref[b] -= 1
             if self._ref[b] > 0:
                 continue
@@ -684,3 +767,142 @@ class BlockManager:
     def capacity_tokens(self, rid: str) -> int:
         """Cache rows the request's current allocation can hold."""
         return len(self._tables[rid]) * self.page_size
+
+    def page_ids(self, rid: str, lo: int, hi: int, width: int):
+        """``rid``'s table entries ``lo .. hi - 1`` at their positions in a
+        ``width``-wide row of null blocks: the block ids a scratch's pages
+        scatter to (the engine's ``fill_pages`` operand)."""
+        ids = np.full((width,), self.null_block, np.int32)
+        ids[lo:hi] = self._tables[rid][lo:hi]
+        return ids
+
+
+class KvGroups:
+    """One :class:`BlockManager` a GROUP of layers, behind the interface
+    the scheduler and the engine use of one: layers whose reach differs
+    (full attention beside a sliding window) keep their pages in pools of
+    their own geometry, and a request has one table a group.
+
+    Admission, growth, preemption and release hold for every group or for
+    none: ``can_allocate`` asks all, ``allocate`` / ``ensure`` / ``free``
+    act on all.  ``num_free`` / ``num_allocatable`` are sums over the
+    groups (whole free lists: equal).  ``utilization`` is LOAD, as the
+    brownout ladder, the fleet's router and the benchmark read it: the
+    fullest share among the groups that grow with the context.  A window
+    group is left out — what it holds follows the rows in the batch, not
+    their contexts, and the engine sizes it to ``max_batch`` rows' worst
+    case, so it reads near full on every full batch and is never the one
+    that refuses; its numbers are in :meth:`group_stats`.  Tables and
+    block ids come back with a leading group axis (``padded_table``,
+    ``page_ids``).  No prefix chain and no sequence sharding run through
+    it (:class:`KvGroupsUnsupported`)."""
+
+    prefix_cache = False        # the engine's warm-up toggles it: inert
+    on_evict = None             # no cache tier, so nothing evicts
+    index_gen = 0
+
+    def __init__(self, managers: dict):
+        self.groups = dict(managers)        # name -> BlockManager, in order
+        self._all = list(self.groups.values())
+        pages = {m.page_size for m in self._all}
+        if len(pages) != 1:
+            raise ValueError(f"one page size for every group, got {pages}")
+        self.page_size = pages.pop()
+        self.null_block = 0
+        # most blocks requests ever held at once, by group
+        self._peak = dict.fromkeys(self.groups, 0)
+
+    # -- accounting ---------------------------------------------------------
+
+    @property
+    def num_blocks(self) -> int:
+        return sum(m.num_blocks for m in self._all)
+
+    @property
+    def num_free(self) -> int:
+        return sum(m.num_free for m in self._all)
+
+    @property
+    def num_allocatable(self) -> int:
+        return sum(m.num_allocatable for m in self._all)
+
+    @property
+    def utilization(self) -> float:
+        grows = [m for m in self._all if not m.window] or self._all
+        return max(m.utilization for m in grows)
+
+    def group_stats(self) -> dict:
+        """Blocks in use, by group (``summary()["kv"]["groups"]``)."""
+        return {name: {"blocks": m.num_allocatable,
+                       "in_use": m.num_allocatable - m.num_free,
+                       "peak": self._peak[name],
+                       "window": m.window, "released": m.released}
+                for name, m in self.groups.items()}
+
+    def _note_peak(self) -> None:
+        for name, m in self.groups.items():
+            self._peak[name] = max(self._peak[name],
+                                   m.num_allocatable - m.num_free)
+
+    def blocks_for(self, n_tokens: int) -> int:
+        return self._all[0].blocks_for(n_tokens)
+
+    def fit_error(self, n_tokens: int) -> Optional[str]:
+        for name, m in self.groups.items():
+            need = m.pages_held(n_tokens)
+            if need > m.num_allocatable:
+                return (f"needs {need} blocks of the {name} group, its "
+                        f"pool has {m.num_allocatable}")
+        return None
+
+    def match_prefix(self, tokens, *, count: bool = True) -> list:
+        return []
+
+    def prefix_stats(self) -> dict:
+        return self._all[0].prefix_stats()
+
+    # -- allocate / extend / release / free -----------------------------------
+
+    def can_allocate(self, n_tokens: int, shared: Sequence[int] = ()) -> bool:
+        return all(m.can_allocate(n_tokens) for m in self._all)
+
+    def allocate(self, rid: str, n_tokens: int,
+                 shared: Sequence[int] = ()) -> None:
+        if not self.can_allocate(n_tokens):
+            raise BlockExhausted(f"{rid}: a group cannot hold {n_tokens} "
+                                 f"tokens")
+        for m in self._all:
+            m.allocate(rid, n_tokens)
+        self._note_peak()
+
+    def ensure(self, rid: str, n_tokens: int) -> None:
+        """Grow every group's table to ``n_tokens`` rows.  A group that
+        runs out raises ``BlockExhausted`` with the others' growth kept:
+        those pages stay the request's own, and the retry after a
+        preemption finds them there."""
+        try:
+            for m in self._all:
+                m.ensure(rid, n_tokens)
+        finally:
+            self._note_peak()
+
+    def release_unseen(self, rid: str, kv_len: int) -> int:
+        return sum(m.release_unseen(rid, kv_len) for m in self._all)
+
+    def free(self, rid: str) -> None:
+        for m in self._all:
+            m.free(rid)
+
+    # -- tables ---------------------------------------------------------------
+
+    def padded_table(self, rid: str, width: int):
+        """[groups, width]: the request's table in each group."""
+        return [m.padded_table(rid, width) for m in self._all]
+
+    def page_ids(self, rid: str, lo: int, hi: int, width: int):
+        """[groups, width] (:meth:`BlockManager.page_ids` a group: a
+        window group's released pages hold the null block already)."""
+        return np.stack([m.page_ids(rid, lo, hi, width) for m in self._all])
+
+    def capacity_tokens(self, rid: str) -> int:
+        return min(m.capacity_tokens(rid) for m in self._all)

@@ -141,7 +141,12 @@ from triton_dist_tpu.runtime.watchdog import (
     run_with_watchdog,
 )
 from triton_dist_tpu.serve import mesh as serve_mesh
-from triton_dist_tpu.serve.block_manager import BlockExhausted, BlockManager
+from triton_dist_tpu.serve.block_manager import (
+    BlockExhausted,
+    BlockManager,
+    KvGroups,
+    KvGroupsUnsupported,
+)
 from triton_dist_tpu.serve.metrics import RequestMetrics, ServeMetrics
 from triton_dist_tpu.serve.programs import (
     _copy_pool_block,
@@ -205,6 +210,25 @@ def _refuse_latent(latent: bool, what: str) -> None:
         raise LatentPoolUnsupported(
             f"{what}: not served on latent (MLA) pools yet (neither the "
             f"latent rows' plane nor an indexer's key plane carries it)")
+
+
+def _kv_groups(gen):
+    """The cache groups a generator names (``kv_groups``) where it names
+    several, else None: one group is the engine as it always was."""
+    groups = list(getattr(gen, "kv_groups", ()) or ())
+    return groups if len(groups) > 1 else None
+
+
+def _refuse_groups(groups, what: str) -> None:
+    """What has not been carried over to a cache of several layer GROUPS
+    (window and global layers: one block table a group, pools of their own
+    geometry — docs/serving.md) refuses by name, where the engine is built
+    or the entry point is called."""
+    if groups:
+        raise KvGroupsUnsupported(
+            f"{what}: not served over cache groups "
+            f"({', '.join(g['name'] for g in groups)}) yet — a request has "
+            f"one block table a group and no format or layout carries them")
 
 
 def build_bucket_ladder(base: int, cap: int, page: int) -> list[int]:
@@ -328,6 +352,23 @@ class ServeEngine:
                 ("snapshot_dir (journal, snapshot / restore)",
                  snapshot_dir is not None)):
             _refuse_latent(self.latent and asked, what)
+        # Layers of different reach (sliding-window beside full attention)
+        # fall into cache GROUPS — the generator names them
+        # (``kv_groups``); one group is the engine as it always was.
+        self.kv_groups = _kv_groups(gen)
+        for what, asked in (
+                ("a mesh", mesh is not None),
+                ("int8 pools", self.kv_quant),
+                ("w8a8 weights", w8a8),
+                ("speculative rounds (spec_k / draft)",
+                 bool(spec_k) or draft is not None),
+                ("snapshot_dir (journal, snapshot / restore)",
+                 snapshot_dir is not None),
+                ("prefix_cache=True (a hit would have to hold in every "
+                 "group: the whole prefix in the full group, the last "
+                 "window before its end in the window group)",
+                 bool(prefix_cache))):
+            _refuse_groups(asked and self.kv_groups, what)
         if self.kv_quant and spec_k:
             raise ValueError(
                 "int8 KV pools cannot drive speculative decoding yet "
@@ -430,11 +471,34 @@ class ServeEngine:
         # partition count is the SP world — the tp axis splits heads
         # inside each block, never the block-id space.
         seq_shards = self.sp_world
-        self.bm = BlockManager(num_blocks, page_size, faults=faults,
-                               prefix_cache=self.prefix_cache,
-                               shards=seq_shards,
-                               pages_per_shard=self.n_pages_max
-                               // seq_shards)
+        if self.kv_groups:
+            # One allocator a group, each over its own block-id space:
+            # ``num_blocks`` is the full group's; a window group's count is
+            # DERIVED — what ``max_batch`` rows can hold at once.  A row's
+            # live span is its window plus what one chain of links may
+            # write ahead (``horizon * pipeline`` rows): ``(window +
+            # ahead - 2) // page + 2`` pages however it lies on the page
+            # grid, so the group can never be the one that runs out.
+            ahead = horizon * pipeline if horizon > 1 else 1
+            self.group_blocks = [
+                num_blocks if not g["window"] else 1 + max_batch * (
+                    (g["window"] + ahead - 2) // page_size + 2)
+                for g in self.kv_groups]
+            self.bm = KvGroups({
+                g["name"]: BlockManager(nb, page_size, faults=faults,
+                                        window=g["window"])
+                for g, nb in zip(self.kv_groups, self.group_blocks)})
+        else:
+            self.group_blocks = [num_blocks]
+            self.bm = BlockManager(num_blocks, page_size, faults=faults,
+                                   prefix_cache=self.prefix_cache,
+                                   shards=seq_shards,
+                                   pages_per_shard=self.n_pages_max
+                                   // seq_shards)
+        # the decode programs' table operand: [B, pages], or one a group
+        self._tables_shape = (
+            (len(self.kv_groups),) if self.kv_groups else ()) + (
+            max_batch, self.n_pages_max)
         self.scheduler = FCFSScheduler(
             self.bm,
             prefill_budget=prefill_budget or 4 * prefill_chunk,
@@ -753,7 +817,8 @@ class ServeEngine:
             # scratch is not donatable (the page reshape transposes it);
             # pools are — the scatter updates them in place.
             self._fill_fn = CountingJit(jax.jit(named(
-                _fill_pool_pages, "fill_pages", page=page_size),
+                _fill_pool_pages, "fill_pages", page=page_size,
+                **({"kinds": hooks["kinds"]} if self.kv_groups else {})),
                 donate_argnums=(0,)), "fill_pages")
             # Prefix-cache device programs: the warm-prefill gather
             # (pools read back into scratch — NOT donated, the pools
@@ -784,18 +849,22 @@ class ServeEngine:
             # plane — one scale per (block, head, in-page row), the exact
             # shape _scatter_kv's quantize_kv emits, living in the SAME
             # pool tuple so pages and scales can never travel separately.
-            def zpool(h, d):
-                return {"q": jnp.zeros((num_blocks, h, page_size, d),
+            def zpool(nb, h, d):
+                return {"q": jnp.zeros((nb, h, page_size, d),
                                        jnp.int8, device=self._pool_sharding),
-                        "s": jnp.zeros((num_blocks, h, page_size),
+                        "s": jnp.zeros((nb, h, page_size),
                                        jnp.float32,
                                        device=self._pool_sharding)}
         else:
-            def zpool(h, d):
-                return jnp.zeros((num_blocks, h, page_size, d), cfg.dtype,
+            def zpool(nb, h, d):
+                return jnp.zeros((nb, h, page_size, d), cfg.dtype,
                                  device=self._pool_sharding)
-        self._pools = [tuple(zpool(h, d) for h, d in self.kv_planes)
-                       for _ in range(cfg.n_layers)]
+        # a layer's planes hold its GROUP's block count (one group: all
+        # of them ``num_blocks``)
+        layer_blocks = ([self.group_blocks[k.group] for k in hooks["kinds"]]
+                        if self.kv_groups else [num_blocks] * cfg.n_layers)
+        self._pools = [tuple(zpool(nb, h, d) for h, d in self.kv_planes)
+                       for nb in layer_blocks]
         self._sample_fn = CountingJit(
             jax.jit(named(_sample_token, "sample_token")), "sample_token")
         for c in (self._chunk_fn, self._fill_fn, self._decode_fn,
@@ -1169,6 +1238,7 @@ class ServeEngine:
         from triton_dist_tpu.serve import recovery
 
         _refuse_latent(self.latent, "snapshot()")
+        _refuse_groups(self.kv_groups, "snapshot()")
         d = directory or self.snapshot_dir
         if d is None:
             raise ValueError("snapshot() needs a directory: pass one or "
@@ -1287,6 +1357,7 @@ class ServeEngine:
         from triton_dist_tpu.serve import recovery
 
         _refuse_latent(gen.latent, "restore()")
+        _refuse_groups(_kv_groups(gen), "restore()")
         return recovery.restore_engine(directory, gen, params, **kwargs)
 
     # -- live migration ---------------------------------------------------
@@ -1327,6 +1398,8 @@ class ServeEngine:
 
         _refuse_latent(self.latent, "push_out() of latent pages" if push
                             else "drain() / migrate-out")
+        _refuse_groups(self.kv_groups, "push_out() (disaggregated push)"
+                       if push else "drain() / migrate-out")
 
         if rids is None:
             rids = self.unfinished_rids()
@@ -1497,6 +1570,8 @@ class ServeEngine:
 
         _refuse_latent(self.latent, "admit_pushed() of latent pages" if push
                             else "migrate_in()")
+        _refuse_groups(self.kv_groups, "admit_pushed() (disaggregated push)"
+                       if push else "migrate_in()")
         if manifest.get("format") != MANIFEST_FORMAT:
             raise ValueError(
                 f"migration manifest format {manifest.get('format')}; "
@@ -2269,10 +2344,11 @@ class ServeEngine:
         with span("prefill.stage"):
             n_hit = (rs.cached_prefix // self.page if self.prefix_cache
                      else 0)
-            ids = np.zeros((rs.s_ext // self.page,), np.int32)
-            ids[n_hit:n_prompt_pages] = \
-                self.bm.table(rid)[n_hit:n_prompt_pages]
-            ids_d = jnp.asarray(ids)
+            # (one row a cache group where the model has them: a window
+            # group's entries hold the null block wherever no decode
+            # query can see the page, so only pages in sight are kept)
+            ids_d = jnp.asarray(self.bm.page_ids(
+                rid, n_hit, n_prompt_pages, rs.s_ext // self.page))
         self._pools = self._device_call(
             "fill_pages", (rid,), self._fill_fn, self._pools, rs.scratch,
             ids_d)
@@ -2874,6 +2950,22 @@ class ServeEngine:
             self.metrics.observe_family(
                 np.sum(jax.device_get(done), axis=0))
 
+    def _note_reach(self, kv_len: int, n: int) -> None:
+        """Count the cached tokens the ``n`` decode queries of one row
+        from length ``kv_len`` read, a layer, by layer group (cache
+        groups only: ``summary()["swa"]``): the context on full layers,
+        ``min(context, window)`` on window layers."""
+        if not self.kv_groups:
+            return
+        ctx = np.arange(kv_len + 1, kv_len + n + 1)
+        for g in self.kv_groups:
+            seen = int((np.minimum(ctx, g["window"]) if g["window"]
+                        else ctx).sum()) * len(g["layers"])
+            if g["window"]:
+                self.metrics.swa_window_tokens += seen
+            else:
+                self.metrics.swa_full_tokens += seen
+
     def _preempt(self, victim: ReqState) -> None:
         self.trace.emit("preempt", victim.req.request_id,
                         kv_len=victim.kv_len,
@@ -2897,6 +2989,8 @@ class ServeEngine:
         page BEFORE any write can land.  Admission-shared prefix pages
         are full pages strictly below the write range, so steady-state
         traffic never pays a copy — the loop is a few dict lookups."""
+        if self.kv_groups:
+            return      # no sharing path runs through cache groups
         rid = rs.req.request_id
         table = self.bm.table(rid)
         for logical in range(rs.kv_len // self.page, len(table)):
@@ -2962,6 +3056,15 @@ class ServeEngine:
                     w.req.params.deadline_s is not None
                     for w in self.scheduler.waiting))
             links = self.pipeline if h_plan > 1 else 1
+            if self.kv_groups:
+                # Nothing is in flight between chains, so the earliest
+                # query still to come for a row sits at its committed
+                # length: the window group's pages wholly behind that
+                # query's window go back before anyone grows.
+                with self.trace.span("decode.plan.release"):
+                    self.metrics.kv_window_released += sum(
+                        self.bm.release_unseen(rs.req.request_id, rs.kv_len)
+                        for rs in running if rs.status is Status.RUNNING)
             for rs in sorted(running, key=lambda r: r.seq):
                 if rs.status is Status.RUNNING:  # may get preempted below
                     want = rs.kv_len + min(max(h_plan, 1) * links,
@@ -3016,14 +3119,14 @@ class ServeEngine:
             tokens = np.zeros((B,), np.int32)
             lens = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
-            tables = np.zeros((B, self.n_pages_max), np.int32)
+            tables = np.zeros(self._tables_shape, np.int32)
             for rs in rows:
                 b = rs.slot
                 tokens[b] = rs.pending_token
                 lens[b] = rs.kv_len
                 active[b] = True
-                tables[b] = self.bm.padded_table(rs.req.request_id,
-                                                 self.n_pages_max)
+                tables[..., b, :] = self.bm.padded_table(
+                    rs.req.request_id, self.n_pages_max)
             operands = (jnp.asarray(tables), jnp.asarray(lens),
                         jnp.asarray(tokens), jnp.asarray(active))
         pools, logits, *aux = self._device_call(
@@ -3041,6 +3144,7 @@ class ServeEngine:
             for rs in rows:
                 if rs.status is not Status.RUNNING:
                     continue  # aborted mid-loop by a slot-mate's callback
+                self._note_reach(rs.kv_len, 1)
                 rs.kv_len += 1
                 rs.pending_token = None
                 self._commit_full_blocks(rs)  # the write just landed
@@ -3093,7 +3197,7 @@ class ServeEngine:
             tokens = np.zeros((B,), np.int32)
             lens = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
-            tables = np.zeros((B, self.n_pages_max), np.int32)
+            tables = np.zeros(self._tables_shape, np.int32)
             counts = np.zeros((B,), np.int32)
             temps = np.ones((B,), np.float32)
             top_ks = np.zeros((B,), np.int32)
@@ -3107,8 +3211,8 @@ class ServeEngine:
                 tokens[b] = rs.pending_token
                 lens[b] = rs.kv_len
                 active[b] = True
-                tables[b] = self.bm.padded_table(rs.req.request_id,
-                                                 self.n_pages_max)
+                tables[..., b, :] = self.bm.padded_table(
+                    rs.req.request_id, self.n_pages_max)
                 counts[b] = len(rs.generated)
                 temps[b] = p.temperature if not p.greedy else 1.0
                 top_ks[b] = p.top_k or 0
@@ -3204,6 +3308,7 @@ class ServeEngine:
                         n = int(mask_np[b].sum())
                         if n == 0:
                             continue
+                        self._note_reach(rs.kv_len, n)
                         rs.kv_len += n  # the device already wrote these rows
                         times = rs.metrics.burst_times(now, n, step_s)
                         out = None
@@ -3308,7 +3413,7 @@ class ServeEngine:
             B = self.max_batch
             lens = np.zeros((B,), np.int32)
             active = np.zeros((B,), bool)
-            tables = np.zeros((B, self.n_pages_max), np.int32)
+            tables = np.zeros(self._tables_shape, np.int32)
             counts = np.zeros((B,), np.int32)
             limits = np.zeros((B,), np.int32)
             k_rows = np.ones((B,), np.int32)
@@ -3323,8 +3428,8 @@ class ServeEngine:
                 p = rs.req.params
                 lens[b] = rs.kv_len
                 active[b] = True
-                tables[b] = self.bm.padded_table(rs.req.request_id,
-                                                 self.n_pages_max)
+                tables[..., b, :] = self.bm.padded_table(
+                    rs.req.request_id, self.n_pages_max)
                 counts[b] = len(rs.generated)
                 # Per-row emission budget: remaining max-tokens AND the
                 # reserved page capacity (never binds after a successful

@@ -77,6 +77,7 @@ MERGE_COUNTERS = (
     "moe_experts_hit",
     "dsa_indexed_tokens", "dsa_selected_rows", "dsa_rows_sparse",
     "dsa_rows_dense",
+    "swa_window_tokens", "swa_full_tokens", "kv_window_released",
     "net_requests", "net_dup_hits", "net_redelivered_tokens",
     "brownout_transitions",
     "journal_corrupt", "manifest_corrupt",
@@ -286,6 +287,13 @@ class ServeMetrics:
     dsa_selected_rows: int = 0
     dsa_rows_sparse: int = 0
     dsa_rows_dense: int = 0
+    # window and global layers (docs/serving.md): cached tokens the decode
+    # queries read, a layer, on window layers and on full ones (host
+    # arithmetic at commit: min(context, window) and the context), and the
+    # window-group pages given back while their requests ran
+    swa_window_tokens: int = 0
+    swa_full_tokens: int = 0
+    kv_window_released: int = 0
     decode_tokens: int = 0        # tokens committed by the decode loop
     dispatches: int = 0           # decode-path device dispatches
     host_syncs: int = 0           # decode-path host sync points
@@ -526,6 +534,18 @@ class ServeMetrics:
                                  if self.dsa_indexed_tokens else 0.0)
         return out
 
+    def swa_stats(self) -> dict:
+        """summary()["swa"]: cached tokens the decode queries read on
+        window layers and on full layers (each counted a layer), and the
+        window layers' share of both — what the window saves is the
+        distance of that share from the layers' own."""
+        both = self.swa_window_tokens + self.swa_full_tokens
+        return {"window_tokens": self.swa_window_tokens,
+                "full_tokens": self.swa_full_tokens,
+                "window_share": (self.swa_window_tokens / both
+                                 if both else 0.0),
+                "window_released_pages": self.kv_window_released}
+
     def moe_stats(self) -> dict:
         """summary()["moe"]: the four counters and the share of routed
         assignments that landed on the experts held here (1 / chips that
@@ -540,6 +560,7 @@ class ServeMetrics:
         """KV pool capacity (summary()["kv"]): pool bytes, token slots,
         and bytes/token — the memory-economics view the int8 pools
         exist to move (docs/serving.md "Quantized serving")."""
+        groups = self.kv_group_stats()
         return {
             "pool_bytes": self.kv_pool_bytes,
             "token_slots": self.kv_token_slots,
@@ -549,7 +570,16 @@ class ServeMetrics:
             # a latent (MLA) pool: the numbers a token's row holds a
             # layer, and the width it is stored at (whole lane tiles)
             **self.kv_row,
+            # window and global layers: blocks in use and their peak, by
+            # group (one allocator a group; absent with one group)
+            **({"groups": groups} if groups else {}),
         }
+
+    def kv_group_stats(self) -> dict:
+        """Blocks in use by cache group ({} with one group, or with no
+        block manager attached)."""
+        bm = self.block_manager
+        return bm.group_stats() if bm is not None else {}
 
     # -- per-program wall-time attribution --------------------------------
 
@@ -989,6 +1019,7 @@ class ServeMetrics:
             "kv": self.kv_stats(),
             "moe": self.moe_stats(),
             "dsa": self.dsa_stats(),
+            "swa": self.swa_stats(),
             "spec": self.spec_stats(),
             "slo": self.slo_stats(),
             "failures": self.failure_stats(),
@@ -1041,6 +1072,12 @@ class ServeMetrics:
         counter("serve_preemptions_total", self.preemptions)
         for name in self.FAMILY_COUNTERS:
             counter(f"serve_{name}_total", getattr(self, name))
+        counter("serve_swa_window_tokens_total", self.swa_window_tokens,
+                "cached tokens decode queries read on window layers")
+        counter("serve_swa_full_tokens_total", self.swa_full_tokens,
+                "cached tokens decode queries read on full layers")
+        counter("serve_kv_window_released_total", self.kv_window_released,
+                "window-group pages given back while requests ran")
         counter("serve_shed_total", self.shed)
         counter("serve_deadline_expired_total", self.deadline_expired)
         counter("serve_quarantined_total", self.quarantined)
@@ -1109,6 +1146,16 @@ class ServeMetrics:
               "waiting requests at the last engine step")
         gauge("serve_running", self.running_last)
         gauge("serve_kv_utilization", round(self.kv_util_last, 6))
+        stats = self.kv_group_stats()
+        if stats:
+            L.append("# TYPE serve_kv_group_blocks_in_use gauge")
+            for g, st in stats.items():
+                L.append(f'serve_kv_group_blocks_in_use{{group="{g}"}} '
+                         f'{st["in_use"]}')
+            L.append("# TYPE serve_kv_group_blocks_peak gauge")
+            for g, st in stats.items():
+                L.append(f'serve_kv_group_blocks_peak{{group="{g}"}} '
+                         f'{st["peak"]}')
         gauge("serve_kv_pool_bytes", self.kv_pool_bytes,
               "device bytes pinned by the paged KV pools "
               "(int8 pages + f32 scales both count)")

@@ -21,6 +21,7 @@ import numpy as np
 
 from triton_dist_tpu.kernels.flash_decode import gqa_decode_shard, quantize_kv
 from triton_dist_tpu.models.generate import (
+    _kind_kw,
     _layer_stack,
     _write_rows,
     dense_block,
@@ -90,9 +91,20 @@ def _scatter_kv(pool, k, v, pool_row, in_page):
     return tuple(out)
 
 
+def _by_group(tables, kinds, one: int):
+    """-> (the distinct tables, ``group_of(li)``): ONE table (``one``
+    dimensions: [B, n_pages] block tables, [n] block ids) for every
+    layer, or — a model whose layers fall into cache groups (``kinds``: a
+    ``generate.LayerKind`` a layer) — a leading group axis, layer ``li``
+    reading entry ``kinds[li].group``."""
+    if kinds is None or tables.ndim == one:     # one group, whatever the kinds
+        return [tables], lambda li: 0
+    return list(tables), lambda li: kinds[li].group
+
+
 def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
                           cfg, page, project, out_proj, ffn, paged_attend,
-                          slots=_page_slots):
+                          slots=_page_slots, kinds=None):
     """One decode token for every batch row over the paged pools:
     ``generate._layer_stack`` at T = 1 (the same math as
     ``Generator._step_impl`` — the greedy stream must be bit-identical to
@@ -108,24 +120,31 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
     rank passes the family over its local-head config with psum hooks,
     a sequence-sharded one its own ``slots`` and ``paged_attend``
     (serve/mesh.py); the block-table addressing is this one copy for
-    the world-1 engine and every rank."""
+    the world-1 engine and every rank.
+
+    ``kinds`` (a ``generate.LayerKind`` a layer, static) comes with a
+    model whose layers differ in kind: ``tables`` then holds one table a
+    cache GROUP ([G, B, n_pages]), a layer writes and reads through its
+    group's, and ``project`` / ``paged_attend`` are told the kind."""
     inc = active.astype(kv_lens.dtype)
-    pool_row, in_page = slots(tables, kv_lens, active, page=page)
+    by_group, group_of = _by_group(tables, kinds, one=2)
+    where = [slots(t, kv_lens, active, page=page) for t in by_group]
 
     def write_kv(li, pool, k, v):
         return _scatter_kv(pool, k[:, 0], None if v is None else v[:, 0],
-                           pool_row, in_page)
+                           *where[group_of(li)])
 
     def attend(li, q, pool):
         # q: an array, or whatever tree of [B, 1, ...] arrays the family's
         # ``project`` hands its ``paged_attend`` (an indexer's beside it)
         return paged_attend(jax.tree.map(lambda t: t[:, 0], q), pool,
-                            tables, kv_lens + inc)[:, None]
+                            by_group[group_of(li)], kv_lens + inc,
+                            **_kind_kw(kinds, li))[:, None]
 
     pools, logits = _layer_stack(
         params, token[:, None], kv_lens[:, None], pools, cfg=cfg,
         project=project, out_proj=out_proj, ffn=ffn, write_kv=write_kv,
-        attend=attend)
+        attend=attend, kinds=kinds)
     return pools, logits[:, 0]
 
 
@@ -478,9 +497,13 @@ def _copy_pool_block(pools, src, dst):
     return jax.tree.map(lambda p: p.at[dst].set(p[src]), pools)
 
 
-def _fill_pool_pages(pools, scratch, block_ids, *, page):
+def _fill_pool_pages(pools, scratch, block_ids, *, page, kinds=None):
     """Scatter a completed prefill's cache rows (contiguous scratch
     planes [1, Hkv, n*page, D] per layer) into the request's pool pages.
+    With cache groups (``kinds``) ``block_ids`` is [G, n] and a layer's
+    pages go to its group's ids: a window group's hold the null block
+    wherever no decode query can see the page any more, so only the pages
+    still in sight are kept.
 
     ``block_ids`` covers EVERY scratch page (n = s_ext // page): entries
     past the prompt's allocation hold the null block, so a bucketed
@@ -490,10 +513,18 @@ def _fill_pool_pages(pools, scratch, block_ids, *, page):
     A quantized scratch's int8 bytes + scales scatter AS-IS: the pool
     rows are bit-identical to the scratch rows, so a warm-prefix
     gather-back reproduces the cold prefill exactly."""
-    n = block_ids.shape[0]
-    return jax.tree.map(
-        lambda p, c: p.at[block_ids].set(
-            _plane_pages(c, n, page).astype(p.dtype)), pools, scratch)
+    n = block_ids.shape[-1]
+
+    def fill(ids, pools, scratch):
+        return jax.tree.map(
+            lambda p, c: p.at[ids].set(
+                _plane_pages(c, n, page).astype(p.dtype)), pools, scratch)
+
+    by_group, group_of = _by_group(block_ids, kinds, one=1)
+    if len(by_group) == 1:
+        return fill(block_ids, pools, scratch)
+    return [fill(by_group[group_of(li)], pool, sc)
+            for li, (pool, sc) in enumerate(zip(pools, scratch, strict=True))]
 
 
 def _splice_draft_rows(bcaches, blens, blogits, tcaches, slot, s0, last):

@@ -165,6 +165,8 @@ STEP_PHASES = frozenset({
     "prefill.wait",    # host blocked on the last chunk's logits
     "prefill.commit",  # first token choice + commit, content-index commits
     "decode.plan",     # plan_horizon, capacity growth, preemption
+    "decode.plan.release",  # cache groups: window pages no query sees go
+                       # back (child of decode.plan; absent with one group)
     "decode.stage",    # numpy batch arrays -> device operands
     "decode.wait",     # host blocked on the device (logits / token burst)
     "decode.commit",   # token choice, _commit_token, callbacks, journal
