@@ -304,7 +304,7 @@ def engine_logits(engine, prompt, first_token=None):
 
     cfg, page = engine.cfg, engine.page
     n = int(prompt.shape[0])
-    chunk = engine.scheduler.prefill_chunk
+    chunk = engine.prefill_width     # rows of one prefill call
     ext = engine._bucket_s_ext(n)
     shape = (1, cfg.n_kv_heads, ext, cfg.head_dim)
     scratch = [(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))

@@ -6,8 +6,9 @@ installed libtpu provides on a CPU host.  What this pins, at llama3-8B
 widths (depth cut to 2 layers — a compile check, not a run):
 
 - world-1 ``paged_decode``, ``decode_horizon[H=8]`` (greedy and sampled) and
-  ``[H=1]`` (sampled) and ``prefill_chunk[c=128]`` compile for the v5e and
-  hold exactly one Mosaic custom call per layer;
+  ``[H=1]`` (sampled) and ``prefill_chunk`` at 128 and 256 rows compile for
+  the v5e and hold exactly one Mosaic custom call per layer; the Mistral
+  cells' 256-row call at all 16 layers on every rung of their ladder;
 - the world-4 decode and chunked-prefill programs of ``serve/mesh.py``
   compile for ``heads``, ``seq`` and ``heads+seq`` (2x2), with one Mosaic
   call per layer under ``heads`` and two (attention kernel + SP combine)
@@ -47,6 +48,7 @@ from triton_dist_tpu.serve import programs as PR
 LAYERS = 2
 B, MAX_SEQ = 8, 2048
 I32 = jnp.int32
+HBM_GIB = 15.75     # what the v5e's compiler allows one program
 
 
 @pytest.fixture(scope="module")
@@ -156,14 +158,65 @@ def test_world1_programs_compile_with_one_mosaic_call_per_layer(v5e, as_tpu):
     for H, all_greedy in ((8, True), (8, False), (1, False)):
         assert _compile(horizon, *h_args, H=H,
                         all_greedy=all_greedy) == LAYERS
-    for extent in (128, MAX_SEQ):
-        args, n_valid = put(_chunk_args(cfg, 128, extent))
+    # a call of one chunk's rows (budget = chunk), and the 256 rows of the
+    # smoke's engine (chunk 128 at the default budget: prefill_width)
+    wide = E.prefill_width(128, 4 * 128)
+    assert wide == 256
+    for rows, extent in ((128, 128), (128, MAX_SEQ), (wide, wide),
+                         (wide, MAX_SEQ)):
+        args, n_valid = put(_chunk_args(cfg, rows, extent))
         assert _compile(chunk, *args, quantized=False, extent=extent,
                         n_valid=n_valid) == LAYERS
-    assert E.attention_kernel_gaps(
-        head_dim=cfg.head_dim, page_size=128, prefill_chunk=128,
-        ladder=[128, 256, 512, 1024, 2048], kv_itemsize=2, kv_quant=False,
-        impl="auto", interpret=False) == {}
+    for rows, ladder in ((128, [128, 256, 512, 1024, 2048]),
+                         (wide, [256, 512, 1024, 2048])):
+        assert E.attention_kernel_gaps(
+            head_dim=cfg.head_dim, page_size=128, prefill_chunk=rows,
+            ladder=ladder, kv_itemsize=2, kv_quant=False,
+            impl="auto", interpret=False) == {}
+
+
+def test_the_dense_cells_prefill_call_compiles_on_every_rung(v5e, as_tpu):
+    """The Mistral cells' prefill program since PR 33 — ``prefill_chunk`` at
+    ``[1, W]``, W = 256 (chunk 128, budget 512: ``engine.prefill_width``) —
+    at the file's widths and all 16 layers, on EVERY rung of the ladder the
+    engine builds at that width: one Mosaic call a layer, under the name the
+    benchmark's reader matches, beside the 7.5 GB of weights in what the
+    v5e's compiler allows one program, and no attention path off its
+    kernel."""
+    import json
+    import os
+
+    from benchmarks import builders
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks/configs/mistral-7b-v0.2-l16.json")) as f:
+        config = json.load(f)
+    cfg, eng = builders.llama_config(config), config["engine"]
+    page, chunk, max_seq = (eng["page_size"], eng["prefill_chunk"],
+                            eng["max_seq"])
+    width = E.prefill_width(chunk, 4 * chunk)
+    assert width == 256
+    ladder = E.build_bucket_ladder(max(page, width), max_seq, page)
+    assert ladder == [256, 512, 1024, 2048, 4096, 8192]
+    assert _dense_gen(cfg).kernel_gaps(
+        page_size=page, prefill_chunk=width, ladder=ladder) == {}
+    put = functools.partial(_on, sharding=SingleDeviceSharding(v5e.devices[0]))
+    chunk_jit = _dense_gen(cfg)._chunk_jit
+    peak = {}
+    for extent in ladder:
+        args, n_valid = put(_chunk_args(cfg, width, extent))
+        compiled = chunk_jit.lower(*args, quantized=False, extent=extent,
+                                   n_valid=n_valid).compile()
+        text = compiled.as_text()
+        assert text.split(",", 1)[0] == "HloModule jit_prefill_chunk"
+        assert text.count(MOSAIC_CALL) == cfg.n_layers, extent
+        ma = compiled.memory_analysis()
+        peak[extent] = (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+                        + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+        assert peak[extent] < HBM_GIB * 2 ** 30, (extent, peak)
+    print(f"prefill_chunk[1, {width}] GiB a program by extent:",
+          {e: round(b / 2 ** 30, 2) for e, b in peak.items()})
 
 
 # (batch, max_seq, pool blocks): the smoke's geometry, and the benchmark
@@ -301,11 +354,15 @@ def test_world4_programs_compile(v5e, as_tpu, kv_shard, shape, per_layer):
         == per_layer * LAYERS
     # chunked prefill too: under seq its combine merges c x Hq partial
     # rows, which overflowed the fused kernel's VMEM on the chip (PR 21)
-    # — a failure this compile reproduces without one
+    # — a failure this compile reproduces without one; at one chunk's
+    # rows and at the 256 of an engine built with the default budget
+    # (``engine.prefill_width``: the chip smoke's mesh legs)
     chunk = progs["prefill_chunk"]._maker(512)
-    args, n_valid = _chunk_args(cfg, 128, 512)
-    assert _compile(chunk._prog(()), *on_mesh(chunk, args + (n_valid,))) \
-        == per_layer * LAYERS
+    for rows in (256, 128):     # the 128-row arguments serve below too
+        args, n_valid = _chunk_args(cfg, rows, 512)
+        assert _compile(chunk._prog(()),
+                        *on_mesh(chunk, args + (n_valid,))) \
+            == per_layer * LAYERS, rows
     # and impl="xla", asked for by name, holds no kernel in any layout
     # (the seq prefill attend once dispatched "auto" regardless: the chip
     # smoke's XLA reference turned out to run the kernel it was judging)
@@ -440,9 +497,6 @@ def test_the_paged_write_lands_in_place(v5e, as_tpu, kind, num_blocks,
 # ---------------------------------------------------------------------------
 # The latent-attention + expert-share cell (ISSUE 26), at published widths
 # ---------------------------------------------------------------------------
-
-HBM_GIB = 15.75     # what the v5e's compiler allows one program
-
 
 def _mla_moe_cell(name="gigachat3.1-702b-ep16-l5"):
     """A latent-family benchmark cell's configuration as its builder

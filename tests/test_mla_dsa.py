@@ -293,15 +293,28 @@ def test_dsa_counters(tiny, undisturbed):
     cfg, params, gen = tiny
     prompts, greedy, _ = undisturbed
     eng = _engine(gen, params, max_batch=3, prefix_cache=False)
+    assert eng.prefill_width == 128         # chunk 32, budget 4 x 32
+    windows, seam = [], eng._device_call
+
+    def tapped(op, rids, fn, *a, **kw):
+        if op == "prefill_chunk":
+            windows.append(int(a[3]))       # where the call's rows start
+        return seam(op, rids, fn, *a, **kw)
+
+    eng._device_call = tapped
     assert _serve(eng, prompts, 12) == greedy
     dsa = eng.metrics.summary()["dsa"]
     L, k = cfg.n_layers, cfg.index_topk
-    # every query the programs computed, over both layers — the prompt's
-    # chunks of 32 (the last one's pad queries too) and the 11 decode
-    # steps a request: position p sees p + 1 tokens and reads min(., k)
-    seen = [np.concatenate([np.arange(1, -(-len(p) // 32) * 32 + 1),
-                            np.arange(len(p) + 1, len(p) + 12)])
-            for p in prompts]
+    # every query the programs computed, over both layers — each prefill
+    # call's 128 rows from where its window starts (pad queries, and the
+    # rows a window that slid back at the scratch's end computed again,
+    # too: r1's 95 tokens are 64 on the first step's leftover budget, then
+    # 31 in a call that starts at row 0 of its 128-row scratch) and the 11
+    # decode steps a request: position p sees p + 1 tokens and reads
+    # min(., k)
+    assert sorted(windows) == [0, 0, 0, 0]
+    seen = [np.arange(at + 1, at + 128 + 1) for at in windows] + [
+        np.arange(len(p) + 1, len(p) + 12) for p in prompts]
     assert dsa["indexed_tokens"] == L * sum(int(v.sum()) for v in seen)
     assert dsa["selected_rows"] == L * sum(int(np.minimum(v, k).sum())
                                            for v in seen)
@@ -539,9 +552,10 @@ def test_expanded_prefill_chunks_match_reference_and_the_absorbed_ones(
     toks, got = _served_logits(gen_x, params, prompt, 6)
     monkeypatch.setattr(M, "PREFILL_EXPAND_MIN", 256)
     # every chunk program traced the expanded call once a layer over its
-    # 128-row scratch, and the absorbed one served single queries only
+    # 128-row scratch — a call is the step's budget of 4 x 32 rows — and
+    # the absorbed one served single queries only
     H, dk = cfg.n_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    assert calls["expanded"] == [(1, 32, H * dk)] * cfg.n_layers
+    assert calls["expanded"] == [(1, 128, H * dk)] * cfg.n_layers
     assert calls["absorbed"] and all(s[1] == 1 for s in calls["absorbed"])
     toks_a, got_a = _served_logits(gen, params, prompt, 6)
     assert toks == toks_a

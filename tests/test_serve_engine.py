@@ -343,12 +343,18 @@ def test_engine_bounded_compilation_and_warmup():
         assert outs2[rid].token_ids == o.token_ids
 
 
-def test_engine_warmup_covers_top_rung_odd_chunk():
+@pytest.mark.parametrize("budget,width,ladder", [
+    (7, 7, [16, 32]),       # a call is one chunk: the ladder of old
+    (None, 28, [32]),       # a call is the step's budget, 4 x 7 rows
+])
+def test_engine_warmup_covers_top_rung_odd_chunk(budget, width, ladder):
     """Regression: with a chunk that divides neither page nor max_seq
-    (page 16, chunk 7, max_seq 16 -> ladder [16, 32]), the top rung is
-    only reachable by near-max-length prompts; warmup's per-rung prompt
-    picker must invert _scratch_need exactly or that rung stays cold and
-    a 15-token prompt compiles on the admission path post-warmup."""
+    (page 16, chunk 7, max_seq 16 -> ladder [16, 32] at calls of one
+    chunk), the top rung is only reachable by near-max-length prompts;
+    warmup's per-rung prompt picker must invert _scratch_need exactly or
+    that rung stays cold and a 15-token prompt compiles on the admission
+    path post-warmup.  At the default budget one call's 28 rows are past
+    every prompt: the one rung that holds them is the ladder."""
     cfg = llama.LlamaConfig(vocab=64, dim=16, n_layers=1, n_heads=2,
                             n_kv_heads=1, ffn_dim=32, max_seq=16,
                             dtype=jnp.float32)
@@ -356,8 +362,9 @@ def test_engine_warmup_covers_top_rung_odd_chunk():
     params = llama.init_params(cfg, jax.random.key(3))
     gen = Generator(cfg, mesh, axis="sp", max_seq=16)
     eng = ServeEngine(gen, params, num_blocks=8, page_size=16,
-                      max_batch=1, prefill_chunk=7, clock=_Tick())
-    assert eng.ladder == [16, 32]
+                      max_batch=1, prefill_chunk=7, prefill_budget=budget,
+                      clock=_Tick())
+    assert eng.prefill_width == width and eng.ladder == ladder
     assert eng._bucket_s_ext(15) == 32      # roundup(15, 7) = 21 > 16
     eng.warmup()
     flat = eng.metrics.compile_misses
@@ -403,12 +410,275 @@ def test_engine_custom_bucket_ladder_validated():
         ServeEngine(gen, params, num_blocks=8, page_size=4, max_batch=1,
                     prefill_chunk=8, bucket_ladder=[4])   # < one chunk
     eng = ServeEngine(gen, params, num_blocks=8, page_size=4, max_batch=1,
-                      prefill_chunk=4, bucket_ladder=[8, 24])
+                      prefill_chunk=4, prefill_budget=4,
+                      bucket_ladder=[8, 24])
     assert eng.ladder == [8, 24, 64]      # cap appended to cover max_seq
     assert eng._bucket_s_ext(5) == 8
     assert eng._bucket_s_ext(9) == 24
     assert eng._bucket_s_ext(25) == 64
     assert eng._bucket_s_ext(63) == 64
+    # a rung under one call's rows (the default budget: 4 x 4) holds no
+    # call: it folds into the first that does, and brings no program
+    eng = ServeEngine(gen, params, num_blocks=8, page_size=4, max_batch=1,
+                      prefill_chunk=4, bucket_ladder=[4, 8, 24])
+    assert eng.prefill_width == 16 and eng.ladder == [16, 24, 64]
+    assert eng._bucket_s_ext(5) == 16
+    assert eng._bucket_s_ext(17) == 64    # two calls of 16 rows: 32 > 24
+
+
+# ---------------------------------------------------------------------------
+# The width of a prefill call (ISSUE 33): a request's share of a step's
+# budget is ONE ``prefill_chunk`` program call of ``prefill_width`` rows
+# ---------------------------------------------------------------------------
+
+
+def _wide_model(kv_dtype=None, max_seq=256):
+    """1-layer toy with room for a prompt of three calls and a bit."""
+    cfg = llama.LlamaConfig(vocab=64, dim=16, n_layers=1, n_heads=2,
+                            n_kv_heads=1, ffn_dim=32, max_seq=max_seq,
+                            dtype=jnp.float32)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("sp",))
+    params = llama.init_params(cfg, jax.random.key(3))
+    gen = Generator(cfg, mesh, axis="sp", max_seq=max_seq,
+                    kv_dtype=kv_dtype)
+    return cfg, params, gen
+
+
+def _tap_prefill(eng):
+    """Record every ``prefill_chunk`` call of ``eng``: (rows of the token
+    buffer, scratch extent, where the call's rows start, rows fed)."""
+    calls, seam = [], eng._device_call
+
+    def tapped(op, rids, fn, *a, **kw):
+        if op == "prefill_chunk":
+            calls.append((a[1].shape[1], kw["extent"], int(a[3]),
+                          int(kw["n_valid"])))
+        return seam(op, rids, fn, *a, **kw)
+
+    eng._device_call = tapped
+    return calls
+
+
+def test_prefill_width_rule():
+    """``W = max(chunk, min(budget, 256))`` in whole chunks: the Mistral
+    cells (chunk 128, budget 512) get 256, the ridge; an engine whose
+    chunk is at or past it (gc3: 512 / 2,048; glm5, mellum2: 2,048 /
+    16,384), or whose budget is one chunk or less, keeps calls of one
+    chunk."""
+    from triton_dist_tpu.serve.engine import prefill_width
+
+    assert prefill_width(128, 512) == 256
+    assert prefill_width(128, 4096) == 256      # capped at the ridge
+    assert prefill_width(96, 400) == 192        # whole chunks only
+    assert prefill_width(64, 256) == 256        # the engine's defaults
+    assert prefill_width(16, 64) == 64 and prefill_width(4, 16) == 16
+    for chunk, budget in ((512, 2048), (2048, 16384), (200, 800),
+                          (256, 1024), (64, 64), (64, 32), (7, 7)):
+        assert prefill_width(chunk, budget) == chunk
+
+
+@pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
+@pytest.mark.parametrize("kv_dtype", [None, jnp.int8], ids=["float", "int8"])
+def test_wide_prefill_calls_serve_the_streams_of_chunk_wide_ones(kv_dtype,
+                                                                 warm):
+    """An engine at chunk 16 / budget 64 (a call is 64 rows) serves a mix
+    of greedy requests token-for-token as the same engine at budget 16 (a
+    call is one chunk) does — prompts of 1, W - 1, W, W + 1 and 3 W + 5
+    tokens, three slots so that requests start on a step's leftover
+    budget and carry an offset to their last call — over float and int8
+    pools, and with a warm prefix (the prompts past two pages open with
+    the same 32 tokens, which a primer served first leaves in the content
+    index: their prefill starts at row 32, off the width's grid)."""
+    cfg, params, gen = _wide_model(kv_dtype)
+    W = 64
+    lens = [1, W - 1, W, W + 1, 3 * W + 5]
+    rng = np.random.default_rng(5)
+    base = rng.integers(0, cfg.vocab, size=40).astype(np.int32)
+    prompts = [np.concatenate([base[:32], rng.integers(
+        0, cfg.vocab, size=max(n - 32, 0)).astype(np.int32)])[:n]
+        for n in lens]
+    served = {}
+    for budget in (64, 16):
+        eng = ServeEngine(gen, params, num_blocks=80, page_size=16,
+                          max_batch=3, prefill_chunk=16,
+                          prefill_budget=budget, prefix_cache=warm,
+                          clock=_Tick())
+        assert eng.prefill_width == budget
+        assert eng.kv_quant == (kv_dtype is not None)
+        if warm:
+            eng.submit(Request("primer", base,
+                               SamplingParams(max_new_tokens=2)))
+            eng.run()
+        calls = _tap_prefill(eng)
+        outs = _drive(eng, prompts, 5, stagger=1)
+        assert all(c[0] == budget for c in calls)
+        served[budget] = ({r: o.token_ids for r, o in outs.items()},
+                          len(calls), eng.metrics.summary())
+        assert eng.bm.num_free == eng.bm.num_allocatable
+    (wide, n_wide, s_wide), (narrow, n_narrow, s_narrow) = (served[64],
+                                                            served[16])
+    assert wide == narrow and len(wide) == len(lens)
+    assert s_wide["prefill"]["width"] == 64
+    assert s_narrow["prefill"]["width"] == 16
+    assert s_wide["prefill"]["tokens"] == s_narrow["prefill"]["tokens"]
+    assert n_wide < n_narrow / 2
+    if warm:
+        assert s_wide["prefix_cache"]["prefix_skipped_tokens"] > 0
+        assert (s_wide["prefix_cache"]["prefix_skipped_tokens"]
+                == s_narrow["prefix_cache"]["prefix_skipped_tokens"])
+
+
+def test_prefill_summary_counts_calls_and_padding():
+    """``summary()["prefill"]``: one call for every ``W`` rows (or part)
+    of each share the scheduler assigned, and the rows of those calls
+    that prefilled nothing, counted by hand from the plan."""
+    cfg, params, gen = _wide_model()
+    eng = ServeEngine(gen, params, num_blocks=80, page_size=16, max_batch=3,
+                      prefill_chunk=16, prefill_budget=128,
+                      prefix_cache=False, clock=_Tick())
+    W = eng.prefill_width
+    assert W == 128 and eng.metrics.summary()["prefill"] == {
+        "tokens": 0, "dispatches": 0, "tokens_per_dispatch": 0.0,
+        "pad_share": 0.0, "width": 128}
+    shares, plan = [], eng.scheduler.prefill_plan
+
+    def recorded(prefilling):
+        out = plan(prefilling)
+        shares.extend(n for _, n in out)
+        return out
+
+    eng.scheduler.prefill_plan = recorded
+    rng = np.random.default_rng(9)
+    lens = [200, 37, 129, 16, 90]
+    _drive(eng, [rng.integers(0, cfg.vocab, size=n).astype(np.int32)
+                 for n in lens], 3, stagger=1)
+    got = eng.metrics.summary()["prefill"]
+    n_calls = sum(-(-n // W) for n in shares)
+    assert sum(shares) == sum(lens) and n_calls > len(lens)
+    assert got["tokens"] == sum(lens) and got["dispatches"] == n_calls
+    assert got["tokens_per_dispatch"] == pytest.approx(sum(lens) / n_calls)
+    assert got["pad_share"] == pytest.approx(
+        (n_calls * W - sum(lens)) / (n_calls * W))
+    assert eng._chunk_fn.stats()["hits"] + eng._chunk_fn.misses == n_calls
+    text = eng.metrics.to_prometheus()
+    assert f"serve_prefill_dispatches_total {n_calls}" in text
+    assert (f"serve_prefill_pad_tokens_total "
+            f"{n_calls * W - sum(lens)}") in text
+    # the fleet's aggregate adds the counters and keeps the width
+    both = ServeMetrics().merge(eng.metrics).merge(eng.metrics)
+    assert both.prefill_stats()["dispatches"] == 2 * n_calls
+    assert both.prefill_stats()["width"] == W
+    assert both.prefill_stats()["pad_share"] == got["pad_share"]
+
+
+@pytest.mark.parametrize("page,chunk,budget,max_seq", [
+    (128, 512, 2048, 2048),     # gc3's shape: the chunk is past the ridge
+    (16, 16, 16, 256),          # a budget of one chunk
+    (16, 32, 8, 256),           # a budget under one chunk
+])
+def test_an_engine_whose_call_is_one_chunk_is_as_it_was(page, chunk, budget,
+                                                        max_seq):
+    """Where ``W == prefill_chunk`` the mechanism does not engage: the
+    ladder is the one the chunk built, a prompt's scratch the rung it had,
+    and every call ``[1, chunk]`` from a chunk multiple — the same program
+    keys as before the width existed."""
+    from triton_dist_tpu.serve.engine import build_bucket_ladder
+
+    cfg, params, gen = _wide_model(max_seq=max_seq)
+    eng = ServeEngine(gen, params, num_blocks=2 * max_seq // page + 4,
+                      page_size=page, max_batch=2, prefill_chunk=chunk,
+                      prefill_budget=budget, clock=_Tick())
+    assert eng.prefill_width == chunk
+    assert eng.metrics.summary()["prefill"]["width"] == chunk
+
+    def need(n):            # the sizing formula as it was: pages or chunks
+        return max(-(-n // page) * page, -(-n // chunk) * chunk)
+
+    assert eng.ladder == build_bucket_ladder(
+        max(page, chunk), need(max_seq - 1), page)
+    calls = _tap_prefill(eng)
+    rng = np.random.default_rng(2)
+    lens = [1, chunk - 1, chunk + 1, max_seq // 2 + 3]
+    _drive(eng, [rng.integers(0, cfg.vocab, size=n).astype(np.int32)
+                 for n in lens], 2, stagger=1)
+    want = sorted((chunk, next(r for r in eng.ladder if r >= need(n)),
+                   at, min(chunk, n - at))
+                  for n in lens for at in range(0, n, chunk))
+    assert sorted(calls) == want
+    keys = {(c[0], c[1]) for c in calls}
+    assert eng._chunk_fn.misses == len(keys)
+    assert eng.metrics.summary()["prefill"]["dispatches"] == len(want)
+
+
+def test_after_warmup_no_prompt_length_compiles():
+    """Every prompt length an engine admits, 1 .. max_seq - 1, served
+    after ``warmup()`` — two slots, so that requests start on leftover
+    budget and their calls slide back at the scratch's end — compiles
+    nothing: one ``[1, W]`` program a rung covers them all."""
+    cfg, params, gen = _tiny_model()
+    eng = ServeEngine(gen, params, num_blocks=80, page_size=4, max_batch=2,
+                      prefill_chunk=4, clock=_Tick())
+    assert eng.prefill_width == 16 and eng.ladder == [16, 32, 64]
+    eng.warmup()
+    flat = eng.metrics.compile_misses
+    calls = _tap_prefill(eng)
+    slid, window = [], eng._call_window
+
+    def watched(pos, s_ext):
+        at = window(pos, s_ext)
+        slid.append(pos - at)
+        return at
+
+    eng._call_window = watched
+    rng = np.random.default_rng(4)
+    lens = list(range(1, cfg.max_seq))
+    outs = _drive(eng, [rng.integers(0, cfg.vocab, size=n).astype(np.int32)
+                        for n in lens],
+                  1, stagger=1)
+    assert len(outs) == len(lens)
+    assert eng.metrics.compile_misses == flat, (
+        eng.metrics.summary()["compilation"])
+    assert {c[0] for c in calls} == {16}
+    assert {c[1] for c in calls} == set(eng.ladder)
+    # the sweep reached calls that start off the width's multiples, and
+    # ones whose window slid back from where their tokens start
+    assert any(at % 16 for _, _, at, _ in calls)
+    assert len(slid) == len(calls) and 0 < sum(1 for d in slid if d) \
+        < len(calls) / 4
+    assert all(at + 16 <= ext for _, ext, at, _ in calls)
+
+
+@pytest.mark.parametrize("page,chunk,budget", [
+    (4, 4, None), (16, 7, None), (16, 16, 64), (8, 32, None), (4, 4, 4),
+    (16, 4, 24),
+])
+def test_no_prefill_call_writes_past_its_scratch(page, chunk, budget):
+    """Property: for every admissible prompt and every chunk-multiple
+    position a call may start from (budget is metered in chunks: warm
+    starts and leftover budget put a request anywhere on that grid), the
+    call's ``W``-row window lies inside the scratch ``_scratch_need``
+    sized, holds the tokens it prefills, and moves back only where the
+    request left the width's grid — ``dynamic_update_slice`` never gets to
+    clamp."""
+    cfg, params, gen = _tiny_model()
+    eng = ServeEngine(gen, params, num_blocks=40, page_size=page,
+                      max_batch=1, prefill_chunk=chunk,
+                      prefill_budget=budget, clock=_Tick())
+    W = eng.prefill_width
+    assert eng.ladder[0] >= W
+    for n in range(1, cfg.max_seq):
+        assert eng._scratch_need(n) >= max(n, -(-n // W) * W)
+        ext = eng._bucket_s_ext(n)
+        assert ext >= eng._scratch_need(n) and ext % page == 0
+        for pos in range(0, n, chunk):
+            c = min(W, n - pos)
+            at = eng._call_window(pos, ext)
+            assert 0 <= at <= pos and at + W <= ext
+            assert pos + c <= at + W            # the new tokens fit
+            if pos % W == 0:
+                assert at == pos                # sized for these as is
+            if at < pos:
+                assert at + W == ext            # slid back to the end only
 
 
 def test_attention_kernel_gaps_names_every_silent_xla_reroute():

@@ -213,6 +213,16 @@ def test_brownout_rung_semantics_white_box(tiny):
     eng._set_brownout(2)
     assert eng.scheduler.prefill_budget == max(
         eng.scheduler.prefill_chunk, base_budget // 2)
+    # the halving fills prefill calls less and compiles nothing: a call's
+    # rows, and the budget a snapshot hands the restored engine, are the
+    # ones the engine was built with
+    from triton_dist_tpu.serve import recovery
+    from triton_dist_tpu.serve.engine import prefill_width
+
+    assert eng.prefill_width == prefill_width(
+        eng.scheduler.prefill_chunk, base_budget)
+    assert recovery._capture_meta(eng, 0.0, journal_here=False)[
+        "engine"]["prefill_budget"] == base_budget
 
     eng._set_brownout(4)
     out = eng.submit(Request("be", ps[0], sp, slo_class="best_effort"))

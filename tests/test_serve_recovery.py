@@ -363,9 +363,12 @@ def test_kill_restart_chaos_bit_exact(tiny, tmp_path):
         return arm
 
     cases = {
-        # 2nd prefill-chunk dispatch: mid-prompt, nothing emitted yet
+        # 2nd prefill-chunk dispatch: mid-prompt, nothing emitted yet (a
+        # budget of one chunk: g0's 5 tokens are two calls of 4 rows, a
+        # step each — at the default budget a call is 16 rows and every
+        # prompt here is one)
         "mid_prefill": dict(
-            horizon=1,
+            horizon=1, prefill_budget=4,
             pre=lambda inj: inj.inject("forward", op="prefill_chunk",
                                        at_call=2, kill=True),
             arm=None),
@@ -407,6 +410,7 @@ def test_kill_restart_chaos_bit_exact(tiny, tmp_path):
         on_token = (lambda rid, t: None)
         eng = _engine(gen, params, clock=_Tick(), snapshot_dir=str(d),
                       snapshot_every=3, horizon=case["horizon"],
+                      prefill_budget=case.get("prefill_budget"),
                       faults=inj)
         drained = _drive(eng, _make_reqs(prompts, on_token=on_token),
                          arm=case["arm"])

@@ -40,9 +40,10 @@ latest-admitted request (recompute-style: emitted tokens are kept and the
 victim re-prefills ``prompt + generated``).
 
 Compilation is BOUNDED and observable (PR 2): prefill always runs the
-one fixed ``prefill_chunk`` shape (final residual padded, its K/V writes
-zero-masked via ``n_valid``), scratch extents and the page scatter
-bucket to a powers-of-two ladder, :meth:`ServeEngine.warmup`
+one fixed ``[1, prefill_width]`` shape (a request's share of a step's
+budget in calls as wide as the weights pay for; a residual padded, its
+K/V writes zero-masked via ``n_valid``), scratch extents and the page
+scatter bucket to a powers-of-two ladder, :meth:`ServeEngine.warmup`
 pre-compiles the lot, and every program's trace-cache hit/miss/stall
 counters ride ``ServeMetrics`` (docs/serving.md "bucket ladder").
 
@@ -249,6 +250,32 @@ def build_bucket_ladder(base: int, cap: int, page: int) -> list[int]:
     return rungs
 
 
+# Past this many rows a prefill call is bound by its products and a wider
+# one buys nothing a row.  A call reads every weight once whatever its
+# rows: at bf16 that is 2 FLOP a row for every 2 bytes of weight, so it
+# turns from weight-bound to product-bound at the chip's FLOP a byte — the
+# v5e's 197 TF/s over 819 GB/s = ~240 rows.  256 is that ridge in whole
+# 128-row chunks.  Measured on the v5e (PERF.md §6 "PR 33": Mistral-7B at
+# 16 layers, 7.5 GB of weights, extent 2,048): one call of 128 / 256 /
+# 384 / 512 rows takes 13.6 / 16.0 / 22.3 / 30.8 ms = 106 / 62 / 58 /
+# 60 us a row — 256 rows share the 9.2 ms read of the weights as well as
+# 512 do, two calls of 256 cost what one of 512 costs, and a residual or a
+# request's first tokens on a step's leftover budget pad to 256, not 512
+# (in the engine: 1,113-1,129 tokens/s at 256 against 1,043-1,052 at 512).
+_PREFILL_WIDTH_MAX = 256
+
+
+def prefill_width(prefill_chunk: int, prefill_budget: int) -> int:
+    """Rows of ONE ``prefill_chunk`` program call: what the weights pay
+    for, not the scheduler's metering granule.  The step's budget up to
+    ``_PREFILL_WIDTH_MAX``, in whole chunks, and never under one chunk —
+    so an engine whose chunk is at or past the ridge, or whose budget is
+    one chunk, runs ``[1, prefill_chunk]`` calls as it always did."""
+    return max(prefill_chunk,
+               min(prefill_budget, _PREFILL_WIDTH_MAX)
+               // prefill_chunk * prefill_chunk)
+
+
 # ---------------------------------------------------------------------------
 # The engine
 # ---------------------------------------------------------------------------
@@ -286,8 +313,10 @@ class ServeEngine:
     program and runs no speculative rounds, or it does not).
 
     **Shape bucketing** (docs/serving.md): prefill always runs the ONE
-    fixed ``prefill_chunk`` shape (the final residual pads, its K/V
-    writes zero-masked by ``n_valid``), and each prompt's scratch extent
+    fixed shape ``[1, prefill_width]`` — the step's budget up to 256
+    rows, in whole ``prefill_chunk``s, which stay the scheduler's
+    metering granule (a residual pads, its K/V writes zero-masked by
+    ``n_valid``) — and each prompt's scratch extent
     rounds up a powers-of-two ``bucket_ladder`` — so O(len(ladder))
     compiled programs cover EVERY prompt length, and :meth:`warmup`
     pre-compiles them all so steady-state serving never compiles.
@@ -550,7 +579,13 @@ class ServeEngine:
         self._pressure_t: Optional[float] = None
         self._brownout_dwell = 0
         self._base_prefill_budget = self.scheduler.prefill_budget
+        # Rows of one ``prefill_chunk`` call, from the budget the engine
+        # was BUILT with: the brownout ladder's halving fills calls less
+        # and compiles nothing.
+        self.prefill_width = prefill_width(prefill_chunk,
+                                           self._base_prefill_budget)
         self.metrics = ServeMetrics()
+        self.metrics.prefill_width = self.prefill_width
         # flight recorder (docs/observability.md): a bounded ring of
         # typed engine events — submit/admit/prefill/decode drains, spec
         # rounds, preemptions, COW splits, faults, retirements — that
@@ -679,9 +714,10 @@ class ServeEngine:
         # the largest extent an admissible prompt can need (submit()
         # holds prompt <= max_seq - 1).
         cap = self._scratch_need(gen.max_seq - 1)
+        width = self.prefill_width
         if bucket_ladder is None:
             self.ladder = build_bucket_ladder(
-                max(page_size, prefill_chunk), cap, page_size)
+                max(page_size, width), cap, page_size)
         else:
             rungs = sorted({int(r) for r in bucket_ladder})
             bad = [r for r in rungs
@@ -691,6 +727,10 @@ class ServeEngine:
                     f"bucket_ladder rungs must be multiples of page_size "
                     f"{page_size} and hold one prefill_chunk "
                     f"{prefill_chunk}; got {bad}")
+            # a rung under one call's rows cannot take its write: such
+            # rungs fold into the first that can (no program of their own)
+            first = -(-width // page_size) * page_size
+            rungs = sorted({max(r, first) for r in rungs})
             if rungs[-1] < cap:
                 rungs.append(-(-cap // page_size) * page_size)
             self.ladder = rungs
@@ -703,7 +743,7 @@ class ServeEngine:
         # TPU — where a silent XLA reroute is a lost kernel, not a test
         # convenience — said once at construction.
         self.kernel_gaps = gen.kernel_gaps(
-            page_size=page_size, prefill_chunk=prefill_chunk,
+            page_size=page_size, prefill_chunk=width,
             ladder=self.ladder, sp_world=self.sp_world)
         self.metrics.kernel_gaps = self.kernel_gaps
         # How the paged decode call is blocked (static, decided where the
@@ -1981,7 +2021,7 @@ class ServeEngine:
         assert not self.has_work(), "warmup() must run before traffic"
         t0 = time.perf_counter()
         misses0 = self.metrics.compile_misses
-        chunk = self.scheduler.prefill_chunk
+        width = self.prefill_width
         # dummy traffic must not pollute serving metrics; the CountingJit
         # wrappers are shared so compile accounting continues
         saved, self.metrics = self.metrics, ServeMetrics()
@@ -2012,11 +2052,11 @@ class ServeEngine:
                     for i, rung in enumerate(self.ladder):
                         # Longest prompt whose _scratch_need fits this
                         # rung: n <= rung keeps the pool pages in, and
-                        # n <= (rung // chunk) * chunk keeps the padded
-                        # final chunk in.  If even that n buckets LOWER,
+                        # n <= (rung // width) * width keeps the padded
+                        # final call in.  If even that n buckets LOWER,
                         # no admissible prompt can reach this rung —
                         # skip it (production can't hit it either).
-                        n_max = min(rung, (rung // chunk) * chunk,
+                        n_max = min(rung, (rung // width) * width,
                                     self.gen.max_seq - 1)
                         if n_max < 1 or self._bucket_s_ext(n_max) != rung:
                             continue
@@ -2029,8 +2069,8 @@ class ServeEngine:
                         else:
                             below = self.ladder[i - 1]
                             n_min = 1 + max(0, min(below,
-                                                   (below // chunk)
-                                                   * chunk))
+                                                   (below // width)
+                                                   * width))
                         self._warmup_try(f"w{round_}_{i}", n_max, n_min)
                     if self.spec_k:
                         # Sweep the DRAFT extent ladder too: its rungs
@@ -2226,13 +2266,30 @@ class ServeEngine:
 
     def _scratch_need(self, n_prompt: int) -> int:
         """Unbucketed scratch extent an ``n_prompt``-token prefill needs:
-        its pool pages, OR the padded final chunk's write rounded up to
-        prefill_chunk (dynamic_update_slice must never clamp), whichever
-        is larger.  THE sizing formula — the ladder cap, the bucket
-        lookup, and warmup's per-rung prompt picker all derive from it."""
-        chunk = self.scheduler.prefill_chunk
+        its pool pages, OR the padded final call's write rounded up to
+        the call's rows (dynamic_update_slice must never clamp),
+        whichever is larger.  THE sizing formula — the ladder cap, the
+        bucket lookup, and warmup's per-rung prompt picker all derive
+        from it.  It is sized for calls that start on multiples of the
+        width; one that starts elsewhere (:meth:`_call_window`) slides
+        back inside the same extent."""
+        width = self.prefill_width
         return max(self.bm.blocks_for(n_prompt) * self.page,
-                   -(-n_prompt // chunk) * chunk)
+                   -(-n_prompt // width) * width)
+
+    def _call_window(self, pos: int, s_ext: int) -> int:
+        """Where the ``prefill_width``-row call that prefills from ``pos``
+        starts: at ``pos``, or — where the scratch has fewer rows than
+        that left past it — as far back as makes its write end at the
+        extent.  Budget is metered in ``prefill_chunk`` multiples, so a
+        request that began on a step's leftover budget (or on a warm
+        prefix) carries an offset, and its last call may begin within a
+        width of the extent's end.  The rows between the window's start
+        and ``pos`` are fed again and recompute to what they held (the
+        same tokens over the same earlier rows — the warm start's
+        argument), so the write never clamps and the scratch needs no
+        rung of its own for the offset."""
+        return min(pos, s_ext - self.prefill_width)
 
     def _bucket_s_ext(self, n_prompt: int) -> int:
         """Scratch extent for an ``n_prompt``-token prefill, bucketed up
@@ -2265,10 +2322,12 @@ class ServeEngine:
         chunk = self.scheduler.prefill_chunk
         # Warm prefix (docs/serving.md "Prefix caching"): admission
         # mapped `cached` block-aligned tokens of shared KV into the
-        # table; chunked prefill starts at the chunk FLOOR of that (the
-        # fixed-chunk trace contract needs chunk-aligned starts — the
-        # few tokens between floor and hit recompute bit-identically
-        # over the gathered rows) and only the residual pays compute.
+        # table; chunked prefill starts at the chunk FLOOR of that
+        # (``prefill_pos`` stays a chunk multiple, what the scheduler
+        # meters in — the few tokens between floor and hit recompute
+        # bit-identically over the gathered rows) and only the residual
+        # pays compute.  Any such start is one the scratch covers: a
+        # call that would pass the extent slides back (_call_window).
         start = (cached // chunk) * chunk
         if start > 0:
             rs.prefill_pos = start
@@ -2300,28 +2359,33 @@ class ServeEngine:
         prompt = rs.prompt_tokens
         S0 = int(prompt.shape[0])
         end = min(rs.prefill_pos + n_tokens, S0)
-        chunk_sz = self.scheduler.prefill_chunk
+        width = self.prefill_width
         logits = None
         n_last = 0
         while rs.prefill_pos < end:
-            c = min(chunk_sz, end - rs.prefill_pos)
-            # Every call is the ONE fixed chunk shape: the final residual
+            c = min(width, end - rs.prefill_pos)
+            # Every call is the ONE fixed shape [1, width] — as many rows
+            # as it takes to share one read of the weights: a residual
             # pads with zeros and n_valid masks its K/V writes, so the
-            # trace is keyed by (chunk_sz, s_ext bucket) only — varied
+            # trace is keyed by (width, s_ext bucket) only — varied
             # prompt lengths never compile on the admission path.
             with self.trace.span("prefill.stage"):
-                buf = np.zeros((1, chunk_sz), np.int32)
-                buf[0, :c] = prompt[rs.prefill_pos:rs.prefill_pos + c]
+                at = self._call_window(rs.prefill_pos, rs.s_ext)
+                n_fed = rs.prefill_pos + c - at
+                buf = np.zeros((1, width), np.int32)
+                buf[0, :n_fed] = prompt[at:at + n_fed]
                 buf_d = jnp.asarray(buf)
-                pos_d, valid_d = jnp.int32(rs.prefill_pos), jnp.int32(c)
+                pos_d, valid_d = jnp.int32(at), jnp.int32(n_fed)
             rs.scratch, logits, *aux = self._device_call(
                 "prefill_chunk", (rs.req.request_id,), self._chunk_fn,
                 self.params, buf_d, rs.scratch, pos_d,
                 quantized=self.kv_quant, extent=rs.s_ext, n_valid=valid_d)
             self._note_aux(aux)
             rs.prefill_pos += c
-            n_last = c
+            n_last = n_fed
             self.metrics.prefill_tokens += c
+            self.metrics.prefill_dispatches += 1
+            self.metrics.prefill_pad_tokens += width - c
             if self.trace.level >= 2:
                 self.trace.emit("prefill_chunk", rs.req.request_id,
                                 n=c, pos=rs.prefill_pos)

@@ -61,7 +61,8 @@ REQUESTS_RETAIN = 4096
 #: aggregate, so keep them in lockstep.
 MERGE_COUNTERS = (
     "steps", "decode_steps", "verify_rounds", "prefill_tokens",
-    "preemptions", "completed", "decode_tokens", "dispatches",
+    "prefill_dispatches", "prefill_pad_tokens", "preemptions", "completed",
+    "decode_tokens", "dispatches",
     "host_syncs", "host_choices", "shed", "deadline_expired", "quarantined",
     "callback_errors", "forward_retries", "forward_bisections",
     "watchdog_trips", "spec_bailouts", "spec_rounds", "spec_proposed",
@@ -259,6 +260,13 @@ class ServeMetrics:
     decode_steps: int = 0
     verify_rounds: int = 0
     prefill_tokens: int = 0
+    # how often the wide prefill call engages (docs/serving.md "bucket
+    # ladder"): ``prefill_chunk`` program calls, and the rows of those
+    # calls that prefilled no new token (a residual's zero padding, rows
+    # a call recomputed where its window slid back at the scratch's end)
+    prefill_dispatches: int = 0
+    prefill_pad_tokens: int = 0
+    prefill_width: int = 0        # rows of one call (stamped by the engine)
     preemptions: int = 0
     completed: int = 0
     # decode-loop dispatch accounting (docs/serving.md "Decode horizon"):
@@ -808,6 +816,7 @@ class ServeMetrics:
         self.kv_pool_bytes += other.kv_pool_bytes
         self.kv_token_slots += other.kv_token_slots
         self.kv_quant = self.kv_quant or other.kv_quant
+        self.prefill_width = max(self.prefill_width, other.prefill_width)
         for reason, n in other.finish_reasons.items():
             self.finish_reasons[reason] = \
                 self.finish_reasons.get(reason, 0) + n
@@ -902,6 +911,23 @@ class ServeMetrics:
                                     if self.dispatches else 0.0),
             "dispatches_per_token": (self.dispatches / self.decode_tokens
                                      if self.decode_tokens else 0.0),
+        }
+
+    def prefill_stats(self) -> dict:
+        """How full the prefill program's calls run (summary()["prefill"]):
+        every call is ``width`` rows whatever it was given, so
+        ``tokens_per_dispatch`` against ``width`` is what the weights'
+        read was shared over and ``pad_share`` the rows that bought
+        nothing."""
+        rows = self.prefill_tokens + self.prefill_pad_tokens
+        return {
+            "tokens": self.prefill_tokens,
+            "dispatches": self.prefill_dispatches,
+            "tokens_per_dispatch": (
+                self.prefill_tokens / self.prefill_dispatches
+                if self.prefill_dispatches else 0.0),
+            "pad_share": self.prefill_pad_tokens / rows if rows else 0.0,
+            "width": self.prefill_width,
         }
 
     def latency_stats(self) -> dict:
@@ -1016,6 +1042,7 @@ class ServeMetrics:
             "programs": self.program_stats(),
             "phases": self.phase_stats(),
             "decode": self.decode_stats(),
+            "prefill": self.prefill_stats(),
             "kv": self.kv_stats(),
             "moe": self.moe_stats(),
             "dsa": self.dsa_stats(),
@@ -1062,6 +1089,10 @@ class ServeMetrics:
         counter("serve_decode_steps_total", self.decode_steps)
         counter("serve_decode_tokens_total", self.decode_tokens)
         counter("serve_prefill_tokens_total", self.prefill_tokens)
+        counter("serve_prefill_dispatches_total", self.prefill_dispatches,
+                "prefill_chunk program calls")
+        counter("serve_prefill_pad_tokens_total", self.prefill_pad_tokens,
+                "rows of those calls that prefilled no new token")
         counter("serve_dispatches_total", self.dispatches,
                 "decode-path device dispatches")
         counter("serve_host_syncs_total", self.host_syncs)

@@ -779,7 +779,10 @@ def _capture_meta(engine, now: float, *, journal_here: bool) -> dict:
         "max_batch": engine.max_batch,
         "max_seq": engine.gen.max_seq,
         "prefill_chunk": engine.scheduler.prefill_chunk,
-        "prefill_budget": engine.scheduler.prefill_budget,
+        # the budget the engine was BUILT with (a brownout rung halves
+        # the scheduler's; the width of a prefill call derives from this
+        # one, alike on both sides of a restore)
+        "prefill_budget": engine._base_prefill_budget,
         "horizon": engine.horizon,
         "pipeline": engine.pipeline,
         "spec_k": engine.spec_k,

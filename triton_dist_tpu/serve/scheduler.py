@@ -258,12 +258,13 @@ class FCFSScheduler:
         assignment always gets at least one chunk (progress guarantee).
 
         Assignments are quantized to WHOLE ``prefill_chunk`` multiples
-        (except a prompt's final residual, which the engine pads up to a
-        full chunk): every ``_chunk_jit`` call then has the one fixed
-        chunk shape, so prefill never retraces on prompt length — the
-        trace-cache contract of docs/serving.md's bucket ladder.  A
-        padded final chunk is charged as a full chunk of budget (it
-        costs a full chunk of compute)."""
+        (except a prompt's final residual): the chunk is the METERING
+        granule.  The engine runs an assignment in calls of its one fixed
+        shape ``[1, W]`` (``ServeEngine.prefill_width``: the step's
+        budget up to 256 rows, in whole chunks — a residual pads up), so
+        prefill never retraces on prompt length — the trace-cache
+        contract of docs/serving.md's bucket ladder.  A padded final
+        chunk is charged as a full chunk of budget."""
         plan = []
         budget = self.prefill_budget
         chunk = self.prefill_chunk
