@@ -233,6 +233,12 @@ _FAST_GATE_MODULES = {
     # name over a table with released pages (the whole file is the fast
     # tier, ~3 min).
     "test_swa_moe",
+    # regions of a device program (ISSUE 36): the closed set's meta-test,
+    # the lowered computation of every family's decode_horizon /
+    # prefill_chunk / paged_decode identical with and without the scopes,
+    # and a product of every seam under its (innermost) region; programs
+    # are lowered, never compiled or run (~1 min).
+    "test_regions",
 }
 
 # Heavy tests inside core modules whose coverage is duplicated by a

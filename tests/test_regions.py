@@ -1,0 +1,235 @@
+"""Regions of a device program (``runtime/profiling.py`` ``region``): the
+scopes that say, operation by operation, which seam of the one layer loop
+a device trace's time belongs to (``benchmarks/regions.py`` reads them).
+
+- the closed set: every ``region("...")`` literal under ``triton_dist_tpu/``
+  is a member of ``profiling.REGIONS`` and every member has a use (the
+  twin of ``test_serve_trace``'s ``STEP_PHASES`` meta-test);
+- INVARIANT (a), the computation is the parent's: for each of the four
+  model families, the engine's ``decode_horizon``, ``prefill_chunk`` and
+  ``paged_decode`` lower to the same StableHLO text (no debug info) with
+  ``region`` as written and with it patched to ``contextlib.nullcontext``;
+- every seam's product carries its region in the lowered module's
+  ``op_name`` path, the INNERMOST where two nest — and the dense family's
+  paged programs hold no ``attn`` scope at all: their Mosaic call has no
+  name of its own and must keep the one the benchmark reads it by.
+
+Nothing runs here beyond building toy engines: the programs are lowered,
+not compiled (the slow tier's ``test_chip_aot`` compiles the cells' for the
+v5e and holds invariant (b), the Mosaic calls' names).
+"""
+
+import contextlib
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from triton_dist_tpu.models import generate as G
+from triton_dist_tpu.models import llama
+from triton_dist_tpu.models import mla_moe as M
+from triton_dist_tpu.models import swa_moe as S
+from triton_dist_tpu.runtime import profiling
+from triton_dist_tpu.serve import ServeEngine
+from triton_dist_tpu.serve import programs as PR
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FAMILIES = ("dense", "latent", "sparse", "window")
+PROGRAMS = ("decode_horizon", "prefill_chunk", "paged_decode")
+# the modules that open regions (they import ``region`` by name)
+SCOPED = (G, M, PR)
+I32 = jnp.int32
+B, H, CHUNK = 2, 4, 64
+
+
+def test_region_takes_its_closed_set_only():
+    with pytest.raises(ValueError, match="region 'mlp'"):
+        profiling.region("mlp")
+    for name in profiling.REGIONS:
+        with profiling.region(name):
+            pass
+    # one HLO-legal word a region, and none that a cut at the first "."
+    # (``benchmarks/xplane.py`` ``op_name``) would shorten
+    assert all(re.fullmatch(r"[a-z]+(_[a-z]+)?(\.[a-z]+)?", n)
+               for n in profiling.REGIONS)
+    assert len(set(profiling.REGIONS)) == len(profiling.REGIONS)
+
+
+def test_every_region_literal_is_a_member_and_every_member_has_a_use():
+    literals = set()
+    for root, _, files in os.walk(os.path.join(REPO, "triton_dist_tpu")):
+        for f in files:
+            if f.endswith(".py"):
+                with open(os.path.join(root, f)) as fh:
+                    literals |= set(re.findall(
+                        r'\bregion\(\s*"([^"]+)"', fh.read()))
+    assert literals == set(profiling.REGIONS)
+
+
+# ---------------------------------------------------------------------------
+# A toy engine of each family, and its programs' abstract arguments
+# ---------------------------------------------------------------------------
+
+
+def _build(family):
+    if family == "dense":
+        cfg = llama.LlamaConfig(vocab=64, dim=16, n_layers=2, n_heads=2,
+                                n_kv_heads=1, ffn_dim=32, max_seq=256,
+                                dtype=jnp.float32)
+        gen = G.Generator(cfg, Mesh(np.array(jax.devices()[:1]), ("sp",)),
+                          axis="sp", max_seq=256)
+        params = llama.init_params(cfg, jax.random.key(3))
+    elif family in ("latent", "sparse"):
+        cfg = (M.MlaMoeConfig.tiny_sparse(n_layers=2) if family == "sparse"
+               else M.MlaMoeConfig.tiny(n_layers=2))
+        gen = M.MlaMoeGenerator(cfg, max_seq=256, interpret=True)
+        params = M.init_params(cfg, jax.random.key(3))
+    else:
+        cfg = S.SwaMoeConfig.tiny(n_layers=4)
+        gen = S.SwaMoeGenerator(cfg, max_seq=256)
+        params = S.init_params(cfg, jax.random.key(3))
+    # the sparse block expands a chunk of 256 queries or more
+    chunk = M.PREFILL_EXPAND_MIN if family == "sparse" else CHUNK
+    eng = ServeEngine(gen, params, num_blocks=40, page_size=16, max_batch=B,
+                      prefill_chunk=chunk, horizon=H, prefix_cache=False,
+                      trace_level=0)
+    return eng, chunk
+
+
+def _lowered(eng, chunk, program):
+    """The engine's own jitted ``program`` lowered on abstract arguments
+    of the engine's own shapes."""
+    s = jax.ShapeDtypeStruct
+    shapes = lambda tree: jax.tree.map(     # noqa: E731
+        lambda x: s(x.shape, x.dtype), tree)
+    params, pools = shapes(eng.params), shapes(eng._pools)
+    if program == "prefill_chunk":
+        extent = 256
+        scratch = [tuple(s((1, h, extent, d), eng.gen.cfg.dtype)
+                         for h, d in eng.kv_planes)] * len(pools)
+        return eng._chunk_fn.fn.lower(
+            params, s((1, chunk), I32), scratch, s((), I32),
+            quantized=False, extent=extent, n_valid=s((), I32))
+    groups = len(eng.kv_groups) if eng.kv_groups else 0
+    tables = s(((groups,) if groups else ()) + (B, eng.n_pages_max), I32)
+    vec = lambda dt: s((B,), dt)    # noqa: E731
+    decode = (params, pools, tables, vec(I32), vec(I32), vec(bool))
+    if program == "paged_decode":
+        return eng._decode_fn.fn.lower(*decode)
+    keys = jax.eval_shape(lambda: jnp.stack([jax.random.key(0)] * B))
+    return eng._horizon_fn.fn.lower(
+        *decode, vec(bool), vec(I32), vec(I32), keys, vec(jnp.float32),
+        vec(I32), vec(jnp.float32), vec(bool), vec(I32), H=H,
+        all_greedy=False)
+
+
+@pytest.fixture(scope="module")
+def engines():
+    """family -> (engine, chunk), built once with ``region`` as written."""
+    cache = {}
+
+    def get(family):
+        if family not in cache:
+            cache[family] = _build(family)
+        return cache[family]
+
+    return get
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("family", FAMILIES)
+def test_the_computation_is_the_same_without_the_scopes(
+        engines, monkeypatch, family, program):
+    """Invariant (a): a scope is metadata."""
+    eng, chunk = engines(family)
+    written = _lowered(eng, chunk, program).as_text()
+    assert "/rg_" not in written          # no debug info: no scope shows
+    for mod in SCOPED:
+        monkeypatch.setattr(mod, "region",
+                            lambda name: contextlib.nullcontext())
+    bare, _ = _build(family)              # fresh jits: nothing cached
+    patched = _lowered(bare, chunk, program)
+    assert "/rg_" not in patched.as_text(debug_info=True)
+    assert patched.as_text() == written
+
+
+# ---------------------------------------------------------------------------
+# Every seam's product carries its region, the innermost where two nest
+# ---------------------------------------------------------------------------
+
+# a called function's locations are relative to its call site (the scan
+# body's are ``rg_ffn/dot_general``; XLA joins them to ``jit(..)/while/body/
+# closed_call/rg_ffn/dot_general`` when it inlines the call)
+_PATH = re.compile(r'loc\("([^"]+)"')
+
+
+def _products(lowered) -> set:
+    """{(innermost region or None, primitive)} over the module's ops."""
+    out = set()
+    for path in _PATH.findall(lowered.as_text(debug_info=True)):
+        parts = path.split("/")
+        inner = [p for p in parts if p.startswith(profiling.REGION_PREFIX)]
+        region = inner[-1][len(profiling.REGION_PREFIX):].replace(
+            "__", ".") if inner else None
+        out.add((region, parts[-1]))
+    return out
+
+
+# region -> (family, program, a primitive its seam must have produced)
+_SEAM_PRODUCTS = {
+    "embed": ("dense", "decode_horizon", "gather"),
+    "proj": ("dense", "decode_horizon", "dot_general"),
+    "kv_write": ("dense", "decode_horizon", "scatter"),
+    "attn": ("window", "decode_horizon", "dot_general"),
+    "out_proj": ("dense", "decode_horizon", "dot_general"),
+    "ffn": ("dense", "prefill_chunk", "dot_general"),
+    "head": ("dense", "prefill_chunk", "dot_general"),
+    "moe.route": ("latent", "decode_horizon", "dot_general"),
+    "moe.align": ("window", "prefill_chunk", "jit(searchsorted)"),
+    "moe.experts": ("latent", "decode_horizon", "pallas_call"),
+    "moe.combine": ("window", "decode_horizon", "dot_general"),
+    "moe.shared": ("latent", "prefill_chunk", "dot_general"),
+    "dsa.index": ("sparse", "decode_horizon", "dot_general"),
+    "dsa.select": ("sparse", "decode_horizon", "while"),
+    "mla.expand": ("sparse", "prefill_chunk", "dot_general"),
+    "sample": ("dense", "decode_horizon", "while"),
+}
+
+
+def test_the_seam_table_covers_the_closed_set():
+    assert set(_SEAM_PRODUCTS) == set(profiling.REGIONS)
+
+
+@pytest.mark.parametrize("name", profiling.REGIONS)
+def test_a_product_of_each_seam_carries_its_region(engines, name):
+    family, program, primitive = _SEAM_PRODUCTS[name]
+    eng, chunk = engines(family)
+    products = _products(_lowered(eng, chunk, program))
+    assert (name, primitive) in products, sorted(
+        p for r, p in products if r == name)
+
+
+def test_the_dense_paged_call_stays_outside_attn(engines):
+    """The dense family's paged call has no name of its own: XLA names it
+    after the scope around it, and the benchmark reads it as
+    ``closed_call`` / ``_unknown_``.  So its decode programs open no
+    ``attn`` scope (``benchmarks/regions.py`` files the call there by its
+    name), while its prefill chunk — flash attention, named by its own
+    ``annotate`` scope — does, and a family whose call has a name (the
+    window family's carries its layer kind's) scopes its paged call."""
+    eng, chunk = engines("dense")
+    for program in ("decode_horizon", "paged_decode"):
+        regions = {r for r, _ in _products(_lowered(eng, chunk, program))}
+        assert "attn" not in regions and {"proj", "ffn", "head"} <= regions
+    assert "attn" in {r for r, _ in _products(
+        _lowered(eng, chunk, "prefill_chunk"))}
+    # the single-step program keeps the scope its call is named after
+    text = _lowered(eng, chunk, "paged_decode").as_text(debug_info=True)
+    assert "/_unknown_/rg_proj/" in text
+    weng, wchunk = engines("window")
+    assert "attn" in {r for r, _ in _products(
+        _lowered(weng, wchunk, "paged_decode"))}

@@ -13,6 +13,7 @@ the KV cache); works on any mesh axis, including world 1.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from dataclasses import dataclass
 
@@ -30,6 +31,7 @@ from triton_dist_tpu.kernels.gemm import resolve_impl
 from triton_dist_tpu.layers.sp_flash_decode import SpGQAFlashDecodeAttention
 from triton_dist_tpu.models.llama import LlamaConfig, _rms_norm
 from triton_dist_tpu.runtime.jit_cache import named
+from triton_dist_tpu.runtime.profiling import region
 
 
 @dataclass(frozen=True)
@@ -275,7 +277,8 @@ class Generator:
             return self.attn.append_kv(*cache, k[:, 0], v[:, 0], kv_lens)
 
         def attend(li, q, cache):
-            return self.attn(q[:, 0], *cache, kv_lens + inc)[:, None]
+            with region("attn"):
+                return self.attn(q[:, 0], *cache, kv_lens + inc)[:, None]
 
         new_caches, logits = _layer_stack(
             params, token[:, None], kv_lens[:, None], caches, cfg=self.cfg,
@@ -442,6 +445,14 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     - ``attend(li, q, cache') -> [B, T, Hq, .]`` scores the queries
       against the updated cache.
 
+    Each seam runs under its :func:`profiling.region` (``embed``, ``proj``,
+    ``kv_write``, ``out_proj``, ``ffn``, ``head``; a family opens finer
+    ones inside), so a device trace says what the model was doing in every
+    operation (``benchmarks/regions.py``).  ``attn`` is the PAIR's to open,
+    not this loop's: XLA names a Mosaic call with no name of its own after
+    the scope around it, and the dense family's paged call must keep the
+    name the benchmark reads it by (:func:`paged_attend`).
+
     ``kinds`` (a :class:`LayerKind` a layer, static) is given by a model
     whose layers differ in kind: ``project`` then takes ``kind=kinds[li]``
     (a RoPE per kind), and the pair, which has ``li``, looks its own up.
@@ -455,22 +466,28 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     want it: 11.4 against 12.4 ms a chunk with flat rows) and a decode
     step does not (12.7 against 12.4 ms a step with its rows flat)."""
     B, T = tokens.shape
-    x = params["embed"][tokens.reshape((B,) if T == 1 else (B, T))]
+    with region("embed"):
+        x = params["embed"][tokens.reshape((B,) if T == 1 else (B, T))]
     new_caches = []
     for li, layer in enumerate(params["layers"]):
-        h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-        q, k, v = project(h.reshape(B, T, -1), layer, pos,
-                          **_kind_kw(kinds, li))
-        cache = write_kv(li, caches[li], k, v)
+        with region("proj"):
+            h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+            q, k, v = project(h.reshape(B, T, -1), layer, pos,
+                              **_kind_kw(kinds, li))
+        with region("kv_write"):
+            cache = write_kv(li, caches[li], k, v)
         o = attend(li, q, cache)                         # [B, T, Hq, .]
-        o2 = o.reshape(B * T, -1).astype(cfg.dtype)
-        x = x + out_proj(o2, layer).reshape(x.shape)
-        h2 = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-        x = x + ffn(h2.reshape(B * T, -1), layer).reshape(x.shape)
+        with region("out_proj"):
+            o2 = o.reshape(B * T, -1).astype(cfg.dtype)
+            x = x + out_proj(o2, layer).reshape(x.shape)
+        with region("ffn"):
+            h2 = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+            x = x + ffn(h2.reshape(B * T, -1), layer).reshape(x.shape)
         new_caches.append(cache)
-    x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.dot(x, params["lm_head"],
-                     preferred_element_type=jnp.float32)
+    with region("head"):
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.dot(x, params["lm_head"],
+                         preferred_element_type=jnp.float32)
     return new_caches, logits.reshape(B, T, -1)
 
 
@@ -532,12 +549,17 @@ def paged_attend(q, pool, tables, lens, *, cfg, impl, interpret,
     a ``kind``, the layer's own — and the call then carries the kind's
     name."""
     kq, vq, ks, vs = _pool_views(pool)
-    o, _ = gqa_decode_paged_shard(
-        q, kq, vq, tables, lens, impl=impl, interpret=interpret,
-        soft_cap=cfg.attn_soft_cap,
-        window=cfg.attn_window if kind is None else kind.window,
-        k_scale=ks, v_scale=vs,
-        name=None if kind is None else kind.call_name)
+    # a call with no name of its own takes its instruction's name from the
+    # scope around it (``closed_call`` / ``_unknown_``: what the benchmark's
+    # ``paged_attn_roofline`` matches), so it alone runs outside ``attn``
+    # and ``benchmarks/regions.py`` files it there by that name
+    with contextlib.nullcontext() if kind is None else region("attn"):
+        o, _ = gqa_decode_paged_shard(
+            q, kq, vq, tables, lens, impl=impl, interpret=interpret,
+            soft_cap=cfg.attn_soft_cap,
+            window=cfg.attn_window if kind is None else kind.window,
+            k_scale=ks, v_scale=vs,
+            name=None if kind is None else kind.call_name)
     return o
 
 
@@ -829,14 +851,15 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
     def attend_views(li, q, planes):
         ext = extent or (planes[0]["q"] if quantized
                          else planes[0]).shape[2]
-        if quantized:
-            k_c, v_c = planes
-            return attend(q, k_c["q"][:, :, :ext], v_c["q"][:, :, :ext],
-                          prefix_len, k_scale=k_c["s"][:, :, :ext],
-                          v_scale=v_c["s"][:, :, :ext],
-                          **_kind_kw(kinds, li))
-        return attend(q, *(p[:, :, :ext] for p in planes), prefix_len,
-                      k_scale=None, v_scale=None, **_kind_kw(kinds, li))
+        with region("attn"):
+            if quantized:
+                k_c, v_c = planes
+                return attend(q, k_c["q"][:, :, :ext], v_c["q"][:, :, :ext],
+                              prefix_len, k_scale=k_c["s"][:, :, :ext],
+                              v_scale=v_c["s"][:, :, :ext],
+                              **_kind_kw(kinds, li))
+            return attend(q, *(p[:, :, :ext] for p in planes), prefix_len,
+                          k_scale=None, v_scale=None, **_kind_kw(kinds, li))
 
     return _layer_stack(params, chunk, positions[None], caches, cfg=cfg,
                         project=project, out_proj=out_proj, ffn=ffn,
@@ -889,10 +912,11 @@ def _verify_forward(params, chunk, caches, kv_lens, *, cfg: LlamaConfig,
                 _write_rows(v_c, v.transpose(0, 2, 1, 3), kv_lens))
 
     def attend(li, q, cache):
-        o, _ = gqa_decode_shard(q, cache[0], cache[1], kv_lens + T,
-                                impl=impl, interpret=interpret,
-                                soft_cap=cfg.attn_soft_cap,
-                                window=cfg.attn_window)
+        with region("attn"):
+            o, _ = gqa_decode_shard(q, cache[0], cache[1], kv_lens + T,
+                                    impl=impl, interpret=interpret,
+                                    soft_cap=cfg.attn_soft_cap,
+                                    window=cfg.attn_window)
         return o
 
     return _layer_stack(params, chunk, pos, caches, cfg=cfg,
@@ -908,11 +932,14 @@ def _prompt_forward(params, tokens, *, cfg, project, out_proj, ffn, attend,
     rows it is handed.  ``attend(q [B, S, Hq, .], *rows [B, S, Hkv, .])
     -> [B, S, Hq, .]`` is the family's causal attention over them
     (:func:`_attend_prompt`; ``mla_moe.attend_prompt``)."""
+    def attend_rows(li, q, kv):
+        with region("attn"):
+            return attend(q, *kv, **_kind_kw(kinds, li))
+
     rows, logits = _layer_stack(
         params, tokens, jnp.arange(tokens.shape[1], dtype=jnp.int32)[None],
         [None] * len(params["layers"]), cfg=cfg, project=project,
         out_proj=out_proj, ffn=ffn,
         write_kv=lambda li, _, k, v: (k,) if v is None else (k, v),
-        attend=lambda li, q, kv: attend(q, *kv, **_kind_kw(kinds, li)),
-        kinds=kinds)
+        attend=attend_rows, kinds=kinds)
     return [tuple(t.transpose(0, 2, 1, 3) for t in kv) for kv in rows], logits

@@ -79,6 +79,7 @@ from triton_dist_tpu.models.sampling import (
     _ordered_bits,
 )
 from triton_dist_tpu.runtime.jit_cache import named
+from triton_dist_tpu.runtime.profiling import region
 
 LANES = 128
 GATE_UP_CALL, DOWN_CALL = "moe_gate_up", "moe_down"
@@ -505,18 +506,20 @@ def project(h, layer, pos, *, cfg: MlaMoeConfig):
     if not c.sparse:
         return q_abs, latent, None
     Hi, Di = c.index_n_heads, c.index_head_dim
-    qi = (cq @ layer["idx_wq"]).reshape(B, T, Hi, Di)
-    qi = jnp.concatenate([rope(qi[..., :dr]), qi[..., dr:]], axis=-1)
-    ki = jnp.dot(h2, layer["idx_wk"], preferred_element_type=jnp.float32)
-    mu = jnp.mean(ki, -1, keepdims=True)
-    ki = ((ki - mu) * jax.lax.rsqrt(
-        jnp.mean(jnp.square(ki - mu), -1, keepdims=True) + c.index_norm_eps)
-        * layer["idx_k_norm"].astype(jnp.float32)
-        + layer["idx_k_bias"].astype(jnp.float32)).reshape(B, T, 1, Di)
-    ki = jnp.concatenate([rope(ki[..., :dr]), ki[..., dr:]],
-                         axis=-1).astype(h.dtype)
-    w = jnp.dot(h2, layer["idx_ww"],
-                preferred_element_type=jnp.float32) * Hi ** -0.5
+    with region("dsa.index"):
+        qi = (cq @ layer["idx_wq"]).reshape(B, T, Hi, Di)
+        qi = jnp.concatenate([rope(qi[..., :dr]), qi[..., dr:]], axis=-1)
+        ki = jnp.dot(h2, layer["idx_wk"], preferred_element_type=jnp.float32)
+        mu = jnp.mean(ki, -1, keepdims=True)
+        ki = ((ki - mu) * jax.lax.rsqrt(
+            jnp.mean(jnp.square(ki - mu), -1, keepdims=True)
+            + c.index_norm_eps)
+            * layer["idx_k_norm"].astype(jnp.float32)
+            + layer["idx_k_bias"].astype(jnp.float32)).reshape(B, T, 1, Di)
+        ki = jnp.concatenate([rope(ki[..., :dr]), ki[..., dr:]],
+                             axis=-1).astype(h.dtype)
+        w = jnp.dot(h2, layer["idx_ww"],
+                    preferred_element_type=jnp.float32) * Hi ** -0.5
     query = (q_abs, qi, w.reshape(B, T, Hi))
     if c.expands(T):
         query += ((jnp.concatenate([q[..., :dn], q_r], axis=-1),
@@ -622,25 +625,32 @@ def routed_experts(h2, layer, cfg, *, impl="auto", interpret=False):
     T, D = h2.shape
     F = c.moe_ffn_dim
     block_m = c.row_tile(T)
-    ids, w = route(h2, layer, c)
-    plan = moe_utils.sort_align_held(ids, c.experts_held, block_m,
-                                     c.expert_offset)
-    live = plan["valid_rows"][:, None]
-    x_sorted = jnp.where(live, h2[plan["src_token"]], jnp.zeros((), h2.dtype))
-    gg = functools.partial(group_gemm_live, tile_expert=plan["tile_expert"],
-                           n_live=plan["n_live_tiles"],
-                           block_m=block_m, impl=impl,
-                           interpret=interpret)
-    gu = gg(x_sorted, layer["w_gate_up"], name=GATE_UP_CALL)
-    # dead tiles are not written: whatever they hold stays out of the sum
-    act = jnp.where(live, (jax.nn.silu(gu[:, :F].astype(jnp.float32))
-                           .astype(h2.dtype) * gu[:, F:]),
-                    jnp.zeros((), h2.dtype))
-    y = gg(act, layer["w_down"], name=DOWN_CALL)
-    local = plan["local"].reshape(T, c.top_k)
-    rows = jnp.minimum(plan["dest"], plan["m_pad"] - 1).reshape(T, c.top_k)
-    picked = jnp.where(local[..., None], y[rows].astype(jnp.float32), 0.0)
-    out = jnp.einsum("tk,tkd->td", jnp.where(local, w, 0.0), picked)
+    with region("moe.route"):
+        ids, w = route(h2, layer, c)
+    with region("moe.align"):
+        plan = moe_utils.sort_align_held(ids, c.experts_held, block_m,
+                                         c.expert_offset)
+    with region("moe.experts"):
+        live = plan["valid_rows"][:, None]
+        x_sorted = jnp.where(live, h2[plan["src_token"]],
+                             jnp.zeros((), h2.dtype))
+        gg = functools.partial(group_gemm_live,
+                               tile_expert=plan["tile_expert"],
+                               n_live=plan["n_live_tiles"],
+                               block_m=block_m, impl=impl,
+                               interpret=interpret)
+        gu = gg(x_sorted, layer["w_gate_up"], name=GATE_UP_CALL)
+        # dead tiles are not written: whatever they hold stays out of the sum
+        act = jnp.where(live, (jax.nn.silu(gu[:, :F].astype(jnp.float32))
+                               .astype(h2.dtype) * gu[:, F:]),
+                        jnp.zeros((), h2.dtype))
+        y = gg(act, layer["w_down"], name=DOWN_CALL)
+    with region("moe.combine"):
+        local = plan["local"].reshape(T, c.top_k)
+        rows = jnp.minimum(plan["dest"],
+                           plan["m_pad"] - 1).reshape(T, c.top_k)
+        picked = jnp.where(local[..., None], y[rows].astype(jnp.float32), 0.0)
+        out = jnp.einsum("tk,tkd->td", jnp.where(local, w, 0.0), picked)
     n_local = jnp.sum(plan["local"].astype(jnp.int32))
     stats = jnp.stack([jnp.int32(T * c.top_k), n_local,
                        plan["n_live_tiles"] * block_m - n_local,
@@ -693,8 +703,9 @@ def ffn(h2, layer, *, cfg, tally: MoeTally | None = None, impl="auto",
     if tally is not None:
         tally.rows.append(stats)
     if "shared" in layer:
-        routed = routed + _dense_prompt_ffn(
-            h2, layer["shared"]).astype(jnp.float32)
+        with region("moe.shared"):
+            routed = routed + _dense_prompt_ffn(
+                h2, layer["shared"]).astype(jnp.float32)
     return routed.astype(h2.dtype)
 
 
@@ -748,15 +759,18 @@ def _attend_pages(q, planes, tables, lens, *, cfg: MlaMoeConfig, impl,
     T = q.shape[1]
     if tables.shape[1] * planes[0].shape[1] <= cfg.index_topk:
         return mla_decode_paged_shard(q, planes[0], tables, lens, **kw)
-    scores = dsa_index_scores(qi, w, planes[1], tables, lens, q_lens=q_lens,
-                              impl=impl, interpret=interpret)
-    if T == 1:      # [B, n_pages, page]: one flat row a query
-        cut = _kth_largest(scores.reshape(scores.shape[0], -1),
-                           cfg.index_topk, (1,))[:, :, None]
-    else:           # [B, n_pages, T, page]
-        cut = _kth_largest(scores, cfg.index_topk, (1, 3))
-    return mla_decode_paged_shard(q, planes[0], tables, lens,
-                                  sel=scores - cut, **kw)
+    with region("dsa.index"):
+        scores = dsa_index_scores(qi, w, planes[1], tables, lens,
+                                  q_lens=q_lens, impl=impl,
+                                  interpret=interpret)
+    with region("dsa.select"):
+        if T == 1:      # [B, n_pages, page]: one flat row a query
+            cut = _kth_largest(scores.reshape(scores.shape[0], -1),
+                               cfg.index_topk, (1,))[:, :, None]
+        else:           # [B, n_pages, T, page]
+            cut = _kth_largest(scores, cfg.index_topk, (1, 3))
+        sel = scores - cut
+    return mla_decode_paged_shard(q, planes[0], tables, lens, sel=sel, **kw)
 
 
 def paged_attend(q, pool, tables, lens, *, cfg: MlaMoeConfig, impl,
@@ -775,9 +789,10 @@ def paged_attend(q, pool, tables, lens, *, cfg: MlaMoeConfig, impl,
             jnp.where(lens[:, None] > 0,
                       first[:, None] + 1 + jnp.arange(T), 0),
             cfg.index_topk, rows=True))
-    out = _attend_pages(
-        q, [p.reshape(p.shape[0], *p.shape[2:]) for p in pool], tables,
-        lens, cfg=cfg, impl=impl, interpret=interpret, q_lens=q_lens)
+    with region("attn"):    # the paged pair's, opened by the family
+        out = _attend_pages(
+            q, [p.reshape(p.shape[0], *p.shape[2:]) for p in pool], tables,
+            lens, cfg=cfg, impl=impl, interpret=interpret, q_lens=q_lens)
     return out[:, 0] if single else out
 
 
@@ -818,13 +833,16 @@ def attend_prefix(q, *views_and_len, cfg: MlaMoeConfig, impl, interpret,
                              interpret=interpret)
     _, qi, w, (q_raw, w_uk, w_uv) = q
     kw = dict(impl=impl, interpret=interpret)
-    scores = dsa_index_scores(qi, w, planes[1], tables, lens, **kw)
-    cut = _cutoffs(scores, cfg.index_topk)
+    with region("dsa.index"):
+        scores = dsa_index_scores(qi, w, planes[1], tables, lens, **kw)
+    with region("dsa.select"):
+        sel = scores - _cutoffs(scores, cfg.index_topk)
     H, dk = cfg.n_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    kv = views[0][:, 0] @ _expansion(w_uk, w_uv, cfg.qk_rope_head_dim,
-                                     views[0].shape[3])
+    with region("mla.expand"):
+        kv = views[0][:, 0] @ _expansion(w_uk, w_uv, cfg.qk_rope_head_dim,
+                                         views[0].shape[3])
     return mla_expanded_prefill(
-        q_raw.reshape(B, c, H * dk), kv, scores - cut, prefix_len, heads=H,
+        q_raw.reshape(B, c, H * dk), kv, sel, prefix_len, heads=H,
         d_qk=dk, scale=cfg.softmax_scale, **kw).reshape(B, c, H, -1)
 
 

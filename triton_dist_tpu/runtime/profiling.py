@@ -43,19 +43,11 @@ class group_profile:
     """
 
     def __init__(self, name: str = "trace", do_prof: bool = True,
-                 base_dir: str = "prof", merge: bool = True,
-                 gather: bool = False):
+                 base_dir: str = "prof", merge: bool = True):
         self.name = name
         self.do_prof = do_prof
         self.base_dir = base_dir
         self.merge = merge
-        # ``gather=True``: ship every rank's trace files to rank 0 over
-        # the jax.distributed fabric before merging — for multi-host
-        # deployments where ranks write LOCAL disks (the reference
-        # gathers over the torch process group for the same reason,
-        # utils.py:417-501).  Off by default: single-host and shared-FS
-        # jobs see every rank dir already.
-        self.gather = gather
         self.merged_path = None
         self._cm = None
 
@@ -79,9 +71,6 @@ class group_profile:
 
                     multihost_utils.sync_global_devices(
                         "group_profile_merge")
-                    if self.gather:
-                        gather_rank_traces(
-                            os.path.join(self.base_dir, self.name))
                 if jax.process_index() == 0:
                     try:
                         self.merged_path = merge_rank_traces(
@@ -89,71 +78,6 @@ class group_profile:
                     except Exception:
                         self.merged_path = None  # per-rank dirs remain
         return False
-
-
-def gather_rank_traces(job_dir: str) -> None:
-    """Ship every rank's local trace dir to rank 0 over jax.distributed.
-
-    Reference analog: ``group_profile`` gathers per-rank trace files to
-    rank 0 over the torch process group (utils.py:417-501).  Here each
-    process tars its own ``{job_dir}/rank{i}`` in memory, the tars ride a
-    padded uint8 ``process_allgather`` (host collective over DCN), and
-    rank 0 extracts the other ranks' tars under its local ``job_dir`` so
-    :func:`merge_rank_traces` sees all of them.  No shared filesystem
-    required; a no-op at process_count() == 1.
-    """
-    import io
-    import tarfile
-
-    import numpy as np
-    from jax.experimental import multihost_utils
-
-    if jax.process_count() == 1:
-        return
-    me = jax.process_index()
-    rank_dir = os.path.join(job_dir, f"rank{me}")
-    buf = io.BytesIO()
-    with tarfile.open(fileobj=buf, mode="w") as tar:
-        if os.path.isdir(rank_dir):
-            tar.add(rank_dir, arcname=f"rank{me}")
-    blob = np.frombuffer(buf.getvalue(), np.uint8)
-
-    sizes = multihost_utils.process_allgather(
-        np.asarray([blob.size], np.int64))
-    pad = int(sizes.max())
-    # Chunked gather: allgather is the only host collective available,
-    # and a single max-padded allgather would materialize
-    # process_count * max_tar bytes on EVERY host (profiler tars run to
-    # hundreds of MB).  Fixed 64 MiB slices bound the peak at
-    # process_count * chunk regardless of tar size; ranks != 0 drop
-    # each slice immediately.
-    chunk = 64 * 2 ** 20
-    parts = [io.BytesIO() for _ in range(jax.process_count())]
-    for off in range(0, pad, chunk):
-        ln = min(chunk, pad - off)
-        piece = np.zeros((ln,), np.uint8)
-        if off < blob.size:
-            n = min(ln, blob.size - off)
-            piece[:n] = blob[off:off + n]
-        gathered = multihost_utils.process_allgather(piece)
-        if me == 0:
-            for r in range(1, jax.process_count()):
-                # Keep only each rank's REAL bytes (skip rank 0's own
-                # tar and the zero padding past sizes[r]) so rank 0's
-                # accumulation is sum(tar sizes), not P * max_tar.
-                keep = min(ln, max(int(sizes[r][0]) - off, 0))
-                if keep:
-                    parts[r].write(bytes(np.asarray(gathered[r][:keep])))
-        del gathered
-
-    if me != 0:
-        return
-    for r in range(1, jax.process_count()):
-        data = parts[r].getvalue()
-        with tarfile.open(fileobj=io.BytesIO(data), mode="r") as tar:
-            # 'data' filter: strips absolute paths/symlinks — the tars
-            # are self-produced, but stay safe anyway.
-            tar.extractall(job_dir, filter="data")
 
 
 def merge_rank_traces(job_dir: str) -> str | None:
@@ -248,3 +172,35 @@ def annotate(name: str, *, flops: int | None = None,
         label = "#".join(parts)
     with jax.profiler.TraceAnnotation(label), jax.named_scope(label):
         yield
+
+
+# What a device program's body is doing, by SEAM of the one layer loop
+# (``models/generate.py`` ``_layer_stack``) and of the family seams under
+# it: the closed set :func:`region` takes.  docs/observability.md "Regions
+# of a device program" has the seam of each; ``benchmarks/regions.py`` reads
+# them back from a chip trace.
+REGIONS = (
+    "embed", "proj", "kv_write", "attn", "out_proj", "ffn", "head",
+    "moe.route", "moe.align", "moe.experts", "moe.combine", "moe.shared",
+    "dsa.index", "dsa.select", "mla.expand", "sample",
+)
+REGION_PREFIX = "rg_"
+
+
+def region(name: str):
+    """``with region("ffn"): ...`` inside a jitted body: every operation
+    traced under it carries ``rg_ffn`` in its HLO ``op_name`` path, and a
+    device trace read beside the compiled text says what the model was
+    doing in each (``benchmarks/regions.py``; the innermost scope wins).
+    ``name`` is one of :data:`REGIONS`; a dot becomes ``__``, so that the
+    scope is one HLO-legal word that no rule which cuts an instruction's
+    name at its first ``.`` shortens (XLA names a Mosaic call with no name
+    of its own after the scope around it).
+
+    Metadata only: the scope is entered once, while the body is traced,
+    and the lowered computation is the same with it and without (held in
+    tests/test_regions.py) — so no ``TraceAnnotation``, no flops / bytes
+    label (:func:`annotate` keeps those for the kernels) and no switch."""
+    if name not in REGIONS:
+        raise ValueError(f"region {name!r}: one of {REGIONS}")
+    return jax.named_scope(REGION_PREFIX + name.replace(".", "__"))

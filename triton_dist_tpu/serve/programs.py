@@ -31,6 +31,7 @@ from triton_dist_tpu.models.sampling import (
     sample_positions_rowwise,
 )
 from triton_dist_tpu.models.speculative import accept_chain_rowwise
+from triton_dist_tpu.runtime.profiling import region
 
 
 def _page_slots(tables, pos, active, *, page):
@@ -136,7 +137,9 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
 
     def attend(li, q, pool):
         # q: an array, or whatever tree of [B, 1, ...] arrays the family's
-        # ``project`` hands its ``paged_attend`` (an indexer's beside it)
+        # ``project`` hands its ``paged_attend`` (an indexer's beside it);
+        # the family's ``paged_attend`` opens the ``attn`` region itself: it
+        # knows whether its call has a name (``generate.paged_attend``)
         return paged_attend(jax.tree.map(lambda t: t[:, 0], q), pool,
                             by_group[group_of(li)], kv_lens + inc,
                             **_kind_kw(kinds, li))[:, None]
@@ -245,14 +248,15 @@ def _paged_decode_horizon(params, pools, tables, kv_lens, token, active,
         pools, logits, *aux = decode_fwd(params, pools, tables, kv_lens,
                                          token, live)
         kv_lens = kv_lens + live.astype(kv_lens.dtype)
-        if all_greedy:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            keys = jax.vmap(jax.random.fold_in)(base_keys, counts)
-            nxt = sample_logits_rowwise(logits, keys, temperature=temps,
-                                        top_k=top_ks, top_p=top_ps,
-                                        greedy=greedy)
-        nxt = jnp.where(live, nxt, token)
+        with region("sample"):
+            if all_greedy:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                keys = jax.vmap(jax.random.fold_in)(base_keys, counts)
+                nxt = sample_logits_rowwise(logits, keys, temperature=temps,
+                                            top_k=top_ks, top_p=top_ps,
+                                            greedy=greedy)
+            nxt = jnp.where(live, nxt, token)
         counts = counts + live.astype(counts.dtype)
         eos_done = eos_done | (live & has_eos & (nxt == eos_ids))
         return (pools, kv_lens, nxt, eos_done, counts), (nxt, live, *aux)
@@ -292,10 +296,11 @@ def _sample_token(logits_row, base_key, count, temperature, top_k, top_p):
     ``compile_misses`` could not see it (18 s at a 128k vocabulary on the
     v5e, PR 21).  The draw is emission ``count`` of the row's stream,
     bit-identical to the fused horizon's."""
-    return sample_positions_rowwise(
-        logits_row[None, None], base_key[None], count[None],
-        temperature=temperature[None], top_k=top_k[None],
-        top_p=top_p[None], greedy=jnp.zeros((1,), bool))[0, 0]
+    with region("sample"):
+        return sample_positions_rowwise(
+            logits_row[None, None], base_key[None], count[None],
+            temperature=temperature[None], top_k=top_k[None],
+            top_p=top_p[None], greedy=jnp.zeros((1,), bool))[0, 0]
 
 
 def _draft_decode_forward(params, caches, kv_lens, token, active, *,
@@ -321,10 +326,12 @@ def _draft_decode_forward(params, caches, kv_lens, token, active, *,
                 _write_rows(v_c, v.transpose(0, 2, 1, 3), kv_lens))
 
     def attend(li, q, cache):
-        o, _ = gqa_decode_shard(q[:, 0], cache[0], cache[1], kv_lens + inc,
-                                impl=impl, interpret=interpret,
-                                soft_cap=cfg.attn_soft_cap,
-                                window=cfg.attn_window)
+        with region("attn"):
+            o, _ = gqa_decode_shard(q[:, 0], cache[0], cache[1],
+                                    kv_lens + inc, impl=impl,
+                                    interpret=interpret,
+                                    soft_cap=cfg.attn_soft_cap,
+                                    window=cfg.attn_window)
         return o[:, None]
 
     new_caches, logits = _layer_stack(
@@ -391,13 +398,14 @@ def _spec_round_fused(params, draft_params, pools, dcaches, tables,
     # writes land in their dead slot, masked by length).
     def propose(carry, t):
         dcaches, dlens, dlogits = carry
-        if all_greedy:
-            tok = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
-        else:
-            keys = jax.vmap(jax.random.fold_in)(base_keys, counts + t)
-            tok = sample_logits_rowwise(dlogits, keys, temperature=temps,
-                                        top_k=top_ks, top_p=top_ps,
-                                        greedy=greedy)
+        with region("sample"):
+            if all_greedy:
+                tok = jnp.argmax(dlogits, axis=-1).astype(jnp.int32)
+            else:
+                keys = jax.vmap(jax.random.fold_in)(base_keys, counts + t)
+                tok = sample_logits_rowwise(dlogits, keys,
+                                            temperature=temps, top_k=top_ks,
+                                            top_p=top_ps, greedy=greedy)
         dcaches, dlens, dlogits = draft_step(draft_params, dcaches,
                                              dlens, tok, live)
         return (dcaches, dlens, dlogits), tok
@@ -414,13 +422,14 @@ def _spec_round_fused(params, draft_params, pools, dcaches, tables,
                                    proposals, live)
 
     # 3. On-device accept against the target's own stream.
-    allv = jnp.concatenate([last_logits[:, None], logits_all], axis=1)
-    if all_greedy:
-        expected = jnp.argmax(allv, axis=-1).astype(jnp.int32)
-    else:
-        expected = sample_positions_rowwise(
-            allv, base_keys, counts, temperature=temps, top_k=top_ks,
-            top_p=top_ps, greedy=greedy)
+    with region("sample"):
+        allv = jnp.concatenate([last_logits[:, None], logits_all], axis=1)
+        if all_greedy:
+            expected = jnp.argmax(allv, axis=-1).astype(jnp.int32)
+        else:
+            expected = sample_positions_rowwise(
+                allv, base_keys, counts, temperature=temps, top_k=top_ks,
+                top_p=top_ps, greedy=greedy)
     m = accept_chain_rowwise(proposals, expected, k_rows)
     m_used = jnp.clip(jnp.minimum(m, limits - 1), 0, K)
     idx = jnp.arange(K + 1, dtype=jnp.int32)[None]
