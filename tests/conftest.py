@@ -239,6 +239,10 @@ _FAST_GATE_MODULES = {
     # and a product of every seam under its (innermost) region; programs
     # are lowered, never compiled or run (~1 min).
     "test_regions",
+    # the grouped GEMMs' feeder (ISSUE 37): sort_align_held against a
+    # plain loop over assignments at every shape the expert cells
+    # compile, and its largest intermediate linear in the rows (~15 s).
+    "test_moe_utils",
 }
 
 # Heavy tests inside core modules whose coverage is duplicated by a

@@ -11,11 +11,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from triton_dist_tpu.analysis.jaxpr_audit import _iter_subjaxprs
 from triton_dist_tpu.kernels.moe_utils import (
     combine_topk,
     gather_sorted,
     padded_rows,
     sort_align,
+    sort_align_held,
     topk_routing,
 )
 
@@ -92,3 +94,111 @@ def test_end_to_end_moe_matches_dense(topk):
         jnp.einsum("td,tkdf->tkf", x, w[experts]))
     np.testing.assert_allclose(np.asarray(out), np.asarray(dense),
                                rtol=1e-4, atol=1e-4)
+
+
+# ---------------------------------------------------------------------------
+# sort_align_held: the plan of a layer that holds some of the experts
+# ---------------------------------------------------------------------------
+
+
+def _held_plan_by_hand(ids, n_held, block_m, offset):
+    """The plan's contract as a plain loop over assignments."""
+    T, topk = ids.shape
+    n = T * topk
+    flat = ids.reshape(-1).astype(np.int64) - offset
+    local = (flat >= 0) & (flat < n_held)
+    counts = np.zeros(n_held, np.int64)
+    rank = np.zeros(n, np.int64)
+    for j in range(n):
+        if local[j]:
+            rank[j] = counts[flat[j]]
+            counts[flat[j]] += 1
+    padded = -(-counts // block_m) * block_m
+    starts = np.cumsum(padded) - padded
+    m_pad = -(-(n + n_held * (block_m - 1)) // block_m) * block_m
+    dest = np.full(n, m_pad)
+    valid = np.zeros(m_pad, bool)
+    src = np.zeros(m_pad, np.int64)
+    for j in range(n):
+        if local[j]:
+            dest[j] = starts[flat[j]] + rank[j]
+            valid[dest[j]] = True
+            src[dest[j]] = j // topk
+    tile_expert = np.full(m_pad // block_m, n_held - 1)   # dead tiles
+    for e in range(n_held):
+        tile_expert[starts[e] // block_m:
+                    (starts[e] + padded[e]) // block_m] = e
+    return {"dest": dest, "tile_expert": tile_expert, "valid_rows": valid,
+            "m_pad": m_pad, "local": local, "src_token": src,
+            "n_live_tiles": padded.sum() // block_m, "counts": counts}
+
+
+def _routed(T, topk, n_experts, seed=0):
+    """Distinct experts a token, as a router's top-k gives them."""
+    rng = np.random.default_rng(seed)
+    return np.argsort(rng.random((T, n_experts)), axis=1)[:, :topk]
+
+
+# name -> (ids [T, topk], n_held, block_m, offset)
+_HELD_CASES = {
+    # the shapes the expert cells compile (chunk and decode step)
+    "mellum2_chunk": (_routed(2048, 8, 64), 64, 256, 0),
+    "mellum2_decode": (_routed(64, 8, 64), 64, 32, 0),
+    "glm5_chunk": (_routed(2048, 8, 256), 16, 32, 0),
+    "glm5_decode": (_routed(32, 8, 256), 16, 32, 0),
+    "gc3_chunk": (_routed(512, 8, 256), 16, 32, 0),
+    "gc3_decode": (_routed(64, 8, 256), 16, 32, 0),
+    "all_to_one_held_expert": (np.full((40, 2), 11), 8, 16, 8),
+    "no_local_assignment": (16 + _routed(24, 4, 48), 16, 8, 0),
+    "ids_on_both_sides_of_the_held_range": (_routed(300, 8, 256), 16, 32,
+                                            120),
+    "a_count_that_fills_its_tiles": (np.repeat([[0, 1], [2, 3]], 8, axis=0),
+                                     4, 8, 0),
+    "one_token": (_routed(1, 8, 64), 64, 32, 0),
+    # 256 tokens: the token index + 1 needs a second bf16 digit
+    "a_token_index_past_one_digit": (_routed(256, 2, 8), 8, 16, 0),
+}
+
+
+@pytest.mark.parametrize("case", _HELD_CASES)
+def test_sort_align_held_matches_a_loop_over_assignments(case):
+    ids, n_held, block_m, offset = _HELD_CASES[case]
+    got = jax.jit(sort_align_held, static_argnums=(1, 2, 3))(
+        jnp.asarray(ids, jnp.int32), n_held, block_m, offset)
+    want = _held_plan_by_hand(ids, n_held, block_m, offset)
+    assert set(got) == set(want)
+    for key, ref in want.items():
+        np.testing.assert_array_equal(np.asarray(got[key]), ref, err_msg=key)
+    for key, dtype in (("dest", jnp.int32), ("src_token", jnp.int32),
+                       ("tile_expert", jnp.int32), ("valid_rows", bool),
+                       ("local", bool), ("n_live_tiles", jnp.int32)):
+        assert got[key].dtype == dtype, key
+    if case == "a_count_that_fills_its_tiles":
+        assert (np.asarray(got["counts"]) % block_m == 0).all()
+    if case == "no_local_assignment":
+        assert not np.asarray(got["valid_rows"]).any()
+
+
+def _avals(jaxpr):
+    """Every intermediate of a jaxpr, sub-computations included."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield eqn.primitive.name, var.aval
+        for sub in _iter_subjaxprs(eqn.params):
+            yield from _avals(sub)
+
+
+def test_sort_align_held_is_linear_in_the_rows():
+    """Nothing of ``[m_pad, T*topk]`` (every buffer row against every
+    assignment: 537 M at this shape, rows SQUARED) is ever formed: the
+    largest intermediate is an assignment against a tile, a position or a
+    held expert."""
+    T, topk, n_held, block_m = 2048, 8, 64, 256
+    n_tiles = padded_rows(T * topk, n_held, block_m) // block_m
+    limit = T * topk * max(n_tiles, block_m, n_held)
+    closed = jax.make_jaxpr(lambda ids: {
+        k: v for k, v in sort_align_held(ids, n_held, block_m).items()
+        if k != "m_pad"})(jnp.zeros((T, topk), jnp.int32))
+    sizes = [(int(np.prod(aval.shape)), name, aval.shape)
+             for name, aval in _avals(closed.jaxpr) if hasattr(aval, "shape")]
+    assert len(sizes) > 20 and max(sizes)[0] <= limit, max(sizes)

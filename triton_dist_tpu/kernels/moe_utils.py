@@ -21,6 +21,13 @@ Data flow (matching the reference's GroupGEMM contract):
   -> gather_sorted: x_sorted [M_pad, D] (padding rows zero)
   -> group_gemm (kernels/group_gemm.py): y_sorted [M_pad, F]
   -> combine_topk: out [T, F] = sum_k w[t,k] * y_sorted[dest[t,k]]
+
+The serving engine's expert layer holds SOME of the experts and skips dead
+tiles (``models/mla_moe.py``, ``group_gemm_live``): its plan is
+:func:`sort_align_held`, which neither sorts nor scatters — ``dest`` from
+compares over ``[T*topk, n_held]``, the inverse map (buffer row -> token)
+from a tile one-hot times a position one-hot on the MXU — so its work is
+linear in the rows from a decode step's 64 to a prefill chunk's 2,048.
 """
 
 from __future__ import annotations
@@ -106,6 +113,11 @@ def sort_align(experts, n_experts: int, block_m: int):
             "valid_rows": valid, "m_pad": m_pad}
 
 
+# bits of a token index one bf16 product carries: bf16 holds the integers
+# up to 256 exactly, float32 accumulates the one term a row has
+_DIGIT = 8
+
+
 def sort_align_held(experts, n_held: int, block_m: int, offset=0):
     """:func:`sort_align` for a layer that holds ``n_held`` of the experts
     its router chooses among (expert parallelism: ids ``offset .. offset
@@ -120,9 +132,17 @@ def sort_align_held(experts, n_held: int, block_m: int, offset=0):
       n_live_tiles scalar int32: the tiles that hold any row
       counts       [n_held] assignments an expert got
 
-    A decode step gives a held expert a handful of rows, so this is dense
-    compare-and-sum arithmetic over ``[T*topk, n_held]``, with no sort
-    and no scatter (both serialise on the chip)."""
+    ``dest`` (assignment -> row) is dense compare-and-sum arithmetic over
+    ``[T*topk, n_held]``, with no sort and no scatter (both serialise on
+    the chip).  The INVERSE (row -> token, and which rows are live) is one
+    small product: a row is ``(tile, position)`` and a tile is one
+    expert's, so "assignment j sits in row r" factors into a tile one-hot
+    ``[n_tiles, T*topk]`` times a position one-hot ``[T*topk, block_m]``
+    — ``T*topk x (n_tiles + block_m)`` compares and one MXU product a
+    :data:`_DIGIT` of the token index, where comparing every row with
+    every assignment is ``m_pad x T*topk`` (a 2,048-token chunk at 64
+    held experts, tile 256: 6 M against 537 M).  At most one assignment
+    sits in a row, so each sum has one term and comes out exact."""
     T, topk = experts.shape
     n = T * topk
     flat = experts.reshape(-1).astype(jnp.int32) - offset
@@ -140,12 +160,25 @@ def sort_align_held(experts, n_held: int, block_m: int, offset=0):
     tile_expert = jnp.minimum(
         jnp.searchsorted(ends, jnp.arange(n_tiles) * block_m,
                          side="right"), n_held - 1).astype(jnp.int32)
-    hit = dest[None, :] == jnp.arange(m_pad, dtype=jnp.int32)[:, None]
-    valid = jnp.any(hit, axis=1)
-    src = jnp.argmax(hit, axis=1).astype(jnp.int32) // topk
+    # a non-local assignment's tile is n_tiles: no row of ``in_tile``
+    in_tile = (dest // block_m)[None, :] == jnp.arange(
+        n_tiles, dtype=jnp.int32)[:, None]                 # [n_tiles, n]
+    at_pos = ((dest % block_m)[:, None] == jnp.arange(
+        block_m, dtype=jnp.int32)[None, :]).astype(jnp.bfloat16)
+    # token + 1 a row, digit by digit (0: no assignment sits there)
+    token1 = jnp.arange(n, dtype=jnp.int32) // topk + 1
+    found = jnp.zeros((n_tiles, block_m), jnp.int32)
+    for shift in range(0, T.bit_length(), _DIGIT):
+        digit = (token1 >> shift) & ((1 << _DIGIT) - 1)
+        found += jnp.dot(
+            jnp.where(in_tile, digit[None, :], 0).astype(jnp.bfloat16),
+            at_pos, preferred_element_type=jnp.float32,
+        ).astype(jnp.int32) << shift
+    found = found.reshape(m_pad)
+    valid = found > 0
     return {"dest": dest, "tile_expert": tile_expert, "valid_rows": valid,
             "m_pad": m_pad, "local": local,
-            "src_token": jnp.where(valid, src, 0),
+            "src_token": jnp.where(valid, found - 1, 0),
             "n_live_tiles": (ends[-1] // block_m).astype(jnp.int32),
             "counts": counts}
 

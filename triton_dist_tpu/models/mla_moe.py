@@ -619,8 +619,14 @@ def routed_experts(h2, layer, cfg, *, impl="auto", interpret=False):
     stream once, and costs the MXU what any tile under 128 rows costs;
     ``models/swa_moe.py``: :func:`row_tile`, read off this program's
     static shapes), then two grouped GEMMs: gate and up side by side, and
-    down.  ``stats``: assignments routed, those that landed here, pad rows
-    of the live tiles, experts hit."""
+    down.  The plan that feeds them (``moe_utils.sort_align_held``, region
+    ``moe.align``) is linear in the rows: ``T . top_k`` assignments against
+    the held experts, the row tiles and a tile's positions — at a
+    2,048-token chunk over 64 held experts 16,384 x (64 + 128 + 256)
+    compares and two ``[128, 16,384] @ [16,384, 256]`` products a layer,
+    at a decode step of 64 rows 512 x (64 + 78 + 32) and one.  ``stats``:
+    assignments routed, those that landed here, pad rows of the live
+    tiles, experts hit."""
     c = cfg
     T, D = h2.shape
     F = c.moe_ffn_dim
