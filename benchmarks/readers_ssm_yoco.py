@@ -1,0 +1,52 @@
+"""Readers of the decoder-hybrid-decoder family's roofline shares:
+``"reader": "benchmarks.readers_ssm_yoco:roofline"`` (a decode step's
+shares) and ``...:scan_roofline`` (one ``ssm_scan`` call of a prefill
+chunk) in a ``layer_metrics/<name>.json``.
+
+``readers_swa_moe.roofline``'s reading — least time for what the call
+needs over the device time it took — with the counting functions of
+``shapes_ssm_yoco.py``.  Where the trace holds no operation of the name it
+reads, or the configuration is another family's (the parent commit has no
+such program), a reader returns nothing and the line leaves the metric
+out.
+"""
+
+from __future__ import annotations
+
+import re
+
+from benchmarks import readers, shapes, shapes_ssm_yoco
+
+
+def _ours(ctx) -> bool:
+    return ctx["config"].get("model_type") == "phi4flash"
+
+
+def _share(need: dict, took: float, ctx) -> float:
+    least, _ = shapes.least_seconds(need, shapes.peaks(ctx["device_kind"]))
+    return 100.0 * least / took
+
+
+def roofline(args, ctx):
+    took = readers._device_time(args["time"], ctx)
+    rows = ctx["counters"].get("decode.rows_mean")
+    ctx_sum = ctx["counters"].get("decode.ctx_sum_mean")
+    if not took or not rows or not _ours(ctx):
+        return None
+    return _share(shapes_ssm_yoco.FUNCTIONS[args["shape_fn"]](
+        ctx["config"], rows=rows, ctx_sum=ctx_sum), took, ctx)
+
+
+def scan_roofline(args, ctx):
+    """One execution of the operation ``args["op"]`` (a regular expression
+    over operation names): its device seconds over its executions."""
+    tr = ctx["trace"]
+    if tr is None or not _ours(ctx):
+        return None
+    rx = re.compile(args["op"])
+    secs = sum(v for k, v in tr["op_s"].items() if rx.search(k))
+    n = sum(v for k, v in tr["op_n"].items() if rx.search(k))
+    if not secs or not n:
+        return None
+    return _share(shapes_ssm_yoco.FUNCTIONS[args["shape_fn"]](ctx["config"]),
+                  secs / n, ctx)

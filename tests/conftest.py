@@ -243,6 +243,14 @@ _FAST_GATE_MODULES = {
     # plain loop over assignments at every shape the expert cells
     # compile, and its largest intermediate linear in the rows (~15 s).
     "test_moe_utils",
+    # a state beside pages (ISSUE 38: the phi4flash block): chunked
+    # prefill + paged decode through the full, window AND state groups
+    # against the float32 reference's one forward, logits; N chunks = one
+    # scan; a reused slot from zero; preemption-and-recompute; heads 64
+    # wide in pairs at the window edge; the scan kernel in the
+    # interpreter; the parameter count; every refusal beside a state
+    # group by name (~3 min).
+    "test_ssm_yoco",
 }
 
 # Heavy tests inside core modules whose coverage is duplicated by a

@@ -737,6 +737,117 @@ def test_swa_moe_programs_compile_at_published_widths(v5e, as_tpu):
 
 
 # ---------------------------------------------------------------------------
+# A state beside pages: the decoder-hybrid-decoder block whole on one chip
+# (ISSUE 38), at published widths
+# ---------------------------------------------------------------------------
+
+
+def _ssm_yoco_programs():
+    """Every program of ``phi4mf_reason96_sat`` at the file's widths and
+    engine sizes over its THREE cache groups (the full group's 2,560
+    blocks on layer 17 alone, the window group's derived 577 on 8 layers,
+    the state group's 97 slots on 9; cross and gated-memory layers own no
+    pool) -> ``[(name, jitted, args, statics, the Mosaic calls it must
+    hold)]``: a decode step 8 ``gqa_paged_window`` + 1 ``gqa_paged_full``
+    + 7 ``gqa_paged_cross``; a prefill chunk 9 ``ssm_scan`` and flash
+    attention on 16 layers (``None``: counted by the test)."""
+    import json
+    import os
+
+    from benchmarks import builders_ssm_yoco
+    from triton_dist_tpu.models import ssm_yoco as Y
+    from triton_dist_tpu.runtime.jit_cache import named
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks/configs/phi-4-mini-flash-reasoning.json")) as f:
+        config = json.load(f)
+    cfg = builders_ssm_yoco.model_config(config)
+    eng = config["engine"]
+    batch, page, max_seq = eng["max_batch"], eng["page_size"], eng["max_seq"]
+    chunk = eng["prefill_chunk"]
+    s = jax.ShapeDtypeStruct
+    params = jax.eval_shape(functools.partial(Y.init_params, cfg),
+                            jax.random.key(0))
+    gen = Y.SsmYocoGenerator(cfg, max_seq=max_seq)
+    ladder = E.build_bucket_ladder(max(page, chunk), max_seq, page)
+    assert ladder == [512, 1024, 2048, 4096, 5120]
+    assert gen.kernel_gaps(page_size=page, ladder=ladder,
+                           prefill_chunk=chunk) == {}
+    assert gen.kv_planes == [(10, 128)] * 2
+    ahead = eng["horizon"] * eng["pipeline"]
+    blocks = [eng["num_blocks"],
+              1 + batch * ((cfg.sliding_window + ahead - 2) // page + 2),
+              1 + batch]
+    assert blocks == [2560, 577, 97]
+    groups = gen.kv_groups
+    assert [len(g["layers"]) for g in groups] == [1, 8, 9]
+
+    def planes(lead, extent=None):
+        """Per layer: K and V pages (or scratch rows), a slot of state, or
+        nothing — what the engine builds from ``kv_groups``."""
+        out = [()] * cfg.n_layers
+        for g, nb in zip(groups, blocks):
+            for li in g["layers"]:
+                if "state_planes" in g:
+                    out[li] = tuple(s((lead or nb, *sh), dt)
+                                    for sh, dt in g["state_planes"])
+                elif extent:
+                    out[li] = tuple(s((1, h, extent, d), cfg.dtype)
+                                    for h, d in gen.kv_planes)
+                else:
+                    out[li] = tuple(s((nb, h, page, d), cfg.dtype)
+                                    for h, d in gen.kv_planes)
+        return out
+
+    pools = planes(None)
+    d_args, h_args = _decode_args(cfg, page, batch=batch, max_seq=max_seq,
+                                  params=params, pools=pools)
+    tables = s((3, batch, max_seq // page), I32)
+    d_args = d_args[:2] + (tables,) + d_args[3:]
+    h_args = h_args[:2] + (tables,) + h_args[3:]
+    kw = dict(cfg=cfg, page=page, **gen.serve_hooks())
+    want = {"gqa_paged_window": 8, "gqa_paged_full": 1, "gqa_paged_cross": 7}
+    programs = [p + (want,) for p in _decode_programs(
+        gen, kw, d_args, h_args, eng["horizon"])]
+    fill = jax.jit(named(PR._fill_pool_pages, "fill_pages", page=page,
+                         kinds=cfg.kinds), donate_argnums=(0,))
+    for extent in ladder:
+        sc = planes(1, extent)
+        programs.append((
+            "prefill_chunk", gen._chunk_jit,
+            (params, s((1, chunk), I32), sc, s((), I32)),
+            dict(quantized=False, extent=extent, n_valid=s((), I32)), None))
+        programs.append(("fill_pages", fill,
+                         (pools, sc, s((3, extent // page), I32)), {}, {}))
+    return programs
+
+
+def test_ssm_yoco_programs_compile_at_published_widths(v5e, as_tpu):
+    """Every program of ``phi4mf_reason96_sat`` — single-step decode, the
+    fused horizon (greedy and mixed) and its one-step link, prefill chunks
+    on every rung, the page-and-state fill — compiled for the v5e at the
+    published widths (32 layers, 200,064 rows, 96 rows' pools beside 7.7
+    GB of weights): the paged calls carry their layer kind's names (the
+    readers' patterns), a chunk holds 9 ``ssm_scan`` calls, and each
+    program fits the chip beside nothing else."""
+    worst = {}
+    for prog, jitted, args, statics, want in _ssm_yoco_programs():
+        compiled = _compiled(v5e, jitted, args, statics)
+        text = compiled.as_text()
+        assert text.split(",", 1)[0] == f"HloModule jit_{prog}"
+        calls = _mosaic_names(text)
+        if want is not None:
+            assert calls == want, (prog, statics, calls)
+        else:
+            assert calls["ssm_scan"] == 9, calls
+            assert sum(calls.values()) == 9 + 16, calls    # + flash calls
+        assert _gib(compiled) < HBM_GIB, (prog, statics, _gib(compiled))
+        worst[prog] = max(worst.get(prog, 0), _gib(compiled))
+    print("GiB a program:", {k: round(v, 2) for k, v in worst.items()})
+
+
+# ---------------------------------------------------------------------------
 # The region scopes rename no Mosaic call (ISSUE 36, invariant b)
 # ---------------------------------------------------------------------------
 

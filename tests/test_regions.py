@@ -32,16 +32,20 @@ from jax.sharding import Mesh
 from triton_dist_tpu.models import generate as G
 from triton_dist_tpu.models import llama
 from triton_dist_tpu.models import mla_moe as M
+from triton_dist_tpu.models import ssm_yoco as Y
 from triton_dist_tpu.models import swa_moe as S
+from triton_dist_tpu.models.llama import _rms_norm
 from triton_dist_tpu.runtime import profiling
 from triton_dist_tpu.serve import ServeEngine
 from triton_dist_tpu.serve import programs as PR
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FAMILIES = ("dense", "latent", "sparse", "window")
+FAMILIES = ("dense", "latent", "sparse", "window", "state")
+# the four families the benchmark had before the layer loop took a ``mixer``
+QUARTET_FAMILIES = FAMILIES[:4]
 PROGRAMS = ("decode_horizon", "prefill_chunk", "paged_decode")
 # the modules that open regions (they import ``region`` by name)
-SCOPED = (G, M, PR)
+SCOPED = (G, M, PR, Y)
 I32 = jnp.int32
 B, H, CHUNK = 2, 4, 64
 
@@ -88,6 +92,10 @@ def _build(family):
                else M.MlaMoeConfig.tiny(n_layers=2))
         gen = M.MlaMoeGenerator(cfg, max_seq=256, interpret=True)
         params = M.init_params(cfg, jax.random.key(3))
+    elif family == "state":
+        cfg = Y.SsmYocoConfig.tiny()
+        gen = Y.SsmYocoGenerator(cfg, max_seq=256)
+        params = Y.init_params(cfg, jax.random.key(3))
     else:
         cfg = S.SwaMoeConfig.tiny(n_layers=4)
         gen = S.SwaMoeGenerator(cfg, max_seq=256)
@@ -109,8 +117,11 @@ def _lowered(eng, chunk, program):
     params, pools = shapes(eng.params), shapes(eng._pools)
     if program == "prefill_chunk":
         extent = 256
-        scratch = [tuple(s((1, h, extent, d), eng.gen.cfg.dtype)
-                         for h, d in eng.kv_planes)] * len(pools)
+        # K and V rows a layer — or a state-space layer's slot of state,
+        # or nothing (``_plane_specs``: what the engine's own scratch is)
+        scratch = [tuple(s((1, p[0], extent, p[1]), eng.gen.cfg.dtype)
+                         if isinstance(p[0], int) else s((1, *p[0]), p[1])
+                         for p in planes) for planes in eng._plane_specs]
         return eng._chunk_fn.fn.lower(
             params, s((1, chunk), I32), scratch, s((), I32),
             quantized=False, extent=extent, n_valid=s((), I32))
@@ -158,6 +169,59 @@ def test_the_computation_is_the_same_without_the_scopes(
 
 
 # ---------------------------------------------------------------------------
+# No ``mixer``, no ``shared``: the layer loop is the quartet's, as it was
+# ---------------------------------------------------------------------------
+
+
+def _quartet_layer_stack(params, tokens, pos, caches, *, cfg, project,
+                         out_proj, ffn, write_kv, attend, kinds=None):
+    """The layer loop as it stood before a family could bring a ``mixer``
+    (ISSUE 38's parent, to the letter): RMSNorm and ``lm_head`` written
+    in, the project / write / attend / out_proj quartet for every layer."""
+    region = profiling.region
+    B_, T = tokens.shape
+    with region("embed"):
+        x = params["embed"][tokens.reshape((B_,) if T == 1 else (B_, T))]
+    new_caches = []
+    for li, layer in enumerate(params["layers"]):
+        with region("proj"):
+            h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
+            q, k, v = project(h.reshape(B_, T, -1), layer, pos,
+                              **G._kind_kw(kinds, li))
+        with region("kv_write"):
+            cache = write_kv(li, caches[li], k, v)
+        o = attend(li, q, cache)
+        with region("out_proj"):
+            o2 = o.reshape(B_ * T, -1).astype(cfg.dtype)
+            x = x + out_proj(o2, layer).reshape(x.shape)
+        with region("ffn"):
+            h2 = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+            x = x + ffn(h2.reshape(B_ * T, -1), layer).reshape(x.shape)
+        new_caches.append(cache)
+    with region("head"):
+        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+        logits = jnp.dot(x, params["lm_head"],
+                         preferred_element_type=jnp.float32)
+    return new_caches, logits.reshape(B_, T, -1)
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("family", QUARTET_FAMILIES)
+def test_without_a_mixer_the_programs_are_the_quartets(
+        engines, monkeypatch, family, program):
+    """The four families the benchmark already had pass no ``mixer`` and
+    no ``shared``, and their configs name no norm and no tied head: their
+    engines' programs lower to the same StableHLO text over today's layer
+    loop and over the quartet-only loop it replaced."""
+    eng, chunk = engines(family)
+    written = _lowered(eng, chunk, program).as_text()
+    for mod in (G, PR):
+        monkeypatch.setattr(mod, "_layer_stack", _quartet_layer_stack)
+    bare, _ = _build(family)              # fresh jits: nothing cached
+    assert _lowered(bare, chunk, program).as_text() == written
+
+
+# ---------------------------------------------------------------------------
 # Every seam's product carries its region, the innermost where two nest
 # ---------------------------------------------------------------------------
 
@@ -197,6 +261,11 @@ _SEAM_PRODUCTS = {
     "dsa.select": ("sparse", "decode_horizon", "while"),
     "mla.expand": ("sparse", "prefill_chunk", "dot_general"),
     "sample": ("dense", "decode_horizon", "while"),
+    "ssm.in": ("state", "decode_horizon", "dot_general"),
+    "ssm.conv": ("state", "prefill_chunk", "dynamic_slice"),
+    "ssm.scan": ("state", "decode_horizon", "exp"),
+    "ssm.out": ("state", "prefill_chunk", "dot_general"),
+    "gmu": ("state", "decode_horizon", "dot_general"),
 }
 
 

@@ -700,7 +700,8 @@ def _flash_attention_dispatch(q, k, v, *, causal=True, scale=None,
                     flash_shapes_ok(Sq, Sk, D, n_runs_q, n_runs_k),
                     "flash_attention",
                     f"(Sq={Sq}, Sk={Sk}, D={D}, runs={n_runs_q}/{n_runs_k})"
-                    f" needs each run %128 == 0 and D%128 == 0"):
+                    f" needs each run %128 == 0 and D%128 == 0 (a 64-wide"
+                    f" head: in pairs, flash_decode.pack_kv_pairs)"):
         out, lse = _flash_xla(q, k, v, causal=causal, scale=scale,
                               q_offset=q_offset, kv_offset=kv_offset,
                               k_scale=k_scale, v_scale=v_scale,

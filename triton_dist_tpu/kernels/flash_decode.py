@@ -444,13 +444,55 @@ def quantize_kv(x):
     return symmetric_quantize(x, -1)
 
 
+# What the legality messages say of a 64-wide head since heads ride in pairs.
+_PAIRS_NOTE = (" (a 64-wide head reaches the kernel stored in PAIRS, as "
+               "128-lane rows: pack_kv_pairs / pack_q_pairs)")
+
+
+def pack_kv_pairs(x):
+    """K or V rows of 64-wide heads, two heads a 128-lane row: ``[..., Hkv,
+    64] -> [..., Hkv / 2, 128]`` (a reshape: heads ``2p`` and ``2p + 1``
+    side by side).  The cache then holds what the heads hold — no lane of
+    it is padding — and every 128-wide kernel of this file and of
+    ``flash_attention.py`` reads it unchanged."""
+    *lead, hkv, d = x.shape
+    assert d == 64 and hkv % 2 == 0, (hkv, d)
+    return x.reshape(*lead, hkv // 2, 128)
+
+
+def pack_q_pairs(q, n_kv_heads: int):
+    """Queries of 64-wide heads for a cache stored by :func:`pack_kv_pairs`:
+    ``[..., Hq, 64] -> [..., Hq, 128]``, each zero-padded into the half its
+    KV head lives in (``[q | 0]`` for an even KV head, ``[0 | q]`` for an odd
+    one), so that GQA ``Hq`` on ``Hkv / 2`` at width 128 scores ``q . k``
+    of its own head exactly (the other half multiplies zeros) and
+    :func:`unpack_out_pairs` keeps the matching half of the result.  The
+    scores' scale stays ``1 / sqrt(64)``: pass ``scale=0.125`` to the call.
+    Twice the MXU work of calls that are bound by the cache's bytes."""
+    *lead, hq, d = q.shape
+    assert d == 64 and hq % n_kv_heads == 0, (hq, n_kv_heads, d)
+    odd = (jnp.arange(hq) // (hq // n_kv_heads)) % 2 == 1       # [Hq]
+    zero = jnp.zeros_like(q)
+    return jnp.where(odd[:, None], jnp.concatenate([zero, q], -1),
+                     jnp.concatenate([q, zero], -1))
+
+
+def unpack_out_pairs(o, n_kv_heads: int):
+    """The half of each 128-wide result that is the head's own:
+    ``[..., Hq, 128] -> [..., Hq, 64]``."""
+    hq = o.shape[-2]
+    odd = (jnp.arange(hq) // (hq // n_kv_heads)) % 2 == 1
+    return jnp.where(odd[:, None], o[..., 64:], o[..., :64])
+
+
 def decode_kernel_gap(s: int, head_dim: int) -> str | None:
     """Why :func:`gqa_decode_shard` would take its XLA path over a cache
     of ``s`` rows (``None``: the split-KV kernel tiles it).  The ONE copy
     of the guard — the dispatcher below and the serving engine's
     construction-time kernel-reach report both read it."""
     if head_dim % 128 or s % 128:
-        return f"(D={head_dim}, S={s}) needs D%128 == S%128 == 0"
+        return (f"(D={head_dim}, S={s}) needs D%128 == S%128 == 0"
+                f"{_PAIRS_NOTE if head_dim == 64 else ''}")
     return None
 
 
@@ -458,7 +500,7 @@ def decode_kernel_gap(s: int, head_dim: int) -> str | None:
 def gqa_decode_shard(q, k, v, local_lens, *, block_s=None, impl="auto",
                      interpret=False, k_scale=None, v_scale=None,
                      soft_cap=0.0, window=0, window_lens=None,
-                     q_lens=None):
+                     q_lens=None, scale=None):
     """Single-shard GQA decode: q [B, Hq, D], k/v [B, Hkv, S_loc, D],
     local_lens [B] (valid rows in this shard).  Returns float32 partials
     (out [B, Hq, D], lse [B, Hq]).
@@ -506,7 +548,8 @@ def gqa_decode_shard(q, k, v, local_lens, *, block_s=None, impl="auto",
     _, Hkv, S, _ = k.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     g = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:       # (64-wide heads in pairs: pack_q_pairs)
+        scale = 1.0 / math.sqrt(D)
     raw_impl = impl
     impl = resolve_impl(impl, interpret)
 
@@ -750,7 +793,8 @@ def paged_kernel_gap(page: int, head_dim: int, itemsize: int, *,
                 "in-program dequant (no paged int8 kernel yet)")
     if head_dim % 128 or page % 128:
         return (f"(page={page}, D={head_dim}) needs "
-                f"page%128 == D%128 == 0")
+                f"page%128 == D%128 == 0"
+                f"{_PAIRS_NOTE if head_dim == 64 else ''}")
     # A page is the kernel's KV block — it cannot shrink (it IS the cache
     # layout), so a page of which not even ONE head fits must
     # reroute/raise, not reach Mosaic's opaque VMEM failure.
@@ -764,7 +808,8 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
                            impl="auto", interpret=False, soft_cap=0.0,
                            window=0, window_lens=None, q_lens=None,
                            k_scale=None, v_scale=None,
-                           name: str | None = None):
+                           name: str | None = None,
+                           scale: float | None = None):
     """Single-shard GQA decode over a PAGED KV cache.
 
     q [B, Hq, D]; k/v_pool [N_pages, Hkv, page, D] (the physical page
@@ -789,7 +834,9 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
     returns (out [B, T, Hq, D], lse [B, T, Hq]).
 
     ``name`` is the Mosaic call's name in a device trace (the comment
-    above the budget: none by default).
+    above the budget: none by default).  ``scale`` is the scores' where it
+    is not ``1 / sqrt(D)`` of the pool's rows (64-wide heads stored in
+    pairs: :func:`pack_q_pairs`).
     """
     multi = q.ndim == 4
     n_tok = q.shape[1] if multi else 1
@@ -798,7 +845,8 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
     n_pages = block_table.shape[1]
     assert Hq % Hkv == 0, (Hq, Hkv)
     g = Hq // Hkv
-    scale = 1.0 / math.sqrt(D)
+    if scale is None:
+        scale = 1.0 / math.sqrt(D)
     raw_impl = impl
     impl = resolve_impl(impl, interpret)
 

@@ -37,15 +37,29 @@ from triton_dist_tpu.runtime.profiling import region
 @dataclass(frozen=True)
 class LayerKind:
     """What ONE layer is, as a static the seams see at trace time: a model
-    whose layers differ in kind (``models/swa_moe.py``: sliding-window
-    layers beside full ones) hands :func:`_layer_stack` one of these a
-    layer (``kinds``), and ``project``, the attend pair and ``write_kv``
-    read ``kinds[li]``.  A model with one kind of layer passes none.
+    whose layers differ in kind hands :func:`_layer_stack` one of these a
+    layer (``kinds``), and ``project``, the attend pair, ``write_kv`` and a
+    family's ``mixer`` read ``kinds[li]``.  A model with one kind of layer
+    passes none.
 
-    ``attn`` names the attention (``"full"`` | ``"window"``), ``window``
-    its reach in positions (0: the whole context), ``group`` the cache
-    group — block table and pool geometry — the layer reads and writes
-    (serve/block_manager.py ``KvGroups``)."""
+    ``attn`` names the layer's mixer, five kinds:
+
+    * ``"full"`` / ``"window"`` — causal attention over the whole context /
+      the last ``window`` positions (``models/swa_moe.py``), writing its own
+      K and V rows;
+    * ``"cross"`` — a query and an output projection only: it reads the
+      cache an EARLIER full layer of the same forward wrote
+      (``models/ssm_yoco.py``: one cache, eight readers) and holds none;
+    * ``"ssm"`` — a selective state-space layer: no cache that grows, a
+      fixed state a request (its group is a STATE group);
+    * ``"gmu"`` — a gate over what an earlier state-space layer handed
+      down for the same token: no cache, no state.
+
+    ``window`` is the attention's reach in positions (0: the whole
+    context).  ``group`` is the cache group — block table and pool
+    geometry (serve/block_manager.py ``KvGroups``) — whose table the layer
+    reads: its own for ``full`` / ``window`` / ``ssm``, the full layer's
+    for ``cross``, -1 for a layer that reads none (``gmu``)."""
 
     attn: str = "full"
     window: int = 0
@@ -415,8 +429,35 @@ class Generator:
         return jax.jit(run)
 
 
+def _layer_norm(x, w, b, eps):
+    """LayerNorm with weight and bias, the statistics in float32."""
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(xf - mu), axis=-1, keepdims=True)
+    return ((xf - mu) * jax.lax.rsqrt(var + eps)).astype(x.dtype) * w + b
+
+
+def _norm(x, at: dict, name: str, cfg):
+    """The model's norm over ``at[name]`` — the config's, as data:
+    ``cfg.norm == "layer"`` is LayerNorm with ``at[name + "_bias"]``, a
+    config that does not say is RMSNorm."""
+    if getattr(cfg, "norm", "rms") == "layer":
+        return _layer_norm(x, at[name], at[name + "_bias"], cfg.norm_eps)
+    return _rms_norm(x, at[name], cfg.norm_eps)
+
+
+def _head(x, params, cfg):
+    """Float32 logits: over ``lm_head``, or — ``cfg.tie_embeddings`` — over
+    the embedding's own rows (no second table is held)."""
+    if getattr(cfg, "tie_embeddings", False):
+        return jax.lax.dot_general(
+            x, params["embed"], (((x.ndim - 1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    return jnp.dot(x, params["lm_head"], preferred_element_type=jnp.float32)
+
+
 def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
-                 ffn, write_kv, attend, kinds=None):
+                 ffn, write_kv, attend, kinds=None, mixer=None, shared=None):
     """THE layer loop of serving and its oracles: tokens [B, T] at global
     positions pos [B, T] (a leading 1 broadcasts) through every layer of
     ``params`` -> (new caches, logits [B, T, V] float32).  Decode is this
@@ -457,6 +498,19 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     whose layers differ in kind: ``project`` then takes ``kind=kinds[li]``
     (a RoPE per kind), and the pair, which has ``li``, looks its own up.
 
+    ``mixer`` is given by a family whose layers are not all "project ->
+    write K/V -> attend -> out_proj" (``models/ssm_yoco.py``): it stands in
+    place of that quartet, ``mixer(li, h [B, T, D], layer, pos, cache,
+    shared, write_kv=, attend=) -> (rows [B * T, D], cache', shared')``,
+    and is handed the caller's access pair for its attention layers.
+    ``shared`` (a dict) is what a layer hands to LATER layers of the same
+    forward — a state-space layer's output for the gates below it, the one
+    cache eight layers read — and what the caller tells the mixer of its
+    own addressing (a decode step's state slots, a chunk's valid rows).
+    Without ``mixer`` nothing of this is traced: the program is the one it
+    was (tests/test_regions.py).  The norm and the head are the config's
+    (:func:`_norm`, :func:`_head`): data, not a branch a family.
+
     The residual stream is ``[B, T, D]`` — and ``[B, D]`` at T = 1, by
     evidence, not taste (PERF.md §6, PR 28): the chip's compiler folds
     the ``[B, T, D] -> [B * T, D] -> [B, T, H, hd]`` reshapes around the
@@ -470,24 +524,29 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
         x = params["embed"][tokens.reshape((B,) if T == 1 else (B, T))]
     new_caches = []
     for li, layer in enumerate(params["layers"]):
-        with region("proj"):
-            h = _rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-            q, k, v = project(h.reshape(B, T, -1), layer, pos,
-                              **_kind_kw(kinds, li))
-        with region("kv_write"):
-            cache = write_kv(li, caches[li], k, v)
-        o = attend(li, q, cache)                         # [B, T, Hq, .]
-        with region("out_proj"):
-            o2 = o.reshape(B * T, -1).astype(cfg.dtype)
-            x = x + out_proj(o2, layer).reshape(x.shape)
+        if mixer is not None:
+            h = _norm(x, layer, "attn_norm", cfg)
+            rows, cache, shared = mixer(
+                li, h.reshape(B, T, -1), layer, pos, caches[li], shared,
+                write_kv=write_kv, attend=attend)
+            x = x + rows.reshape(x.shape)
+        else:
+            with region("proj"):
+                h = _norm(x, layer, "attn_norm", cfg)
+                q, k, v = project(h.reshape(B, T, -1), layer, pos,
+                                  **_kind_kw(kinds, li))
+            with region("kv_write"):
+                cache = write_kv(li, caches[li], k, v)
+            o = attend(li, q, cache)                     # [B, T, Hq, .]
+            with region("out_proj"):
+                o2 = o.reshape(B * T, -1).astype(cfg.dtype)
+                x = x + out_proj(o2, layer).reshape(x.shape)
         with region("ffn"):
-            h2 = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
+            h2 = _norm(x, layer, "mlp_norm", cfg)
             x = x + ffn(h2.reshape(B * T, -1), layer).reshape(x.shape)
         new_caches.append(cache)
     with region("head"):
-        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = jnp.dot(x, params["lm_head"],
-                         preferred_element_type=jnp.float32)
+        logits = _head(_norm(x, params, "final_norm", cfg), params, cfg)
     return new_caches, logits.reshape(B, T, -1)
 
 
@@ -541,13 +600,14 @@ def _pool_views(pool):
 
 
 def paged_attend(q, pool, tables, lens, *, cfg, impl, interpret,
-                 kind: LayerKind | None = None):
+                 kind: LayerKind | None = None, scale=None):
     """The engine's paged attend over ONE layer's ``(K, V)`` pool
     (``[NB, Hkv, page, hd]`` planes, float or int8 ``{"q", "s"}``):
     q [B, (T,) Hq, hd] -> [B, (T,) Hq, hd] through the block-table
     kernel.  The window is the model's one (``cfg.attn_window``) or, with
     a ``kind``, the layer's own — and the call then carries the kind's
-    name."""
+    name.  ``scale`` is the scores' where it is not ``1 / sqrt(hd)`` of the
+    pool's rows (heads stored in pairs: ``flash_decode.pack_q_pairs``)."""
     kq, vq, ks, vs = _pool_views(pool)
     # a call with no name of its own takes its instruction's name from the
     # scope around it (``closed_call`` / ``_unknown_``: what the benchmark's
@@ -559,7 +619,8 @@ def paged_attend(q, pool, tables, lens, *, cfg, impl, interpret,
             soft_cap=cfg.attn_soft_cap,
             window=cfg.attn_window if kind is None else kind.window,
             k_scale=ks, v_scale=vs,
-            name=None if kind is None else kind.call_name)
+            name=None if kind is None else kind.call_name,
+            **({} if scale is None else {"scale": scale}))
     return o
 
 
@@ -644,9 +705,10 @@ def prefill_kernel_gap(chunk: int, extent: int, head_dim: int) -> str | None:
 def _attend_prefix(q, k_all, v_all, prefix_len, *, k_scale=None,
                    v_scale=None, impl="auto", interpret=False,
                    mesh=None, axis=None, window=0, soft_cap=0.0,
-                   kind=None):
+                   kind=None, scale=None):
     """Chunk attention against the cache prefix + itself (under the
-    layer's own window where a ``kind`` is given).
+    layer's own window where a ``kind`` is given; ``scale``: the scores'
+    where it is not ``1 / sqrt(hd)``: world 1 and the dense program).
 
     q [B, c, Hq, hd]; k/v_all [B, Hkv, S, hd] (the full cache, chunk rows
     already written at [prefix, prefix+c)); position j is visible to chunk
@@ -700,12 +762,14 @@ def _attend_prefix(q, k_all, v_all, prefix_len, *, k_scale=None,
                 out, _ = gqa_decode_shard(
                     q, k_all, v_all, lens, impl="auto",
                     interpret=interpret, k_scale=k_scale, v_scale=v_scale,
-                    soft_cap=soft_cap, window=window)
+                    soft_cap=soft_cap, window=window,
+                    **({} if scale is None else {"scale": scale}))
                 return out.astype(jnp.float32)
             out = flash_attention(
                 qt, k_all, v_all, causal=True, q_offset=prefix_len,
                 impl="auto", interpret=interpret, k_scale=k_scale,
-                v_scale=v_scale, window=window, soft_cap=soft_cap)
+                v_scale=v_scale, window=window, soft_cap=soft_cap,
+                scale=scale)
             return out.transpose(0, 2, 1, 3).astype(jnp.float32)
         if use_decode and S_all % world == 0:
             from jax.sharding import PartitionSpec as P
@@ -761,8 +825,8 @@ def _attend_prefix(q, k_all, v_all, prefix_len, *, k_scale=None,
     _, Hkv, S, _ = k_all.shape
     g = Hq // Hkv
     qf = q.astype(jnp.float32).reshape(B, c, Hkv, g, hd)
-    logits = jnp.einsum("bchgd,bhsd->bhgcs", qf,
-                        k_all.astype(jnp.float32)) / np.sqrt(hd)
+    logits = jnp.einsum("bchgd,bhsd->bhgcs", qf, k_all.astype(jnp.float32))
+    logits = logits / np.sqrt(hd) if scale is None else logits * scale
     if k_scale is not None:
         logits = logits * k_scale[:, :, None, None, :]
     if soft_cap:
@@ -799,7 +863,8 @@ def _write_chunk(cache, new, prefix_len, quantized):
 
 def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
                    project, out_proj, ffn, attend,
-                   extent: int | None = None, n_valid=None, kinds=None):
+                   extent: int | None = None, n_valid=None, kinds=None,
+                   mixer=None):
     """One prompt chunk [B, c] against the cached prefix; returns
     (new_caches, logits [B, c, V] — position i predicts the token after
     chunk[:, i]): :func:`_layer_stack` with the pair of a CONTIGUOUS
@@ -861,9 +926,14 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
             return attend(q, *(p[:, :, :ext] for p in planes), prefix_len,
                           k_scale=None, v_scale=None, **_kind_kw(kinds, li))
 
+    # a family's ``mixer`` is told the chunk's valid rows: a state carried
+    # from chunk to chunk must not scan a residual's padding
+    more = {} if mixer is None else {
+        "mixer": mixer, "shared": {"n_valid": n_valid}}
     return _layer_stack(params, chunk, positions[None], caches, cfg=cfg,
                         project=project, out_proj=out_proj, ffn=ffn,
-                        write_kv=write_kv, attend=attend_views, kinds=kinds)
+                        write_kv=write_kv, attend=attend_views, kinds=kinds,
+                        **more)
 
 
 def _write_rows(cache, new, offs):

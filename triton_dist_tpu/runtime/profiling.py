@@ -183,6 +183,7 @@ REGIONS = (
     "embed", "proj", "kv_write", "attn", "out_proj", "ffn", "head",
     "moe.route", "moe.align", "moe.experts", "moe.combine", "moe.shared",
     "dsa.index", "dsa.select", "mla.expand", "sample",
+    "ssm.in", "ssm.conv", "ssm.scan", "ssm.out", "gmu",
 )
 REGION_PREFIX = "rg_"
 

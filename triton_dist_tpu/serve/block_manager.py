@@ -81,7 +81,91 @@ class KvGroupsUnsupported(NotImplementedError):
     is called — never a quiet fallback."""
 
 
+class StateCacheUnsupported(KvGroupsUnsupported):
+    """What has not been carried over to a cache with a STATE group (a
+    fixed slot a running request beside its pages: models/ssm_yoco.py)
+    refuses by this name where the engine is built — never a quiet
+    fallback."""
+
+
+class StateSlots:
+    """The allocator of a STATE group: one fixed slot a running request,
+    behind the part of :class:`BlockManager`'s interface that
+    :class:`KvGroups` uses.  A state-space layer's cache does not grow — a
+    request holds the same bytes at token 10 and at token 5,000 — so a slot
+    is taken at admission, kept through every chunk and decode step, and
+    given back at finish or preemption; ``ensure`` never allocates, and the
+    group is never the one that refuses growth.  Slot 0 is the null slot
+    (inactive rows of a decode step read and write it).  A request's
+    "table" is its slot in column 0 of a row of nulls, so the engine's
+    table and block-id operands carry it like any group's."""
+
+    window = 0
+    released = 0
+    state = True
+
+    def __init__(self, slots: int, page_size: int):
+        self.num_blocks = slots + 1
+        self.page_size = page_size
+        self.null_block = 0
+        self._free = list(range(slots, 0, -1))      # pop() gives slot 1 first
+        self._slot: dict[str, int] = {}
+
+    @property
+    def num_free(self) -> int:
+        return len(self._free)
+
+    @property
+    def num_allocatable(self) -> int:
+        return self.num_blocks - 1
+
+    @property
+    def utilization(self) -> float:
+        return len(self._slot) / self.num_allocatable
+
+    def blocks_for(self, n_tokens: int) -> int:
+        return 1
+
+    def pages_held(self, n_tokens: int) -> int:
+        return 1
+
+    def can_allocate(self, n_tokens: int, shared: Sequence[int] = ()) -> bool:
+        return bool(self._free)
+
+    def allocate(self, rid: str, n_tokens: int,
+                 shared: Sequence[int] = ()) -> list[int]:
+        if not self._free:
+            raise BlockExhausted(f"{rid}: no state slot is free")
+        self._slot[rid] = self._free.pop()
+        return [self._slot[rid]]
+
+    def ensure(self, rid: str, n_tokens: int) -> list[int]:
+        return [self._slot[rid]]                    # a state never grows
+
+    def release_unseen(self, rid: str, kv_len: int) -> int:
+        return 0
+
+    def free(self, rid: str) -> None:
+        self._free.append(self._slot.pop(rid))
+
+    def table(self, rid: str) -> list[int]:
+        return [self._slot[rid]]
+
+    def padded_table(self, rid: str, width: int) -> list[int]:
+        return [self._slot[rid]] + [self.null_block] * (width - 1)
+
+    def capacity_tokens(self, rid: str) -> int:
+        return 1 << 62                              # never the binding group
+
+    def page_ids(self, rid: str, lo: int, hi: int, width: int):
+        ids = np.full((width,), self.null_block, np.int32)
+        ids[0] = self._slot[rid]
+        return ids
+
+
 class BlockManager:
+    state = False               # a group of pages (StateSlots: of slots)
+
     def __init__(self, num_blocks: int, page_size: int, *, faults=None,
                  prefix_cache: bool = False, shards: int = 1,
                  pages_per_shard: Optional[int] = None, window: int = 0):
@@ -792,10 +876,16 @@ class KvGroups:
     group is left out — what it holds follows the rows in the batch, not
     their contexts, and the engine sizes it to ``max_batch`` rows' worst
     case, so it reads near full on every full batch and is never the one
-    that refuses; its numbers are in :meth:`group_stats`.  Tables and
-    block ids come back with a leading group axis (``padded_table``,
-    ``page_ids``).  No prefix chain and no sequence sharding run through
-    it (:class:`KvGroupsUnsupported`)."""
+    that refuses; its numbers are in :meth:`group_stats`.  A STATE group
+    (:class:`StateSlots`: one fixed slot a running request) is left out
+    for the same reason and reported the same way.  Planes differ by
+    group — pages of K and V rows, or a slot of state — and a layer may
+    own a pool in none (it reads another layer's, or nothing): that is the
+    generator's to say (``kv_groups[..]["layers"]``), the tables here are a
+    request's whatever reads them.  Tables and block ids come back with a
+    leading group axis (``padded_table``, ``page_ids``).  No prefix chain
+    and no sequence sharding run through it
+    (:class:`KvGroupsUnsupported`)."""
 
     prefix_cache = False        # the engine's warm-up toggles it: inert
     on_evict = None             # no cache tier, so nothing evicts
@@ -828,7 +918,8 @@ class KvGroups:
 
     @property
     def utilization(self) -> float:
-        grows = [m for m in self._all if not m.window] or self._all
+        grows = [m for m in self._all
+                 if not m.window and not m.state] or self._all
         return max(m.utilization for m in grows)
 
     def group_stats(self) -> dict:
@@ -836,10 +927,14 @@ class KvGroups:
         return {name: {"blocks": m.num_allocatable,
                        "in_use": m.num_allocatable - m.num_free,
                        "peak": self._peak[name],
-                       "window": m.window, "released": m.released}
+                       "window": m.window, "released": m.released,
+                       **({"state": True} if m.state else {})}
                 for name, m in self.groups.items()}
 
-    def _note_peak(self) -> None:
+    def note_peak(self) -> None:
+        """Fold the blocks (and slots) held right now into each group's
+        peak: after every allocation here, and by the engine where requests
+        came and went since (``serve.decode.plan.state``)."""
         for name, m in self.groups.items():
             self._peak[name] = max(self._peak[name],
                                    m.num_allocatable - m.num_free)
@@ -873,7 +968,7 @@ class KvGroups:
                                  f"tokens")
         for m in self._all:
             m.allocate(rid, n_tokens)
-        self._note_peak()
+        self.note_peak()
 
     def ensure(self, rid: str, n_tokens: int) -> None:
         """Grow every group's table to ``n_tokens`` rows.  A group that
@@ -884,7 +979,7 @@ class KvGroups:
             for m in self._all:
                 m.ensure(rid, n_tokens)
         finally:
-            self._note_peak()
+            self.note_peak()
 
     def release_unseen(self, rid: str, kv_len: int) -> int:
         return sum(m.release_unseen(rid, kv_len) for m in self._all)

@@ -147,6 +147,8 @@ from triton_dist_tpu.serve.block_manager import (
     BlockManager,
     KvGroups,
     KvGroupsUnsupported,
+    StateCacheUnsupported,
+    StateSlots,
 )
 from triton_dist_tpu.serve.metrics import RequestMetrics, ServeMetrics
 from triton_dist_tpu.serve.programs import (
@@ -220,11 +222,23 @@ def _kv_groups(gen):
     return groups if len(groups) > 1 else None
 
 
+def _state_group(groups):
+    """The STATE group among a generator's cache groups (one fixed slot a
+    running request: it names ``state_planes``), or None."""
+    return next((g for g in groups or () if g.get("state_planes")), None)
+
+
 def _refuse_groups(groups, what: str) -> None:
     """What has not been carried over to a cache of several layer GROUPS
     (window and global layers: one block table a group, pools of their own
     geometry — docs/serving.md) refuses by name, where the engine is built
     or the entry point is called."""
+    if groups and _state_group(groups):
+        raise StateCacheUnsupported(
+            f"{what}: not served beside a state group yet — a request "
+            f"holds a fixed slot of state-space state beside its pages "
+            f"({', '.join(g['name'] for g in groups)}), and no prefix hit, "
+            f"layout or format carries the state at a position")
     if groups:
         raise KvGroupsUnsupported(
             f"{what}: not served over cache groups "
@@ -385,6 +399,8 @@ class ServeEngine:
         # fall into cache GROUPS — the generator names them
         # (``kv_groups``); one group is the engine as it always was.
         self.kv_groups = _kv_groups(gen)
+        # ... and one of them may be a STATE group: a fixed slot a request
+        self._has_state = _state_group(self.kv_groups) is not None
         for what, asked in (
                 ("a mesh", mesh is not None),
                 ("int8 pools", self.kv_quant),
@@ -508,14 +524,19 @@ class ServeEngine:
             # write ahead (``horizon * pipeline`` rows): ``(window +
             # ahead - 2) // page + 2`` pages however it lies on the page
             # grid, so the group can never be the one that runs out.
+            # A STATE group (state-space layers) has one fixed slot a row,
+            # and the null slot.
             ahead = horizon * pipeline if horizon > 1 else 1
             self.group_blocks = [
+                1 + max_batch if g.get("state_planes") else
                 num_blocks if not g["window"] else 1 + max_batch * (
                     (g["window"] + ahead - 2) // page_size + 2)
                 for g in self.kv_groups]
             self.bm = KvGroups({
-                g["name"]: BlockManager(nb, page_size, faults=faults,
-                                        window=g["window"])
+                g["name"]: StateSlots(max_batch, page_size)
+                if g.get("state_planes") else
+                BlockManager(nb, page_size, faults=faults,
+                             window=g["window"])
                 for g, nb in zip(self.kv_groups, self.group_blocks)})
         else:
             self.group_blocks = [num_blocks]
@@ -584,6 +605,12 @@ class ServeEngine:
         # and compiles nothing.
         self.prefill_width = prefill_width(prefill_chunk,
                                            self._base_prefill_budget)
+        if self._has_state:
+            # a call that starts off a multiple of its rows slides back
+            # and feeds rows again (_call_window): rows a scan has been
+            # through cannot be fed twice, so a call is ONE chunk and every
+            # call starts where the last one ended
+            self.prefill_width = prefill_chunk
         self.metrics = ServeMetrics()
         self.metrics.prefill_width = self.prefill_width
         # flight recorder (docs/observability.md): a bounded ring of
@@ -753,9 +780,9 @@ class ServeEngine:
         self.paged_attn_blocking = (
             {} if "paged_decode" in self.kernel_gaps or self.latent
             else paged_kernel_blocking(
-                cfg.n_kv_heads // (self.mesh_world // self.sp_world),
-                page_size, cfg.head_dim, jnp.dtype(cfg.dtype).itemsize,
-                batch=max_batch))
+                self.kv_planes[0][0] // (self.mesh_world // self.sp_world),
+                page_size, self.kv_planes[0][1],
+                jnp.dtype(cfg.dtype).itemsize, batch=max_batch))
         self.metrics.paged_attn_blocking = self.paged_attn_blocking
         if topology.is_tpu():
             for prog, why in self.kernel_gaps.items():
@@ -769,6 +796,17 @@ class ServeEngine:
         # captures it; its hooks ride the same ffn / out_proj seams the
         # mesh TP bodies use, so every program stays one copy.
         hooks = gen.serve_hooks()
+        # Where ONE cache is read by layers that own none (cross-attention
+        # over an earlier layer's K and V), the layers that read each
+        # group's table, for the reach counters; None where every reader
+        # owns its cache.
+        self._cache_readers = None
+        if self.kv_groups:
+            readers = {g["name"]: sum(k.group == gi for k in hooks["kinds"])
+                       for gi, g in enumerate(self.kv_groups)}
+            if any(readers[g["name"]] != len(g["layers"])
+                   for g in self.kv_groups):
+                self._cache_readers = readers
         # a family's programs may end in counters of their own (the MoE
         # tally): ``_note_aux`` takes them off every program's outputs
         wrap = gen.wrap_program
@@ -899,12 +937,26 @@ class ServeEngine:
             def zpool(nb, h, d):
                 return jnp.zeros((nb, h, page_size, d), cfg.dtype,
                                  device=self._pool_sharding)
-        # a layer's planes hold its GROUP's block count (one group: all
-        # of them ``num_blocks``)
-        layer_blocks = ([self.group_blocks[k.group] for k in hooks["kinds"]]
-                        if self.kv_groups else [num_blocks] * cfg.n_layers)
-        self._pools = [tuple(zpool(nb, h, d) for h, d in self.kv_planes)
-                       for nb in layer_blocks]
+        # A layer's planes hold its GROUP's block count (one group: all of
+        # them ``num_blocks``) — of the group it OWNS a pool in
+        # (``kv_groups[..]["layers"]``): a layer that reads another's cache,
+        # or none, has no planes.  A state group's planes are one slot's
+        # (shape, dtype) a block: ``_plane_specs[li]`` says which.
+        if self.kv_groups:
+            self._plane_specs = [()] * cfg.n_layers
+            layer_blocks = [0] * cfg.n_layers
+            for g, nb in zip(self.kv_groups, self.group_blocks):
+                for li in g["layers"]:
+                    self._plane_specs[li] = tuple(
+                        g.get("state_planes") or self.kv_planes)
+                    layer_blocks[li] = nb
+        else:
+            self._plane_specs = [tuple(self.kv_planes)] * cfg.n_layers
+            layer_blocks = [num_blocks] * cfg.n_layers
+        self._pools = [
+            tuple(zpool(nb, *p) if isinstance(p[0], int) else
+                  jnp.zeros((nb, *p[0]), p[1]) for p in planes)
+            for nb, planes in zip(layer_blocks, self._plane_specs)]
         self._sample_fn = CountingJit(
             jax.jit(named(_sample_token, "sample_token")), "sample_token")
         for c in (self._chunk_fn, self._fill_fn, self._decode_fn,
@@ -2350,9 +2402,17 @@ class ServeEngine:
         else:
             def _zs(h, d):
                 return jnp.zeros((1, h, s_ext, d), cfg.dtype)
-        # one scratch plane per pool plane (K and V; or the latent row)
-        rs.scratch = [tuple(_zs(h, d) for h, d in self.kv_planes)
-                      for _ in range(cfg.n_layers)]
+        # one scratch plane per pool plane (K and V; or the latent row; a
+        # state-space layer's: the request's state, from zero)
+        rs.scratch = [
+            tuple(_zs(*p) if isinstance(p[0], int) else
+                  jnp.zeros((1, *p[0]), p[1]) for p in planes)
+            for planes in self._plane_specs]
+        if self._has_state:
+            self.metrics.state_resets += 1
+            if rs.generated:        # a preempted request's second life
+                self.metrics.state_recomputed_tokens += int(
+                    rs.prompt_tokens.shape[0])
 
     def _run_prefill(self, rs: ReqState, n_tokens: int,
                      now: float) -> Optional[RequestOutput]:
@@ -2384,6 +2444,8 @@ class ServeEngine:
             rs.prefill_pos += c
             n_last = n_fed
             self.metrics.prefill_tokens += c
+            if self._has_state:
+                self.metrics.ssm_scan_tokens += c
             self.metrics.prefill_dispatches += 1
             self.metrics.prefill_pad_tokens += width - c
             if self.trace.level >= 2:
@@ -3022,10 +3084,21 @@ class ServeEngine:
         if not self.kv_groups:
             return
         ctx = np.arange(kv_len + 1, kv_len + n + 1)
+        shared = self._cache_readers is not None
         for g in self.kv_groups:
+            if g.get("state_planes"):
+                continue
+            # a cache read by layers that own none counts each reader
+            readers = (self._cache_readers[g["name"]] if shared
+                       else len(g["layers"]))
             seen = int((np.minimum(ctx, g["window"]) if g["window"]
-                        else ctx).sum()) * len(g["layers"])
-            if g["window"]:
+                        else ctx).sum()) * readers
+            if shared:
+                if g["window"]:
+                    self.metrics.yoco_window_tokens += seen
+                else:
+                    self.metrics.yoco_shared_tokens += seen
+            elif g["window"]:
                 self.metrics.swa_window_tokens += seen
             else:
                 self.metrics.swa_full_tokens += seen
@@ -3129,6 +3202,11 @@ class ServeEngine:
                     self.metrics.kv_window_released += sum(
                         self.bm.release_unseen(rs.req.request_id, rs.kv_len)
                         for rs in running if rs.status is Status.RUNNING)
+                if self._has_state:
+                    # slots were taken at admission and given back at
+                    # finish or preemption since the last chain: the peak
+                    with self.trace.span("decode.plan.state"):
+                        self.bm.note_peak()
             for rs in sorted(running, key=lambda r: r.seq):
                 if rs.status is Status.RUNNING:  # may get preempted below
                     want = rs.kv_len + min(max(h_plan, 1) * links,

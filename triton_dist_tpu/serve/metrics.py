@@ -79,6 +79,8 @@ MERGE_COUNTERS = (
     "dsa_indexed_tokens", "dsa_selected_rows", "dsa_rows_sparse",
     "dsa_rows_dense",
     "swa_window_tokens", "swa_full_tokens", "kv_window_released",
+    "state_resets", "state_recomputed_tokens", "ssm_scan_tokens",
+    "yoco_shared_tokens", "yoco_window_tokens",
     "net_requests", "net_dup_hits", "net_redelivered_tokens",
     "brownout_transitions",
     "journal_corrupt", "manifest_corrupt",
@@ -302,6 +304,17 @@ class ServeMetrics:
     swa_window_tokens: int = 0
     swa_full_tokens: int = 0
     kv_window_released: int = 0
+    # a state beside pages (docs/serving.md "State beside pages"): slots
+    # zeroed for a request's first chunk, tokens scanned again after a
+    # preemption, prompt tokens through the chunk's scan; and, where ONE
+    # cache is read by several layers, the cached tokens the decode queries
+    # read through it (readers x context) and on window layers (host
+    # arithmetic at commit, as the swa_* pair)
+    state_resets: int = 0
+    state_recomputed_tokens: int = 0
+    ssm_scan_tokens: int = 0
+    yoco_shared_tokens: int = 0
+    yoco_window_tokens: int = 0
     decode_tokens: int = 0        # tokens committed by the decode loop
     dispatches: int = 0           # decode-path device dispatches
     host_syncs: int = 0           # decode-path host sync points
@@ -553,6 +566,33 @@ class ServeMetrics:
                 "window_share": (self.swa_window_tokens / both
                                  if both else 0.0),
                 "window_released_pages": self.kv_window_released}
+
+    def state_group(self) -> dict:
+        """The state group's entry of :meth:`kv_group_stats` ({} where the
+        cache has none)."""
+        return next((st for st in self.kv_group_stats().values()
+                     if st.get("state")), {})
+
+    def ssm_stats(self) -> dict:
+        """summary()["ssm"]: the state slots in use and their peak, slots
+        zeroed for a first chunk, tokens scanned again after a preemption,
+        prompt tokens through the chunk's scan."""
+        st = self.state_group()
+        return {"state_slots_in_use": st.get("in_use", 0),
+                "state_slots_peak": st.get("peak", 0),
+                "state_resets": self.state_resets,
+                "state_recomputed_tokens": self.state_recomputed_tokens,
+                "scan_tokens": self.ssm_scan_tokens}
+
+    def yoco_stats(self) -> dict:
+        """summary()["yoco"]: cached tokens the decode queries read
+        through the ONE shared cache (each reader layer counted) and on
+        window layers, and the shared cache's share of both."""
+        both = self.yoco_shared_tokens + self.yoco_window_tokens
+        return {"shared_tokens": self.yoco_shared_tokens,
+                "window_tokens": self.yoco_window_tokens,
+                "shared_share": (self.yoco_shared_tokens / both
+                                 if both else 0.0)}
 
     def moe_stats(self) -> dict:
         """summary()["moe"]: the four counters and the share of routed
@@ -1047,6 +1087,8 @@ class ServeMetrics:
             "moe": self.moe_stats(),
             "dsa": self.dsa_stats(),
             "swa": self.swa_stats(),
+            "ssm": self.ssm_stats(),
+            "yoco": self.yoco_stats(),
             "spec": self.spec_stats(),
             "slo": self.slo_stats(),
             "failures": self.failure_stats(),
@@ -1109,6 +1151,18 @@ class ServeMetrics:
                 "cached tokens decode queries read on full layers")
         counter("serve_kv_window_released_total", self.kv_window_released,
                 "window-group pages given back while requests ran")
+        counter("serve_state_resets_total", self.state_resets,
+                "state slots zeroed for a request's first chunk")
+        counter("serve_state_recomputed_tokens_total",
+                self.state_recomputed_tokens,
+                "tokens scanned again after a preemption")
+        counter("serve_ssm_scan_tokens_total", self.ssm_scan_tokens,
+                "prompt tokens through the chunk's selective scan")
+        counter("serve_yoco_shared_tokens_total", self.yoco_shared_tokens,
+                "cached tokens decode queries read through the shared cache")
+        counter("serve_yoco_window_tokens_total", self.yoco_window_tokens,
+                "cached tokens decode queries read on window layers "
+                "beside a shared cache")
         counter("serve_shed_total", self.shed)
         counter("serve_deadline_expired_total", self.deadline_expired)
         counter("serve_quarantined_total", self.quarantined)
@@ -1187,6 +1241,11 @@ class ServeMetrics:
             for g, st in stats.items():
                 L.append(f'serve_kv_group_blocks_peak{{group="{g}"}} '
                          f'{st["peak"]}')
+        slots = self.state_group()
+        if slots:
+            gauge("serve_state_slots_in_use", slots["in_use"],
+                  "state slots held by running requests")
+            gauge("serve_state_slots_peak", slots["peak"])
         gauge("serve_kv_pool_bytes", self.kv_pool_bytes,
               "device bytes pinned by the paged KV pools "
               "(int8 pages + f32 scales both count)")

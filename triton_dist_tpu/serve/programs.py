@@ -105,7 +105,7 @@ def _by_group(tables, kinds, one: int):
 
 def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
                           cfg, page, project, out_proj, ffn, paged_attend,
-                          slots=_page_slots, kinds=None):
+                          slots=_page_slots, kinds=None, mixer=None):
     """One decode token for every batch row over the paged pools:
     ``generate._layer_stack`` at T = 1 (the same math as
     ``Generator._step_impl`` — the greedy stream must be bit-identical to
@@ -126,10 +126,22 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
     ``kinds`` (a ``generate.LayerKind`` a layer, static) comes with a
     model whose layers differ in kind: ``tables`` then holds one table a
     cache GROUP ([G, B, n_pages]), a layer writes and reads through its
-    group's, and ``project`` / ``paged_attend`` are told the kind."""
+    group's, and ``project`` / ``paged_attend`` are told the kind.
+
+    ``mixer`` comes with a family that has one (``generate._layer_stack``).
+    Where it has state-space layers, their group's table holds each row's
+    STATE SLOT in its first column: the mixer is told ``shared["slot"]``
+    [B] — an inactive row's redirected to the null slot 0, as its page
+    writes are to the null block — and reads and writes the layer's state
+    planes there, in place."""
     inc = active.astype(kv_lens.dtype)
     by_group, group_of = _by_group(tables, kinds, one=2)
     where = [slots(t, kv_lens, active, page=page) for t in by_group]
+    more = {}
+    if mixer is not None:
+        state = [k.group for k in kinds if k.attn == "ssm"]
+        more = {"mixer": mixer, "shared": {} if not state else {
+            "slot": jnp.where(active, by_group[state[0]][:, 0], 0)}}
 
     def write_kv(li, pool, k, v):
         return _scatter_kv(pool, k[:, 0], None if v is None else v[:, 0],
@@ -147,7 +159,7 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
     pools, logits = _layer_stack(
         params, token[:, None], kv_lens[:, None], pools, cfg=cfg,
         project=project, out_proj=out_proj, ffn=ffn, write_kv=write_kv,
-        attend=attend, kinds=kinds)
+        attend=attend, kinds=kinds, **more)
     return pools, logits[:, 0]
 
 
@@ -521,7 +533,12 @@ def _fill_pool_pages(pools, scratch, block_ids, *, page, kinds=None):
     prompt-page count — the trace is keyed by the s_ext bucket alone.
     A quantized scratch's int8 bytes + scales scatter AS-IS: the pool
     rows are bit-identical to the scratch rows, so a warm-prefix
-    gather-back reproduces the cold prefill exactly."""
+    gather-back reproduces the cold prefill exactly.
+
+    A state-space layer's scratch is the request's STATE after its last
+    prompt row (``[1, ..]`` planes): it lands whole in the request's slot,
+    the first id of the state group's row.  A layer that owns no pool has
+    nothing to move."""
     n = block_ids.shape[-1]
 
     def fill(ids, pools, scratch):
@@ -529,10 +546,16 @@ def _fill_pool_pages(pools, scratch, block_ids, *, page, kinds=None):
             lambda p, c: p.at[ids].set(
                 _plane_pages(c, n, page).astype(p.dtype)), pools, scratch)
 
+    def fill_state(ids, pool, sc):
+        return tuple(p.at[ids[0]].set(c[0].astype(p.dtype))
+                     for p, c in zip(pool, sc, strict=True))
+
     by_group, group_of = _by_group(block_ids, kinds, one=1)
     if len(by_group) == 1:
         return fill(block_ids, pools, scratch)
-    return [fill(by_group[group_of(li)], pool, sc)
+    return [pool if not pool else
+            (fill_state if kinds[li].attn == "ssm" else fill)(
+                by_group[group_of(li)], pool, sc)
             for li, (pool, sc) in enumerate(zip(pools, scratch, strict=True))]
 
 

@@ -167,6 +167,8 @@ STEP_PHASES = frozenset({
     "decode.plan",     # plan_horizon, capacity growth, preemption
     "decode.plan.release",  # cache groups: window pages no query sees go
                        # back (child of decode.plan; absent with one group)
+    "decode.plan.state",  # a state group: slots taken and given back since
+                       # the last chain, counted (child of decode.plan)
     "decode.stage",    # numpy batch arrays -> device operands
     "decode.wait",     # host blocked on the device (logits / token burst)
     "decode.commit",   # token choice, _commit_token, callbacks, journal
