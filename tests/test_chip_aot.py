@@ -844,6 +844,19 @@ def test_ssm_yoco_programs_compile_at_published_widths(v5e, as_tpu):
             assert sum(calls.values()) == 9 + 16, calls    # + flash calls
         assert _gib(compiled) < HBM_GIB, (prog, statics, _gib(compiled))
         worst[prog] = max(worst.get(prog, 0), _gib(compiled))
+        if prog == "decode_horizon":
+            # the cell runs at 93-95% of the chip: the sampler's two ways
+            # to a row's cut-offs (ISSUE 39) must not add up — the
+            # candidates are 96 x 16,384 float32 = 6 MiB, and no more may
+            # come on top of what the program planned before them (PR 38's
+            # tree, this test's own reading)
+            before = {(8, True): 12.1844, (8, False): 12.1846,
+                      (1, False): 12.1866}[statics["H"],
+                                           statics["all_greedy"]]
+            assert _gib(compiled) <= before + 8 / 1024, (
+                statics, _gib(compiled), before)
+            assert text.count(" conditional(") == (
+                0 if statics["all_greedy"] else 1)
     print("GiB a program:", {k: round(v, 2) for k, v in worst.items()})
 
 

@@ -105,28 +105,55 @@ def test_zero_temperature_guard(key):
                                   np.asarray(jnp.argmax(logits, -1)))
 
 
-def test_rowwise_sampler_matches_host_path(key):
+_HOST_PATH_ROWS = {
+    # greedy, plain-temperature, top-k, top-p, filters-off: today's batch
+    "small": dict(V=48, temps=[1.0, 0.8, 1.5, 0.5, 1.0, 0.9],
+                  top_ks=[0, 16, 5, 0, 0, 48],              # 48 = off (=V)
+                  top_ps=[1.0, 0.9, 1.0, 0.6, 1.0, 0.95], whole=None),
+    # a vocabulary on the candidate plan: greedy; both cut-offs among the
+    # candidates; a top_p with no top-k before it and a top_k above the
+    # groups a row keeps (either sends the BATCH to the whole rows); a
+    # top-k alone; no filter
+    "wide": dict(V=200064, temps=[1.0, 0.8, 1.5, 0.5, 1.0, 0.9],
+                 top_ks=[0, 64, 0, 200, 5, 0],
+                 top_ps=[1.0, 0.95, 0.9, 1.0, 1.0, 1.0], whole=True),
+    # the same vocabulary, rows the candidates serve: no row falls back
+    "wide_narrow": dict(V=200064, temps=[1.0, 0.8, 1.5, 0.5, 1.0, 0.9],
+                        top_ks=[0, 64, 128, 1, 5, 0],
+                        top_ps=[1.0, 0.95, 0.5, 0.0, 1.0, 1.0],
+                        whole=False),
+}
+
+
+@pytest.mark.parametrize("rows", sorted(_HOST_PATH_ROWS))
+def test_rowwise_sampler_matches_host_path(key, rows):
     """THE host/device dedup pin (serve engine): for every row,
     `sample_logits_rowwise` (the traced per-row sampler the decode
     horizon runs on device) must emit the SAME token as the scalar
-    `sample_logits` host fallback with that row's knobs and key — across
-    greedy, plain-temperature, top-k, top-p, and filters-off rows in one
-    mixed batch, under the engine's fold_in(key(seed), emission) stream."""
-    from triton_dist_tpu.models.sampling import sample_logits_rowwise
+    `sample_logits` host fallback with that row's knobs and key, and as
+    the engine's one-row `_sample_token` program — across greedy,
+    plain-temperature, top-k, top-p, and filters-off rows in one mixed
+    batch, under the engine's fold_in(key(seed), emission) stream; on a
+    wide vocabulary whichever way the batch's cut-offs were found."""
+    from triton_dist_tpu.models.sampling import sample_logits_rowwise_path
+    from triton_dist_tpu.serve.programs import _sample_token
 
-    logits = _logits(key, B=6, V=48)
+    case = _HOST_PATH_ROWS[rows]
+    logits = _logits(key, B=6, V=case["V"])
     seeds = jnp.array([3, 11, 11, 7, 5, 9], jnp.int32)
     counts = jnp.array([0, 4, 9, 2, 0, 31], jnp.int32)
-    temps = jnp.array([1.0, 0.8, 1.5, 0.5, 1.0, 0.9], jnp.float32)
-    top_ks = jnp.array([0, 16, 5, 0, 0, 48], jnp.int32)     # 48 = off (=V)
-    top_ps = jnp.array([1.0, 0.9, 1.0, 0.6, 1.0, 0.95], jnp.float32)
+    temps = jnp.array(case["temps"], jnp.float32)
+    top_ks = jnp.array(case["top_ks"], jnp.int32)
+    top_ps = jnp.array(case["top_ps"], jnp.float32)
     greedy = jnp.array([True, False, False, False, False, False])
 
     keys = jax.vmap(jax.random.fold_in)(jax.vmap(jax.random.key)(seeds),
                                         counts)
-    dev = jax.jit(lambda lo, ks: sample_logits_rowwise(
+    dev, whole = jax.jit(lambda lo, ks: sample_logits_rowwise_path(
         lo, ks, temperature=temps, top_k=top_ks, top_p=top_ps,
         greedy=greedy))(logits, keys)
+    assert (whole if whole is None else bool(whole)) == case["whole"]
+    one_row = jax.jit(_sample_token)
     for b in range(6):
         if bool(greedy[b]):
             want = int(np.argmax(np.asarray(logits[b])))
@@ -138,6 +165,9 @@ def test_rowwise_sampler_matches_host_path(key):
             want = int(sample_logits(
                 logits[b:b + 1], k_host, temperature=float(temps[b]),
                 top_k=tk, top_p=tp if tp < 1.0 else None)[0])
+            assert int(one_row(logits[b], jax.random.key(int(seeds[b])),
+                               counts[b], temps[b], top_ks[b],
+                               top_ps[b])) == want, f"row {b}: one-row call"
         assert int(dev[b]) == want, f"row {b}: device {int(dev[b])} != host {want}"
 
 
@@ -208,7 +238,7 @@ def _oracle_rowwise(logits, keys, *, temperature, top_k, top_p, greedy):
 
 _TOP_KS = (1, 5, 64, 128, 129, 0)
 _TOP_PS = (1.0, 0.95, 0.5, 0.0)
-_VOCABS = (32000, 16032)
+_VOCABS = (32000, 16032, 98304, 200064)
 _KINDS = ("f32", "bf16_ties")
 #: rank whose value the ``bf16_ties`` logits repeat, and how often: the
 #: ties start inside the 64 largest values and run far past the 128th
@@ -235,10 +265,45 @@ def sampler_and_oracle():
     from triton_dist_tpu.models import sampling
 
     def new(logits, keys, **kw):
-        return (sampling._filtered_logits_rowwise(logits, **kw),
+        return (*sampling._filtered_logits_rowwise(logits, **kw),
                 sampling.sample_logits_rowwise(logits, keys, **kw))
 
     return jax.jit(new), jax.jit(_oracle_rowwise)
+
+
+def _assert_matches_oracle(new, oracle, logits, top_k, top_p):
+    """Kept set, kept values and drawn token of ``new`` equal the two-sort
+    form's, row for row, in a batch of a greedy row and three sampled
+    ones -> (kept [3, V], which way the cut-offs were found)."""
+    keys = jax.vmap(jax.random.fold_in)(
+        jax.vmap(jax.random.key)(jnp.array([3, 2 ** 31 - 5, 7, 11])),
+        jnp.array([0, 4, 9, 31]))
+    kw = dict(temperature=jnp.array([0.0, 0.8, 1.0, 1.7], jnp.float32),
+              top_k=jnp.full((4,), top_k, jnp.int32),
+              top_p=jnp.full((4,), top_p, jnp.float32),
+              greedy=jnp.array([True, False, False, False]))
+    x_new, whole, tok_new = new(logits, keys, **kw)
+    x_old, tok_old = oracle(logits, keys, **kw)
+    kept_new = np.asarray(x_new)[1:] > _NEG_INF / 2
+    kept_old = np.asarray(x_old)[1:] > _NEG_INF / 2
+    np.testing.assert_array_equal(kept_new, kept_old)
+    np.testing.assert_array_equal(np.asarray(x_new)[1:][kept_new],
+                                  np.asarray(x_old)[1:][kept_old])
+    np.testing.assert_array_equal(np.asarray(tok_new), np.asarray(tok_old))
+    assert int(tok_new[0]) == int(np.argmax(np.asarray(logits[0])))
+    return kept_new, whole
+
+
+def _falls_back(top_k, top_p, V, spilt_ties):
+    """Whether a sampled row of these knobs sends its batch to the whole
+    rows on the candidate plan: a ``top_k`` above the groups a row keeps;
+    a ``top_p`` with no top-k before it; a ``top_p`` whose k-th value ties
+    in more groups than were gathered (``spilt_ties``)."""
+    from triton_dist_tpu.models.sampling import _CAND_GROUPS
+
+    use_k, use_p = 0 < top_k < V, top_p < 1.0
+    return ((use_k and top_k > _CAND_GROUPS)
+            or (use_p and (not use_k or spilt_ties)))
 
 
 @pytest.mark.parametrize("V", _VOCABS)
@@ -251,30 +316,69 @@ def test_sampler_matches_two_sort_oracle(sampler_and_oracle, top_k, top_p,
     in a batch that mixes a greedy row with three sampled ones — float32
     logits, and bf16-rounded ones in which 200 more values tie with the
     40th largest (a cut at the 64th or 128th value keeps every one of
-    them; the mass they carry moves the nucleus)."""
+    them; the mass they carry moves the nucleus).  The two widest
+    vocabularies run the candidate plan, where those ties, scattered over
+    the lower half, reach the k-th value in more groups than a row keeps:
+    a ``top_p`` behind such a ``top_k`` falls back to the whole rows."""
+    from triton_dist_tpu.models.sampling import takes_candidates
+
     new, oracle = sampler_and_oracle
-    logits = _oracle_logits(kind, V)
-    keys = jax.vmap(jax.random.fold_in)(
-        jax.vmap(jax.random.key)(jnp.array([3, 2 ** 31 - 5, 7, 11])),
-        jnp.array([0, 4, 9, 31]))
-    kw = dict(temperature=jnp.array([0.0, 0.8, 1.0, 1.7], jnp.float32),
-              top_k=jnp.full((4,), top_k, jnp.int32),
-              top_p=jnp.full((4,), top_p, jnp.float32),
-              greedy=jnp.array([True, False, False, False]))
-    x_new, tok_new = new(logits, keys, **kw)
-    x_old, tok_old = oracle(logits, keys, **kw)
-    kept_new = np.asarray(x_new)[1:] > _NEG_INF / 2
-    kept_old = np.asarray(x_old)[1:] > _NEG_INF / 2
-    np.testing.assert_array_equal(kept_new, kept_old)
-    np.testing.assert_array_equal(np.asarray(x_new)[1:][kept_new],
-                                  np.asarray(x_old)[1:][kept_old])
-    np.testing.assert_array_equal(np.asarray(tok_new), np.asarray(tok_old))
-    assert int(tok_new[0]) == int(np.argmax(np.asarray(logits[0])))
+    kept_new, whole = _assert_matches_oracle(
+        new, oracle, _oracle_logits(kind, V), top_k, top_p)
+    ties = kind == "bf16_ties" and top_k > _TIE_RANK
     if 0 < top_k < V:
-        ties = _TIE_COPIES if kind == "bf16_ties" and top_k > _TIE_RANK else 0
-        assert (kept_new.sum(axis=1) <= top_k + ties + 8).all()
+        assert (kept_new.sum(axis=1)
+                <= top_k + (_TIE_COPIES if ties else 0) + 8).all()
         if top_p == 1.0:
             assert (kept_new.sum(axis=1) >= top_k).all()
+    if takes_candidates(V):
+        assert bool(whole) == _falls_back(top_k, top_p, V, ties)
+    else:
+        assert whole is None
+
+
+def _edge_logits(edge, V):
+    """Float32 rows for the candidate plan's edges: ``ties_in_one_group``
+    — 100 copies of the 40th largest value side by side in ONE group of
+    128 (no more groups reach the k-th value than a row keeps: nothing
+    falls back, and every tie stays); ``ragged`` — plain rows of a
+    vocabulary that is no multiple of 128 (the last group is padded)."""
+    x = np.asarray(jax.random.normal(jax.random.key(V), (4, V),
+                                     jnp.float32)) * 3.0
+    if edge == "ties_in_one_group":
+        for row in x:
+            tie = np.sort(row)[-_TIE_RANK]
+            g = int(np.argmin(row.reshape(-1, 128).max(axis=1)))
+            row[g * 128:g * 128 + 100] = tie
+    return jnp.asarray(x)
+
+
+@pytest.mark.parametrize("top_p", (1.0, 0.95, 0.0))
+@pytest.mark.parametrize("top_k", (5, 64, 128, 129, 0))
+@pytest.mark.parametrize("edge,V", [("ties_in_one_group", 32128),
+                                    ("ragged", 32001)])
+def test_candidate_plan_edges(monkeypatch, edge, V, top_k, top_p):
+    """The candidate plan, forced onto a vocabulary under the crossover
+    through the module's constant, against the two-sort form: ties that
+    all fall inside one group, and a vocabulary that is not a multiple of
+    the group (32,001: 251 groups, so a row's 128 are a true subset —
+    16,033 would have fewer groups than a row keeps)."""
+    from triton_dist_tpu.models import sampling
+
+    monkeypatch.setattr(sampling, "_CAND_MIN_VOCAB", 32001)
+
+    # a function of this test's own: a trace cached for the fixture's
+    # would hold the plan chosen under the module's constant
+    def new(logits, keys, **kw):
+        return (*sampling._filtered_logits_rowwise(logits, **kw),
+                sampling.sample_logits_rowwise(logits, keys, **kw))
+
+    kept, whole = _assert_matches_oracle(
+        jax.jit(new), jax.jit(_oracle_rowwise), _edge_logits(edge, V),
+        top_k, top_p)
+    assert bool(whole) == _falls_back(top_k, top_p, V, False)
+    if edge == "ties_in_one_group" and 64 <= top_k <= 129 and top_p == 1.0:
+        assert (kept.sum(axis=1) == _TIE_RANK + 100).all()
 
 
 def test_ordered_bits_keep_the_floats_order():
@@ -298,6 +402,12 @@ def test_ordered_bits_keep_the_floats_order():
     assert np.isnan(np.asarray(_from_ordered_bits(jnp.uint32(0))))
 
 
+def _sorts(hlo):
+    """The compiled text's sort and top-k instructions."""
+    return [line for line in hlo.splitlines()
+            if " sort(" in line or "topk" in line.lower()]
+
+
 def test_sampler_sorts_nothing(key):
     """Neither sampler surface holds a sort or a top-k any more: both
     cut-offs come from reduces."""
@@ -318,3 +428,31 @@ def test_sampler_sorts_nothing(key):
         lg[:, None], base, jnp.zeros((3,), jnp.int32), **kw)).lower(
         logits).compile().as_text()
     assert " sort(" not in hlo and "topk" not in hlo.lower()
+
+
+def test_wide_sampler_sorts_no_vocabulary(key):
+    """On the candidate plan nothing of the vocabulary's length is sorted
+    or given to a top-k either.  What IS ordered is the 1,563 group maxima
+    a row (``lax.top_k`` picks the groups a row keeps; the compiler makes
+    it one sort of ``[rows, 1563]``, ~0.1 ms of the sampler's ~1 at 96
+    rows on the v5e: PERF.md §6, PR 39)."""
+    from triton_dist_tpu.models.sampling import (
+        sample_logits,
+        sample_positions_rowwise,
+    )
+
+    V = 200064
+    logits = jax.ShapeDtypeStruct((3, V), jnp.float32)
+    base = jax.vmap(jax.random.key)(jnp.arange(3))
+    kw = dict(temperature=jnp.full((3,), 0.8), top_k=jnp.array([64, 0, 5]),
+              top_p=jnp.array([0.95, 0.9, 1.0]),
+              greedy=jnp.array([False, False, True]))
+    for hlo in (
+            sample_logits.lower(logits, key, temperature=0.8, top_k=64,
+                                top_p=0.95).compile().as_text(),
+            jax.jit(lambda lg: sample_positions_rowwise(
+                lg[:, None], base, jnp.zeros((3,), jnp.int32), **kw)).lower(
+                logits).compile().as_text()):
+        found = _sorts(hlo)
+        assert found and all(str(V) not in line and "1563" in line
+                             for line in found), found

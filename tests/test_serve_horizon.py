@@ -208,6 +208,75 @@ def test_horizon_sampled_streams_match_host_and_reproduce():
     assert all(0 <= t < cfg.vocab for t in o8["s"].token_ids)
 
 
+@pytest.mark.parametrize("batch,whole", [
+    ("candidates", False), ("whole_rows", True), ("mixed", None)])
+def test_horizon_on_the_candidate_plan_matches_host_and_counts_paths(
+        monkeypatch, batch, whole):
+    """A vocabulary forced onto the sampler's candidate plan (1,024 rows
+    = 8 groups of 128, a row keeps 4): every request's horizon stream
+    equals the ``horizon=1`` engine's, whose tokens come from the one-row
+    ``sample_token`` call — whichever way a step's batch found its
+    cut-offs — and ``summary()["sample"]`` counts each sampled row-step
+    the horizon served: among the candidates where every sampled row's
+    ``top_k`` is at most 4, from the whole rows in every step that holds a
+    ``top_p`` with no top-k before it or a ``top_k`` above 4."""
+    from triton_dist_tpu.models import sampling
+
+    monkeypatch.setattr(sampling, "_CAND_MIN_VOCAB", 1024)
+    monkeypatch.setattr(sampling, "_CAND_GROUPS", 4)
+    cfg = llama.LlamaConfig(vocab=1024, dim=16, n_layers=1, n_heads=2,
+                            n_kv_heads=1, ffn_dim=32, max_seq=64,
+                            dtype=jnp.float32)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("sp",))
+    params = llama.init_params(cfg, jax.random.key(5))
+    gen = Generator(cfg, mesh, axis="sp", max_seq=64)
+    rng = np.random.default_rng(9)
+    narrow = dict(temperature=0.8, top_k=3, top_p=0.95)
+    knobs = {"candidates": [narrow, dict(temperature=1.2, top_k=4)],
+             "whole_rows": [dict(temperature=0.8, top_p=0.9),
+                            dict(temperature=0.8, top_k=200)],
+             "mixed": [narrow, dict(temperature=0.8, top_p=0.9)]}[batch]
+    # the first sampled request outlives the second: in "mixed" its last
+    # steps run beside the greedy row alone, among the candidates
+    reqs = [("g", 7, SamplingParams(max_new_tokens=9))] + [
+        (f"s{i}", 5 + i, SamplingParams(max_new_tokens=20 - 12 * i,
+                                        seed=2 ** 31 + i, **kw))
+        for i, kw in enumerate(knobs)]
+    reqs = [(rid, rng.integers(0, cfg.vocab, size=n).astype(np.int32), sp)
+            for rid, n, sp in reqs]
+
+    def run(h):
+        eng = ServeEngine(gen, params, num_blocks=40, page_size=4,
+                          max_batch=3, prefill_chunk=4, horizon=h,
+                          pipeline=2, clock=_Tick())
+        for rid, prompt, sp in reqs:
+            eng.submit(Request(rid, prompt, sp))
+        return eng.run(), eng.metrics
+
+    (o1, m1), (o8, m8) = run(1), run(8)
+    s1, s8 = m1.summary()["sample"], m8.summary()["sample"]
+    for path in ("narrow", "full"):
+        assert (f'serve_sample_rows_total{{path="{path}"}} '
+                f'{s8[path + "_rows"]}\n') in m8.to_prometheus()
+    assert m8.merge(m8).sample_stats()["full_rows"] == 2 * s8["full_rows"]
+    assert o8["g"].token_ids == _oracle(gen, params, reqs[0][1], 9)
+    for rid, _, sp in reqs:
+        assert o8[rid].token_ids == o1[rid].token_ids, rid
+        assert len(o8[rid].token_ids) == sp.max_new_tokens
+    # a request's first token is the host's choice; the horizon served
+    # every later one (the horizon=1 engine runs no horizon: nothing)
+    served = sum(sp.max_new_tokens - 1 for _, _, sp in reqs[1:])
+    assert s1 == {"narrow_rows": 0, "full_rows": 0, "narrow_share": 0.0}
+    assert s8["narrow_rows"] + s8["full_rows"] == served
+    if whole is None:
+        # every step of the short request is the whole rows' for both
+        assert s8["full_rows"] >= 2 * (reqs[2][2].max_new_tokens - 1)
+        assert s8["narrow_rows"] >= 4
+    else:
+        assert s8["full_rows"] == (served if whole else 0)
+        assert s8["narrow_share"] == (0.0 if whole else 1.0)
+
+
 @pytest.mark.parametrize("seeds", [
     [None, 5, 2 ** 31 - 1, 2 ** 31 + 7, 0],     # either side of int32
     [None] * 4,                                 # an all-greedy batch

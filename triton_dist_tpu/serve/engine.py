@@ -127,6 +127,7 @@ from triton_dist_tpu.models.generate import (  # noqa: F401
     Generator,
     attention_kernel_gaps,
 )
+from triton_dist_tpu.models.sampling import takes_candidates
 from triton_dist_tpu.runtime import dump as ir_dump
 from triton_dist_tpu.runtime import topology
 from triton_dist_tpu.runtime.faults import FaultInjector
@@ -3425,17 +3426,23 @@ class ServeEngine:
                 all_greedy=all_greedy and h_link > 1,
                 fire_injector=(j == 0))
             self._pools = pools
-            outs.append((toks, mask, self._note_aux(aux)))
+            # a wide vocabulary's sampler says LAST how many sampled
+            # row-steps took their cut-offs from the whole rows
+            whole = aux.pop() if takes_candidates(self.cfg.vocab) else None
+            outs.append((toks, mask, whole, self._note_aux(aux)))
 
         # Drain in order: committing link j's burst overlaps the device
         # executing links > j (nothing here forces their results).
         committed = False
         try:
-            for toks, mask, n_aux in outs:
+            for toks, mask, whole, n_aux in outs:
                 with span("decode.wait"):
-                    toks_np, mask_np = jax.device_get((toks, mask))
+                    toks_np, mask_np, whole = jax.device_get(
+                        (toks, mask, whole))
                 self.metrics.host_syncs += 1
                 self._fold_aux(n_aux)
+                self.metrics.observe_sampled(int(mask_np[~greedy].sum()),
+                                             whole)
                 now = self._clock()
                 steps = int(mask_np.any(axis=0).sum())
                 self.metrics.decode_steps += steps

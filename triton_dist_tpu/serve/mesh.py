@@ -88,6 +88,7 @@ from triton_dist_tpu.models.generate import (
     paged_attend,
 )
 from triton_dist_tpu.models.llama import param_specs
+from triton_dist_tpu.models.sampling import takes_candidates
 from triton_dist_tpu.runtime import jit_cache
 from triton_dist_tpu.serve.programs import (
     _copy_pool_block,
@@ -790,7 +791,9 @@ def build_programs(*, mesh, tp_axis, kv_shard, cfg, params, page_size,
         out["decode_horizon"] = ShardedProgram(
             functools.partial(_paged_decode_horizon, decode_fwd=decode_body),
             mesh, (p_specs, pools_specs) + (P(),) * 13,
-            (pools_specs,) + (P(),) * 6, donate_argnums=(1,))
+            # a wide vocabulary's sampler count rides last
+            (pools_specs,) + (P(),) * (6 + takes_candidates(cfg.vocab)),
+            donate_argnums=(1,))
     out["fill_pages"] = ShardedProgram(
         fill_body, mesh,
         (pools_specs, [(sc_spec, sc_spec)] * cfg.n_layers, P()),

@@ -81,6 +81,7 @@ MERGE_COUNTERS = (
     "swa_window_tokens", "swa_full_tokens", "kv_window_released",
     "state_resets", "state_recomputed_tokens", "ssm_scan_tokens",
     "yoco_shared_tokens", "yoco_window_tokens",
+    "sample_narrow_rows", "sample_full_rows",
     "net_requests", "net_dup_hits", "net_redelivered_tokens",
     "brownout_transitions",
     "journal_corrupt", "manifest_corrupt",
@@ -315,6 +316,12 @@ class ServeMetrics:
     ssm_scan_tokens: int = 0
     yoco_shared_tokens: int = 0
     yoco_window_tokens: int = 0
+    # the sampler inside the decode horizon (models/sampling.py): sampled
+    # row-steps whose cut-offs were found among one pass's candidates,
+    # and those that read the whole row (every one on a vocabulary under
+    # the crossover; on a wide one a step's batch that fell back)
+    sample_narrow_rows: int = 0
+    sample_full_rows: int = 0
     decode_tokens: int = 0        # tokens committed by the decode loop
     dispatches: int = 0           # decode-path device dispatches
     host_syncs: int = 0           # decode-path host sync points
@@ -544,6 +551,24 @@ class ServeMetrics:
         indexer, ``DSA_COUNTERS``' four behind them."""
         for name, v in zip(self.FAMILY_COUNTERS, stats):
             setattr(self, name, getattr(self, name) + int(v))
+
+    def observe_sampled(self, rows: int, whole) -> None:
+        """One drained horizon link: ``rows`` sampled row-steps (host
+        arithmetic over the link's live mask), ``whole`` of them served
+        from the whole row — the program's count on a wide vocabulary,
+        None on one under the crossover, where all are."""
+        full = rows if whole is None else int(whole)
+        self.sample_full_rows += full
+        self.sample_narrow_rows += rows - full
+
+    def sample_stats(self) -> dict:
+        """summary()["sample"]: sampled row-steps of the decode horizon by
+        where their cut-offs were found, and the candidates' share."""
+        both = self.sample_narrow_rows + self.sample_full_rows
+        return {"narrow_rows": self.sample_narrow_rows,
+                "full_rows": self.sample_full_rows,
+                "narrow_share": (self.sample_narrow_rows / both
+                                 if both else 0.0)}
 
     def dsa_stats(self) -> dict:
         """summary()["dsa"]: the four counters and the share of the
@@ -1089,6 +1114,7 @@ class ServeMetrics:
             "swa": self.swa_stats(),
             "ssm": self.ssm_stats(),
             "yoco": self.yoco_stats(),
+            "sample": self.sample_stats(),
             "spec": self.spec_stats(),
             "slo": self.slo_stats(),
             "failures": self.failure_stats(),
@@ -1163,6 +1189,12 @@ class ServeMetrics:
         counter("serve_yoco_window_tokens_total", self.yoco_window_tokens,
                 "cached tokens decode queries read on window layers "
                 "beside a shared cache")
+        L.append("# HELP serve_sample_rows_total sampled row-steps of the "
+                 "decode horizon by where their cut-offs were found")
+        L.append("# TYPE serve_sample_rows_total counter")
+        for path, n in (("narrow", self.sample_narrow_rows),
+                        ("full", self.sample_full_rows)):
+            L.append(f'serve_sample_rows_total{{path="{path}"}} {n}')
         counter("serve_shed_total", self.shed)
         counter("serve_deadline_expired_total", self.deadline_expired)
         counter("serve_quarantined_total", self.quarantined)

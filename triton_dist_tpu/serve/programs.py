@@ -28,7 +28,9 @@ from triton_dist_tpu.models.generate import (
 )
 from triton_dist_tpu.models.sampling import (
     sample_logits_rowwise,
+    sample_logits_rowwise_path,
     sample_positions_rowwise,
+    takes_candidates,
 )
 from triton_dist_tpu.models.speculative import accept_chain_rowwise
 from triton_dist_tpu.runtime.profiling import region
@@ -240,7 +242,10 @@ def _paged_decode_horizon(params, pools, tables, kv_lens, token, active,
     :func:`_paged_decode_forward` with its keywords bound; one
     that returns more than ``(pools, logits)`` (a model family's counters:
     ``Generator.wrap_program``) has each extra output summed over the
-    steps and appended.
+    steps and appended.  LAST, on a vocabulary whose sampler
+    ``takes_candidates`` (greedy-only variant included: 0): the sampled
+    row-steps whose cut-offs came from the whole rows
+    (``summary()["sample"]``).
     """
     # ``base_keys`` are HOST-built per-row typed keys (the engine stacks
     # jax.random.key(p.seed) — the exact call `_choose_token` makes, so
@@ -261,14 +266,22 @@ def _paged_decode_horizon(params, pools, tables, kv_lens, token, active,
                                          token, live)
         kv_lens = kv_lens + live.astype(kv_lens.dtype)
         with region("sample"):
+            wide = takes_candidates(logits.shape[-1])
+            whole = None
             if all_greedy:
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             else:
                 keys = jax.vmap(jax.random.fold_in)(base_keys, counts)
-                nxt = sample_logits_rowwise(logits, keys, temperature=temps,
-                                            top_k=top_ks, top_p=top_ps,
-                                            greedy=greedy)
+                # a frozen row's token is discarded: on the candidate plan
+                # its knobs must not send the batch to the whole rows
+                nxt, whole = sample_logits_rowwise_path(
+                    logits, keys, temperature=temps, top_k=top_ks,
+                    top_p=top_ps, greedy=greedy | ~live if wide else greedy)
             nxt = jnp.where(live, nxt, token)
+            if wide:
+                # sampled rows of a step whose cut-offs the whole rows gave
+                aux.append(jnp.zeros((), jnp.int32) if whole is None else
+                           jnp.sum(live & ~greedy & whole, dtype=jnp.int32))
         counts = counts + live.astype(counts.dtype)
         eos_done = eos_done | (live & has_eos & (nxt == eos_ids))
         return (pools, kv_lens, nxt, eos_done, counts), (nxt, live, *aux)
