@@ -251,6 +251,7 @@ _FAST_GATE_MODULES = {
     # interpreter; the parameter count; every refusal beside a state
     # group by name (~3 min).
     "test_ssm_yoco",
+    "test_gdn_hybrid",
 }
 
 # Heavy tests inside core modules whose coverage is duplicated by a

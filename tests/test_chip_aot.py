@@ -742,6 +742,27 @@ def test_swa_moe_programs_compile_at_published_widths(v5e, as_tpu):
 # ---------------------------------------------------------------------------
 
 
+def _group_planes(cfg, gen, blocks, page, lead=None, extent=None):
+    """Per layer what the engine builds from ``gen.kv_groups``: K and V
+    pages of the layer's group (or, with ``extent``, a request's scratch
+    rows), a state group's slot planes (``lead`` slots: the pool's
+    ``blocks`` unless given), or nothing."""
+    s = jax.ShapeDtypeStruct
+    out = [()] * cfg.n_layers
+    for g, nb in zip(gen.kv_groups, blocks):
+        for li in g["layers"]:
+            if "state_planes" in g:
+                out[li] = tuple(s((lead or nb, *sh), dt)
+                                for sh, dt in g["state_planes"])
+            elif extent:
+                out[li] = tuple(s((1, h, extent, d), cfg.dtype)
+                                for h, d in gen.kv_planes)
+            else:
+                out[li] = tuple(s((nb, h, page, d), cfg.dtype)
+                                for h, d in gen.kv_planes)
+    return out
+
+
 def _ssm_yoco_programs():
     """Every program of ``phi4mf_reason96_sat`` at the file's widths and
     engine sizes over its THREE cache groups (the full group's 2,560
@@ -783,23 +804,7 @@ def _ssm_yoco_programs():
     groups = gen.kv_groups
     assert [len(g["layers"]) for g in groups] == [1, 8, 9]
 
-    def planes(lead, extent=None):
-        """Per layer: K and V pages (or scratch rows), a slot of state, or
-        nothing — what the engine builds from ``kv_groups``."""
-        out = [()] * cfg.n_layers
-        for g, nb in zip(groups, blocks):
-            for li in g["layers"]:
-                if "state_planes" in g:
-                    out[li] = tuple(s((lead or nb, *sh), dt)
-                                    for sh, dt in g["state_planes"])
-                elif extent:
-                    out[li] = tuple(s((1, h, extent, d), cfg.dtype)
-                                    for h, d in gen.kv_planes)
-                else:
-                    out[li] = tuple(s((nb, h, page, d), cfg.dtype)
-                                    for h, d in gen.kv_planes)
-        return out
-
+    planes = functools.partial(_group_planes, cfg, gen, blocks, page)
     pools = planes(None)
     d_args, h_args = _decode_args(cfg, page, batch=batch, max_seq=max_seq,
                                   params=params, pools=pools)
@@ -857,6 +862,101 @@ def test_ssm_yoco_programs_compile_at_published_widths(v5e, as_tpu):
                 statics, _gib(compiled), before)
             assert text.count(" conditional(") == (
                 0 if statics["all_greedy"] else 1)
+    print("GiB a program:", {k: round(v, 2) for k, v in worst.items()})
+
+
+def _gdn_hybrid_programs():
+    """Every program of ``olmoh_l8_reason96_sat`` at the file's widths and
+    engine sizes over its TWO cache groups (the full group's blocks on
+    layers 3 and 7, the state group's 97 slots on the six linear layers)
+    -> ``[(name, jitted, args, statics, the Mosaic calls it must hold)]``:
+    a decode step 2 ``gqa_paged_full`` + 6 ``gdn_step``; a prefill chunk 6
+    ``gdn_chunk`` and flash attention on 2 layers (``None``: counted by
+    the test)."""
+    import json
+    import os
+
+    from benchmarks import builders_gdn_hybrid
+    from triton_dist_tpu.models import gdn_hybrid as GH
+    from triton_dist_tpu.runtime.jit_cache import named
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(
+            root, "benchmarks/configs/olmo-hybrid-7b-l8.json")) as f:
+        config = json.load(f)
+    cfg = builders_gdn_hybrid.model_config(config)
+    eng = config["engine"]
+    batch, page, max_seq = eng["max_batch"], eng["page_size"], eng["max_seq"]
+    chunk = eng["prefill_chunk"]
+    s = jax.ShapeDtypeStruct
+    params = jax.eval_shape(functools.partial(GH.init_params, cfg),
+                            jax.random.key(0))
+    gen = GH.GdnHybridGenerator(cfg, max_seq=max_seq)
+    ladder = E.build_bucket_ladder(max(page, chunk), max_seq, page)
+    assert ladder == [512, 1024, 2048, 4096, 5120]
+    assert gen.kernel_gaps(page_size=page, ladder=ladder,
+                           prefill_chunk=chunk) == {}
+    assert gen.kv_planes == [(30, 128)] * 2
+    blocks = [eng["num_blocks"], 1 + batch]
+    groups = gen.kv_groups
+    assert [g["layers"] for g in groups] == [(3, 7), (0, 1, 2, 4, 5, 6)]
+    assert [sh for sh, _ in groups[1]["state_planes"]] == [(270, 128),
+                                                           (96, 5760)]
+
+    planes = functools.partial(_group_planes, cfg, gen, blocks, page)
+    pools = planes(None)
+    d_args, h_args = _decode_args(cfg, page, batch=batch, max_seq=max_seq,
+                                  params=params, pools=pools)
+    tables = s((2, batch, max_seq // page), I32)
+    d_args = d_args[:2] + (tables,) + d_args[3:]
+    h_args = h_args[:2] + (tables,) + h_args[3:]
+    kw = dict(cfg=cfg, page=page, **gen.serve_hooks())
+    want = {"gqa_paged_full": 2, "gdn_step": 6}
+    programs = [p + (want,) for p in _decode_programs(
+        gen, kw, d_args, h_args, eng["horizon"])]
+    fill = jax.jit(named(PR._fill_pool_pages, "fill_pages", page=page,
+                         kinds=cfg.kinds), donate_argnums=(0,))
+    for extent in ladder:
+        sc = planes(1, extent)
+        programs.append((
+            "prefill_chunk", gen._chunk_jit,
+            (params, s((1, chunk), I32), sc, s((), I32)),
+            dict(quantized=False, extent=extent, n_valid=s((), I32)), None))
+        programs.append(("fill_pages", fill,
+                         (pools, sc, s((2, extent // page), I32)), {}, {}))
+    return programs
+
+
+def test_gdn_hybrid_programs_compile_at_published_widths(v5e, as_tpu):
+    """Every program of ``olmoh_l8_reason96_sat`` — single-step decode, the
+    fused horizon (greedy and mixed) and its one-step link, prefill chunks
+    on every rung, the page-and-state fill — compiled for the v5e at the
+    published widths (8 layers, 100,352 rows, 96 rows' 13.7 MB states and
+    196,608 cached tokens of 30 KV heads beside 4.9 GB of weights): the
+    paged call carries its layer kind's name and the delta rule's two calls
+    their own (the readers' patterns), the state pool is stepped in place
+    (no program holds a second copy of it), and each program fits the
+    chip beside nothing else."""
+    worst = {}
+    state_pool = 97 * 96 * 5760 * 4
+    for prog, jitted, args, statics, want in _gdn_hybrid_programs():
+        compiled = _compiled(v5e, jitted, args, statics)
+        text = compiled.as_text()
+        assert text.split(",", 1)[0] == f"HloModule jit_{prog}"
+        calls = _mosaic_names(text)
+        if want is not None:
+            assert calls == want, (prog, statics, calls)
+        else:
+            assert calls["gdn_chunk"] == 6, calls
+            assert sum(calls.values()) == 6 + 2, calls      # + flash calls
+        assert _gib(compiled) < HBM_GIB, (prog, statics, _gib(compiled))
+        worst[prog] = max(worst.get(prog, 0), _gib(compiled))
+        if prog in ("paged_decode", "decode_horizon"):
+            # the six state pools are 1.29 GB: a step that gathered,
+            # updated and scattered them would hold copies as temporaries
+            ma = compiled.memory_analysis()
+            assert ma.temp_size_in_bytes < state_pool, (
+                prog, statics, ma.temp_size_in_bytes)
     print("GiB a program:", {k: round(v, 2) for k, v in worst.items()})
 
 

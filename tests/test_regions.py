@@ -29,6 +29,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh
 
+from triton_dist_tpu.models import gdn_hybrid as GH
 from triton_dist_tpu.models import generate as G
 from triton_dist_tpu.models import llama
 from triton_dist_tpu.models import mla_moe as M
@@ -40,12 +41,12 @@ from triton_dist_tpu.serve import ServeEngine
 from triton_dist_tpu.serve import programs as PR
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FAMILIES = ("dense", "latent", "sparse", "window", "state")
+FAMILIES = ("dense", "latent", "sparse", "window", "state", "matrix")
 # the four families the benchmark had before the layer loop took a ``mixer``
 QUARTET_FAMILIES = FAMILIES[:4]
 PROGRAMS = ("decode_horizon", "prefill_chunk", "paged_decode")
 # the modules that open regions (they import ``region`` by name)
-SCOPED = (G, M, PR, Y)
+SCOPED = (G, M, PR, Y, GH)
 I32 = jnp.int32
 B, H, CHUNK = 2, 4, 64
 
@@ -96,6 +97,10 @@ def _build(family):
         cfg = Y.SsmYocoConfig.tiny()
         gen = Y.SsmYocoGenerator(cfg, max_seq=256)
         params = Y.init_params(cfg, jax.random.key(3))
+    elif family == "matrix":
+        cfg = GH.GdnHybridConfig.tiny()
+        gen = GH.GdnHybridGenerator(cfg, max_seq=256)
+        params = GH.init_params(cfg, jax.random.key(3))
     else:
         cfg = S.SwaMoeConfig.tiny(n_layers=4)
         gen = S.SwaMoeGenerator(cfg, max_seq=256)
@@ -266,6 +271,10 @@ _SEAM_PRODUCTS = {
     "ssm.scan": ("state", "decode_horizon", "exp"),
     "ssm.out": ("state", "prefill_chunk", "dot_general"),
     "gmu": ("state", "decode_horizon", "dot_general"),
+    "gdn.in": ("matrix", "decode_horizon", "dot_general"),
+    "gdn.conv": ("matrix", "prefill_chunk", "dynamic_slice"),
+    "gdn.rule": ("matrix", "decode_horizon", "exp"),
+    "gdn.out": ("matrix", "prefill_chunk", "dot_general"),
 }
 
 

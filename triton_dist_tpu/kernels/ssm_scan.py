@@ -156,10 +156,11 @@ def causal_conv(x, carry, w, b, n_valid=None):
     before a request's first token): ``silu(b + sum_k w[k] * x_{t-K+1+k})``
     with ``w`` [K, E].  -> (the result [B, T, E] in ``x``'s dtype, the
     carry for the rows after: the last ``K - 1`` inputs — of the first
-    ``n_valid`` rows where a chunk's tail is padding)."""
+    ``n_valid`` rows where a chunk's tail is padding).  ``b`` may be
+    ``None``: a convolution without a bias."""
     T, K = x.shape[1], w.shape[0]
     seq = jnp.concatenate([carry.astype(x.dtype), x], axis=1)
-    acc = b.astype(jnp.float32)
+    acc = 0.0 if b is None else b.astype(jnp.float32)
     for k in range(K):
         acc = acc + w[k].astype(jnp.float32) * seq[:, k:k + T]
     at = T if n_valid is None else n_valid

@@ -42,7 +42,7 @@ class LayerKind:
     family's ``mixer`` read ``kinds[li]``.  A model with one kind of layer
     passes none.
 
-    ``attn`` names the layer's mixer, five kinds:
+    ``attn`` names the layer's mixer, six kinds:
 
     * ``"full"`` / ``"window"`` — causal attention over the whole context /
       the last ``window`` positions (``models/swa_moe.py``), writing its own
@@ -52,18 +52,26 @@ class LayerKind:
       (``models/ssm_yoco.py``: one cache, eight readers) and holds none;
     * ``"ssm"`` — a selective state-space layer: no cache that grows, a
       fixed state a request (its group is a STATE group);
+    * ``"linear"`` — a linear-attention layer (``models/gdn_hybrid.py``):
+      a fixed MATRIX state a head a request, in a STATE group like ``ssm``;
     * ``"gmu"`` — a gate over what an earlier state-space layer handed
       down for the same token: no cache, no state.
 
     ``window`` is the attention's reach in positions (0: the whole
     context).  ``group`` is the cache group — block table and pool
     geometry (serve/block_manager.py ``KvGroups``) — whose table the layer
-    reads: its own for ``full`` / ``window`` / ``ssm``, the full layer's
-    for ``cross``, -1 for a layer that reads none (``gmu``)."""
+    reads: its own for ``full`` / ``window`` / ``ssm`` / ``linear``, the
+    full layer's for ``cross``, -1 for a layer that reads none (``gmu``)."""
 
     attn: str = "full"
     window: int = 0
     group: int = 0
+
+    @property
+    def state(self) -> bool:
+        """The layer holds a fixed state a request (a slot of a STATE
+        group) and no cache that grows."""
+        return self.attn in ("ssm", "linear")
 
     @property
     def call_name(self) -> str:
@@ -509,7 +517,12 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     own addressing (a decode step's state slots, a chunk's valid rows).
     Without ``mixer`` nothing of this is traced: the program is the one it
     was (tests/test_regions.py).  The norm and the head are the config's
-    (:func:`_norm`, :func:`_head`): data, not a branch a family.
+    (:func:`_norm`, :func:`_head`): data, not a branch a family.  So is
+    WHERE the norm sits: ``cfg.norm_after`` (the Olmo-2/3 lineage,
+    ``models/gdn_hybrid.py``) puts it on a sub-layer's OUTPUT — ``x +
+    norm(mixer(x))``, ``x + norm(ffn(x))`` — the mixer is then handed the
+    residual stream itself and norms its own rows (inside its last region),
+    and the MLP's output is normed here.
 
     The residual stream is ``[B, T, D]`` — and ``[B, D]`` at T = 1, by
     evidence, not taste (PERF.md §6, PR 28): the chip's compiler folds
@@ -520,12 +533,13 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     want it: 11.4 against 12.4 ms a chunk with flat rows) and a decode
     step does not (12.7 against 12.4 ms a step with its rows flat)."""
     B, T = tokens.shape
+    post = getattr(cfg, "norm_after", False)
     with region("embed"):
         x = params["embed"][tokens.reshape((B,) if T == 1 else (B, T))]
     new_caches = []
     for li, layer in enumerate(params["layers"]):
         if mixer is not None:
-            h = _norm(x, layer, "attn_norm", cfg)
+            h = x if post else _norm(x, layer, "attn_norm", cfg)
             rows, cache, shared = mixer(
                 li, h.reshape(B, T, -1), layer, pos, caches[li], shared,
                 write_kv=write_kv, attend=attend)
@@ -542,8 +556,13 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
                 o2 = o.reshape(B * T, -1).astype(cfg.dtype)
                 x = x + out_proj(o2, layer).reshape(x.shape)
         with region("ffn"):
-            h2 = _norm(x, layer, "mlp_norm", cfg)
-            x = x + ffn(h2.reshape(B * T, -1), layer).reshape(x.shape)
+            if post:
+                y = _norm(ffn(x.reshape(B * T, -1), layer), layer,
+                          "mlp_norm", cfg)
+            else:
+                h2 = _norm(x, layer, "mlp_norm", cfg)
+                y = ffn(h2.reshape(B * T, -1), layer)
+            x = x + y.reshape(x.shape)
         new_caches.append(cache)
     with region("head"):
         logits = _head(_norm(x, params, "final_norm", cfg), params, cfg)

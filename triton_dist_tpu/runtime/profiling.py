@@ -184,6 +184,7 @@ REGIONS = (
     "moe.route", "moe.align", "moe.experts", "moe.combine", "moe.shared",
     "dsa.index", "dsa.select", "mla.expand", "sample",
     "ssm.in", "ssm.conv", "ssm.scan", "ssm.out", "gmu",
+    "gdn.in", "gdn.conv", "gdn.rule", "gdn.out",
 )
 REGION_PREFIX = "rg_"
 

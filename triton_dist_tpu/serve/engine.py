@@ -614,6 +614,13 @@ class ServeEngine:
             self.prefill_width = prefill_chunk
         self.metrics = ServeMetrics()
         self.metrics.prefill_width = self.prefill_width
+        if self._has_state:
+            sg = _state_group(self.kv_groups)
+            self.metrics.state_kind = sg.get("kind", "ssm")
+            self.metrics.state_layers = len(sg["layers"])
+            self.metrics.state_bytes_per_request = len(sg["layers"]) * sum(
+                math.prod(sh) * jnp.dtype(dt).itemsize
+                for sh, dt in sg["state_planes"])
         # flight recorder (docs/observability.md): a bounded ring of
         # typed engine events — submit/admit/prefill/decode drains, spec
         # rounds, preemptions, COW splits, faults, retirements — that

@@ -314,6 +314,12 @@ class ServeMetrics:
     state_resets: int = 0
     state_recomputed_tokens: int = 0
     ssm_scan_tokens: int = 0
+    # what the state group holds, stamped by the engine: the kind of state
+    # ("ssm": a selective scan's; "gdn": a gated delta rule's matrices),
+    # the layers that hold one and the bytes of one request's slot
+    state_kind: str = ""
+    state_layers: int = 0
+    state_bytes_per_request: int = 0
     yoco_shared_tokens: int = 0
     yoco_window_tokens: int = 0
     # the sampler inside the decode horizon (models/sampling.py): sampled
@@ -608,6 +614,25 @@ class ServeMetrics:
                 "state_resets": self.state_resets,
                 "state_recomputed_tokens": self.state_recomputed_tokens,
                 "scan_tokens": self.ssm_scan_tokens}
+
+    def gdn_stats(self) -> dict:
+        """summary()["gdn"]: a linear-attention state group — the bytes of
+        one request's slot (every layer's matrix state and carried
+        convolution inputs), the slots held and their peak, the
+        ``gdn_chunk`` and ``gdn_step`` calls made (a call a linear layer a
+        prefill chunk / a decode step) and the prompt tokens that went
+        through the rule ({} where the state is of another kind)."""
+        if self.state_kind != "gdn":
+            return {}
+        st = self.state_group()
+        return {"state_bytes_per_request": self.state_bytes_per_request,
+                "state_slots_in_use": st.get("in_use", 0),
+                "state_slots_peak": st.get("peak", 0),
+                "chunk_calls": self.prefill_dispatches * self.state_layers,
+                "step_calls": self.decode_steps * self.state_layers,
+                "rule_tokens": self.ssm_scan_tokens,
+                "state_resets": self.state_resets,
+                "state_recomputed_tokens": self.state_recomputed_tokens}
 
     def yoco_stats(self) -> dict:
         """summary()["yoco"]: cached tokens the decode queries read
@@ -1113,6 +1138,7 @@ class ServeMetrics:
             "dsa": self.dsa_stats(),
             "swa": self.swa_stats(),
             "ssm": self.ssm_stats(),
+            "gdn": self.gdn_stats(),
             "yoco": self.yoco_stats(),
             "sample": self.sample_stats(),
             "spec": self.spec_stats(),

@@ -141,7 +141,7 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
     where = [slots(t, kv_lens, active, page=page) for t in by_group]
     more = {}
     if mixer is not None:
-        state = [k.group for k in kinds if k.attn == "ssm"]
+        state = [k.group for k in kinds if k.state]
         more = {"mixer": mixer, "shared": {} if not state else {
             "slot": jnp.where(active, by_group[state[0]][:, 0], 0)}}
 
@@ -567,7 +567,7 @@ def _fill_pool_pages(pools, scratch, block_ids, *, page, kinds=None):
     if len(by_group) == 1:
         return fill(block_ids, pools, scratch)
     return [pool if not pool else
-            (fill_state if kinds[li].attn == "ssm" else fill)(
+            (fill_state if kinds[li].state else fill)(
                 by_group[group_of(li)], pool, sc)
             for li, (pool, sc) in enumerate(zip(pools, scratch, strict=True))]
 
