@@ -302,20 +302,18 @@ def engine_logits(engine, prompt, first_token=None):
     the horizon's links scan."""
     import jax.numpy as jnp
 
-    cfg, page = engine.cfg, engine.page
+    page = engine.page
     n = int(prompt.shape[0])
     chunk = engine.prefill_width     # rows of one prefill call
     ext = engine._bucket_s_ext(n)
-    shape = (1, cfg.n_kv_heads, ext, cfg.head_dim)
-    scratch = [(jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype))
-               for _ in range(cfg.n_layers)]
+    scratch = engine._zero_fn(s_ext=ext)
     for pos in range(0, n, chunk):
         c = min(chunk, n - pos)
         buf = np.zeros((1, chunk), np.int32)
         buf[0, :c] = prompt[pos:pos + c]
         scratch, logits = engine._chunk_fn(
-            engine.params, jnp.asarray(buf), scratch, jnp.int32(pos),
-            quantized=False, extent=ext, n_valid=jnp.int32(c))
+            engine.params, jnp.asarray(buf), scratch, np.int32(pos),
+            quantized=False, extent=ext, n_valid=np.int32(c))
     prefill_last = np.asarray(logits[0, c - 1], np.float32)
     if first_token is None:
         first_token = int(prefill_last.argmax())

@@ -89,6 +89,12 @@ _FAST_GATE_MODULES = {
     # metrics) + the r5 regression fixes run in the gate; the end-to-end
     # engine-vs-oracle tests carry explicit @pytest.mark.slow.
     "test_serve_engine",
+    # a request's way into the batch (ISSUE 41): a cold admission is ONE
+    # named launch with the scratch of the loop it replaced, no eager
+    # launch from admission to the last token, one compile a rung — on a
+    # toy engine of each family, int8 pools, a 2-device mesh, a draft
+    # (~3 min: the latent engines warm up in the interpreter).
+    "test_serve_admit",
     # failure containment: the deterministic chaos drain (fixed
     # FaultInjector schedule -> exact SHED/DEADLINE/ERROR accounting,
     # bit-exact untouched streams, whole free list) + watchdog/heartbeat

@@ -267,6 +267,29 @@ def test_named_programs_keep_the_operation_names_the_benchmark_reads(
         module, names = calls(horizon, *h_args, H=H, all_greedy=all_greedy)
         assert module == "HloModule jit_decode_horizon"
         assert names == {"closed_call": LAYERS}, (H, all_greedy)
+    # a cold request's scratch (PR 41) is a program of NO arguments: what
+    # it is compiled for is where its outputs live — one chip, or born on
+    # a TP-4 mesh under the spec the chunk program takes it on
+    zero = jax.jit(
+        named(PR._zero_scratch, "zero_scratch", quantized=False,
+              dtype=cfg.dtype,
+              specs=[((cfg.n_kv_heads, cfg.head_dim),) * 2] * LAYERS),
+        static_argnames=("s_ext",),
+        out_shardings=SingleDeviceSharding(v5e.devices[0]))
+    text = zero.lower(s_ext=max_seq).compile().as_text()
+    assert text.split(",", 1)[0] == "HloModule jit_zero_scratch"
+    assert not _mosaic_names(text)
+    mesh = Mesh(np.array(v5e.devices), ("tp",))
+    progs = serve_mesh.build_programs(
+        mesh=mesh, tp_axis="tp", kv_shard="heads", cfg=cfg,
+        params=d_args[0], page_size=128, num_blocks=num_blocks,
+        n_pages_max=max_seq // 128, impl="auto", interpret=False, horizon=8)
+    born = progs["zero_scratch"]._prog((("s_ext", max_seq),)).lower().compile()
+    assert born.as_text().split(",", 1)[0] == "HloModule jit_zero_scratch"
+    k, v = born.output_shardings[0]
+    assert k == v == progs["prefill_chunk"]._maker(max_seq)._placements[2][0][0]
+    assert jax.tree.leaves(born.out_info)[0].shape == (
+        1, cfg.n_kv_heads, max_seq, cfg.head_dim)
 
 
 @pytest.mark.parametrize("hkv", [8, 2, 1])

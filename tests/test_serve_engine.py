@@ -539,7 +539,7 @@ def test_prefill_summary_counts_calls_and_padding():
     W = eng.prefill_width
     assert W == 128 and eng.metrics.summary()["prefill"] == {
         "tokens": 0, "dispatches": 0, "tokens_per_dispatch": 0.0,
-        "pad_share": 0.0, "width": 128}
+        "pad_share": 0.0, "width": 128, "scratch_dispatches": 0}
     shares, plan = [], eng.scheduler.prefill_plan
 
     def recorded(prefilling):
@@ -564,9 +564,13 @@ def test_prefill_summary_counts_calls_and_padding():
     assert f"serve_prefill_dispatches_total {n_calls}" in text
     assert (f"serve_prefill_pad_tokens_total "
             f"{n_calls * W - sum(lens)}") in text
+    # one launch a cold request on its way in, whatever its planes
+    assert got["scratch_dispatches"] == len(lens)
+    assert f"serve_prefill_scratch_dispatches_total {len(lens)}" in text
     # the fleet's aggregate adds the counters and keeps the width
     both = ServeMetrics().merge(eng.metrics).merge(eng.metrics)
     assert both.prefill_stats()["dispatches"] == 2 * n_calls
+    assert both.prefill_stats()["scratch_dispatches"] == 2 * len(lens)
     assert both.prefill_stats()["width"] == W
     assert both.prefill_stats()["pad_share"] == got["pad_share"]
 

@@ -165,6 +165,7 @@ from triton_dist_tpu.serve.programs import (
     _sample_token,
     _spec_round_fused,
     _splice_draft_rows,
+    _zero_scratch,
 )
 from triton_dist_tpu.serve.recovery import (
     JOURNAL_NAME,
@@ -967,8 +968,18 @@ class ServeEngine:
             for nb, planes in zip(layer_blocks, self._plane_specs)]
         self._sample_fn = CountingJit(
             jax.jit(named(_sample_token, "sample_token")), "sample_token")
+        # A cold request's scratch: ONE program of no arguments a rung
+        # (its tree is a function of ``_plane_specs`` alone), where an
+        # eager ``jnp.zeros`` a plane is two launches a plane with the
+        # chip idle.  On a mesh it is born on the chunk program's spec.
+        self._zero_fn = CountingJit(
+            self._mesh_progs["zero_scratch"] if mesh is not None
+            else jax.jit(named(
+                _zero_scratch, "zero_scratch", specs=self._plane_specs,
+                quantized=self.kv_quant, dtype=cfg.dtype),
+                static_argnames=("s_ext",)), "zero_scratch")
         for c in (self._chunk_fn, self._fill_fn, self._decode_fn,
-                  self._sample_fn):
+                  self._sample_fn, self._zero_fn):
             self.metrics.register_compiled(c)
         if self.horizon > 1:
             self.metrics.register_compiled(self._horizon_fn)
@@ -1063,11 +1074,20 @@ class ServeEngine:
                     jax.jit(named(_splice_draft_rows, "draft_join"),
                             donate_argnums=(0, 1, 2)),
                     "draft_join")
-            self.metrics.register_compiled(self._draft_chunk_fn)
-            self.metrics.register_compiled(self._draft_join_fn)
+            dcfg = draft.cfg
+            # the draft's temp caches, from the same body (K and V a layer)
+            self._draft_zero_fn = CountingJit(
+                self._mesh_progs["draft_zero_scratch"] if mesh is not None
+                else jax.jit(named(
+                    _zero_scratch, "draft_zero_scratch",
+                    specs=[((dcfg.n_kv_heads, dcfg.head_dim),) * 2]
+                    * dcfg.n_layers, quantized=False, dtype=dcfg.dtype),
+                    static_argnames=("s_ext",)), "draft_zero_scratch")
+            for c in (self._draft_chunk_fn, self._draft_join_fn,
+                      self._draft_zero_fn):
+                self.metrics.register_compiled(c)
             self._last_logits = jnp.zeros((max_batch, cfg.vocab),
                                           jnp.float32)
-            dcfg = draft.cfg
             self._draft_state = GenerationState(
                 caches=[(jnp.zeros((max_batch, dcfg.n_kv_heads,
                                     draft.max_seq, dcfg.head_dim),
@@ -1968,9 +1988,11 @@ class ServeEngine:
                 except Exception as e:
                     if not self._state_intact():
                         raise  # pools consumed: engine-fatal
-                    # the warm-prefix gather is the only device call here;
-                    # it reads (never donates) the pools, so a failure is
-                    # per-request by construction — quarantine and serve on
+                    # two device calls can fail here, the warm-prefix
+                    # gather (reads the pools) and the cold scratch's
+                    # zero program (reads nothing): neither donates, so a
+                    # failure is per-request by construction — quarantine
+                    # and serve on
                     finished.append(self._quarantine(rs, f"prefill start: "
                                                          f"{e!r}"))
 
@@ -2187,7 +2209,6 @@ class ServeEngine:
                             # Draft-side prefix programs: the draft
                             # pool gather + scatter per draft-ladder
                             # rung (all-null ids -> block 0 only).
-                            dcfg = self.draft.cfg
                             for rung in self._draft_ladder:
                                 ids = jnp.asarray(np.zeros(
                                     (rung // self.page,), np.int32))
@@ -2195,14 +2216,9 @@ class ServeEngine:
                                     "draft_load_pages", (),
                                     self._draft_load_fn,
                                     self._draft_pools, ids)
-                                scratch = [
-                                    (jnp.zeros((1, dcfg.n_kv_heads,
-                                                rung, dcfg.head_dim),
-                                               dcfg.dtype),
-                                     jnp.zeros((1, dcfg.n_kv_heads,
-                                                rung, dcfg.head_dim),
-                                               dcfg.dtype))
-                                    for _ in range(dcfg.n_layers)]
+                                scratch = self._device_call(
+                                    "draft_zero_scratch", (),
+                                    self._draft_zero_fn, s_ext=rung)
                                 self._draft_pools = self._device_call(
                                     "draft_fill_pages", (),
                                     self._draft_fill_fn,
@@ -2221,7 +2237,7 @@ class ServeEngine:
                                     (rung // self.page,), np.int32)))
                         self._pools = self._device_call(
                             "cow_copy", (), self._cow_fn, self._pools,
-                            jnp.int32(0), jnp.int32(0))
+                            np.int32(0), np.int32(0))
                     for rid in [r for r in self._outputs
                                 if r.startswith("__warmup_")]:
                         if (self._outputs[rid].finish_reason
@@ -2375,7 +2391,6 @@ class ServeEngine:
             f"{need} (prompt {n_prompt})")
 
     def _start_prefill(self, rs: ReqState) -> None:
-        cfg = self.cfg
         s_ext = self._bucket_s_ext(int(rs.prompt_tokens.shape[0]))
         rs.s_ext = s_ext
         cached = rs.cached_prefix if self.prefix_cache else 0
@@ -2399,23 +2414,16 @@ class ServeEngine:
                 self._pools, jnp.asarray(ids))
             self.metrics.prefix_skipped_tokens += start
             return
-        if self.kv_quant:
-            # quantized scratch in the pool layout: chunked prefill
-            # quantizes each chunk's rows as it writes them (the
-            # generate._write_chunk convention), so fill_pages moves
-            # finished bytes + scales into the pool verbatim.
-            def _zs(h, d):
-                return {"q": jnp.zeros((1, h, s_ext, d), jnp.int8),
-                        "s": jnp.zeros((1, h, s_ext), jnp.float32)}
-        else:
-            def _zs(h, d):
-                return jnp.zeros((1, h, s_ext, d), cfg.dtype)
-        # one scratch plane per pool plane (K and V; or the latent row; a
-        # state-space layer's: the request's state, from zero)
-        rs.scratch = [
-            tuple(_zs(*p) if isinstance(p[0], int) else
-                  jnp.zeros((1, *p[0]), p[1]) for p in planes)
-            for planes in self._plane_specs]
+        # One scratch plane per pool plane (K and V; or the latent row; a
+        # state-space layer's: the request's state, from zero), all from
+        # ONE launch.  A quantized scratch is in the pool layout: chunked
+        # prefill quantizes each chunk's rows as it writes them (the
+        # generate._write_chunk convention), so fill_pages moves finished
+        # bytes + scales into the pool verbatim.
+        rs.scratch = self._device_call(
+            "zero_scratch", (rs.req.request_id,), self._zero_fn,
+            s_ext=s_ext)
+        self.metrics.scratch_dispatches += 1
         if self._has_state:
             self.metrics.state_resets += 1
             if rs.generated:        # a preempted request's second life
@@ -2443,7 +2451,9 @@ class ServeEngine:
                 buf = np.zeros((1, width), np.int32)
                 buf[0, :n_fed] = prompt[at:at + n_fed]
                 buf_d = jnp.asarray(buf)
-                pos_d, valid_d = jnp.int32(at), jnp.int32(n_fed)
+                # host numbers, transferred by the call itself: an eager
+                # jnp.int32 is a launch of its own for four bytes
+                pos_d, valid_d = np.int32(at), np.int32(n_fed)
             rs.scratch, logits, *aux = self._device_call(
                 "prefill_chunk", (rs.req.request_id,), self._chunk_fn,
                 self.params, buf_d, rs.scratch, pos_d,
@@ -2535,7 +2545,6 @@ class ServeEngine:
         S0 = int(prompt.shape[0])
         chunk = self.scheduler.prefill_chunk
         page = self.page
-        dcfg = self.draft.cfg
         ext = self._draft_bucket(S0)
         table = (self.bm.table(rid) if self._draft_pools is not None
                  else [])
@@ -2560,12 +2569,9 @@ class ServeEngine:
                 self._draft_pools, jnp.asarray(ids))
             self.metrics.draft_prefix_skipped_tokens += start
         else:
-            caches = [
-                (jnp.zeros((1, dcfg.n_kv_heads, ext, dcfg.head_dim),
-                           dcfg.dtype),
-                 jnp.zeros((1, dcfg.n_kv_heads, ext, dcfg.head_dim),
-                           dcfg.dtype))
-                for _ in range(dcfg.n_layers)]
+            caches = self._device_call(
+                "draft_zero_scratch", (rid,), self._draft_zero_fn,
+                s_ext=ext)
         logits = None
         n_last = 0
         for off in range(start, S0, chunk):
@@ -2575,8 +2581,8 @@ class ServeEngine:
             caches, logits = self._device_call(
                 "draft_prefill", (rid,), self._draft_chunk_fn,
                 self.draft_params, jnp.asarray(buf), caches,
-                jnp.int32(off), quantized=False, extent=ext,
-                n_valid=jnp.int32(c))
+                np.int32(off), quantized=False, extent=ext,
+                n_valid=np.int32(c))
             n_last = c
         if self._draft_pools is not None:
             # Commit the draft's prompt pages (before the splice — the
@@ -2598,8 +2604,8 @@ class ServeEngine:
         sd = self._draft_state
         new_caches, kv_lens, last_logits = self._device_call(
             "draft_join", (rid,), self._draft_join_fn, sd.caches,
-            sd.kv_lens, sd.last_logits, caches, jnp.int32(rs.slot),
-            jnp.int32(S0), logits[0, n_last - 1])
+            sd.kv_lens, sd.last_logits, caches, np.int32(rs.slot),
+            np.int32(S0), logits[0, n_last - 1])
         self._draft_state = GenerationState(
             caches=new_caches, kv_lens=kv_lens, last_logits=last_logits)
 
@@ -2926,6 +2932,9 @@ class ServeEngine:
         class) and its allowed collective seams (world-1 programs allow
         none; mesh programs declare ``serve.mesh.collective_seams``)."""
         ladders = {
+            "zero_scratch": {"s_ext": tuple(self.ladder)},
+            "draft_zero_scratch": {
+                "s_ext": tuple(getattr(self, "_draft_ladder", ()))},
             "decode_horizon": {"H": tuple(self.h_ladder),
                                "all_greedy": (True, False)},
             "spec_round": {"K": tuple(getattr(self, "_k_ladder", ())),
@@ -3146,7 +3155,7 @@ class ServeEngine:
                             logical=logical)
             self._pools = self._device_call(
                 "cow_copy", (rid,), self._cow_fn, self._pools,
-                jnp.int32(old), jnp.int32(new))
+                np.int32(old), np.int32(new))
 
     def _commit_full_blocks(self, rs: ReqState) -> None:
         """Register every newly-FULL logical page of ``rs`` in the
@@ -3402,7 +3411,7 @@ class ServeEngine:
             # the carry arrays never touch the host between links.
             kv_d = jnp.asarray(lens)
             tok_d = jnp.asarray(tokens)
-            done_d = jnp.zeros((B,), bool)
+            done_d = jnp.asarray(np.zeros((B,), bool))
             cnt_d = jnp.asarray(counts)
             tables_d = jnp.asarray(tables)
             active_d = jnp.asarray(active)
@@ -3617,7 +3626,7 @@ class ServeEngine:
 
             kv_d = jnp.asarray(lens)
             act_d = jnp.asarray(active)
-            done_d = jnp.zeros((B,), bool)
+            done_d = jnp.asarray(np.zeros((B,), bool))
             tables_d = jnp.asarray(tables)
             cnt_d = jnp.asarray(counts)
             lim_d = jnp.asarray(limits)

@@ -101,6 +101,7 @@ from triton_dist_tpu.serve.programs import (
     _paged_verify_forward,
     _spec_round_fused,
     _splice_draft_rows,
+    _zero_scratch,
 )
 
 
@@ -617,6 +618,7 @@ def collective_seams(cfg, *, kv_shard: str, draft_cfg=None) -> dict:
             # page scatter/gather/COW move KV bytes inside each rank's
             # own head shard: collective-free.
             "fill_pages": {}, "load_pages": {}, "cow_copy": {},
+            "zero_scratch": {}, "draft_zero_scratch": {},
             # spec round: draft scan replicated (collective-free),
             # verify + closing decode are 2 target forwards.
             "spec_round": {"psum": 2 * (2 * n)},
@@ -639,6 +641,7 @@ def collective_seams(cfg, *, kv_shard: str, draft_cfg=None) -> dict:
             "fill_pages": {},
             "load_pages": {"psum": 1},
             "cow_copy": {},
+            "zero_scratch": {}, "draft_zero_scratch": {},
             "spec_round": spec,
             "draft_tail_step": {},
             "draft_prefill": {}, "draft_join": {},
@@ -661,8 +664,8 @@ def build_programs(*, mesh, tp_axis, kv_shard, cfg, params, page_size,
                    sp_axis=None) -> dict:
     """All mesh device programs for one engine, keyed by the engine's
     program names (``paged_decode``, ``fill_pages``, ``load_pages``,
-    ``cow_copy``, ``decode_horizon``, ``prefill_chunk`` — plus
-    ``spec_round`` and the draft family with a ``draft``).  Shapes /
+    ``cow_copy``, ``zero_scratch``, ``decode_horizon``, ``prefill_chunk``
+    — plus ``spec_round`` and the draft family with a ``draft``).  Shapes /
     donation mirror the world-1 programs exactly, so warmup, metrics,
     and the step loop need no mesh-specific branches past construction.
 
@@ -804,6 +807,14 @@ def build_programs(*, mesh, tp_axis, kv_shard, cfg, params, page_size,
     out["cow_copy"] = ShardedProgram(
         cow_body, mesh, (pools_specs, P(), P()), pools_specs,
         donate_argnums=(0,))
+    # a cold request's scratch, born on the spec the chunk program takes
+    # it on (each rank zeroes its own heads; static ``s_ext``)
+    out["zero_scratch"] = ShardedProgram(
+        functools.partial(
+            _zero_scratch, quantized=kv_quant, dtype=cfg.dtype,
+            specs=[((cfg.n_kv_heads // tp_world, cfg.head_dim),) * 2]
+            * cfg.n_layers),
+        mesh, (), [(sc_spec, sc_spec)] * cfg.n_layers)
 
     def make_chunk(extent: int) -> ShardedProgram:
         return ShardedProgram(
@@ -857,6 +868,12 @@ def build_programs(*, mesh, tp_axis, kv_shard, cfg, params, page_size,
                 name="draft_prefill")
 
         out["draft_prefill"] = MeshChunkJit(make_draft_chunk)
+        out["draft_zero_scratch"] = ShardedProgram(
+            functools.partial(
+                _zero_scratch, quantized=False, dtype=dcfg.dtype,
+                specs=[((dcfg.n_kv_heads, dcfg.head_dim),) * 2]
+                * dcfg.n_layers),
+            mesh, (), [(P(), P())] * dcfg.n_layers)
         if prefix_cache:
             out["draft_fill_pages"] = ShardedProgram(
                 functools.partial(_fill_pool_pages, page=page_size), mesh,

@@ -61,7 +61,8 @@ REQUESTS_RETAIN = 4096
 #: aggregate, so keep them in lockstep.
 MERGE_COUNTERS = (
     "steps", "decode_steps", "verify_rounds", "prefill_tokens",
-    "prefill_dispatches", "prefill_pad_tokens", "preemptions", "completed",
+    "prefill_dispatches", "prefill_pad_tokens", "scratch_dispatches",
+    "preemptions", "completed",
     "decode_tokens", "dispatches",
     "host_syncs", "host_choices", "shed", "deadline_expired", "quarantined",
     "callback_errors", "forward_retries", "forward_bisections",
@@ -269,6 +270,9 @@ class ServeMetrics:
     # a call recomputed where its window slid back at the scratch's end)
     prefill_dispatches: int = 0
     prefill_pad_tokens: int = 0
+    # launches a request costs on its way in: ``zero_scratch`` program
+    # calls, ONE a cold admission (a warm one gathers its scratch instead)
+    scratch_dispatches: int = 0
     prefill_width: int = 0        # rows of one call (stamped by the engine)
     preemptions: int = 0
     completed: int = 0
@@ -1018,6 +1022,7 @@ class ServeMetrics:
                 if self.prefill_dispatches else 0.0),
             "pad_share": self.prefill_pad_tokens / rows if rows else 0.0,
             "width": self.prefill_width,
+            "scratch_dispatches": self.scratch_dispatches,
         }
 
     def latency_stats(self) -> dict:
@@ -1187,6 +1192,9 @@ class ServeMetrics:
                 "prefill_chunk program calls")
         counter("serve_prefill_pad_tokens_total", self.prefill_pad_tokens,
                 "rows of those calls that prefilled no new token")
+        counter("serve_prefill_scratch_dispatches_total",
+                self.scratch_dispatches,
+                "zero_scratch program calls: one a cold admission")
         counter("serve_dispatches_total", self.dispatches,
                 "decode-path device dispatches")
         counter("serve_host_syncs_total", self.host_syncs)

@@ -3,12 +3,13 @@
 What :class:`serve.engine.ServeEngine` jits on one chip and
 ``serve/mesh.py`` wraps in ``shard_map`` on a mesh — the paged decode /
 verify / horizon forwards, the fused speculative round, the draft's
-mesh-free decode, the page movers and the host sampler's program — kept
-under both so the arrows point one way: engine -> mesh -> programs ->
-models -> kernels.  Nothing here knows the engine, its scheduler or its
-block manager; a model family enters through the seams its generator
-hands out (``Generator.serve_hooks``, ``MlaMoeGenerator.serve_hooks``)
-and the ONE layer loop is ``models.generate._layer_stack``.
+mesh-free decode, the page movers, a request's zeroed prefill scratch and
+the host sampler's program — kept under both so the arrows point one way:
+engine -> mesh -> programs -> models -> kernels.  Nothing here knows the
+engine, its scheduler or its block manager; a model family enters through
+the seams its generator hands out (``Generator.serve_hooks``,
+``MlaMoeGenerator.serve_hooks``) and the ONE layer loop is
+``models.generate._layer_stack``.
 """
 
 from __future__ import annotations
@@ -487,6 +488,31 @@ def _spec_round_fused(params, draft_params, pools, dcaches, tables,
     done = done | (live & (any_eos | (limits <= 0)))
     return (pools, dcaches, expected, n_emit, m, kv_lens, last_logits,
             dlast_logits, counts, limits, done)
+
+
+def _zero_scratch(*, specs, s_ext, quantized, dtype):
+    """A cold request's prefill scratch, every plane of every layer from
+    zero, in ONE program of no arguments (trace keyed by the ``s_ext``
+    rung, like the page movers).  ``specs`` is a tuple of planes a layer
+    (the engine's ``_plane_specs``): ``(heads, width)`` is a K/V plane (or
+    the latent row) ``[1, heads, s_ext, width]`` at ``dtype`` — the
+    ``{"q": int8, "s": float32}`` pair of an int8 pool when ``quantized``
+    — ``(shape, dtype)`` is a state group's slot ``[1, *shape]`` at its
+    own dtype (the request's state, from zero), and a layer that owns no
+    planes gets ``()``.
+
+    Built eagerly this tree is two launches a plane (``jnp.zeros`` with a
+    dtype converts its fill value, then broadcasts) with the chip idle
+    between them: 64 a request at 16 layers (PERF.md §6, PR 41)."""
+    def rows(h, d):
+        if quantized:
+            return {"q": jnp.zeros((1, h, s_ext, d), jnp.int8),
+                    "s": jnp.zeros((1, h, s_ext), jnp.float32)}
+        return jnp.zeros((1, h, s_ext, d), dtype)
+
+    return [tuple(rows(*p) if isinstance(p[0], int) else
+                  jnp.zeros((1, *p[0]), p[1]) for p in planes)
+            for planes in specs]
 
 
 def _plane_pages(c, n, page):
