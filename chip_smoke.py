@@ -314,7 +314,7 @@ def engine_logits(engine, prompt, first_token=None):
         scratch, logits = engine._chunk_fn(
             engine.params, jnp.asarray(buf), scratch, np.int32(pos),
             quantized=False, extent=ext, n_valid=np.int32(c))
-    prefill_last = np.asarray(logits[0, c - 1], np.float32)
+    prefill_last = np.asarray(logits[0, 0], np.float32)   # the kept row
     if first_token is None:
         first_token = int(prefill_last.argmax())
     rid = "__chip_smoke_ref"

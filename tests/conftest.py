@@ -258,6 +258,12 @@ _FAST_GATE_MODULES = {
     # group by name (~3 min).
     "test_ssm_yoco",
     "test_gdn_hybrid",
+    # a prefill chunk keeps the row its caller reads (ISSUE 42): over the
+    # six toy engines, the engine's chunk program against the all-rows one
+    # — logits of the kept row, every cache and state plane bitwise — at a
+    # full chunk and a residual, from an empty scratch and a prefilled
+    # one; and ``prefill_chunked``, which keeps all rows (~1.5 min).
+    "test_chunked_prefill",
 }
 
 # Heavy tests inside core modules whose coverage is duplicated by a

@@ -3,6 +3,9 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from chunk_rows import keep_no_row
+from test_regions import FAMILIES, _build, _lowered
 
 from triton_dist_tpu.models.generate import Generator
 from triton_dist_tpu.models.llama import LlamaConfig, init_params
@@ -185,3 +188,90 @@ def test_chunked_int8_flash_path(key, monkeypatch):
         np.testing.assert_allclose(np.asarray(got.last_logits),
                                    np.asarray(ref.last_logits),
                                    rtol=0.2, atol=0.2)
+
+
+# ---------------------------------------------------------------------------
+# A prefill chunk keeps the ONE row its caller reads (ISSUE 42)
+# ---------------------------------------------------------------------------
+
+# the families' own engine-against-reference logit tolerances (float32:
+# test_serve_engine, test_mla_moe, test_mla_dsa, test_swa_moe,
+# test_ssm_yoco, test_gdn_hybrid): a one-row product sums in another order
+KEEP_TOL = {"dense": 1e-4, "latent": 1e-4, "sparse": 1e-4, "window": 2e-4,
+            "state": 3e-4, "matrix": 5e-4}
+
+
+@pytest.fixture(scope="module")
+def keep_pair():
+    """family -> (toy engine, chunk rows, extent, the engine's own chunk
+    program, the all-rows twin of it): built and compiled once."""
+    cache = {}
+
+    def get(family):
+        if family in cache:
+            return cache[family]
+        eng, chunk = _build(family)
+        extent = 2 * chunk
+        kept = _lowered(eng, chunk, "prefill_chunk", extent=extent).compile()
+        with pytest.MonkeyPatch.context() as mp:
+            keep_no_row(mp)
+            twin, _ = _build(family)            # fresh jits: nothing cached
+            every = _lowered(twin, chunk, "prefill_chunk",
+                             extent=extent).compile()
+        cache[family] = (eng, chunk, extent, kept, every)
+        return cache[family]
+
+    return get
+
+
+@pytest.mark.parametrize("prefix", ["prefix0", "prefixed"])
+@pytest.mark.parametrize("rows", ["full", "residual", "unread"])
+@pytest.mark.parametrize("family", FAMILIES)
+def test_chunk_keeps_the_row_its_caller_reads(keep_pair, family, rows,
+                                              prefix):
+    """The chunk program an engine calls returns logits [1, 1, V]: row
+    ``n_valid - 1`` of the all-rows program's within the family's logit
+    tolerance, at a full chunk and at a padded residual, from an empty
+    scratch and over a chunk already prefilled — and every cache, state
+    and carried-convolution plane it leaves is BITWISE the all-rows
+    program's (the rows it no longer carries past the last layer that
+    writes were computed and dropped).  ``unread``: a NEGATIVE ``n_valid``
+    (a residual that is not its prompt's last call) skips the rest of the
+    program — the logits are zeros — and leaves the same planes."""
+    eng, chunk, extent, kept, every = keep_pair(family)
+    vocab = eng.gen.cfg.vocab
+    rng = np.random.default_rng([FAMILIES.index(family), 42])
+    tokens = rng.integers(0, vocab, (2, 1, chunk)).astype(np.int32)
+    n = chunk if rows == "full" else chunk - chunk // 3 - 1
+    scratch = lambda: eng._zero_fn(s_ext=extent)      # noqa: E731
+    pos = 0
+    if prefix == "prefixed":
+        first = (eng.params, jnp.asarray(tokens[0]))
+        filled, *_ = kept(*first, scratch(), np.int32(0),
+                          n_valid=np.int32(chunk))
+        twin, *_ = every(*first, scratch(), np.int32(0),
+                         n_valid=np.int32(chunk))
+        pos = chunk
+        scratch = None
+    buf = tokens[1].copy()
+    buf[0, n:] = 0                                    # the engine's padding
+    call = (eng.params, jnp.asarray(buf))
+    got_c, got, *_ = kept(*call, filled if scratch is None else scratch(),
+                          np.int32(pos),
+                          n_valid=np.int32(-n if rows == "unread" else n))
+    want_c, want, *_ = every(*call, twin if scratch is None else scratch(),
+                             np.int32(pos), n_valid=np.int32(n))
+    assert got.shape == (1, 1, vocab) and want.shape == (1, chunk, vocab)
+    assert got.dtype == want.dtype == jnp.float32
+    if rows == "unread":
+        assert not np.asarray(got).any()
+    else:
+        np.testing.assert_allclose(np.asarray(got[0, 0]),
+                                   np.asarray(want[0, n - 1]),
+                                   atol=KEEP_TOL[family], rtol=0)
+    # far from any other row's: the row is the right one
+    assert np.abs(np.asarray(want[0, n - 2] - want[0, n - 1])).max() > 1e-2
+    got_l, want_l = jax.tree.leaves(got_c), jax.tree.leaves(want_c)
+    assert len(got_l) == len(want_l) > 0
+    for g, w in zip(got_l, want_l):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))

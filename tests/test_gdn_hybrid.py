@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from chunk_rows import filed_chunk_call
 
 from triton_dist_tpu.kernels import gated_delta as K
 from triton_dist_tpu.models import gdn_hybrid as G
@@ -117,12 +118,10 @@ def _served_logits(gen, params, prompt, n_new, **kw):
     seam = eng._device_call
 
     def tapped(op, rids, fn, *a, **kw):
-        out = seam(op, rids, fn, *a, **kw)
         if op == "prefill_chunk":
-            pos, n = int(a[3]), int(kw["n_valid"])
-            for j in range(n):
-                rows[pos + j] = np.asarray(out[1][0, j])
-        elif op == "paged_decode":
+            return filed_chunk_call(rows, seam, op, rids, fn, a, kw)
+        out = seam(op, rids, fn, *a, **kw)
+        if op == "paged_decode":
             rs = eng._states[rids[0]]
             rows[rs.kv_len] = np.asarray(out[1][rs.slot])
         return out

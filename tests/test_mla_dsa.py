@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from chunk_rows import filed_chunk_call
 
 from triton_dist_tpu.kernels import flash_decode as fd
 from triton_dist_tpu.models import mla_moe as M
@@ -117,12 +118,10 @@ def _served_logits(gen, params, prompt, n_new):
     seam = eng._device_call
 
     def tapped(op, rids, fn, *a, **kw):
-        out = seam(op, rids, fn, *a, **kw)
         if op == "prefill_chunk":
-            pos, n = int(a[3]), int(kw["n_valid"])
-            for j in range(n):
-                rows[pos + j] = np.asarray(out[1][0, j])
-        elif op == "paged_decode":
+            return filed_chunk_call(rows, seam, op, rids, fn, a, kw)
+        out = seam(op, rids, fn, *a, **kw)
+        if op == "paged_decode":
             rs = eng._states[rids[0]]
             rows[rs.kv_len] = np.asarray(out[1][rs.slot])
         return out
@@ -551,11 +550,12 @@ def test_expanded_prefill_chunks_match_reference_and_the_absorbed_ones(
     prompt, = _prompts(cfg, [70])
     toks, got = _served_logits(gen_x, params, prompt, 6)
     monkeypatch.setattr(M, "PREFILL_EXPAND_MIN", 256)
-    # every chunk program traced the expanded call once a layer over its
-    # 128-row scratch — a call is the step's budget of 4 x 32 rows — and
-    # the absorbed one served single queries only
+    # every chunk program — the engine's, and the all-rows form of it that
+    # ``_served_logits`` runs beside each call — traced the expanded call
+    # once a layer over its 128-row scratch — a call is the step's budget
+    # of 4 x 32 rows — and the absorbed one served single queries only
     H, dk = cfg.n_heads, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-    assert calls["expanded"] == [(1, 128, H * dk)] * cfg.n_layers
+    assert calls["expanded"] == [(1, 128, H * dk)] * cfg.n_layers * 2
     assert calls["absorbed"] and all(s[1] == 1 for s in calls["absorbed"])
     toks_a, got_a = _served_logits(gen, params, prompt, 6)
     assert toks == toks_a

@@ -113,23 +113,27 @@ def _build(family):
     return eng, chunk
 
 
-def _lowered(eng, chunk, program):
+def _lowered(eng, chunk, program, all_rows=False, extent=256):
     """The engine's own jitted ``program`` lowered on abstract arguments
-    of the engine's own shapes."""
+    of the engine's own shapes (``all_rows``: the chunk program as the
+    callers that consume every position call it; ``extent``: its
+    scratch's rows)."""
     s = jax.ShapeDtypeStruct
     shapes = lambda tree: jax.tree.map(     # noqa: E731
         lambda x: s(x.shape, x.dtype), tree)
     params, pools = shapes(eng.params), shapes(eng._pools)
     if program == "prefill_chunk":
-        extent = 256
         # K and V rows a layer — or a state-space layer's slot of state,
         # or nothing (``_plane_specs``: what the engine's own scratch is)
         scratch = [tuple(s((1, p[0], extent, p[1]), eng.gen.cfg.dtype)
                          if isinstance(p[0], int) else s((1, *p[0]), p[1])
                          for p in planes) for planes in eng._plane_specs]
+        # the engine says how many rows are valid (and gets ONE row's
+        # logits); speculative verify and ``prefill_chunked`` do not
+        valid = {} if all_rows else {"n_valid": s((), I32)}
         return eng._chunk_fn.fn.lower(
             params, s((1, chunk), I32), scratch, s((), I32),
-            quantized=False, extent=extent, n_valid=s((), I32))
+            quantized=False, extent=extent, **valid)
     groups = len(eng.kv_groups) if eng.kv_groups else 0
     tables = s(((groups,) if groups else ()) + (B, eng.n_pages_max), I32)
     vec = lambda dt: s((B,), dt)    # noqa: E731
@@ -179,10 +183,14 @@ def test_the_computation_is_the_same_without_the_scopes(
 
 
 def _quartet_layer_stack(params, tokens, pos, caches, *, cfg, project,
-                         out_proj, ffn, write_kv, attend, kinds=None):
+                         out_proj, ffn, write_kv, attend, kinds=None,
+                         keep=None, read=None):
     """The layer loop as it stood before a family could bring a ``mixer``
     (ISSUE 38's parent, to the letter): RMSNorm and ``lm_head`` written
-    in, the project / write / attend / out_proj quartet for every layer."""
+    in, the project / write / attend / out_proj quartet for every layer —
+    and, since a prefill chunk keeps the row its caller reads (ISSUE 42),
+    that row alone through the norm and the head, where it will be read:
+    each of these families' last layer writes a cache."""
     region = profiling.region
     B_, T = tokens.shape
     with region("embed"):
@@ -203,11 +211,22 @@ def _quartet_layer_stack(params, tokens, pos, caches, *, cfg, project,
             h2 = _rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
             x = x + ffn(h2.reshape(B_ * T, -1), layer).reshape(x.shape)
         new_caches.append(cache)
-    with region("head"):
-        x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
-        logits = jnp.dot(x, params["lm_head"],
-                         preferred_element_type=jnp.float32)
-    return new_caches, logits.reshape(B_, T, -1)
+
+    def head(x):
+        with region("head"):
+            x = _rms_norm(x, params["final_norm"], cfg.norm_eps)
+            logits = jnp.dot(x, params["lm_head"],
+                             preferred_element_type=jnp.float32)
+        return logits.reshape(B_, -1, logits.shape[-1])
+
+    if keep is None or T == 1:
+        return new_caches, head(x)
+
+    def tail():
+        return head(jax.lax.dynamic_index_in_dim(x, keep, 1, False))
+
+    return new_caches, jax.lax.cond(
+        read, tail, lambda: jnp.zeros_like(jax.eval_shape(tail)))
 
 
 @pytest.mark.parametrize("program", PROGRAMS)
@@ -224,6 +243,83 @@ def test_without_a_mixer_the_programs_are_the_quartets(
         monkeypatch.setattr(mod, "_layer_stack", _quartet_layer_stack)
     bare, _ = _build(family)              # fresh jits: nothing cached
     assert _lowered(bare, chunk, program).as_text() == written
+
+
+# ---------------------------------------------------------------------------
+# No row to keep: every program but the engine's chunk is the parent's
+# ---------------------------------------------------------------------------
+
+
+def _parent_layer_stack(params, tokens, pos, caches, *, cfg, project,
+                        out_proj, ffn, write_kv, attend, kinds=None,
+                        mixer=None, shared=None, keep=None, read=None):
+    """The layer loop as it stood before it could keep a row (ISSUE 42's
+    parent, to the letter), refusing to be told of one."""
+    assert keep is None and read is None
+    region, _norm, _head = profiling.region, G._norm, G._head
+    B, T = tokens.shape
+    post = getattr(cfg, "norm_after", False)
+    with region("embed"):
+        x = params["embed"][tokens.reshape((B,) if T == 1 else (B, T))]
+    new_caches = []
+    for li, layer in enumerate(params["layers"]):
+        if mixer is not None:
+            h = x if post else _norm(x, layer, "attn_norm", cfg)
+            rows, cache, shared = mixer(
+                li, h.reshape(B, T, -1), layer, pos, caches[li], shared,
+                write_kv=write_kv, attend=attend)
+            x = x + rows.reshape(x.shape)
+        else:
+            with region("proj"):
+                h = _norm(x, layer, "attn_norm", cfg)
+                q, k, v = project(h.reshape(B, T, -1), layer, pos,
+                                  **G._kind_kw(kinds, li))
+            with region("kv_write"):
+                cache = write_kv(li, caches[li], k, v)
+            o = attend(li, q, cache)                     # [B, T, Hq, .]
+            with region("out_proj"):
+                o2 = o.reshape(B * T, -1).astype(cfg.dtype)
+                x = x + out_proj(o2, layer).reshape(x.shape)
+        with region("ffn"):
+            if post:
+                y = _norm(ffn(x.reshape(B * T, -1), layer), layer,
+                          "mlp_norm", cfg)
+            else:
+                h2 = _norm(x, layer, "mlp_norm", cfg)
+                y = ffn(h2.reshape(B * T, -1), layer)
+            x = x + y.reshape(x.shape)
+        new_caches.append(cache)
+    with region("head"):
+        logits = _head(_norm(x, params, "final_norm", cfg), params, cfg)
+    return new_caches, logits.reshape(B, T, -1)
+
+
+@pytest.mark.parametrize("program", ("decode_horizon", "paged_decode",
+                                     "all_rows_chunk"))
+@pytest.mark.parametrize("family", FAMILIES)
+def test_with_no_row_to_keep_the_programs_are_the_parents(
+        engines, monkeypatch, family, program):
+    """A prefill chunk keeps the row its caller reads (ISSUE 42) and no
+    other program learns of it: every family's ``decode_horizon`` and
+    ``paged_decode``, and the chunk program as the callers that consume
+    every position call it (no ``n_valid``: speculative verify,
+    ``prefill_chunked``), lower to the same StableHLO text over today's
+    layer loop and over the loop it replaced."""
+    eng, chunk = engines(family)
+    chunked = program == "all_rows_chunk"
+    program = "prefill_chunk" if chunked else program
+    written = _lowered(eng, chunk, program, all_rows=chunked).as_text()
+    # and the engine's own call IS another program: one row of logits
+    if chunked:
+        kept = _lowered(eng, chunk, program).as_text()
+        vocab = eng.gen.cfg.vocab
+        assert kept != written and f"1x1x{vocab}xf32" in kept
+    for mod in SCOPED:
+        if hasattr(mod, "_layer_stack"):
+            monkeypatch.setattr(mod, "_layer_stack", _parent_layer_stack)
+    bare, _ = _build(family)              # fresh jits: nothing cached
+    assert _lowered(bare, chunk, program,
+                    all_rows=chunked).as_text() == written
 
 
 # ---------------------------------------------------------------------------

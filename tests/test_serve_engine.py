@@ -452,7 +452,7 @@ def _tap_prefill(eng):
     def tapped(op, rids, fn, *a, **kw):
         if op == "prefill_chunk":
             calls.append((a[1].shape[1], kw["extent"], int(a[3]),
-                          int(kw["n_valid"])))
+                          abs(int(kw["n_valid"]))))
         return seam(op, rids, fn, *a, **kw)
 
     eng._device_call = tapped
@@ -539,7 +539,8 @@ def test_prefill_summary_counts_calls_and_padding():
     W = eng.prefill_width
     assert W == 128 and eng.metrics.summary()["prefill"] == {
         "tokens": 0, "dispatches": 0, "tokens_per_dispatch": 0.0,
-        "pad_share": 0.0, "width": 128, "scratch_dispatches": 0}
+        "pad_share": 0.0, "tail_rows": 0, "width": 128,
+        "scratch_dispatches": 0}
     shares, plan = [], eng.scheduler.prefill_plan
 
     def recorded(prefilling):
@@ -564,6 +565,9 @@ def test_prefill_summary_counts_calls_and_padding():
     assert f"serve_prefill_dispatches_total {n_calls}" in text
     assert (f"serve_prefill_pad_tokens_total "
             f"{n_calls * W - sum(lens)}") in text
+    # ONE row a prompt goes past the last layer that writes, and the head
+    assert got["tail_rows"] == len(lens)
+    assert f"serve_prefill_tail_rows_total {len(lens)}" in text
     # one launch a cold request on its way in, whatever its planes
     assert got["scratch_dispatches"] == len(lens)
     assert f"serve_prefill_scratch_dispatches_total {len(lens)}" in text
@@ -571,8 +575,53 @@ def test_prefill_summary_counts_calls_and_padding():
     both = ServeMetrics().merge(eng.metrics).merge(eng.metrics)
     assert both.prefill_stats()["dispatches"] == 2 * n_calls
     assert both.prefill_stats()["scratch_dispatches"] == 2 * len(lens)
+    assert both.prefill_stats()["tail_rows"] == 2 * len(lens)
     assert both.prefill_stats()["width"] == W
     assert both.prefill_stats()["pad_share"] == got["pad_share"]
+
+
+@pytest.mark.parametrize("all_rows", [False, True],
+                         ids=["kept_row", "parents_program"])
+def test_prefill_tail_rows_and_first_tokens(monkeypatch, all_rows):
+    """``summary()["prefill"]["tail_rows"]`` counts the rows of the logits
+    a prompt's LAST ``prefill_chunk`` call returned (its other calls say by
+    the sign of ``n_valid`` that nobody reads theirs, and skip the head):
+    ONE a finished prefill of the program that keeps the row the engine
+    reads, ``W`` of a program that carries every row to the head, as the
+    parent's did on every call (the layer loop told of no row to keep).
+    And every request's greedy first token is the parent's: the argmax of
+    the last valid row of the all-rows program on the finishing call's own
+    inputs."""
+    from chunk_rows import all_chunk_rows, keep_no_row
+
+    if all_rows:
+        keep_no_row(monkeypatch)
+    cfg, params, gen = _wide_model()
+    eng = ServeEngine(gen, params, num_blocks=80, page_size=16, max_batch=3,
+                      prefill_chunk=16, prefill_budget=128,
+                      prefix_cache=False, clock=_Tick())
+    W, parents, seam = eng.prefill_width, {}, eng._device_call
+
+    def tapped(op, rids, fn, *a, **kw):
+        if op == "prefill_chunk" and not all_rows and kw["n_valid"] > 0:
+            assert rids[0] not in parents       # ONE call a prompt is read
+            parents[rids[0]] = int(all_chunk_rows(fn, a, kw)[
+                int(kw["n_valid"]) - 1].argmax())
+        return seam(op, rids, fn, *a, **kw)
+
+    eng._device_call = tapped
+    rng = np.random.default_rng(42)
+    lens = [200, 37, 129, 16, 90, 128]
+    outs = _drive(eng, [rng.integers(0, cfg.vocab, size=n).astype(np.int32)
+                        for n in lens], 2, stagger=1)
+    got = eng.metrics.summary()["prefill"]
+    assert got["tokens"] == sum(lens) and got["dispatches"] > len(lens)
+    if all_rows:
+        assert got["tail_rows"] == len(lens) * W
+        return
+    assert got["tail_rows"] == len(lens)
+    assert {rid: o.token_ids[0] for rid, o in outs.items()} == parents
+    assert len(set(parents.values())) > 1
 
 
 @pytest.mark.parametrize("page,chunk,budget,max_seq", [

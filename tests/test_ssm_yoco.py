@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from chunk_rows import filed_chunk_call, keep_no_row
 
 from triton_dist_tpu.kernels import flash_decode as fd
 from triton_dist_tpu.kernels import ssm_scan as K
@@ -113,12 +114,10 @@ def _served_logits(gen, params, prompt, n_new, **kw):
     seam = eng._device_call
 
     def tapped(op, rids, fn, *a, **kw):
-        out = seam(op, rids, fn, *a, **kw)
         if op == "prefill_chunk":
-            pos, n = int(a[3]), int(kw["n_valid"])
-            for j in range(n):
-                rows[pos + j] = np.asarray(out[1][0, j])
-        elif op == "paged_decode":
+            return filed_chunk_call(rows, seam, op, rids, fn, a, kw)
+        out = seam(op, rids, fn, *a, **kw)
+        if op == "paged_decode":
             rs = eng._states[rids[0]]
             rows[rs.kv_len] = np.asarray(out[1][rs.slot])
         return out
@@ -237,6 +236,55 @@ def test_n_chunks_leave_the_state_of_one_scan(tiny):
         assert np.abs(got_conv.reshape(conv.shape[1:])
                       - np.asarray(conv[0])).max() < 1e-4
         assert np.abs(got_state - np.asarray(state[0])).max() < 1e-4
+
+
+def _lowered_chunk(cfg, rows, extent, monkeypatch=None):
+    """The family's ``prefill_chunk`` lowered on abstract arguments, as the
+    engine calls it (``n_valid`` given) — with ``monkeypatch``, over a
+    layer loop told of no row to keep: the all-rows program it was."""
+    if monkeypatch is not None:
+        keep_no_row(monkeypatch)
+    s = jax.ShapeDtypeStruct
+    scratch = []
+    for kind in cfg.kinds:
+        if kind.attn in ("full", "window"):
+            scratch.append((s((1, cfg.kv_plane[0], extent, cfg.kv_plane[1]),
+                              cfg.dtype),) * 2)
+        else:
+            scratch.append(tuple(s((1, *sh), dt)
+                                 for sh, dt in cfg.state_planes)
+                           if kind.state else ())
+    params = jax.eval_shape(lambda: Y.init_params(cfg, jax.random.key(0)))
+    return _gen(cfg)._chunk_jit.lower(
+        params, s((1, rows), jnp.int32), scratch, s((), jnp.int32),
+        quantized=False, extent=extent, n_valid=s((), jnp.int32))
+
+
+def test_a_prefill_chunk_stops_its_rows_at_the_shared_cache(monkeypatch):
+    """What the architecture was published for (ISSUE 42): past layer L/2
+    + 1, the last that leaves anything a later token reads, a prefill
+    chunk carries ONE row — through the ``gmu`` and ``cross`` layers and
+    the head.  The lowered program holds no [rows, V] float32 array, and
+    the compiler counts under 60% of the all-rows program's operations at
+    the published proportions (16 layers: 6 of them and the head after the
+    full layer; a head worth ~4 layers: V = 8,192 at the toy widths).  The
+    toy config itself (8 layers, two of them after the full layer, V =
+    256) reads 78%: its state-space layers' scans, which XLA counts at 6 T
+    E N a layer, weigh more beside its two small layers and head."""
+    rows, extent = 64, 128
+    for over, most in ((dict(n_layers=16, vocab=8192), 0.60), ({}, 0.80)):
+        cfg = Y.SsmYocoConfig.tiny(**over)
+        kept = _lowered_chunk(cfg, rows, extent)
+        with monkeypatch.context() as mp:
+            every = _lowered_chunk(cfg, rows, extent, mp)
+        if over:
+            logits = f"{rows}x{cfg.vocab}xf32"
+            assert logits in every.as_text()
+            assert logits not in kept.as_text()
+            assert f"1x1x{cfg.vocab}xf32" in kept.as_text()
+        flops = [lo.compile().cost_analysis()["flops"]
+                 for lo in (kept, every)]
+        assert flops[0] < most * flops[1], (over, flops)
 
 
 def test_a_reused_slot_starts_from_zero(tiny):

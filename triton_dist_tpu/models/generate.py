@@ -74,6 +74,12 @@ class LayerKind:
         return self.attn in ("ssm", "linear")
 
     @property
+    def owns(self) -> bool:
+        """The layer leaves something a LATER token reads — cache rows or
+        a state; a ``cross`` or ``gmu`` layer leaves nothing."""
+        return self.attn not in ("cross", "gmu")
+
+    @property
     def call_name(self) -> str:
         """The paged attention call's name in a device trace."""
         return f"gqa_paged_{self.attn}"
@@ -464,14 +470,40 @@ def _head(x, params, cfg):
     return jnp.dot(x, params["lm_head"], preferred_element_type=jnp.float32)
 
 
+def _tail_start(kinds, n_layers: int) -> int:
+    """The first layer after the LAST that leaves anything a later token
+    reads (``LayerKind.owns``): from there on only the rows whose logits
+    are wanted need computing.  A model of one kind of layer (no
+    ``kinds``), or whose last layer owns a cache, has no such layer."""
+    if kinds is None:
+        return n_layers
+    return max((li + 1 for li, k in enumerate(kinds) if k.owns), default=0)
+
+
 def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
-                 ffn, write_kv, attend, kinds=None, mixer=None, shared=None):
+                 ffn, write_kv, attend, kinds=None, mixer=None, shared=None,
+                 keep=None, read=None):
     """THE layer loop of serving and its oracles: tokens [B, T] at global
     positions pos [B, T] (a leading 1 broadcasts) through every layer of
     ``params`` -> (new caches, logits [B, T, V] float32).  Decode is this
     at T = 1, speculative verify at T = k + 1, a prefill chunk at B = 1,
     whole-prompt prefill at T = S; what differs between them is the
     cache access pair, and between model families the three block seams.
+
+    ``keep`` (a traced row index, optional) says that ONE row's logits are
+    wanted: -> (new caches, logits [B, 1, V]).  All T rows go through the
+    layers up to the last that leaves anything a later token reads
+    (:func:`_tail_start`, read off ``kinds``: every layer, for a model
+    that passes none) — every cache row and state is what it is without
+    ``keep`` — and from there row ``keep`` alone, in the T = 1 layout,
+    through the remaining layers, the final norm and the head.  A
+    ``mixer`` finds the row in ``shared["keep"]`` and slices what it
+    carries a row of; the access pair is handed the one-row query and
+    knows where it sits.  ``read`` (a traced bool beside ``keep``,
+    optional) says whether anyone WILL read the row: where it is false
+    the rest of the program — those layers, the norm, the head — is
+    skipped (a ``lax.cond`` in the one program) and the logits are zeros.
+    With no ``keep`` nothing of this is traced.
 
     The family's seams (``Generator.serve_hooks`` / ``dense_block``;
     ``models/mla_moe.py`` for latent attention + experts):
@@ -534,10 +566,12 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     step does not (12.7 against 12.4 ms a step with its rows flat)."""
     B, T = tokens.shape
     post = getattr(cfg, "norm_after", False)
-    with region("embed"):
-        x = params["embed"][tokens.reshape((B,) if T == 1 else (B, T))]
-    new_caches = []
-    for li, layer in enumerate(params["layers"]):
+    layers = params["layers"]
+
+    def block(li, x, pos, shared):
+        """Layer ``li`` over the residual stream ``x`` ([B, T, D]; [B, D] at
+        T = 1) -> (x', the layer's cache, shared')."""
+        layer, T = layers[li], 1 if x.ndim == 2 else x.shape[1]
         if mixer is not None:
             h = x if post else _norm(x, layer, "attn_norm", cfg)
             rows, cache, shared = mixer(
@@ -563,10 +597,42 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
                 h2 = _norm(x, layer, "mlp_norm", cfg)
                 y = ffn(h2.reshape(B * T, -1), layer)
             x = x + y.reshape(x.shape)
+        return x, cache, shared
+
+    def head(x):
+        with region("head"):
+            logits = _head(_norm(x, params, "final_norm", cfg), params, cfg)
+        return logits.reshape(B, -1, logits.shape[-1])
+
+    with region("embed"):
+        x = params["embed"][tokens.reshape((B,) if T == 1 else (B, T))]
+    one_row = keep is not None and T > 1
+    tail_at = _tail_start(kinds, len(layers)) if one_row else len(layers)
+    new_caches = []
+    for li in range(tail_at):
+        x, cache, shared = block(li, x, pos, shared)
         new_caches.append(cache)
-    with region("head"):
-        logits = _head(_norm(x, params, "final_norm", cfg), params, cfg)
-    return new_caches, logits.reshape(B, T, -1)
+    if not one_row:
+        return new_caches, head(x)
+
+    def tail():
+        row = jax.lax.dynamic_index_in_dim(x, keep, 1, False)
+        at = jax.lax.dynamic_slice_in_dim(pos, keep, 1, axis=1)
+        own = shared if mixer is None else {**shared, "keep": keep}
+        for li in range(tail_at, len(layers)):
+            row, _, own = block(li, row, at, own)
+        return head(row)
+
+    # the layers of the tail leave nothing: their entries go as they came
+    new_caches.extend(caches[tail_at:])
+    if read is None:
+        return new_caches, tail()
+    # zeros of the head's shape, asked of the head alone: tracing the
+    # layers of ``tail`` a second time is seconds of every warm-up
+    unread = jax.eval_shape(
+        head, jax.ShapeDtypeStruct((B, x.shape[-1]), x.dtype))
+    return new_caches, jax.lax.cond(
+        read, tail, lambda: jnp.zeros(unread.shape, unread.dtype))
 
 
 # ---------------------------------------------------------------------------
@@ -886,13 +952,13 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
                    mixer=None):
     """One prompt chunk [B, c] against the cached prefix; returns
     (new_caches, logits [B, c, V] — position i predicts the token after
-    chunk[:, i]): :func:`_layer_stack` with the pair of a CONTIGUOUS
+    chunk[:, i] — or, with ``n_valid``, [B, 1, V]: the last valid row's):
+    :func:`_layer_stack` with the pair of a CONTIGUOUS
     cache written at the scalar ``prefix_len``.  The chunk's own K/V are
     written to the cache first (quantized if the cache is), then
     attention reads the cache back — so later chunks and the current one
     see identical (possibly quantized) K/V, matching the decode path's
-    behavior.  Speculative verification (models/speculative.py) consumes
-    the full per-position logits.  ``extent`` (static) bounds the cache
+    behavior.  ``extent`` (static) bounds the cache
     rows attention reads — scores stay [c, extent] instead of
     [c, max_seq].
 
@@ -902,10 +968,25 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
     to an unpadded run (pad rows match the zero-init rows it never wrote).
     Padded QUERY rows need no mask — causality already hides rows >=
     n_valid from every valid query (row i attends to positions <=
-    prefix + i < prefix + n_valid), and their own logits are garbage the
-    caller discards.  One trace serves every residual chunk length — the
-    serving engine's admission path never retraces on prompt shape
-    (docs/serving.md: the bucket ladder).
+    prefix + i < prefix + n_valid).  One trace serves every residual
+    chunk length — the serving engine's admission path never retraces on
+    prompt shape (docs/serving.md: the bucket ladder).
+
+    A caller that says how many rows are valid is PREFILLING, and of a
+    prefill only the row that predicts the next token is ever read
+    (``engine._finish_prefill``, ``_join_draft``): the chunk then keeps row
+    ``n_valid - 1`` alone past the last layer that writes anything
+    (:func:`_layer_stack`'s ``keep``: no argument of its own) and returns
+    its logits as ``[B, 1, V]``.  And only of a prompt's LAST chunk: a
+    caller that will read no row at all says so in the one scalar it
+    already sends (a further argument is a further host transfer, with
+    the device idle) — a NEGATIVE ``n_valid`` marks ``-n_valid`` rows valid
+    and the logits unread (``_layer_stack``'s ``read``): everything past
+    the last layer that writes is skipped inside the same program and the
+    logits come back as zeros.  All rows are kept for the callers that
+    pass no ``n_valid`` and consume every position — speculative
+    verification (models/speculative.py), ``Generator.prefill_chunked`` —
+    whose program is the one it was.
 
     ``attend(q, *views, prefix_len, k_scale=, v_scale=)`` is the
     family's prefix attention over the extent-bounded views of the
@@ -919,6 +1000,10 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
     passes the family over its local-head config."""
     c = chunk.shape[1]
     positions = prefix_len + jnp.arange(c, dtype=jnp.int32)
+    keep = read = None
+    if n_valid is not None:
+        read, n_valid = n_valid > 0, jnp.abs(n_valid)
+        keep = n_valid - 1
     pad_mask = (None if n_valid is None else
                 (jnp.arange(c, dtype=jnp.int32) < n_valid)[None, :, None,
                                                            None])
@@ -935,14 +1020,19 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
     def attend_views(li, q, planes):
         ext = extent or (planes[0]["q"] if quantized
                          else planes[0]).shape[2]
+        # ONE query of a wider chunk is the kept row's, from a layer past
+        # the last that writes: it sits at its own position, the chunk's
+        # rows under it (q: an array, or a family's tuple led by one)
+        at = (prefix_len if jax.tree.leaves(q)[0].shape[1] == c
+              else prefix_len + keep)
         with region("attn"):
             if quantized:
                 k_c, v_c = planes
                 return attend(q, k_c["q"][:, :, :ext], v_c["q"][:, :, :ext],
-                              prefix_len, k_scale=k_c["s"][:, :, :ext],
+                              at, k_scale=k_c["s"][:, :, :ext],
                               v_scale=v_c["s"][:, :, :ext],
                               **_kind_kw(kinds, li))
-            return attend(q, *(p[:, :, :ext] for p in planes), prefix_len,
+            return attend(q, *(p[:, :, :ext] for p in planes), at,
                           k_scale=None, v_scale=None, **_kind_kw(kinds, li))
 
     # a family's ``mixer`` is told the chunk's valid rows: a state carried
@@ -952,7 +1042,7 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
     return _layer_stack(params, chunk, positions[None], caches, cfg=cfg,
                         project=project, out_proj=out_proj, ffn=ffn,
                         write_kv=write_kv, attend=attend_views, kinds=kinds,
-                        **more)
+                        keep=keep, read=read, **more)
 
 
 def _write_rows(cache, new, offs):

@@ -400,7 +400,10 @@ def mixer(li, h, layer, pos, cache, shared, *, write_kv, attend, cfg, impl,
     family has: h [B, T, D] (normed) -> (rows [B * T, D], the layer's
     cache, what later layers of this forward read).  The attention kinds
     go through the caller's ``write_kv`` / ``attend`` pair as the quartet
-    would; ``cross`` attends the cache the full layer left in ``shared``."""
+    would; ``cross`` attends the cache the full layer left in ``shared``.
+    Past the last layer that writes (``_layer_stack``'s ``keep``) ``h`` is
+    ONE row of the chunk: ``gmu`` takes that row of ``m``, and ``cross``'s
+    one query is placed by the caller's ``attend``."""
     c, kind = cfg, cfg.kinds[li]
     B, T, _ = h.shape
     if kind.attn == "ssm":
@@ -411,7 +414,10 @@ def mixer(li, h, layer, pos, cache, shared, *, write_kv, attend, cfg, impl,
     if kind.attn == "gmu":
         with region("gmu"):
             gate = jax.nn.silu((h2 @ layer["w_g"]).astype(jnp.float32))
-            m = shared["m"].reshape(B * T, -1).astype(jnp.float32)
+            m = shared["m"]
+            if m.shape[1] != T:     # one row kept of the chunk: its own m
+                m = jax.lax.dynamic_slice_in_dim(m, shared["keep"], 1, 1)
+            m = m.reshape(B * T, -1).astype(jnp.float32)
             return (gate * m).astype(c.dtype) @ layer["w_out"], cache, shared
     with region("proj"):
         q = (h2 @ layer["wq"]).reshape(B, T, c.n_heads, c.head_dim)

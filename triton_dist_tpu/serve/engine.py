@@ -2437,7 +2437,6 @@ class ServeEngine:
         end = min(rs.prefill_pos + n_tokens, S0)
         width = self.prefill_width
         logits = None
-        n_last = 0
         while rs.prefill_pos < end:
             c = min(width, end - rs.prefill_pos)
             # Every call is the ONE fixed shape [1, width] — as many rows
@@ -2452,28 +2451,33 @@ class ServeEngine:
                 buf[0, :n_fed] = prompt[at:at + n_fed]
                 buf_d = jnp.asarray(buf)
                 # host numbers, transferred by the call itself: an eager
-                # jnp.int32 is a launch of its own for four bytes
-                pos_d, valid_d = np.int32(at), np.int32(n_fed)
+                # jnp.int32 is a launch of its own for four bytes.  Only
+                # the prompt's last call has a row read (_finish_prefill):
+                # the others say so by the count's sign (_chunk_forward)
+                last = rs.prefill_pos + c == S0
+                pos_d = np.int32(at)
+                valid_d = np.int32(n_fed if last else -n_fed)
             rs.scratch, logits, *aux = self._device_call(
                 "prefill_chunk", (rs.req.request_id,), self._chunk_fn,
                 self.params, buf_d, rs.scratch, pos_d,
                 quantized=self.kv_quant, extent=rs.s_ext, n_valid=valid_d)
             self._note_aux(aux)
             rs.prefill_pos += c
-            n_last = n_fed
             self.metrics.prefill_tokens += c
             if self._has_state:
                 self.metrics.ssm_scan_tokens += c
             self.metrics.prefill_dispatches += 1
             self.metrics.prefill_pad_tokens += width - c
+            if last:
+                self.metrics.prefill_tail_rows += logits.shape[1]
             if self.trace.level >= 2:
                 self.trace.emit("prefill_chunk", rs.req.request_id,
                                 n=c, pos=rs.prefill_pos)
         if rs.prefill_pos < S0:
             return None
-        return self._finish_prefill(rs, logits, n_last, now)
+        return self._finish_prefill(rs, logits, now)
 
-    def _finish_prefill(self, rs: ReqState, logits, n_last: int,
+    def _finish_prefill(self, rs: ReqState, logits,
                         now: float) -> Optional[RequestOutput]:
         rid = rs.req.request_id
         S0 = int(rs.prompt_tokens.shape[0])
@@ -2504,7 +2508,8 @@ class ServeEngine:
         with span("prefill.commit"):
             self._commit_full_blocks(rs)
         with span("prefill.wait"):
-            last = logits[:, n_last - 1]                   # [1, V]
+            # the call kept its last valid row alone (_chunk_forward)
+            last = logits[:, 0]                            # [1, V]
             if not spec:
                 # the host blocks here until the chunk has run
                 row = np.asarray(last[0], np.float32)
@@ -2573,7 +2578,6 @@ class ServeEngine:
                 "draft_zero_scratch", (rid,), self._draft_zero_fn,
                 s_ext=ext)
         logits = None
-        n_last = 0
         for off in range(start, S0, chunk):
             c = min(chunk, S0 - off)
             buf = np.zeros((1, chunk), np.int32)
@@ -2582,8 +2586,7 @@ class ServeEngine:
                 "draft_prefill", (rid,), self._draft_chunk_fn,
                 self.draft_params, jnp.asarray(buf), caches,
                 np.int32(off), quantized=False, extent=ext,
-                n_valid=np.int32(c))
-            n_last = c
+                n_valid=np.int32(c if off + c == S0 else -c))
         if self._draft_pools is not None:
             # Commit the draft's prompt pages (before the splice — the
             # join donates nothing of ``caches``, this fill only reads
@@ -2605,7 +2608,7 @@ class ServeEngine:
         new_caches, kv_lens, last_logits = self._device_call(
             "draft_join", (rid,), self._draft_join_fn, sd.caches,
             sd.kv_lens, sd.last_logits, caches, np.int32(rs.slot),
-            np.int32(S0), logits[0, n_last - 1])
+            np.int32(S0), logits[0, 0])
         self._draft_state = GenerationState(
             caches=new_caches, kv_lens=kv_lens, last_logits=last_logits)
 

@@ -367,7 +367,8 @@ def _sp_attend_prefix(q, k_view, v_view, plen, *, k_scale=None, v_scale=None,
 
 def _chunk_shard(params, chunk, caches, prefix_len, n_valid, **kw):
     """``generate._chunk_forward`` under shard_map's positional calling
-    convention (``n_valid`` is an array argument there)."""
+    convention (``n_valid`` is an array argument there — always given, so
+    a mesh chunk returns its last valid row's logits, ``[1, 1, V]``)."""
     return _chunk_forward(params, chunk, caches, prefix_len,
                           n_valid=n_valid, **kw)
 

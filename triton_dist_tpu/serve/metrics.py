@@ -61,8 +61,8 @@ REQUESTS_RETAIN = 4096
 #: aggregate, so keep them in lockstep.
 MERGE_COUNTERS = (
     "steps", "decode_steps", "verify_rounds", "prefill_tokens",
-    "prefill_dispatches", "prefill_pad_tokens", "scratch_dispatches",
-    "preemptions", "completed",
+    "prefill_dispatches", "prefill_pad_tokens", "prefill_tail_rows",
+    "scratch_dispatches", "preemptions", "completed",
     "decode_tokens", "dispatches",
     "host_syncs", "host_choices", "shed", "deadline_expired", "quarantined",
     "callback_errors", "forward_retries", "forward_bisections",
@@ -270,6 +270,11 @@ class ServeMetrics:
     # a call recomputed where its window slid back at the scratch's end)
     prefill_dispatches: int = 0
     prefill_pad_tokens: int = 0
+    # rows those calls carried past the last layer that writes a cache or
+    # a state, through the head: the rows of the logits that a prompt's
+    # LAST call returned (the others skip what nobody reads) — ONE a
+    # finished prefill, of a program that keeps the row the engine reads
+    prefill_tail_rows: int = 0
     # launches a request costs on its way in: ``zero_scratch`` program
     # calls, ONE a cold admission (a warm one gathers its scratch instead)
     scratch_dispatches: int = 0
@@ -1021,6 +1026,7 @@ class ServeMetrics:
                 self.prefill_tokens / self.prefill_dispatches
                 if self.prefill_dispatches else 0.0),
             "pad_share": self.prefill_pad_tokens / rows if rows else 0.0,
+            "tail_rows": self.prefill_tail_rows,
             "width": self.prefill_width,
             "scratch_dispatches": self.scratch_dispatches,
         }
@@ -1192,6 +1198,8 @@ class ServeMetrics:
                 "prefill_chunk program calls")
         counter("serve_prefill_pad_tokens_total", self.prefill_pad_tokens,
                 "rows of those calls that prefilled no new token")
+        counter("serve_prefill_tail_rows_total", self.prefill_tail_rows,
+                "rows a prompt's last call carried through the head")
         counter("serve_prefill_scratch_dispatches_total",
                 self.scratch_dispatches,
                 "zero_scratch program calls: one a cold admission")
