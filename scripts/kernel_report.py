@@ -16,8 +16,7 @@ legs plus the phase-sliced per-ring-step replay under
   — the same ui.perfetto.dev file a ``group_profile`` device capture
   or an engine ``FlightRecorder.export_profile`` dropped into the
   same directory joins;
-- ONE summary JSON line on stdout (what ``bench.py``'s
-  ``kernel_report`` leg parses).
+- ONE summary JSON line on stdout.
 
 Examples::
 

@@ -24,7 +24,7 @@ Run on the chip through the chip tool, from the repo root:
 
     python scripts/smoke_tpu.py
 
-Off a TPU it refuses to start, the way ``bench.py`` does.
+Off a TPU it refuses to start, the way ``chip_smoke.py`` does.
 """
 
 import os

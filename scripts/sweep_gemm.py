@@ -2,7 +2,7 @@
 
 Paired-diff timing: a 1-iteration and a 17-iteration chain of dependent
 matmuls inside one jit; (t17 - t1) / 16 cancels the per-call dispatch
-overhead.  Short chains (bench.py's 1v9) showed ±10% IQR; 1v17 with 9
+overhead.  Short chains (1 against 9) showed ±10% IQR; 1v17 with 9
 trials was stable to ~2% (docs/perf.md).
 
 Run on the chip through the chip tool: `python scripts/sweep_gemm.py`

@@ -104,7 +104,7 @@ _FAST_GATE_MODULES = {
     # decode horizon: the H in {1, 4, 16} greedy oracle, host-vs-device
     # sampler equality, dispatch-economics bound, and horizon-granular
     # fault containment gate the fused decode path; preemption/spec
-    # interactions and the wall-clock bench carry @pytest.mark.slow.
+    # interactions carry @pytest.mark.slow.
     "test_serve_horizon",
     # sharded-engine serving: the mesh geometry rejection matrix, the
     # partitioned block allocator, the mesh-vs-world-1 bit-exactness
@@ -130,16 +130,15 @@ _FAST_GATE_MODULES = {
     # safety, id-reuse orphaning, LRU eviction, COW splits), the
     # warm≡cold≡Generator.generate oracles (greedy/sampled/horizon-fused),
     # session hits over generated pages, eviction×preemption, warm-cache
-    # snapshot/restore, journal rotation, and the bench floor helper all
-    # run in the gate (the whole file is the fast tier).
+    # snapshot/restore, journal rotation, and shared-prompt traffic as
+    # counts all run in the gate (the whole file is the fast tier).
     "test_serve_prefix",
     # flight recorder / observability: taxonomy meta-test (every
     # FinishReason + fault point has a registered event), chaos-drain
     # event completeness, nested Perfetto spans, histogram-vs-numpy,
     # Prometheus exposition + live endpoint, bounded-memory regressions,
-    # and the kill -> flight_*.json -> restore-provenance loop; only the
-    # wall-clock overhead gate is @pytest.mark.slow (bench.py enforces
-    # the PERF_FLOORS.json serve_trace_overhead floor).
+    # and the kill -> flight_*.json -> restore-provenance loop (the
+    # whole file is the fast tier).
     "test_serve_trace",
     # one-dispatch speculative decoding: the fused-round oracle (greedy
     # == Generator.generate; seeded-sampled == the draft-less engine), k-ladder warmup flatness, adaptive-k
@@ -264,6 +263,9 @@ _FAST_GATE_MODULES = {
     # full chunk and a residual, from an empty scratch and a prefilled
     # one; and ``prefill_chunked``, which keeps all rows (~1.5 min).
     "test_chunked_prefill",
+    # every repo path README.md and five docs name in backticks exists
+    # (ISSUE 43): a case a document, no jax, under a second.
+    "test_docs_paths",
 }
 
 # Heavy tests inside core modules whose coverage is duplicated by a
@@ -309,32 +311,6 @@ _FAST_GATE_EXCLUDES = {
     "test_hier_all_to_all_matches_flat[xla]",
     "test_torus2d_allgather_order_matches_hier",
     "test_torus3d_allgather_bf16_uneven",
-    # PR 21: the gate ran 858 s of its 870 s timeout at the seed, before
-    # the eight jaxpr audits went green (+35 s); with nothing moved it
-    # ran 908 s, and with only the first eight below moved a slow hour
-    # of this shared host had `timeout` kill it at 98%.  No
-    # acceptance-bar chaos harness and no kernel's only parity test is
-    # here: each of these is one more case of a matrix or sweep whose
-    # other cases stay in the gate — the [mesh8] bidir rings ([mesh4]
-    # stays), the gemm_rs twin of the ag_gemm comm-noise test, the small
-    # fused torus GEMM-RS (test_torus_gemm_rs stays), the multi-axis
-    # torus dispatch matrix (each torus kernel's own test stays), the
-    # spec k-ladder warm-up sweep (the horizon-ladder sweep stays), the
-    # hybrid pp x hier-dp training step (the hier allreduce tree stays),
-    # the mid-stream spec restore (the bailed-engine and draft-less
-    # restores stay), and spec under the seq layout (its heads and 2D
-    # twins were slow-tier already; the world-1 spec oracle and the seq
-    # preemption oracle stay).  They come back when the serve files
-    # share warmed engines (ROADMAP Queue 3 item 1).
-    "test_gemm_rs_bidir_matches_xla[mesh8]",
-    "test_ag_gemm_bidir_matches_xla[mesh8]",
-    "test_gemm_rs_bidir_under_comm_noise",
-    "test_torus_gemm_rs_fused_small",
-    "test_multi_axis_dispatch",
-    "test_spec_warmup_flat_misses_across_k_ladder",
-    "test_pp_hybrid_hier_dp_matches_plain",
-    "test_spec_snapshot_restore_mid_stream_bit_exact",
-    "test_mesh_seq_spec_oracle",
 }
 
 

@@ -537,7 +537,7 @@ def test_waiver_mechanics(tmp_path):
 @pytest.mark.slow
 def test_lint_cli_clean_tree_and_report(tmp_path):
     """scripts/lint_dist.py exits 0 on the clean tree and writes the
-    JSON report bench.py stamps."""
+    JSON report."""
     out = tmp_path / "report.json"
     proc = subprocess.run(
         [sys.executable, os.path.join(REPO, "scripts", "lint_dist.py"),

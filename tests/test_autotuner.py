@@ -224,7 +224,7 @@ def test_eager_failure_chains_cause():
 
 def test_measure_hook_overrides_timing():
     """A custom measure hook both drives selection and proves pluggability
-    (autotune_onchip.py plugs in a chain-based protocol)."""
+    (a chip harness plugs in its own protocol)."""
     from triton_dist_tpu.autotuner import AutotunedFunction, Config
 
     calls = []

@@ -43,8 +43,8 @@ def test_require_tpu_refuses_the_cpu():
     """Entry points that report on the device have no CPU fallback."""
     from triton_dist_tpu.runtime import require_tpu
 
-    with pytest.raises(SystemExit, match="bench.py: needs a TPU"):
-        require_tpu("bench.py")
+    with pytest.raises(SystemExit, match="chip_smoke.py: needs a TPU"):
+        require_tpu("chip_smoke.py")
 
 
 def test_compile_cache_is_placed_from_outside_or_in_the_checkout(

@@ -639,9 +639,3 @@ def test_controller_role_validation(tiny, tmp_path):
         FleetController(lambda d: _engine(gen, params, snapshot_dir=d),
                         1, root=str(tmp_path / "v4"),
                         roles={"r9": "decode"})
-
-
-def test_zero_loss_floor_registered():
-    import json
-    floors = json.load(open(os.path.join(REPO, "PERF_FLOORS.json")))
-    assert floors["floors"]["serve_disagg_zero_loss"]["min"] == 1.0

@@ -1211,9 +1211,7 @@ def test_assemble_fleet_trace_from_flight_files(tmp_path):
 
 def test_fleet_trace_level_zero_disables_ring_and_audit(tiny, tmp_path):
     """trace_level=0 on the controller: no controller events, no audit
-    entries, no flight flush — the 'off' leg bench_serve --fleet
-    --trace measures (the PERF_FLOORS serve_fleet_trace_overhead
-    contract)."""
+    entries, no flight flush: off is off."""
     cfg, params, gen = tiny
     clock = _Tick()
     fc = _fleet(gen, params, tmp_path / "fleet", clock, n=2,
@@ -1226,12 +1224,6 @@ def test_fleet_trace_level_zero_disables_ring_and_audit(tiny, tmp_path):
     assert fc.trace.events() == [] and fc.trace.emitted == 0
     assert fc.audit.recorded == 0 and fc.audit.entries() == []
     assert fc.flight_flush("noop") is None
-
-
-def test_floor_file_has_fleet_trace_overhead():
-    with open(os.path.join(REPO, "PERF_FLOORS.json")) as f:
-        floors = json.load(f)["floors"]
-    assert floors["serve_fleet_trace_overhead"]["min"] == 0.95
 
 
 def test_fleet_queue_expires_parked_migration_recs(tiny, tmp_path):

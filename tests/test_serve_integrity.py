@@ -836,11 +836,3 @@ def test_durable_writes_lint_rule_registered_and_waived():
     waived = {w["violation"] for w in rep["waived"]}
     assert any("write_port_file" in w for w in waived)
     assert any("write_trace" in w for w in waived)
-
-
-def test_corrupt_zero_loss_floor_registered():
-    with open(os.path.join(REPO, "PERF_FLOORS.json"),
-              encoding="utf-8") as f:
-        floors = json.load(f)
-    entry = floors["floors"]["serve_corrupt_recovery_zero_loss"]
-    assert entry["min"] == 1.0

@@ -15,7 +15,6 @@ owning rank's partition.
 """
 
 import glob
-import json
 import os
 
 import jax
@@ -769,18 +768,6 @@ def test_mesh_drain_2d_layout_pairs(model, mesh22, mesh2):
         assert res["adopted"] == ["m"], (src_shard, dst_shard)
         got = dst.run()["m"].token_ids
         assert got == want, (src_shard, dst_shard)
-
-
-def test_mesh_floor_present():
-    """PERF_FLOORS.json carries the serve_mesh_zero_loss correctness
-    floor at 1.0 (bench.py's mesh leg gates on it) and its 2D twin
-    serve_mesh2d_zero_loss (the heads+seq paired-oracle leg)."""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    floors = json.load(open(os.path.join(root, "PERF_FLOORS.json")))
-    spec = floors["floors"]["serve_mesh_zero_loss"]
-    assert spec["min"] == 1.0
-    spec2d = floors["floors"]["serve_mesh2d_zero_loss"]
-    assert spec2d["min"] == 1.0
 
 
 def test_heterogeneous_mesh_fleet_chaos(model, mesh22, oracle, tmp_path):

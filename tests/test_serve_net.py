@@ -894,9 +894,3 @@ def test_supervisor_aggregate_exposes_replica_state():
     assert 'fleet_replica_state{replica="r0",state="dead"} 0' in text
     assert 'fleet_replica_state{replica="r1",state="dead"} 1' in text
     assert "fleet_scraped_replicas 0" in text
-
-
-def test_net_zero_loss_floor_registered():
-    with open(os.path.join(REPO, "PERF_FLOORS.json")) as f:
-        floors = json.load(f)["floors"]
-    assert floors["serve_fleet_net_zero_loss"]["min"] == 1.0

@@ -15,7 +15,6 @@ shed-always-lands-a-terminal regression, and the shed-paths-observable
 lint rule."""
 
 import glob
-import os
 
 import jax
 import jax.numpy as jnp
@@ -326,40 +325,6 @@ def test_brownout_caps_best_effort_output(tiny):
     assert len(outs["be2"].token_ids) <= 2
     assert len(outs["i"].token_ids) == 8          # interactive untouched
     assert outs["i"].token_ids == _oracle(gen, params, ps[1], 8)
-
-
-# ---------------------------------------------------------------------------
-# trace-shaped workload generator (scripts/benchlib.py)
-# ---------------------------------------------------------------------------
-
-
-def test_trace_workload_deterministic_and_bursty():
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..",
-                                    "scripts"))
-    from benchlib import trace_workload
-
-    a = trace_workload(7, 200)
-    assert a == trace_workload(7, 200)            # seeded: bit-identical
-    assert a != trace_workload(8, 200)
-    ts = [r["t"] for r in a]
-    assert ts == sorted(ts) and ts[0] > 0
-    assert {r["slo"] for r in a} == set(SLO_CLASSES)
-    assert len({r["rid"] for r in a}) == 200
-    # bursty means over-dispersed: the interarrival coefficient of
-    # variation sits well above a flat Poisson process's 1.0
-    gaps = np.diff([0.0] + ts)
-    cv = gaps.std() / gaps.mean()
-    assert cv > 1.2
-    # heavy-tailed lognormal lengths honor their clip bounds
-    b = trace_workload(3, 100, prompt_min=4, prompt_max=32,
-                       output_min=2, output_max=16)
-    assert all(4 <= r["prompt_len"] <= 32 for r in b)
-    assert all(2 <= r["max_new"] <= 16 for r in b)
-    with pytest.raises(ValueError):
-        trace_workload(0, 0)
-    with pytest.raises(ValueError):
-        trace_workload(0, 5, burst_factor=0.5)
 
 
 # ---------------------------------------------------------------------------

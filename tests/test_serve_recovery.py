@@ -744,9 +744,9 @@ def test_replay_redelivers_stream_finished_at_crash(tiny, tmp_path):
 
 
 def test_oneshot_foreign_snapshot_keeps_periodic_cadence(tiny, tmp_path):
-    """A one-shot snapshot() to a foreign directory (the bench_serve
-    pattern) must not delay the next periodic home capture, consume
-    home step numbers, or evict the cached home-directory manager."""
+    """A one-shot snapshot() to a foreign directory must not delay the
+    next periodic home capture, consume home step numbers, or evict the
+    cached home-directory manager."""
     cfg, params, gen = tiny
     home = tmp_path / "home"
     eng = _engine(gen, params, clock=_Tick(), snapshot_dir=str(home),

@@ -243,11 +243,15 @@ def test_spec_fused_sampled_matches_plain_engine_and_reproduces():
     assert sp["accept_rate"] > 0.8, sp  # coupled draws: self-draft agrees
 
 
-def test_spec_dispatch_economics_vs_plain_horizon():
-    """ISSUE-7 acceptance: fused spec rounds with a well-matched draft
-    commit at least as many tokens per dispatch as plain fused decode at
-    H=8 (a round emits up to k+1 per row per dispatch vs the horizon's
-    H), and a spec engine pays <= 0.15 dispatches/token."""
+@pytest.mark.parametrize("k", [2, 4, 8])
+def test_spec_dispatch_economics_vs_plain_horizon(k):
+    """The target as its own draft (acceptance ~1, so the counts isolate
+    the fused round from draft quality): a round of depth ``k`` commits
+    up to k + 1 tokens a row in ONE dispatch, so on the same requests it
+    delivers at least the tokens a dispatch of the plain engine that
+    fuses ``k`` decode steps.  At k = 8 that is the ISSUE-7 acceptance
+    bar (spec >= plain fused decode at H=8) and the spec engine pays
+    <= 0.15 dispatches/token.  Counters only, no clock."""
     cfg, params, gen, _, _, _ = _models()
     draft = Generator(cfg, gen.mesh, axis="sp", max_seq=64)  # self-draft
     rng = np.random.default_rng(9)
@@ -257,8 +261,8 @@ def test_spec_dispatch_economics_vs_plain_horizon():
 
     def run(**kw):
         eng = ServeEngine(gen, params, num_blocks=40, page_size=4,
-                          max_batch=2, prefill_chunk=4, clock=_Tick(),
-                          **kw)
+                          max_batch=2, prefill_chunk=4, pipeline=2,
+                          clock=_Tick(), **kw)
         for i, p in enumerate(prompts):
             eng.submit(Request(f"d{i}", p,
                                SamplingParams(max_new_tokens=n_new)))
@@ -266,15 +270,16 @@ def test_spec_dispatch_economics_vs_plain_horizon():
         assert all(len(o.token_ids) == n_new for o in outs.values())
         return eng.metrics.summary()
 
-    s_spec = run(draft=draft, draft_params=params, spec_k=8, pipeline=2)
-    s_plain = run(horizon=8, pipeline=2)
-    d_spec, d_plain = s_spec["decode"], s_plain["decode"]
+    s_spec = run(draft=draft, draft_params=params, spec_k=k)
+    d_spec, d_plain = s_spec["decode"], run(horizon=k)["decode"]
     assert (d_spec["tokens_per_dispatch"]
             >= d_plain["tokens_per_dispatch"]), (d_spec, d_plain)
-    assert d_spec["dispatches_per_token"] <= 0.15, d_spec
     sp = s_spec["spec"]
-    assert sp["spec_tokens_per_dispatch"] >= 8.0, sp
     assert sp["accept_rate"] > 0.8, sp
+    assert sp["bailouts"] == 0 and set(sp["chosen_k"]) == {k}, sp
+    assert sp["spec_tokens_per_dispatch"] >= k, sp
+    if k == 8:
+        assert d_spec["dispatches_per_token"] <= 0.15, d_spec
 
 
 # ---------------------------------------------------------------------------
@@ -705,25 +710,6 @@ def test_spec_snapshot_restore_without_draft_requeues():
         assert outs["r0"].token_ids == want
     finally:
         shutil.rmtree(d, ignore_errors=True)
-
-
-# ---------------------------------------------------------------------------
-# fast tier: the bench_serve --spec gate (the acceptance criterion)
-# ---------------------------------------------------------------------------
-
-
-def test_bench_spec_gate():
-    """scripts/bench_serve.py --spec on a tiny config: fused spec rounds
-    report >= plain fused decode tokens-per-dispatch at H=8 and <= 0.15
-    dispatches/token — the ISSUE-7 acceptance bar, counter-derived (no
-    wall clock), kept fast enough for tier-1."""
-    from scripts.bench_serve import bench_spec
-
-    r = bench_spec(k=8, batch=2, prompt_len=8, new_tokens=24, dim=16,
-                   n_layers=1, vocab=64, page_size=8, warmup=False)
-    assert r["spec_vs_plain_tokens_per_dispatch"] >= 1.0, r
-    assert r["dispatches_per_token"] <= 0.15, r
-    assert r["accept_rate"] > 0.8, r  # the self-draft agrees
 
 
 if __name__ == "__main__":

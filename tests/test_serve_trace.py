@@ -8,9 +8,8 @@ parsing (live endpoint included), bounded-memory regressions (ring,
 token-time windows, gauge aggregates, retired-request map), the
 taxonomy meta-test (a new FinishReason or fault point cannot silently
 skip the recorder), and a kill/restart that leaves a readable
-``flight_*.json`` whose trail a restored engine re-carries.  The
-wall-clock trace-overhead gate is slow-tier (bench.py enforces the
-``serve_trace_overhead`` floor in PERF_FLOORS.json).
+``flight_*.json`` whose trail a restored engine re-carries.  What
+tracing costs is the chip's to say (PERF.md), never a host clock here.
 """
 
 import json
@@ -663,30 +662,6 @@ def test_rotated_journal_preserves_first_token_time(tmp_path):
     assert j["d0"].first_tok == 1.0
     assert j["i0"].first_tok == 2.0
     assert j["d0"].token_list() == [5, 6, 7, 8]
-
-
-def test_floor_file_has_trace_overhead():
-    with open(os.path.join(REPO, "PERF_FLOORS.json")) as f:
-        floors = json.load(f)["floors"]
-    assert floors["serve_trace_overhead"]["min"] == 0.95
-
-
-# ---------------------------------------------------------------------------
-# slow tier: the wall-clock overhead gate (bench.py enforces the real
-# floor; this is the smoke-level sanity bound)
-# ---------------------------------------------------------------------------
-
-
-@pytest.mark.slow
-def test_bench_trace_overhead_gate():
-    from scripts.bench_serve import bench_trace_overhead
-
-    r = bench_trace_overhead(batch=2, prompt_len=8, new_tokens=24,
-                             dim=16, n_layers=1, repeats=2)
-    assert r["toks_per_s_trace_on"] > 0
-    # generous CI bound — PERF_FLOORS.json holds the honest 0.95 on the
-    # quiet bench host
-    assert r["serve_trace_overhead"] >= 0.8, r
 
 
 # ---------------------------------------------------------------------------

@@ -8,8 +8,8 @@ registered rules — one registry, one violation type, one waiver
 mechanism — consumed three ways: the original tests call
 :func:`run_rule` (same assertions, same failures), ``scripts/
 lint_dist.py`` runs the whole registry as a CLI gate (JSON report,
-nonzero exit on unwaived violation), and ``bench.py`` stamps the
-verdict into the bench artifact.
+nonzero exit on unwaived violation), and :func:`run_rules` returns
+the same report to any other caller.
 
 A rule is a zero-argument callable returning ``list[Violation]``;
 register with ``@rule("name")``.  Waivers (``LINT_WAIVERS.json`` at the
@@ -122,7 +122,7 @@ def apply_waivers(violations: list, waivers: list) -> tuple:
 
 def run_rules(names=None, waivers_path: str = None) -> dict:
     """Run rules and fold in waivers; the dict is the JSON-report shape
-    ``scripts/lint_dist.py`` emits and ``bench.py`` stamps."""
+    ``scripts/lint_dist.py`` emits."""
     names = sorted(RULES) if names is None else list(names)
     violations: list = []
     for name in names:

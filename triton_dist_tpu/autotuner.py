@@ -189,8 +189,8 @@ class AutotunedFunction:
 
         The default is one call fenced by ``block_until_ready``.  Pass a
         custom ``measure`` where one call is too short to time against
-        host dispatch (e.g. the dependent-chain protocol of
-        scripts/autotune_onchip.py / scripts/benchlib.py).
+        host dispatch (e.g. a chain of dependent calls timed as one:
+        ``scripts/autotune_onchip.py`` at git ``d7c7cac`` did so).
         """
         if self.measure is not None:
             return self.measure(self.fn, args, kwargs, config)

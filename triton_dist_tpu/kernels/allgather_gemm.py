@@ -277,9 +277,9 @@ def _ag_gemm_kernel(
     World-1: the host aliases A into the gathered-A output
     (``input_output_aliases``), so the kernel is a single pipeline cycle
     with no staging DMA and no semaphores — measured at parity with the
-    dense kernel (scripts/exp_ring_schedule.py: ring-minus-dense delta
-    +0.02..0.22 ms on an ~2.5 ms GEMM; the old per-step code's documented
-    146 TFLOPS was protocol bias plus the staging DMA).
+    dense kernel (docs/perf.md "Ring-kernel schedule overhead decomposed":
+    ring-minus-dense +0.02..0.22 ms on an ~2.5 ms GEMM; the old per-step
+    code's documented 146 TFLOPS was protocol bias plus the staging DMA).
     """
     if wire:
         (a_ref, s_ref, b_ref, ag_ref, ag_s_ref, out_ref,
@@ -734,8 +734,8 @@ def ag_gemm_shard(a_shard, b_shard, *, axis, impl, bm=None, bn=None,
         # (casts, feedback transforms) into the dot's prologue/epilogue,
         # saving whole HBM passes that a custom-call pallas kernel
         # cannot — measured 0.7 ms/pair faster at the bench shape in the
-        # same rotated trial loop (exp_ring_schedule.py 'xdot' vs
-        # 'dense'; standalone rates are equal at ~190).  int8 keeps the
+        # same rotated trial loop ('xdot' vs 'dense', docs/perf.md
+        # "AG-GEMM"; standalone rates are equal at ~190).  int8 keeps the
         # pallas double-rate kernel (358 vs ~280 TOPS through XLA's
         # path).  Explicit impl="pallas" still runs the ring kernel
         # (what the hardware smoke exercises); interpret mode keeps it
@@ -846,7 +846,7 @@ def ag_gemm_shard(a_shard, b_shard, *, axis, impl, bm=None, bn=None,
         ],
         # World-1: gathered A IS A — alias instead of staging (the
         # staging DMA's full [m_loc, K] read+write costs ~8% of the GEMM
-        # at the bench shape; exp_ring_schedule.py).
+        # at the bench shape; docs/perf.md "Ring-kernel schedule ...").
         input_output_aliases={0: 0} if world == 1 else {},
         compiler_params=pltpu.CompilerParams(
             has_side_effects=True,

@@ -154,7 +154,7 @@ def _flash_kernel(qoffs_ref, koffs_ref, q_ref, k_ref, v_ref, out_ref,
         logits = apply_soft_cap(logits, soft_cap)
         # (A base-2 exp fold — exp2 with log2e in the scale — measured
         # NO gain here: Mosaic already lowers exp that way.  r5 ceiling
-        # experiment, scripts/exp_prefill_ceiling.py.)
+        # experiment, scripts/exp_prefill_ceiling.py at git d7c7cac.)
 
         if masked:
             mask = _visibility_mask(q_start, k_start, causal=causal,
@@ -183,8 +183,8 @@ def _flash_kernel(qoffs_ref, koffs_ref, q_ref, k_ref, v_ref, out_ref,
         # Skip blocks with no visible (qpos, kpos) pair — their DMAs
         # already streamed; compute is the prefill bottleneck.  Among
         # the LIVE blocks, route fully-visible ones to the MASK-FREE
-        # body (the r5 ceiling fix, scripts/exp_prefill_ceiling.py:
-        # +7.5% paired; see _block_full).
+        # body (the r5 ceiling fix, docs/perf.md "Flash-attention
+        # prefill": +7.5% paired; see _block_full).
         live = _block_live(q_start, k_start, causal=causal,
                            window=window, bq=bq, bk=bk)
         full = _block_full(q_start, k_start, causal=causal,
@@ -477,7 +477,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, do, q_offset, kv_offset, causal,
     or a tuple of run starts (segmented layout — the zigzag CP shard).
 
     Default blocks (bq=128, bk=512) from the r4 chip sweep
-    (bench_flash_prefill --grad --bwd-blocks); both kernels keep more
+    (docs/perf.md "Flash-attention prefill"); both kernels keep more
     operands resident than the forward (q, k, v, do + two accumulators),
     so the forward's bk=1024 does NOT transfer."""
     B, Hq, Sq, D = q.shape

@@ -534,8 +534,8 @@ def gqa_decode_shard(q, k, v, local_lens, *, block_s=None, impl="auto",
     once).  Since round 2's kernel tuning (K/V fed to the MXU in their
     storage dtype, P cast down for the PV matmul, parallel (b, h)
     dimension semantics) the Pallas split-KV kernel matches-or-beats XLA's
-    fused attention at the serving shapes (measured table: docs/perf.md,
-    protocol: scripts/bench_decode.py), so ``auto`` selects the Pallas
+    fused attention at the serving shapes (measured table and protocol:
+    docs/perf.md "GQA flash decode"), so ``auto`` selects the Pallas
     kernel whenever the shapes allow it — including, since round 4,
     int8-KV caches: the fused int8 split-KV kernel (dequant in the chunk
     loop, lane-packed scale planes) reads 168 µs vs XLA's ~200 at the

@@ -59,8 +59,8 @@ def configure_compile_cache() -> str:
 def require_tpu(what: str, n_devices: int = 1) -> None:
     """Refuse to go on unless this process sees ``n_devices`` TPU chips.
 
-    For entry points whose output is about the device (``bench.py``,
-    ``scripts/bench_*.py``, the chip smokes): a time or a rate taken on
+    For entry points whose output is about the device (the chip
+    smokes, ``scripts/sweep_gemm.py``): a time or a rate taken on
     the CPU backend is not a slower device number, it is a different
     quantity, so there is no fallback — the process exits non-zero.
     Initialises the backend (a TPU process owns the chip from here on)."""

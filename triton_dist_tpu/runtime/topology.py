@@ -98,7 +98,7 @@ def peak_bf16_tflops() -> float:
 
 
 # Best *measured* dense-dot TFLOPS on each chip kind at the bench shape
-# (M=8192 K=8192 N=3584 bf16; docs/perf.md "AG-GEMM").  bench.py uses this
+# (M=8192 K=8192 N=3584 bf16; docs/perf.md "AG-GEMM").  A harness uses it
 # as a self-consistency bound: no honest chain that also pays AG dispatch
 # can beat XLA's own dense dot on the same chip at the same shape, so any
 # reading above it is elision, not performance.

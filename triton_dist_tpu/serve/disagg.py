@@ -50,8 +50,8 @@ Every push decision lands in the router audit (``kind="push"`` /
 did it decode there" with the pressures and the rejected-capacity walk.
 
 See docs/serving.md "Disaggregated serving" for the operator recipe and
-the idempotency argument; ``examples/serve.py --disagg P:D`` and
-``scripts/bench_serve.py --disagg P:D`` drive it.
+the idempotency argument; ``examples/serve.py --disagg P:D`` drives
+it, and ``tests/test_serve_disagg.py`` holds its chaos harnesses.
 """
 
 from __future__ import annotations

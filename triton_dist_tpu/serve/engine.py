@@ -629,8 +629,8 @@ class ServeEngine:
         # flight_<step>.json on any fault/crash path, and rides
         # snapshots so a restored engine carries its previous life's
         # trail.  trace_level=0 turns the hot-path appends off entirely
-        # (bench_serve --trace holds the on/off throughput ratio at
-        # >= 0.95 via PERF_FLOORS.json's serve_trace_overhead).
+        # (every cell of the benchmark runs at the default, 1; what
+        # that costs on the chip has no reading: PERF.md section 7).
         if trace_level < 0:
             raise ValueError(f"trace_level must be >= 0, got {trace_level}")
         self.trace = FlightRecorder(capacity=trace_events,
@@ -641,8 +641,8 @@ class ServeEngine:
         # the recorder, register_compiled below wires every program's
         # CountingJit timer into metrics.observe_program — step time
         # decomposes by device program (summary()["programs"],
-        # serve_program_ms{program=}), and the bench_serve --trace
-        # overhead gate measures the timers together with the ring.
+        # serve_program_ms{program=}); one knob turns the timers and
+        # the ring off together.
         self.metrics.program_timing = trace_level >= 1
         self._trace_fault_idx = 0   # audit entries already mirrored
         self._last_flight_step = -1  # flush throttle: one file per step

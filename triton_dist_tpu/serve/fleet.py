@@ -1123,7 +1123,7 @@ class FleetController:
                                     level=trace_level)
         # the router decision audit ring (docs/observability.md "Fleet
         # observability"); gated by the same level knob as the recorder
-        # so bench_serve --fleet --trace measures both off together
+        # so trace_level=0 turns both off together
         self.audit = DecisionAudit(capacity=audit_events,
                                    enabled=trace_level > 0)
         os.makedirs(root, exist_ok=True)
@@ -1301,8 +1301,8 @@ class FleetController:
         # candidate pressures, captured BEFORE the walk: the audit
         # entry answers "why did this request land there" with the
         # numbers the router actually weighed.  Gated on the audit knob
-        # — the trace_level=0 "off" leg of bench_serve --fleet --trace
-        # must not pay the O(replicas) capture either.
+        # — a trace_level=0 controller must not pay the O(replicas)
+        # capture either.
         pressures = ({n: round(self.router.pressure(l, deadline=deadline),
                                4) for n, l in cands}
                      if self.audit.enabled else None)

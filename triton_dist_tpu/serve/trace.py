@@ -13,8 +13,8 @@ artifact, three ways:
   accept count, blocks touched).  Hot-path discipline: ``emit`` is an
   append to a bounded ring — no device sync, no I/O, no string
   formatting — and a single ``level`` knob gates it off entirely
-  (``bench_serve --trace`` measures the overhead; ``PERF_FLOORS.json``
-  holds ``serve_trace_overhead`` >= 0.95).
+  (what level 1 costs on the chip has no reading yet: PERF.md
+  section 7).
 
 - **Perfetto export** (:meth:`FlightRecorder.to_perfetto`): per-request
   lifecycle *spans* (queue → prefill → decode, re-opened across
