@@ -257,6 +257,17 @@ _FAST_GATE_MODULES = {
     # group by name (~3 min).
     "test_ssm_yoco",
     "test_gdn_hybrid",
+    # the laguna block (ISSUE 44: models/swa_moe.py grown): query heads
+    # that differ by layer over the same KV heads, a per-head output gate,
+    # a theta and a rotary width a layer kind, a dense lead layer, a
+    # shared expert beside 4 held of 8 — engine logits through both groups
+    # against benchmarks/reference/laguna.py over the XLA twins and the
+    # interpreted Mosaic calls, a bfloat16 model failing the tolerance,
+    # every mechanism moving the logits when the reference drops it, the
+    # eight shares + the shared expert once adding up to the uncut layer,
+    # from_hf's refusals by name, the paged call at groups of 9 and 6
+    # (~2 min).
+    "test_laguna",
     # a prefill chunk keeps the row its caller reads (ISSUE 42): over the
     # six toy engines, the engine's chunk program against the all-rows one
     # — logits of the kept row, every cache and state plane bitwise — at a

@@ -660,15 +660,28 @@ def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu, name,
 # ---------------------------------------------------------------------------
 
 
-def _swa_moe_programs():
-    """Every program of ``mellum2_l8_mixedctx_sat`` at the file's widths
-    and engine sizes, over BOTH cache groups (the full group's 5,120
-    blocks on 2 layers, the window group's derived 641 on 6, one table a
-    group) -> ``[(name, jitted, args, statics, the Mosaic calls it must
-    hold)]``: the paged call carries its layer kind's name, every layer
-    one gate-up + one down grouped GEMM; a prefill chunk flash attention a
-    layer under its ``annotate`` label (``None``: counted by the test);
-    the page fill none."""
+# cell -> (configuration file, its ladder, [full, window] blocks, KV heads,
+# the grouped GEMMs' row tile in a chunk)
+_CELLS_SWA_MOE = {
+    "mellum2": ("mellum2-12b-a2.5b-l8.json",
+                [2048, 4096, 8192, 16384, 20480], [5120, 641], 4, 256),
+    # ISSUE 44: 72 / 48 query heads over 8 KV heads (q blocks of 9 and 6
+    # rows), 32 of 256 experts held at top-10, a dense lead layer
+    "laguna": ("laguna-s-2.1-ep8-l9.json",
+               [2048, 4096, 8192, 10240], [2560, 385], 8, 128),
+}
+
+
+def _swa_moe_programs(cell="mellum2"):
+    """Every program of ``mellum2_l8_mixedctx_sat`` (or, ``cell="laguna"``,
+    of ``lagS_ep8_l9_agentmix_sat``) at the file's widths and engine
+    sizes, over BOTH cache groups (the full group's blocks as the file
+    states them, the window group's as the engine derives them, one table
+    a group) -> ``[(name, jitted, args, statics, the Mosaic calls it must
+    hold)]``: the paged call carries its layer kind's name, every expert
+    layer one gate-up + one down grouped GEMM; a prefill chunk flash
+    attention a layer under its ``annotate`` label (``None``: counted by
+    the test); the page fill none."""
     import json
     import os
 
@@ -677,9 +690,9 @@ def _swa_moe_programs():
     from triton_dist_tpu.models import swa_moe as S
     from triton_dist_tpu.runtime.jit_cache import named
 
+    file, rungs, want_blocks, hkv, chunk_tile = _CELLS_SWA_MOE[cell]
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(
-            root, "benchmarks/configs/mellum2-12b-a2.5b-l8.json")) as f:
+    with open(os.path.join(root, "benchmarks/configs", file)) as f:
         config = json.load(f)
     cfg = builders_swa_moe.model_config(config)
     eng = config["engine"]
@@ -690,15 +703,15 @@ def _swa_moe_programs():
     gen = S.SwaMoeGenerator(cfg, max_seq=max_seq)
     ladder = E.build_bucket_ladder(max(page, eng["prefill_chunk"]), max_seq,
                                    page)
-    assert ladder == [2048, 4096, 8192, 16384, 20480]
+    assert ladder == rungs
     assert gen.kernel_gaps(page_size=page, ladder=ladder,
                            prefill_chunk=eng["prefill_chunk"]) == {}
-    assert gen.kv_planes == [(4, 128)] * 2
+    assert gen.kv_planes == [(hkv, 128)] * 2
     # the window group's count as the engine derives it
     ahead = eng["horizon"] * eng["pipeline"]
     blocks = [eng["num_blocks"],
               1 + batch * ((cfg.sliding_window + ahead - 2) // page + 2)]
-    assert blocks == [5120, 641]
+    assert blocks == want_blocks
     kinds = cfg.kinds
     pools = [tuple(s((blocks[k.group], h, page, d), cfg.dtype)
                    for h, d in gen.kv_planes) for k in kinds]
@@ -708,13 +721,15 @@ def _swa_moe_programs():
     d_args = d_args[:2] + (tables,) + d_args[3:]
     h_args = h_args[:2] + (tables,) + h_args[3:]
     kw = dict(cfg=cfg, page=page, **gen.serve_hooks())
-    want = {"gqa_paged_window": 6, "gqa_paged_full": 2,
-            M.GATE_UP_CALL: 8, M.DOWN_CALL: 8}
+    n_moe = cfg.n_layers - cfg.first_k_dense
+    want = {"gqa_paged_window": cfg.layer_types.count("window"),
+            "gqa_paged_full": cfg.layer_types.count("full"),
+            M.GATE_UP_CALL: n_moe, M.DOWN_CALL: n_moe}
     programs = [p + (want,) for p in _decode_programs(
         gen, kw, d_args, h_args, eng["horizon"])]
     # a prefill chunk: flash attention a layer (the window as a block
     # skip), the grouped GEMMs at the chunk's own row tile
-    assert cfg.row_tile(eng["prefill_chunk"]) == 256 and \
+    assert cfg.row_tile(eng["prefill_chunk"]) == chunk_tile and \
         cfg.row_tile(batch) == 32
     fill = jax.jit(named(PR._fill_pool_pages, "fill_pages", page=page,
                          kinds=kinds), donate_argnums=(0,))
@@ -731,20 +746,26 @@ def _swa_moe_programs():
     return programs
 
 
-def test_swa_moe_programs_compile_at_published_widths(v5e, as_tpu):
-    """Every program of ``mellum2_l8_mixedctx_sat`` — single-step decode,
-    the fused horizon (greedy and mixed) and its one-step link, prefill
-    chunks at every rung its prompts reach and the cap, the page fill — at
-    the file's widths and engine sizes, over BOTH cache groups
-    (:func:`_swa_moe_programs`): the paged call carries its layer kind's
-    name (6 ``gqa_paged_window`` + 2 ``gqa_paged_full`` a decode step: the
-    readers' patterns), every layer one gate-up + one down grouped GEMM,
-    and each program fits the chip beside nothing else.  The dense
-    family's call keeps no name (the test above)."""
+@pytest.mark.parametrize("cell,moe,layers", [("mellum2", 8, 8),
+                                             ("laguna", 8, 9)])
+def test_swa_moe_programs_compile_at_published_widths(v5e, as_tpu, cell, moe,
+                                                      layers):
+    """Every program of ``mellum2_l8_mixedctx_sat`` and of
+    ``lagS_ep8_l9_agentmix_sat`` — single-step decode, the fused horizon
+    (greedy and mixed) and its one-step link, prefill chunks at every rung
+    its prompts reach and the cap, the page fill — at the file's widths
+    and engine sizes, over BOTH cache groups (:func:`_swa_moe_programs`):
+    the paged call carries its layer kind's name (6 ``gqa_paged_window`` +
+    2 or 3 ``gqa_paged_full`` a decode step: the readers' patterns — at
+    laguna's 72 / 48 query heads over 8 KV heads the call lowers AS IT IS,
+    a q block of 9 or 6 rows, no padded form), every expert layer one
+    gate-up + one down grouped GEMM, a chunk one flash call a layer, and
+    each program fits the chip beside nothing else.  The dense family's
+    call keeps no name (the test above)."""
     from triton_dist_tpu.models import mla_moe as M
 
     worst = {}
-    for prog, jitted, args, statics, want in _swa_moe_programs():
+    for prog, jitted, args, statics, want in _swa_moe_programs(cell):
         compiled = _compiled(v5e, jitted, args, statics)
         text = compiled.as_text()
         assert text.split(",", 1)[0] == f"HloModule jit_{prog}"
@@ -752,8 +773,8 @@ def test_swa_moe_programs_compile_at_published_widths(v5e, as_tpu):
         if want is not None:
             assert calls == want, (prog, statics, calls)
         else:
-            assert calls[M.GATE_UP_CALL] == calls[M.DOWN_CALL] == 8
-            assert sum(calls.values()) == 24, calls    # + 8 flash calls
+            assert calls[M.GATE_UP_CALL] == calls[M.DOWN_CALL] == moe
+            assert sum(calls.values()) == 2 * moe + layers, calls  # + flash
         assert _gib(compiled) < HBM_GIB, (prog, statics, _gib(compiled))
         worst[prog] = max(worst.get(prog, 0), _gib(compiled))
     print("GiB a program:", {k: round(v, 2) for k, v in worst.items()})

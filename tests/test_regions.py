@@ -41,12 +41,13 @@ from triton_dist_tpu.serve import ServeEngine
 from triton_dist_tpu.serve import programs as PR
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-FAMILIES = ("dense", "latent", "sparse", "window", "state", "matrix")
+FAMILIES = ("dense", "latent", "sparse", "window", "state", "matrix",
+            "gated")
 # the four families the benchmark had before the layer loop took a ``mixer``
 QUARTET_FAMILIES = FAMILIES[:4]
 PROGRAMS = ("decode_horizon", "prefill_chunk", "paged_decode")
 # the modules that open regions (they import ``region`` by name)
-SCOPED = (G, M, PR, Y, GH)
+SCOPED = (G, M, PR, Y, GH, S)
 I32 = jnp.int32
 B, H, CHUNK = 2, 4, 64
 
@@ -102,7 +103,10 @@ def _build(family):
         gen = GH.GdnHybridGenerator(cfg, max_seq=256)
         params = GH.init_params(cfg, jax.random.key(3))
     else:
-        cfg = S.SwaMoeConfig.tiny(n_layers=4)
+        # "gated": the laguna block (heads by layer, a gate through the
+        # family's mixer, a dense lead layer, a shared expert)
+        cfg = (S.SwaMoeConfig.tiny_laguna() if family == "gated"
+               else S.SwaMoeConfig.tiny(n_layers=4))
         gen = S.SwaMoeGenerator(cfg, max_seq=256)
         params = S.init_params(cfg, jax.random.key(3))
     # the sparse block expands a chunk of 256 queries or more
@@ -246,6 +250,52 @@ def test_without_a_mixer_the_programs_are_the_quartets(
 
 
 # ---------------------------------------------------------------------------
+# No gate, one head count, one theta: the window family's programs are the
+# parent's
+# ---------------------------------------------------------------------------
+
+
+def _parent_swa_project(h, layer, pos, *, cfg, kind):
+    """``swa_moe.project`` as it stood before a layer could have its own
+    head count, theta and rotary width (ISSUE 44's parent, to the letter,
+    with the ``rope`` it called)."""
+    import functools
+
+    B_, T, _ = h.shape
+    h2 = h.reshape(B_ * T, cfg.dim)
+    q = (h2 @ layer["wq"]).reshape(B_, T, cfg.n_heads, cfg.head_dim)
+    k = (h2 @ layer["wk"]).reshape(B_, T, cfg.n_kv_heads, cfg.head_dim)
+    v = (h2 @ layer["wv"]).reshape(B_, T, cfg.n_kv_heads, cfg.head_dim)
+    yarn = cfg.yarn if kind.attn == "full" else None
+    inv_freq = M.rope_inv_freq(cfg.head_dim, cfg.rope_theta, yarn)
+    scale = 1.0 if yarn is None else float(yarn[4])
+    rope = functools.partial(M._rope, pos=pos, inv_freq=inv_freq,
+                             scale=scale)
+    return (rope(_rms_norm(q, layer["q_norm"], cfg.norm_eps)),
+            rope(_rms_norm(k, layer["k_norm"], cfg.norm_eps)), v)
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_an_ungated_block_lowers_as_the_parents(engines, monkeypatch,
+                                                program):
+    """The ``mellum`` block names no gate, no heads by layer, no second
+    theta and no rotary share (each default of the config): its toy
+    engine's programs lower to the same StableHLO text over today's
+    ``project`` and over the one it replaced, it hands the layer loop no
+    ``mixer``, and its seeded weights are the parent's draws."""
+    eng, chunk = engines("window")
+    assert "mixer" not in eng.gen.serve_hooks()
+    assert "wg" not in eng.params["layers"][0]
+    written = _lowered(eng, chunk, program).as_text()
+    monkeypatch.setattr(S, "project", _parent_swa_project)
+    bare, _ = _build("window")            # fresh jits: nothing cached
+    assert _lowered(bare, chunk, program).as_text() == written
+    for a, b in zip(jax.tree.leaves(eng.params),
+                    jax.tree.leaves(bare.params)):
+        assert np.array_equal(a, b)
+
+
+# ---------------------------------------------------------------------------
 # No row to keep: every program but the engine's chunk is the parent's
 # ---------------------------------------------------------------------------
 
@@ -350,6 +400,7 @@ _SEAM_PRODUCTS = {
     "proj": ("dense", "decode_horizon", "dot_general"),
     "kv_write": ("dense", "decode_horizon", "scatter"),
     "attn": ("window", "decode_horizon", "dot_general"),
+    "attn.gate": ("gated", "decode_horizon", "logistic"),
     "out_proj": ("dense", "decode_horizon", "dot_general"),
     "ffn": ("dense", "prefill_chunk", "dot_general"),
     "head": ("dense", "prefill_chunk", "dot_general"),

@@ -1104,13 +1104,15 @@ def _verify_forward(params, chunk, caches, kv_lens, *, cfg: LlamaConfig,
 
 
 def _prompt_forward(params, tokens, *, cfg, project, out_proj, ffn, attend,
-                    kinds=None):
+                    kinds=None, mixer=None):
     """Full-sequence forward on replicated weights that also returns the
     per-layer cache rows (post-RoPE, cache layout [B, Hkv, S, .], one per
     plane) and logits: :func:`_layer_stack` with a pair that keeps the
     rows it is handed.  ``attend(q [B, S, Hq, .], *rows [B, S, Hkv, .])
     -> [B, S, Hq, .]`` is the family's causal attention over them
-    (:func:`_attend_prompt`; ``mla_moe.attend_prompt``)."""
+    (:func:`_attend_prompt`; ``mla_moe.attend_prompt``).  ``mixer``: a
+    family's, handed on with nothing to tell it (``models/swa_moe.py``'s
+    gate)."""
     def attend_rows(li, q, kv):
         with region("attn"):
             return attend(q, *kv, **_kind_kw(kinds, li))
@@ -1120,5 +1122,6 @@ def _prompt_forward(params, tokens, *, cfg, project, out_proj, ffn, attend,
         [None] * len(params["layers"]), cfg=cfg, project=project,
         out_proj=out_proj, ffn=ffn,
         write_kv=lambda li, _, k, v: (k,) if v is None else (k, v),
-        attend=attend_rows, kinds=kinds)
+        attend=attend_rows, kinds=kinds,
+        **({} if mixer is None else {"mixer": mixer, "shared": {}}))
     return [tuple(t.transpose(0, 2, 1, 3) for t in kv) for kv in rows], logits

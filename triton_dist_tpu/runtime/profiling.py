@@ -180,7 +180,8 @@ def annotate(name: str, *, flops: int | None = None,
 # of a device program" has the seam of each; ``benchmarks/regions.py`` reads
 # them back from a chip trace.
 REGIONS = (
-    "embed", "proj", "kv_write", "attn", "out_proj", "ffn", "head",
+    "embed", "proj", "kv_write", "attn", "attn.gate", "out_proj", "ffn",
+    "head",
     "moe.route", "moe.align", "moe.experts", "moe.combine", "moe.shared",
     "dsa.index", "dsa.select", "mla.expand", "sample",
     "ssm.in", "ssm.conv", "ssm.scan", "ssm.out", "gmu",

@@ -615,6 +615,11 @@ class ServeEngine:
             self.prefill_width = prefill_chunk
         self.metrics = ServeMetrics()
         self.metrics.prefill_width = self.prefill_width
+        # the query heads of each attention group's layers, where the
+        # generator states them (they may differ by kind: swa_moe)
+        self.metrics.swa_heads = {g["name"]: g["heads"]
+                                  for g in self.kv_groups or ()
+                                  if "heads" in g}
         if self._has_state:
             sg = _state_group(self.kv_groups)
             self.metrics.state_kind = sg.get("kind", "ssm")

@@ -314,6 +314,10 @@ class ServeMetrics:
     swa_window_tokens: int = 0
     swa_full_tokens: int = 0
     kv_window_released: int = 0
+    # the query heads of each attention group's layers, stamped by the
+    # engine where the generator states them ({"full": 48, "window": 72}:
+    # they may differ by kind over the same KV planes)
+    swa_heads: dict = field(default_factory=dict)
     # a state beside pages (docs/serving.md "State beside pages"): slots
     # zeroed for a request's first chunk, tokens scanned again after a
     # preemption, prompt tokens through the chunk's scan; and, where ONE
@@ -597,15 +601,17 @@ class ServeMetrics:
 
     def swa_stats(self) -> dict:
         """summary()["swa"]: cached tokens the decode queries read on
-        window layers and on full layers (each counted a layer), and the
+        window layers and on full layers (each counted a layer), the
         window layers' share of both — what the window saves is the
-        distance of that share from the layers' own."""
+        distance of that share from the layers' own — and the query heads
+        of each kind's layers."""
         both = self.swa_window_tokens + self.swa_full_tokens
         return {"window_tokens": self.swa_window_tokens,
                 "full_tokens": self.swa_full_tokens,
                 "window_share": (self.swa_window_tokens / both
                                  if both else 0.0),
-                "window_released_pages": self.kv_window_released}
+                "window_released_pages": self.kv_window_released,
+                "heads": dict(self.swa_heads)}
 
     def state_group(self) -> dict:
         """The state group's entry of :meth:`kv_group_stats` ({} where the
