@@ -607,6 +607,11 @@ def _mla_moe_programs(name, extents):
     # a sparse block's chunk of this size attends in the expanded form:
     # the flash call over expanded rows in the absorbed page walk's place
     chunk_want = dict(want)
+    # a chunk of a layer that holds 1 expert in 16 sums its experts' rows
+    # by walking the live tiles (ISSUE 46); a decode step gathers
+    assert M.combine_form(eng["prefill_chunk"], cfg) == "walk" and \
+        M.combine_form(batch, cfg) == "gather"
+    chunk_want[M.COMBINE_CALL] = n_moe
     if cfg.expands(eng["prefill_chunk"]):
         assert fd.MLA_PREFILL_CALL_NAME == "mla_expanded_prefill"
         chunk_want[fd.MLA_PREFILL_CALL_NAME] = chunk_want.pop(
@@ -680,8 +685,8 @@ def _swa_moe_programs(cell="mellum2"):
     a group) -> ``[(name, jitted, args, statics, the Mosaic calls it must
     hold)]``: the paged call carries its layer kind's name, every expert
     layer one gate-up + one down grouped GEMM; a prefill chunk flash
-    attention a layer under its ``annotate`` label (``None``: counted by
-    the test); the page fill none."""
+    attention a layer under its ``annotate`` label (an int, its
+    ``moe_combine`` calls: counted by the test); the page fill none."""
     import json
     import os
 
@@ -739,7 +744,8 @@ def _swa_moe_programs(cell="mellum2"):
             "prefill_chunk", gen._chunk_jit,
             (params, s((1, eng["prefill_chunk"]), I32),
              [sc] * cfg.n_layers, s((), I32)),
-            dict(quantized=False, extent=extent, n_valid=s((), I32)), None))
+            dict(quantized=False, extent=extent, n_valid=s((), I32)),
+            n_moe * (M.combine_form(eng["prefill_chunk"], cfg) == "walk")))
         programs.append(("fill_pages", fill,
                          (pools, [sc] * cfg.n_layers,
                           s((2, extent // page), I32)), {}, {}))
@@ -770,11 +776,16 @@ def test_swa_moe_programs_compile_at_published_widths(v5e, as_tpu, cell, moe,
         text = compiled.as_text()
         assert text.split(",", 1)[0] == f"HloModule jit_{prog}"
         calls = _mosaic_names(text)
-        if want is not None:
+        if isinstance(want, dict):
             assert calls == want, (prog, statics, calls)
         else:
+            # ``want``: the chunk's ``moe_combine`` calls — one an expert
+            # layer where it holds 32 of 256 (laguna), none where it holds
+            # all 64 (mellum2: the walk never reads fewer)
+            assert want == {"mellum2": 0, "laguna": moe}[cell]
             assert calls[M.GATE_UP_CALL] == calls[M.DOWN_CALL] == moe
-            assert sum(calls.values()) == 2 * moe + layers, calls  # + flash
+            assert calls.get(M.COMBINE_CALL, 0) == want
+            assert sum(calls.values()) == 2 * moe + want + layers, calls
         assert _gib(compiled) < HBM_GIB, (prog, statics, _gib(compiled))
         worst[prog] = max(worst.get(prog, 0), _gib(compiled))
     print("GiB a program:", {k: round(v, 2) for k, v in worst.items()})

@@ -251,6 +251,75 @@ def test_moe_counters_and_latent_row(tiny, undisturbed):
     assert kv["latent_row_width"] == cfg.latent_width == 160
     assert kv["stored_row_width"] == 256
     assert not eng._aux_pending
+    # which form each program's routed sum took, fixed when it was built:
+    # a chunk of 32 rows walks (128 assignments against 32 + 4 tiles of 8
+    # rows), a decode step of 3 rows gathers
+    assert moe["combine"] == {"prefill_chunk": "walk",
+                              "paged_decode": "gather"}
+    assert eng.kernel_gaps == {}
+
+
+# (configuration file, its builder, rows of the program) -> (form,
+# assignments the gather reads, rows the walk reads): ISSUE 46's table
+_COMBINE_TABLE = {
+    ("laguna-s-2.1-ep8-l9", "swa_moe", "prefill_chunk"):
+        ("walk", 20480, 6656),
+    ("laguna-s-2.1-ep8-l9", "swa_moe", 1024): ("walk", 10240, 3328),
+    ("laguna-s-2.1-ep8-l9", "swa_moe", 256): ("walk", 2560, 1344),
+    ("laguna-s-2.1-ep8-l9", "swa_moe", "max_batch"): ("gather", 640, 1104),
+    ("gigachat3.1-702b-ep16-l5", "mla_moe", "prefill_chunk"):
+        ("walk", 4096, 768),
+    ("gigachat3.1-702b-ep16-l5", "mla_moe", "max_batch"):
+        ("gather", 512, 544),
+    ("glm-5-ep16-l5", "mla_moe", "prefill_chunk"): ("walk", 16384, 1536),
+    ("glm-5-ep16-l5", "mla_moe", "max_batch"): ("gather", 256, 528),
+    # 64 of 64 held: the walk never reads fewer
+    ("mellum2-12b-a2.5b-l8", "swa_moe", "prefill_chunk"):
+        ("gather", 16384, 32768),
+    ("mellum2-12b-a2.5b-l8", "swa_moe", 256): ("gather", 2048, 4096),
+    ("mellum2-12b-a2.5b-l8", "swa_moe", "max_batch"): ("gather", 512, 2560),
+}
+
+
+@pytest.mark.parametrize("name,family,rows", _COMBINE_TABLE)
+def test_combine_form_by_the_cells_own_numbers(name, family, rows):
+    """The rule over (rows, top_k, experts held, experts, row tile) at the
+    four expert configurations' chunk rungs and decode steps."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, f"benchmarks/configs/{name}.json")) as f:
+        config = json.load(f)
+    cfg = importlib.import_module(
+        f"benchmarks.builders_{family}").model_config(config)
+    n = config["engine"][rows] if isinstance(rows, str) else rows
+    form, gathered, walked = _COMBINE_TABLE[name, family, rows]
+    assert n * cfg.top_k == gathered
+    assert M.walk_rows(n, cfg.top_k, cfg.experts_held, cfg.n_experts,
+                       cfg.row_tile(n)) == walked
+    assert M.combine_form(n, cfg) == form == (
+        "walk" if walked < gathered else "gather")
+    # the cells' chunks reach the Mosaic call
+    assert M.combine_kernel_gap(cfg, n, impl="pallas",
+                                interpret=False) is None
+
+
+def test_combine_kernel_gap_names_the_reason():
+    cfg = M.MlaMoeConfig.tiny()
+    assert M.combine_form(32, cfg) == "walk"
+    assert M.combine_kernel_gap(cfg, 32, impl="pallas",
+                                interpret=False) is None
+    assert M.combine_kernel_gap(cfg, 32, impl="xla",
+                                interpret=False) == "impl resolves to XLA"
+    assert M.combine_kernel_gap(cfg, 2, impl="xla", interpret=False) is None
+    odd = dataclasses.replace(cfg, moe_block_m=12)
+    assert "block_m%8" in M.combine_kernel_gap(odd, 32, impl="pallas",
+                                               interpret=False)
+    narrow = dataclasses.replace(cfg, dim=192)
+    gen = M.MlaMoeGenerator(narrow, max_seq=64, impl="pallas")
+    assert "D%128" in gen.kernel_gaps(page_size=128,
+                                      prefill_chunk=32)["moe_combine"]
 
 
 # ---------------------------------------------------------------------------

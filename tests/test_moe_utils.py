@@ -12,6 +12,8 @@ import numpy as np
 import pytest
 
 from triton_dist_tpu.analysis.jaxpr_audit import _iter_subjaxprs
+from triton_dist_tpu.kernels import moe_combine
+from triton_dist_tpu.kernels.gemm import PallasShapeError
 from triton_dist_tpu.kernels.moe_utils import (
     combine_topk,
     gather_sorted,
@@ -101,7 +103,7 @@ def test_end_to_end_moe_matches_dense(topk):
 # ---------------------------------------------------------------------------
 
 
-def _held_plan_by_hand(ids, n_held, block_m, offset):
+def _held_plan_by_hand(ids, n_held, block_m, offset, assignment=False):
     """The plan's contract as a plain loop over assignments."""
     T, topk = ids.shape
     n = T * topk
@@ -119,18 +121,21 @@ def _held_plan_by_hand(ids, n_held, block_m, offset):
     dest = np.full(n, m_pad)
     valid = np.zeros(m_pad, bool)
     src = np.zeros(m_pad, np.int64)
+    sat = np.zeros(m_pad, np.int64)
     for j in range(n):
         if local[j]:
             dest[j] = starts[flat[j]] + rank[j]
             valid[dest[j]] = True
             src[dest[j]] = j // topk
+            sat[dest[j]] = j
     tile_expert = np.full(m_pad // block_m, n_held - 1)   # dead tiles
     for e in range(n_held):
         tile_expert[starts[e] // block_m:
                     (starts[e] + padded[e]) // block_m] = e
     return {"dest": dest, "tile_expert": tile_expert, "valid_rows": valid,
             "m_pad": m_pad, "local": local, "src_token": src,
-            "n_live_tiles": padded.sum() // block_m, "counts": counts}
+            "n_live_tiles": padded.sum() // block_m, "counts": counts,
+            **({"src_assignment": sat} if assignment else {})}
 
 
 def _routed(T, topk, n_experts, seed=0):
@@ -179,6 +184,28 @@ def test_sort_align_held_matches_a_loop_over_assignments(case):
         assert not np.asarray(got["valid_rows"]).any()
 
 
+@pytest.mark.parametrize("case", _HELD_CASES)
+def test_sort_align_held_carries_the_assignment(case):
+    """With ``assignment`` every field is as without, each buffer row
+    names the assignment that sits in it, and the weight read there is
+    ``w[t, k]`` bit for bit."""
+    ids, n_held, block_m, offset = _HELD_CASES[case]
+    got = jax.jit(sort_align_held, static_argnums=(1, 2, 3),
+                  static_argnames=("assignment",))(
+        jnp.asarray(ids, jnp.int32), n_held, block_m, offset,
+        assignment=True)
+    want = _held_plan_by_hand(ids, n_held, block_m, offset, True)
+    assert set(got) == set(want)
+    for key, ref in want.items():
+        np.testing.assert_array_equal(np.asarray(got[key]), ref, err_msg=key)
+    assert got["src_assignment"].dtype == got["src_token"].dtype == jnp.int32
+    w = np.random.default_rng(1).random(ids.shape, np.float32)
+    w_row = np.asarray(jnp.asarray(w).reshape(-1)[got["src_assignment"]])
+    dest, local = np.asarray(got["dest"]), np.asarray(got["local"])
+    assert (w_row[dest[local]].view(np.uint32)
+            == w.reshape(-1)[local].view(np.uint32)).all()
+
+
 def _avals(jaxpr):
     """Every intermediate of a jaxpr, sub-computations included."""
     for eqn in jaxpr.eqns:
@@ -202,3 +229,113 @@ def test_sort_align_held_is_linear_in_the_rows():
     sizes = [(int(np.prod(aval.shape)), name, aval.shape)
              for name, aval in _avals(closed.jaxpr) if hasattr(aval, "shape")]
     assert len(sizes) > 20 and max(sizes)[0] <= limit, max(sizes)
+
+
+# ---------------------------------------------------------------------------
+# The routed sum by row: the walk over the live tiles (kernels/moe_combine)
+# ---------------------------------------------------------------------------
+
+
+def _sum_by_hand(y, ids, w, n_held, offset, plan):
+    """The routed sum as a plain loop, in float64."""
+    T, topk = ids.shape
+    out = np.zeros((T, y.shape[1]))
+    dest = np.asarray(plan["dest"]).reshape(T, topk)
+    for t in range(T):
+        for k in range(topk):
+            if offset <= ids[t, k] < offset + n_held:
+                out[t] += float(w[t, k]) * y[dest[t, k]].astype(np.float64)
+    return out
+
+
+def _all_picks_local(T, topk, n_experts, n_held):
+    """Token 0's picks are all held experts, the others' as routed."""
+    ids = _routed(T, topk, n_experts, seed=3)
+    ids[0] = np.arange(topk)
+    return ids
+
+
+# name -> (ids [T, topk], n_held, block_m, offset, D, result block bytes,
+#          the buffer's dtype)
+_WALK_CASES = {
+    "random_tile_32": (_routed(24, 4, 16), 4, 32, 4, 256, None, "bfloat16"),
+    "random_tile_128": (_routed(32, 2, 8), 2, 128, 0, 128, None, "bfloat16"),
+    "a_float32_buffer_tile_8": (_routed(24, 4, 16), 4, 8, 0, 128, None,
+                                "float32"),
+    # 15 tiles of 8 rows of a PACKED dtype: the whole buffer a step
+    "a_bfloat16_buffer_tile_8": (_routed(23, 4, 16), 4, 8, 0, 128, None,
+                                 "bfloat16"),
+    "every_assignment_local": (_routed(16, 2, 4), 4, 32, 0, 128, None,
+                               "bfloat16"),
+    "no_local_assignment": (16 + _routed(24, 4, 48), 16, 32, 0, 128, None,
+                            "bfloat16"),
+    # 80 rows of one expert: three tiles of 32
+    "one_expert_takes_every_row": (np.full((40, 2), 11), 2, 32, 10, 128,
+                                   None, "bfloat16"),
+    "a_token_with_every_pick_local": (_all_picks_local(20, 4, 16, 4), 4, 32,
+                                      0, 128, None, "bfloat16"),
+    "tokens_no_multiple_of_the_tile": (_routed(25, 2, 8), 2, 32, 4, 128,
+                                       None, "bfloat16"),
+    # a result block of 16 tokens: two passes over the live tiles
+    "a_width_that_needs_token_blocks": (_routed(32, 2, 8), 4, 32, 0, 256,
+                                        16 * 256 * 4, "bfloat16"),
+}
+
+
+@pytest.mark.parametrize("case", _WALK_CASES)
+def test_combine_walk_matches_the_gather_and_a_loop(case, monkeypatch):
+    """The Mosaic call in the interpreter against the gather form (its
+    oracle and fallback, compiled) and a plain loop."""
+    ids, n_held, block_m, offset, D, out_bytes, dtype = _WALK_CASES[case]
+    if out_bytes:
+        monkeypatch.setattr(moe_combine, "_OUT_BLOCK_BYTES", out_bytes)
+    rng = np.random.default_rng(2)
+    T, topk = ids.shape
+    w = jnp.asarray(rng.random(ids.shape) + 0.05, jnp.float32)
+    plan = sort_align_held(jnp.asarray(ids, jnp.int32), n_held, block_m,
+                           offset, assignment=True)
+    m_pad = plan["m_pad"]
+    tb = moe_combine._blocks(T, m_pad, D, block_m)[1]
+    if case == "a_bfloat16_buffer_tile_8":
+        assert m_pad // block_m % 2 == 1
+    assert (T // tb > 1) == bool(out_bytes)
+    # rows of dead tiles hold anything: they must stay out of the sum
+    y = rng.standard_normal((m_pad, D)).astype(np.float32)
+    live = int(plan["n_live_tiles"]) * block_m
+    y[:live] *= np.asarray(plan["valid_rows"])[:live, None]
+    y = jnp.asarray(y, dtype)
+    walk = moe_combine.combine_live(y, plan, w, block_m=block_m,
+                                    impl="pallas", interpret=True)
+    gather = moe_combine.combine_gather(y, plan, w)
+    fallback = jax.jit(lambda y, w: moe_combine.combine_live(
+        y, plan, w, block_m=block_m))(y, w)
+    assert walk.dtype == jnp.float32 and walk.shape == (T, D)
+    np.testing.assert_array_equal(np.asarray(fallback), np.asarray(gather))
+    np.testing.assert_allclose(np.asarray(walk), np.asarray(gather),
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_allclose(
+        np.asarray(walk),
+        _sum_by_hand(np.asarray(y.astype(jnp.float32)), ids, np.asarray(w),
+                     n_held, offset, plan), rtol=1e-5, atol=1e-5)
+    if case == "no_local_assignment":
+        assert int(plan["n_live_tiles"]) == 0 and not np.asarray(walk).any()
+
+
+def test_combine_gap_says_why():
+    # the three cells' chunk geometries: (D, row tile) of a bfloat16 buffer
+    for width, block_m in ((3072, 128), (3072, 64), (3072, 32), (6144, 32),
+                           (7168, 32)):
+        assert moe_combine.combine_gap(width, block_m) is None
+    assert "D%128" in moe_combine.combine_gap(192, 32)
+    assert "block_m%8" in moe_combine.combine_gap(256, 12)
+    # a step holds whole packed sublane tiles (16 rows), at tile 8 too:
+    # 15 tiles of 8 go in one step, 16 tiles in one of 128 rows
+    assert moe_combine._blocks(24, 120, 128, 8)[0] == 120
+    assert moe_combine._blocks(24, 128, 128, 8)[0] == 128
+    assert moe_combine._blocks(2048, 24576, 3072, 128)[0] == 512
+    ids = jnp.asarray(_routed(8, 2, 8), jnp.int32)
+    plan = sort_align_held(ids, 4, 8, 0, assignment=True)
+    y = jnp.zeros((plan["m_pad"], 192), jnp.float32)
+    with pytest.raises(PallasShapeError, match="D%128"):
+        moe_combine.combine_live(y, plan, jnp.ones((8, 2)), block_m=8,
+                                 impl="pallas", interpret=True)

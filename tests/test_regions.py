@@ -373,6 +373,66 @@ def test_with_no_row_to_keep_the_programs_are_the_parents(
 
 
 # ---------------------------------------------------------------------------
+# On the gather side of the rule the expert layer is the parent's
+# ---------------------------------------------------------------------------
+
+
+# (family, program) -> the form its expert layers' combine takes: every
+# decode program gathers, and so does every program of a block that holds
+# all of its experts (``window``) or too few rows an expert (``gated``)
+_COMBINE_SIDES = {
+    ("latent", "decode_horizon"): "gather",
+    ("sparse", "decode_horizon"): "gather",
+    ("window", "prefill_chunk"): "gather",
+    ("gated", "decode_horizon"): "gather",
+    ("gated", "prefill_chunk"): "gather",
+    ("latent", "prefill_chunk"): "walk",
+}
+
+
+@pytest.mark.parametrize("family,program", _COMBINE_SIDES)
+def test_on_the_gather_side_the_expert_layer_is_the_parents(
+        engines, monkeypatch, family, program):
+    """ISSUE 46's rule (``mla_moe.combine_form``) reads a program's static
+    shapes.  Where it says ``gather`` the expert layer takes the parent's
+    path and nothing else — the plan carries no assignment, the walk is
+    never traced — so the program lowers to the same StableHLO text as
+    with the rule held to ``gather`` (``combine_gather``, the parent's sum
+    kept as the walk's oracle).  Where it says ``walk`` that text differs:
+    the program's ``moe.combine`` region holds a Pallas call."""
+    eng, chunk = engines(family)
+    side = _COMBINE_SIDES[family, program]
+    rows = chunk if program == "prefill_chunk" else B
+    assert M.combine_form(rows, eng.gen.cfg) == side
+    assert eng.metrics.summary()["moe"]["combine"][program] == (
+        side if family in ("latent", "sparse") else "gather")
+    took = set()
+    plan_of, live = M.moe_utils.sort_align_held, M.combine_live
+
+    def plan_seen(*args, assignment=False, **kw):
+        took.add(assignment)
+        return plan_of(*args, assignment=assignment, **kw)
+
+    def walk_seen(*args, **kw):
+        took.add("walk")
+        return live(*args, **kw)
+
+    monkeypatch.setattr(M.moe_utils, "sort_align_held", plan_seen)
+    monkeypatch.setattr(M, "combine_live", walk_seen)
+    ours, _ = _build(family)              # fresh jits: nothing cached
+    written = _lowered(ours, chunk, program)
+    assert took == ({False} if side == "gather" else {True, "walk"})
+    assert written.as_text() == _lowered(eng, chunk, program).as_text()
+    monkeypatch.setattr(M, "combine_form", lambda rows, cfg: "gather")
+    bare, _ = _build(family)
+    held = _lowered(bare, chunk, program)
+    assert (held.as_text() == written.as_text()) == (side == "gather")
+    if side == "walk":
+        assert ("moe.combine", "pallas_call") in (_products(written)
+                                                  - _products(held))
+
+
+# ---------------------------------------------------------------------------
 # Every seam's product carries its region, the innermost where two nest
 # ---------------------------------------------------------------------------
 

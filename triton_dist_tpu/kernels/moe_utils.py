@@ -28,6 +28,9 @@ tiles (``models/mla_moe.py``, ``group_gemm_live``): its plan is
 compares over ``[T*topk, n_held]``, the inverse map (buffer row -> token)
 from a tile one-hot times a position one-hot on the MXU — so its work is
 linear in the rows from a decode step's 64 to a prefill chunk's 2,048.
+Its products return to their tokens through ``kernels/moe_combine.py``: by
+assignment (:func:`combine_topk`'s form under a mask), or row by row over
+the live tiles, for which the plan names each row's assignment.
 """
 
 from __future__ import annotations
@@ -118,7 +121,8 @@ def sort_align(experts, n_experts: int, block_m: int):
 _DIGIT = 8
 
 
-def sort_align_held(experts, n_held: int, block_m: int, offset=0):
+def sort_align_held(experts, n_held: int, block_m: int, offset=0, *,
+                    assignment: bool = False):
     """:func:`sort_align` for a layer that holds ``n_held`` of the experts
     its router chooses among (expert parallelism: ids ``offset .. offset
     + n_held - 1`` live here, the rest on other chips).  Assignments to
@@ -131,6 +135,13 @@ def sort_align_held(experts, n_held: int, block_m: int, offset=0):
       src_token    [M_pad] token of each buffer row (0 on padding rows)
       n_live_tiles scalar int32: the tiles that hold any row
       counts       [n_held] assignments an expert got
+      src_assignment [M_pad] (``assignment`` only) the assignment ``t .
+                   topk + k`` that sits in each buffer row (0 on padding
+                   rows): a row's weight is ``w.reshape(-1)`` there.  The
+                   products below then carry the assignment's index
+                   where they carry the token's (``src_token`` is its
+                   quotient) — as many of them while ``T . topk`` fits
+                   the digits ``T`` needs, one more at a decode step
 
     ``dest`` (assignment -> row) is dense compare-and-sum arithmetic over
     ``[T*topk, n_held]``, with no sort and no scatter (both serialise on
@@ -165,22 +176,26 @@ def sort_align_held(experts, n_held: int, block_m: int, offset=0):
         n_tiles, dtype=jnp.int32)[:, None]                 # [n_tiles, n]
     at_pos = ((dest % block_m)[:, None] == jnp.arange(
         block_m, dtype=jnp.int32)[None, :]).astype(jnp.bfloat16)
-    # token + 1 a row, digit by digit (0: no assignment sits there)
-    token1 = jnp.arange(n, dtype=jnp.int32) // topk + 1
+    # token (or assignment) + 1 a row, digit by digit (0: no assignment
+    # sits there)
+    per = 1 if assignment else topk
+    index1 = jnp.arange(n, dtype=jnp.int32) // per + 1
     found = jnp.zeros((n_tiles, block_m), jnp.int32)
-    for shift in range(0, T.bit_length(), _DIGIT):
-        digit = (token1 >> shift) & ((1 << _DIGIT) - 1)
+    for shift in range(0, (n // per).bit_length(), _DIGIT):
+        digit = (index1 >> shift) & ((1 << _DIGIT) - 1)
         found += jnp.dot(
             jnp.where(in_tile, digit[None, :], 0).astype(jnp.bfloat16),
             at_pos, preferred_element_type=jnp.float32,
         ).astype(jnp.int32) << shift
     found = found.reshape(m_pad)
     valid = found > 0
+    source = jnp.where(valid, found - 1, 0)
     return {"dest": dest, "tile_expert": tile_expert, "valid_rows": valid,
             "m_pad": m_pad, "local": local,
-            "src_token": jnp.where(valid, found - 1, 0),
             "n_live_tiles": (ends[-1] // block_m).astype(jnp.int32),
-            "counts": counts}
+            "counts": counts,
+            **({"src_token": source // topk, "src_assignment": source}
+               if assignment else {"src_token": source})}
 
 
 def gather_sorted(x, dest, m_pad: int):

@@ -67,6 +67,13 @@ from triton_dist_tpu.kernels.flash_decode import (
 )
 from triton_dist_tpu.kernels.gemm import resolve_impl
 from triton_dist_tpu.kernels.group_gemm import group_gemm_live
+from triton_dist_tpu.kernels.moe_combine import (
+    COMBINE_CALL,
+    combine_gap,
+    combine_gather,
+    combine_live,
+    walk_rows,
+)
 from triton_dist_tpu.models.generate import (
     _chunk_forward,
     _dense_prompt_ffn,
@@ -608,6 +615,48 @@ def row_tile(rows: int, top_k: int, n_experts: int) -> int:
     return next((t for t in ROW_TILES if t >= per_expert), ROW_TILES[-1])
 
 
+def combine_form(rows: int, cfg) -> str:
+    """How a program of ``rows`` rows sums the held experts' products into
+    its tokens, by the rows of the sorted buffer each form reads: the
+    ``"gather"`` reads one for every assignment (``rows . top_k``), the
+    ``"walk"`` those of the live tiles — under even routing the
+    assignments that land here and at most a tile of padding a held
+    expert (``moe_combine.walk_rows``).  The walk where it reads fewer: a
+    prefill chunk of a layer that holds 1 expert in 8 or 16 (20,480
+    against 6,656 at 2,048 tokens, top-10, 32 of 256, tile 128); the
+    gather at a decode step (640 against 1,104) and wherever every expert
+    is held.  Both are right under any routing: the rule is about speed —
+    alone on the chip its side read the faster form, or an equal one (a
+    256-row rung), at each of ten shapes of the four configurations, both
+    sides (PERF.md §6, PR 46)."""
+    c = cfg
+    return "walk" if walk_rows(
+        rows, c.top_k, c.experts_held, c.n_experts,
+        c.row_tile(rows)) < rows * c.top_k else "gather"
+
+
+def combine_kernel_gap(cfg, rows: int, *, impl, interpret):
+    """Why a program of ``rows`` rows whose combine is the walk will NOT
+    reach the Mosaic call ``moe_combine`` (it then sums by assignment, as
+    the gather does), or None."""
+    if combine_form(rows, cfg) != "walk":
+        return None
+    if resolve_impl(impl, interpret) == "xla":
+        return "impl resolves to XLA"
+    return combine_gap(cfg.dim, cfg.row_tile(rows))
+
+
+def combine_forms(cfg, rows: dict, *, impl, interpret) -> dict:
+    """program -> the form its routed sum takes at the ``rows`` it carries
+    (``summary()["moe"]["combine"]``): :func:`combine_form`, and
+    ``"gather"`` too for a walk that cannot reach its kernel — it then
+    sums by assignment (:func:`combine_kernel_gap`)."""
+    return {prog: "walk" if combine_form(n, cfg) == "walk"
+            and not combine_kernel_gap(cfg, n, impl=impl,
+                                       interpret=interpret) else "gather"
+            for prog, n in rows.items()}
+
+
 def routed_experts(h2, layer, cfg, *, impl="auto", interpret=False):
     """The held experts' part of the routed sum for rows h2 [T, D] ->
     (float32 [T, D], stats int32 [4]).
@@ -624,18 +673,24 @@ def routed_experts(h2, layer, cfg, *, impl="auto", interpret=False):
     the held experts, the row tiles and a tile's positions — at a
     2,048-token chunk over 64 held experts 16,384 x (64 + 128 + 256)
     compares and two ``[128, 16,384] @ [16,384, 256]`` products a layer,
-    at a decode step of 64 rows 512 x (64 + 78 + 32) and one.  ``stats``:
+    at a decode step of 64 rows 512 x (64 + 78 + 32) and one.  The
+    products come back to their tokens (region ``moe.combine``) in the
+    form :func:`combine_form` reads off the same static shapes: a row
+    gathered for every assignment, or — a prefill chunk of a layer that
+    holds few of the experts — the Mosaic call ``moe_combine`` walking the
+    live tiles, its plan carrying each row's assignment.  ``stats``:
     assignments routed, those that landed here, pad rows of the live
     tiles, experts hit."""
     c = cfg
     T, D = h2.shape
     F = c.moe_ffn_dim
     block_m = c.row_tile(T)
+    walk = combine_form(T, c) == "walk"
     with region("moe.route"):
         ids, w = route(h2, layer, c)
     with region("moe.align"):
         plan = moe_utils.sort_align_held(ids, c.experts_held, block_m,
-                                         c.expert_offset)
+                                         c.expert_offset, assignment=walk)
     with region("moe.experts"):
         live = plan["valid_rows"][:, None]
         x_sorted = jnp.where(live, h2[plan["src_token"]],
@@ -652,11 +707,9 @@ def routed_experts(h2, layer, cfg, *, impl="auto", interpret=False):
                         jnp.zeros((), h2.dtype))
         y = gg(act, layer["w_down"], name=DOWN_CALL)
     with region("moe.combine"):
-        local = plan["local"].reshape(T, c.top_k)
-        rows = jnp.minimum(plan["dest"],
-                           plan["m_pad"] - 1).reshape(T, c.top_k)
-        picked = jnp.where(local[..., None], y[rows].astype(jnp.float32), 0.0)
-        out = jnp.einsum("tk,tkd->td", jnp.where(local, w, 0.0), picked)
+        out = (combine_live(y, plan, w, block_m=block_m, impl=impl,
+                            interpret=interpret)
+               if walk else combine_gather(y, plan, w))
     n_local = jnp.sum(plan["local"].astype(jnp.int32))
     stats = jnp.stack([jnp.int32(T * c.top_k), n_local,
                        plan["n_live_tiles"] * block_m - n_local,
@@ -966,26 +1019,35 @@ class MlaMoeGenerator:
     def wrap_program(self, fwd):
         return with_moe_stats(fwd, self.tally)
 
+    def moe_combine_forms(self, rows: dict) -> dict:
+        """:func:`combine_forms` under this generator's dispatch."""
+        ctx = self.attn.ctx
+        return combine_forms(self.cfg, rows, impl=ctx.impl,
+                             interpret=ctx.interpret)
+
     def kernel_gaps(self, *, page_size: int, **_prefill_geometry) -> dict:
         """Attention paths that will NOT reach the latent Pallas kernel
         (``generate.attention_kernel_gaps`` for this family): decode and
         the absorbed prefill chunk share one kernel and one answer; a
         sparse block's EXPANDED chunk (``prefill_chunk`` queries over each
-        rung of ``ladder``) has a call and an answer of its own."""
-        ctx = self.attn.ctx
+        rung of ``ladder``) has a call and an answer of its own — and so
+        has the chunk's expert combine (:func:`combine_kernel_gap`)."""
+        ctx, c = self.attn.ctx, self.cfg
+        chunk = _prefill_geometry.get("prefill_chunk")
+        why = chunk and combine_kernel_gap(c, chunk, impl=ctx.impl,
+                                           interpret=ctx.interpret)
+        gaps = {COMBINE_CALL: why} if why else {}
         if resolve_impl(ctx.impl, ctx.interpret) == "xla":
             why = ("impl='xla' was asked for" if ctx.impl == "xla" else
                    "impl='auto' resolves to XLA off a TPU (no interpreter)")
-            return {"paged_decode": why, "prefill_chunk": why}
-        c = self.cfg
+            return {"paged_decode": why, "prefill_chunk": why, **gaps}
         gap = None if ctx.interpret else (
             mla_kernel_gap(page_size, c.kv_lora_rank,
                            c.head_dim - c.kv_lora_rank)
             or (c.sparse and dsa_index_gap(page_size, c.index_head_dim))
             or None)
-        gaps = {} if gap is None else {"paged_decode": gap,
-                                       "prefill_chunk": gap}
-        chunk = _prefill_geometry.get("prefill_chunk")
+        if gap is not None:
+            gaps.update(paged_decode=gap, prefill_chunk=gap)
         if gap is None and not ctx.interpret and chunk and c.expands(chunk):
             for ext in _prefill_geometry.get("ladder") or ():
                 why = ext > c.index_topk and mla_prefill_gap(

@@ -593,17 +593,26 @@ class SwaMoeGenerator:
     def wrap_program(self, fwd):
         return mla_moe.with_moe_stats(fwd, self.tally)
 
+    # the expert layers are mla_moe's: so is what says which form they take
+    moe_combine_forms = mla_moe.MlaMoeGenerator.moe_combine_forms
+
     def kernel_gaps(self, *, page_size: int, prefill_chunk: int,
                     ladder: list, sp_world: int = 1) -> dict:
-        """Attention paths that will NOT reach a Pallas kernel: the dense
-        family's calls at this model's head width."""
+        """Paths that will NOT reach a Pallas kernel: the dense family's
+        attention calls at this model's head width, and the chunk's
+        expert combine (``mla_moe.combine_kernel_gap``)."""
         ctx = self.attn.ctx
-        return attention_kernel_gaps(
+        gaps = attention_kernel_gaps(
             head_dim=self.cfg.head_dim, page_size=page_size,
             prefill_chunk=prefill_chunk, ladder=ladder,
             kv_itemsize=jnp.dtype(self.cfg.dtype).itemsize,
             kv_quant=bool(self.attn.quantized), impl=ctx.impl,
             interpret=ctx.interpret, sp_world=sp_world)
+        why = mla_moe.combine_kernel_gap(
+            self.cfg, prefill_chunk, impl=ctx.impl, interpret=ctx.interpret)
+        if why:
+            gaps[mla_moe.COMBINE_CALL] = why
+        return gaps
 
     def forward_logits(self, params, tokens):
         """Logits [B, S, V] of whole prompts in one pass (no cache kept):

@@ -787,6 +787,13 @@ class ServeEngine:
             page_size=page_size, prefill_chunk=width,
             ladder=self.ladder, sp_world=self.sp_world)
         self.metrics.kernel_gaps = self.kernel_gaps
+        # Which form each program's expert combine takes: a family with
+        # expert layers reads it off the rows the program carries.
+        if hasattr(gen, "moe_combine_forms"):
+            rows = {"prefill_chunk": width, "paged_decode": max_batch}
+            if self.horizon > 1:
+                rows["decode_horizon"] = max_batch
+            self.metrics.moe_combine = gen.moe_combine_forms(rows)
         # How the paged decode call is blocked (static, decided where the
         # programs are built — kernels/flash_decode.py): the KV heads a
         # step carries of the heads THIS rank holds, the grid steps of a

@@ -471,6 +471,10 @@ class ServeMetrics:
     # steps_per_call, ...: flash_decode.paged_kernel_blocking; stamped
     # once at engine construction, empty when the call runs as XLA)
     paged_attn_blocking: dict = field(default_factory=dict, repr=False)
+    # how each program sums its held experts' products into its tokens
+    # (program -> "walk" | "gather": mla_moe.combine_form; stamped once at
+    # engine construction, empty for a family without expert layers)
+    moe_combine: dict = field(default_factory=dict, repr=False)
     # per-step gauges as STREAMING aggregates (last / peak / running
     # sums) — never per-step lists, so a long-lived engine's metrics
     # stay O(1) regardless of how many steps it has served
@@ -662,11 +666,14 @@ class ServeMetrics:
     def moe_stats(self) -> dict:
         """summary()["moe"]: the four counters and the share of routed
         assignments that landed on the experts held here (1 / chips that
-        share a layer, under even routing)."""
+        share a layer, under even routing) — and, with expert layers,
+        ``combine``: the form each program's routed sum took."""
         out = {k[4:]: getattr(self, k) for k in self.MOE_COUNTERS}
         out["local_share"] = (self.moe_local_assignments
                               / self.moe_assignments
                               if self.moe_assignments else 0.0)
+        if self.moe_combine:
+            out["combine"] = dict(self.moe_combine)
         return out
 
     def kv_stats(self) -> dict:
