@@ -292,30 +292,44 @@ def test_named_programs_keep_the_operation_names_the_benchmark_reads(
         1, cfg.n_kv_heads, max_seq, cfg.head_dim)
 
 
-@pytest.mark.parametrize("hkv", [8, 2, 1])
+# (local KV heads a page, q heads a KV head, batch, table width): the
+# cells' paged calls — mellum2's 4 heads, Mistral's 8 (and its TP-4 rank's
+# 2, a single head), Laguna's 8 under 9 query heads each, phi-4's 10 PAIRS
+# of 64-wide heads, olmo-hybrid's 30
+_PAGED_CALLS = [(8, 4, 32, 64), (2, 4, 32, 64), (1, 4, 32, 64),
+                (4, 8, 64, 160), (8, 9, 64, 80), (10, 4, 96, 40),
+                (30, 1, 96, 40)]
+
+
+@pytest.mark.parametrize("hkv,g,batch,width", _PAGED_CALLS)
 @pytest.mark.parametrize("n_tok", [1, 5])
 def test_paged_kernel_compiles_at_the_cell_geometry_and_on_a_tp4_rank(
-        v5e, as_tpu, hkv, n_tok):
-    """The paged decode call alone, bf16, B 32, page 128, table width 64,
-    449 blocks: the whole model's 8 KV heads, the TP-4 rank's 2 and a
-    single head; one decode token and a 5-token verify.  The CPU host
-    reproduces the chip's scoped-VMEM refusals, so this is the check
-    before chip time."""
+        v5e, as_tpu, hkv, g, batch, width, n_tok):
+    """The paged decode call alone, bf16, page 128, 449 blocks, at every
+    cell's heads a page with the page ring the shapes give it (ISSUE 48),
+    plain and under a window; one decode token and a 5-token verify.  The
+    CPU host reproduces the chip's scoped-VMEM refusals, so this is the
+    check before chip time."""
     from triton_dist_tpu.kernels import flash_decode as fd
 
-    g, D, page = 4, 128, 128
+    D, page = 128, 128
     s = functools.partial(jax.ShapeDtypeStruct,
                           sharding=SingleDeviceSharding(v5e.devices[0]))
-    q = s((32, hkv * g, D) if n_tok == 1 else (32, n_tok, hkv * g, D),
+    q = s((batch, hkv * g, D) if n_tok == 1 else (batch, n_tok, hkv * g, D),
           jnp.bfloat16)
     pool = s((449, hkv, page, D), jnp.bfloat16)
-    attend = jax.jit(functools.partial(fd.gqa_decode_paged_shard,
-                                       impl="pallas"))
-    assert _compile(attend, q, pool, pool, s((32, 64), I32),
-                    s((32,), I32)) == 1
-    assert fd.paged_kernel_blocking(hkv, page, D, 2, batch=32) == {
-        "heads_per_step": hkv, "steps_per_call": 32,
-        "pages_per_step": "dynamic", "vmem_bytes": 4 * hkv * page * D * 2}
+    for window in (0, 512):
+        attend = jax.jit(functools.partial(fd.gqa_decode_paged_shard,
+                                           impl="pallas", window=window))
+        assert _compile(attend, q, pool, pool, s((batch, width), I32),
+                        s((batch,), I32)) == 1
+    slots = fd.paged_pages_in_flight(hkv, page, D, 2)
+    ring_bytes = slots * 2 * hkv * page * D * 2
+    assert fd.paged_kernel_blocking(hkv, page, D, 2, batch=batch) == {
+        "heads_per_step": hkv, "steps_per_call": batch,
+        "pages_per_step": "dynamic", "pages_in_flight": slots,
+        "vmem_bytes": ring_bytes}
+    assert ring_bytes <= fd.PAGED_VMEM_BUDGET
 
 
 def test_cpu_demo_geometry_holds_no_kernel_and_the_engine_says_so(v5e,

@@ -21,6 +21,7 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
+from triton_dist_tpu.kernels import flash_decode as fd
 from triton_dist_tpu.models import llama
 from triton_dist_tpu.models.generate import Generator, _write_rows
 from triton_dist_tpu.serve import (
@@ -796,14 +797,18 @@ def test_engine_exposes_its_kernel_gaps_in_the_summary():
     assert eng.metrics.summary()["kernel_gaps"] == eng.kernel_gaps
     # off the paged kernel there is no blocking to report
     assert eng.paged_attn_blocking == {}
-    assert "serve_paged_attn_heads_per_step 0" in eng.metrics.to_prometheus()
+    text = eng.metrics.to_prometheus()
+    assert "serve_paged_attn_heads_per_step 0" in text
+    assert "serve_paged_attn_pages_in_flight 0" in text
 
 
 @pytest.mark.parametrize("tp,want_heads", [(1, 2), (2, 1)])
 def test_engine_reports_the_paged_attention_blocking(tp, want_heads):
     """The start-up report of the paged decode call (ISSUE 25): static,
     so computed once where the programs are built — for the KV heads THIS
-    rank holds — and exposed in the summary and as two gauges."""
+    rank holds — and exposed in the summary and as three gauges (the
+    third, ISSUE 48: the slots of the kernel's page ring, what the call
+    was built with)."""
     cfg = llama.LlamaConfig(vocab=64, dim=512, n_layers=1, n_heads=4,
                             n_kv_heads=2, ffn_dim=64, max_seq=256,
                             dtype=jnp.float32)
@@ -816,15 +821,19 @@ def test_engine_reports_the_paged_attention_blocking(tp, want_heads):
     eng = ServeEngine(gen, params, num_blocks=8, page_size=128, max_batch=4,
                       prefill_chunk=128, **mesh)
     assert eng.kernel_gaps == {}
+    slots = fd.paged_pages_in_flight(want_heads, 128, 128, 4)
     want = {"heads_per_step": want_heads,
             "steps_per_call": 4 * (2 // tp) // want_heads,
             "pages_per_step": "dynamic",
-            "vmem_bytes": 4 * want_heads * 128 * 128 * 4}
+            "pages_in_flight": slots,
+            "vmem_bytes": slots * 2 * want_heads * 128 * 128 * 4}
+    assert slots >= 2
     assert eng.paged_attn_blocking == want
     assert eng.metrics.summary()["paged_attn_blocking"] == want
     text = eng.metrics.to_prometheus()
     assert f"serve_paged_attn_heads_per_step {want_heads}" in text
     assert f"serve_paged_attn_steps_per_call {want['steps_per_call']}" in text
+    assert f"serve_paged_attn_pages_in_flight {slots}" in text
 
 
 # ---------------------------------------------------------------------------

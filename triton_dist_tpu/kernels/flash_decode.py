@@ -700,15 +700,22 @@ def gqa_decode_shard(q, k, v, local_lens, *, block_s=None, impl="auto",
 # a page table).  TPU-native design: the page table rides as a SECOND
 # scalar-prefetch operand and the pools stay in HBM (``memory_space=ANY``);
 # the kernel walks a batch row's LIVE pages itself and copies each page's
-# block in through the table, double-buffered (the structure of
-# ``jax.experimental.pallas.ops.tpu.paged_attention``).
+# block in through the table, through a ring of page slots (the structure
+# of ``jax.experimental.pallas.ops.tpu.paged_attention``, whose two buffers
+# are the ring's least depth).
 #
 # * Blocking: the pool is ``[N_pages, Hkv, page, D]``, so one page of
 #   EVERY local KV head is one contiguous block.  A loop step carries
-#   ``Hh`` heads of a page — the largest divisor of the local ``Hkv`` whose
-#   double-buffered K+V blocks fit ``PAGED_VMEM_BUDGET``
+#   ``Hh`` heads of a page — the largest divisor of the local ``Hkv`` of
+#   which two K+V slots fit ``PAGED_VMEM_BUDGET``
 #   (:func:`paged_heads_per_step`; ``Hh = Hkv`` at llama/Mistral widths,
 #   whole or head-sharded) — and the grid is ``(B, Hkv // Hh)``.
+# * The ring: as many slots as hold ``PAGED_BYTES_IN_FLIGHT``
+#   (:func:`paged_pages_in_flight`: the smaller a slot, the more of them),
+#   kept full through a row AND across the seam between one grid step and
+#   the next — a row's last multiplies run over the next row's first
+#   copies.  The ring's cursor outlives a step, so the grid is walked in
+#   order (both axes ``"arbitrary"``).
 # * Only ``ceil(len / page)`` pages of a row are walked (from the window's
 #   first page under a sliding window): a dead table entry costs neither a
 #   DMA nor a step, and a row with ``len == 0`` only writes its empty
@@ -741,23 +748,37 @@ def _paged_gather_scale(scale_pool, table):
     return g.transpose(0, 2, 1, 3).reshape(B, Hkv, n * Pg)
 
 
-# Scoped VMEM the paged kernel may plan for its double-buffered K+V page
-# blocks, of Mosaic's 16 MiB (the q / partial blocks and the softmax
-# state are KiB beside them).
+# Scoped VMEM the paged kernel may plan for its ring of K+V page slots, of
+# Mosaic's 16 MiB (the q / partial blocks and the softmax state are KiB
+# beside them).
 PAGED_VMEM_BUDGET = 12 * 2 ** 20
+# Bytes of K+V the page walk keeps in flight: its ring has as many slots as
+# hold them, the page being multiplied among them.  On the chip (PR 47: the
+# call alone, 16 calls chained, bf16, page 128, D 128; rows of 5 / 10 / 64
+# pages; fixed cost a page step beside the page's bytes at 819 GB/s, us, by
+# slots 2 | 3 | 4 and more) — 2 heads a page (128 KiB a slot) 0.33 | 0.24 |
+# 0.24; 4 heads 0.26 | 0.11 | 0.10; 8 heads 0.16 | 0.06 | 0.06; 10 heads
+# (640 KiB) 0.11 | 0.07 | 0.07; 30 heads (1.9 MiB) 0.20 | 0.20 | 0.20.  At
+# two slots the next copy is issued only when a multiply ends and its
+# latency shows once a page; a second copy ahead hides it, a third adds
+# nothing at any size, and a 1.9 MiB copy outlasts its multiply by more
+# than the latency already.  1.5 MiB gives 3 slots from 512 KiB a slot up
+# to 768, 2 above, more below (where they cost nothing measurable).
+PAGED_BYTES_IN_FLIGHT = 3 * 2 ** 19
 
 
 def _paged_block_bytes(heads: int, page: int, head_dim: int,
-                       itemsize: int) -> int:
-    """VMEM of the double-buffered K+V blocks at ``heads`` heads a step."""
-    return 4 * heads * page * head_dim * itemsize
+                       itemsize: int, slots: int = 2) -> int:
+    """VMEM of ``slots`` K+V page slots at ``heads`` heads a step (two: the
+    least ring, what :func:`paged_heads_per_step` plans with)."""
+    return slots * 2 * heads * page * head_dim * itemsize
 
 
 def paged_heads_per_step(hkv: int, page: int, head_dim: int,
                          itemsize: int) -> int:
     """KV heads of a page one step of the paged kernel carries: the
-    largest divisor of the LOCAL ``hkv`` whose double-buffered K+V blocks
-    stay inside ``PAGED_VMEM_BUDGET``; 0 when not even one head does
+    largest divisor of the LOCAL ``hkv`` of which a ring of two K+V slots
+    stays inside ``PAGED_VMEM_BUDGET``; 0 when not even one head does
     (:func:`paged_kernel_gap` then names the reroute).  Chosen from the
     shapes alone — the same rule for a whole model and a head-sharded
     rank."""
@@ -766,18 +787,34 @@ def paged_heads_per_step(hkv: int, page: int, head_dim: int,
                 <= PAGED_VMEM_BUDGET), default=0)
 
 
+def paged_pages_in_flight(heads: int, page: int, head_dim: int,
+                          itemsize: int) -> int:
+    """Slots of the page ring at ``heads`` heads a step: as many as hold
+    ``PAGED_BYTES_IN_FLIGHT`` — at least 2, at most what
+    ``PAGED_VMEM_BUDGET`` holds (the heads a step are chosen first and
+    never shrink for the ring's sake); 0 with no head."""
+    if not heads:
+        return 0
+    slot = _paged_block_bytes(heads, page, head_dim, itemsize, slots=1)
+    return max(2, min(-(-PAGED_BYTES_IN_FLIGHT // slot),
+                      PAGED_VMEM_BUDGET // slot))
+
+
 def paged_kernel_blocking(hkv: int, page: int, head_dim: int,
                           itemsize: int, *, batch: int) -> dict:
     """How the paged decode call is blocked at this geometry (static: it
     is decided where the program is built): the heads a step carries, the
     grid steps of one call — the page walk inside a step is as long as
-    the row's live context, hence ``"dynamic"`` — and the VMEM its K/V
-    blocks take."""
+    the row's live context, hence ``"dynamic"`` — the slots of its page
+    ring and the VMEM they take."""
     hh = paged_heads_per_step(hkv, page, head_dim, itemsize)
+    depth = paged_pages_in_flight(hh, page, head_dim, itemsize)
     return {"heads_per_step": hh,
             "steps_per_call": batch * (hkv // hh) if hh else 0,
             "pages_per_step": "dynamic",
-            "vmem_bytes": _paged_block_bytes(hh, page, head_dim, itemsize)}
+            "pages_in_flight": depth,
+            "vmem_bytes": _paged_block_bytes(hh, page, head_dim, itemsize,
+                                             slots=depth)}
 
 
 def paged_kernel_gap(page: int, head_dim: int, itemsize: int, *,
@@ -876,8 +913,9 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
     rows = n_tok * g
     qg = _fold_q_rows(q, n_tok, Hkv)
     hh = paged_heads_per_step(Hkv, Pg, D, itemsize)
+    depth = paged_pages_in_flight(hh, Pg, D, itemsize)
     kern = functools.partial(_paged_decode_kernel, page=Pg, hh=hh,
-                             n_pages=n_pages, scale=scale,
+                             depth=depth, n_pages=n_pages, scale=scale,
                              soft_cap=soft_cap, window=window, n_tok=n_tok,
                              use_qlens=use_qlens)
     out, lse = pl.pallas_call(
@@ -902,9 +940,10 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
                              lambda b, h, lens, tab: (b, h, 0, 0)),
             ],
             scratch_shapes=[
-                pltpu.VMEM((2, hh, Pg, D), k_pool.dtype),
-                pltpu.VMEM((2, hh, Pg, D), v_pool.dtype),
-                pltpu.SemaphoreType.DMA((2, 2)),      # (k | v, slot)
+                pltpu.VMEM((depth, hh, Pg, D), k_pool.dtype),
+                pltpu.VMEM((depth, hh, Pg, D), v_pool.dtype),
+                pltpu.SemaphoreType.DMA((2, depth)),  # (k | v, slot)
+                pltpu.SMEM((1,), jnp.int32),          # the ring's cursor
                 pltpu.VMEM((hh, rows, D), jnp.float32),
                 pltpu.VMEM((hh, rows, 128), jnp.float32),
                 pltpu.VMEM((hh, rows, 128), jnp.float32),
@@ -914,8 +953,9 @@ def gqa_decode_paged_shard(q, k_pool, v_pool, block_table, local_lens, *,
             jax.ShapeDtypeStruct((B, Hkv, rows, D), jnp.float32),
             jax.ShapeDtypeStruct((B, Hkv, rows, 128), jnp.float32),
         ],
+        # the ring's cursor lives across grid steps, in grid order
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
+            dimension_semantics=("arbitrary", "arbitrary")),
         interpret=maybe_interpret(interpret),
     )(lens_arg, block_table, qg, k_pool, v_pool)
     return _unfold_out(out, lse, multi, n_tok, Hq)
@@ -938,55 +978,96 @@ def _live_pages(llen, wlen, qlen, *, page, n_pages, window, n_tok):
 
 
 def _paged_decode_kernel(lens_ref, table_ref, q_ref, k_hbm, v_hbm, out_ref,
-                         lse_ref, k_buf, v_buf, sem, acc_ref, m_ref, l_ref,
-                         *, page, hh, n_pages, scale, soft_cap=0.0,
-                         window=0, n_tok=1, use_qlens=False):
-    """Grid (B, Hkv // hh); one step is one batch row under ``hh`` KV
-    heads, and walks the row's live pages in order with the online
-    softmax of :func:`_decode_kernel` batched over the head axis (same
-    page order, same f32 state, same masking rule).  Page i + 1 streams
-    into the other buffer slot while page i is multiplied."""
-    b = pl.program_id(0)
-    h0 = pl.program_id(1) * hh
-    llen, wlen, qlen = _read_lens(lens_ref, b, window=window,
-                                  use_qlens=use_qlens)
-    lo, hi = _live_pages(llen, wlen, qlen, page=page, n_pages=n_pages,
-                         window=window, n_tok=n_tok)
+                         lse_ref, k_buf, v_buf, sem, cur_ref, acc_ref, m_ref,
+                         l_ref, *, page, hh, depth, n_pages, scale,
+                         soft_cap=0.0, window=0, n_tok=1, use_qlens=False):
+    """Grid (B, Hkv // hh), walked in order; one step is one batch row
+    under ``hh`` KV heads, and walks the row's live pages in order with
+    the online softmax of :func:`_decode_kernel` batched over the head axis
+    (same page order, same f32 state, same masking rule).
+
+    The pages stream through a ring of ``depth`` slots that outlives the
+    step.  The copies are ONE sequence: this step's ``n`` live pages, then
+    the NEXT step's (``(b, h + 1)``, else ``(b + 1, 0)``; none after the
+    last).  Copy ``t`` of it lands in slot ``(cursor + t) % depth`` and is
+    started ``depth - 1`` multiplies before its own, so every step hands
+    the next its first ``min(depth - 1, pages)`` copies in flight — which
+    is what a step counts on (the first of the grid alone starts cold) —
+    and a row's last multiplies run over the first copies of the row after
+    it.  One loop does it all: iteration ``s`` starts copy ``s + depth -
+    1`` and multiplies page ``s``; it begins below 0, multiplying nothing,
+    only where copies under ``depth - 1`` are still to start.  Every copy
+    started is waited for once, by the step that multiplies it."""
+    # Scalars go through ``lax`` by name: an operator on a traced value is
+    # a jitted ``jnp`` function, traced anew at every call site of every
+    # program of every process — ~1.4 ms apiece on the chip's host, and 56
+    # of them a call were PR 47's +10 to +16 s of WARM set-up (PERF.md §6).
+    add, sub, sel = jax.lax.add, jax.lax.sub, jax.lax.select
+    b, h = pl.program_id(0), pl.program_id(1)
+    n_b, n_h = table_ref.shape[0], k_hbm.shape[1] // hh     # the grid
+    ahead = depth - 1
+
+    def walk(bb):
+        lens = _read_lens(lens_ref, bb, window=window, use_qlens=use_qlens)
+        return lens, _live_pages(*lens, page=page, n_pages=n_pages,
+                                 window=window, n_tok=n_tok)
+
+    (llen, wlen, qlen), (lo, hi) = walk(b)
+    n = sub(hi, lo)
+    first = jax.lax.eq(add(b, h), 0)
+    if n_h == 1:                       # every head of a page in one step
+        nb, nh = add(b, 1), 0
+    else:
+        wrap = jax.lax.eq(h, n_h - 1)
+        nb, nh = sel(wrap, add(b, 1), b), sel(wrap, 0, add(h, 1))
+    _, (nlo, nhi) = walk(jax.lax.min(nb, n_b - 1))
+    total = add(n, sel(jax.lax.lt(nb, n_b), sub(nhi, nlo), 0))
     rows = q_ref.shape[2]
 
-    def page_copies(i, slot):
-        row = table_ref[b, i]
+    base = sel(first, 0, cur_ref[0])   # the slot of this step's first page
+    cur_ref[0] = jax.lax.rem(add(base, n), depth)
+    # copies under ``ahead`` still to start: all of them on the first step,
+    # else the next step's where this one has fewer than ``ahead`` pages
+    pre = sel(first, 0, jax.lax.min(n, ahead))
+    s0 = sel(jax.lax.lt(pre, jax.lax.min(total, ahead)), sub(pre, ahead), 0)
+
+    def page_copies(row, h0, slot):
         return (pltpu.make_async_copy(k_hbm.at[row, pl.ds(h0, hh)],
                                       k_buf.at[slot], sem.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[row, pl.ds(h0, hh)],
                                       v_buf.at[slot], sem.at[1, slot]))
 
-    @pl.when(lo < hi)
-    def _():
-        for c in page_copies(lo, 0):
-            c.start()
-
     _softmax_state_init(acc_ref, m_ref, l_ref)
 
-    def page_step(i, _):
-        slot = jax.lax.rem(i - lo, 2)
+    def page_step(s, slot):            # slot: copy ``s + ahead``'s
+        t = add(s, ahead)
 
-        @pl.when(i + 1 < hi)
+        @pl.when(jax.lax.lt(t, total))
         def _():
-            for c in page_copies(i + 1, 1 - slot):
+            own = jax.lax.lt(t, n)
+            row = table_ref[sel(own, b, nb),
+                            sel(own, add(lo, t), add(nlo, sub(t, n)))]
+            h0 = 0 if n_h == 1 else jax.lax.mul(sel(own, h, nh), hh)
+            for c in page_copies(row, h0, slot):
                 c.start()
 
-        for c in page_copies(i, slot):
-            c.wait()
-        pos = i * page + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, page), 1)
-        valid = _chunk_valid(pos, llen, wlen, qlen, window=window,
-                             group=rows // n_tok)
-        _online_softmax_step(q_ref[0], k_buf[slot], v_buf[slot], valid,
-                             acc_ref, m_ref, l_ref, scale=scale,
-                             soft_cap=soft_cap)
+        slot = sel(jax.lax.eq(slot, ahead), 0, add(slot, 1))    # page s's
 
-    jax.lax.fori_loop(lo, hi, page_step, None)
+        @pl.when(jax.lax.ge(s, 0))
+        def _():
+            for c in page_copies(0, 0, slot):  # a wait reads the slot alone
+                c.wait()
+            pos = add(jax.lax.broadcasted_iota(jnp.int32, (rows, page), 1),
+                      jax.lax.mul(add(lo, s), page))
+            valid = _chunk_valid(pos, llen, wlen, qlen, window=window,
+                                 group=rows // n_tok)
+            _online_softmax_step(q_ref[0], k_buf[slot], v_buf[slot], valid,
+                                 acc_ref, m_ref, l_ref, scale=scale,
+                                 soft_cap=soft_cap)
+        return slot
+
+    jax.lax.fori_loop(s0, n, page_step,
+                      jax.lax.rem(add(base, add(s0, ahead)), depth))
     out_ref[0], lse_ref[0] = _softmax_state_emit(acc_ref, m_ref, l_ref)
 
 

@@ -1353,6 +1353,10 @@ class ServeMetrics:
               self.paged_attn_blocking.get("steps_per_call", 0),
               "grid steps of one paged decode attention call; each "
               "walks only its row's live pages")
+        gauge("serve_paged_attn_pages_in_flight",
+              self.paged_attn_blocking.get("pages_in_flight", 0),
+              "slots of the paged decode attention kernel's page ring: "
+              "the page being multiplied and the copies ahead of it")
         if self.recorder is not None:
             counter("serve_trace_events_total", self.recorder.emitted,
                     "flight-recorder events emitted")
