@@ -268,6 +268,7 @@ _FAST_GATE_MODULES = {
     # from_hf's refusals by name, the paged call at groups of 9 and 6
     # (~2 min).
     "test_laguna",
+    "test_mhc",
     # a prefill chunk keeps the row its caller reads (ISSUE 42): over the
     # six toy engines, the engine's chunk program against the all-rows one
     # — logits of the kept row, every cache and state plane bitwise — at a

@@ -548,6 +548,9 @@ _CELLS_MLA_MOE = [
     ("gigachat3.1-702b-ep16-l5", (512, 2048, 8192)),
     # the rungs longctx_sat's prompts (4,186-16,033) reach, and the cap
     ("glm-5-ep16-l5", (8192, 16384, 18432)),
+    # ISSUE 49: the same block inside a residual of four streams, every
+    # expert held, the whole vocabulary: reason96_sat's rungs and the cap
+    ("xing4.0-29b-a4b-stage", (512, 2048, 8192)),
 ]
 
 
@@ -613,6 +616,15 @@ def _mla_moe_programs(name, extents):
         (M.GATE_UP_CALL, n_moe), (M.DOWN_CALL, n_moe)) if n}
     assert (fd.MLA_CALL_NAME, fd.DSA_INDEX_CALL_NAME) == (
         "mla_paged_decode", "dsa_index_scores")     # the readers' patterns
+    if cfg.hc_mult > 1:
+        # a residual of several streams: a pre-mix and a post-mix around
+        # each of a layer's two sub-layers, under their trace names
+        from triton_dist_tpu.kernels import hyper_conn as hc
+
+        assert (hc.HC_PRE_CALL, hc.HC_POST_CALL) == ("hc_pre", "hc_post")
+        want.update({hc.HC_PRE_CALL: 2 * cfg.n_layers,
+                     hc.HC_POST_CALL: 2 * cfg.n_layers})
+        assert gen.stream_rows({"paged_decode": batch})["gaps"] == {}
     programs = [p + (want,) for p in _decode_programs(
         gen, kw, d_args, h_args, eng["horizon"])]
     ladder = E.build_bucket_ladder(max(page, eng["prefill_chunk"]), max_seq,
@@ -622,10 +634,14 @@ def _mla_moe_programs(name, extents):
     # the flash call over expanded rows in the absorbed page walk's place
     chunk_want = dict(want)
     # a chunk of a layer that holds 1 expert in 16 sums its experts' rows
-    # by walking the live tiles (ISSUE 46); a decode step gathers
-    assert M.combine_form(eng["prefill_chunk"], cfg) == "walk" and \
+    # by walking the live tiles (ISSUE 46); a decode step gathers, and so
+    # does every program of a layer that holds ALL its experts
+    whole = cfg.experts_held == cfg.n_experts
+    assert M.combine_form(eng["prefill_chunk"], cfg) == (
+        "gather" if whole else "walk") and \
         M.combine_form(batch, cfg) == "gather"
-    chunk_want[M.COMBINE_CALL] = n_moe
+    if not whole:
+        chunk_want[M.COMBINE_CALL] = n_moe
     if cfg.expands(eng["prefill_chunk"]):
         assert fd.MLA_PREFILL_CALL_NAME == "mla_expanded_prefill"
         chunk_want[fd.MLA_PREFILL_CALL_NAME] = chunk_want.pop(
@@ -671,6 +687,7 @@ def test_mla_moe_programs_compile_at_published_widths(v5e, as_tpu, name,
         assert text.split(",", 1)[0] == f"HloModule jit_{prog}"
         assert _mosaic_names(text) == want, (prog, statics)
         assert _gib(compiled) < HBM_GIB, (prog, _gib(compiled))
+        print(f"[aot] {name} {prog} {statics}: {_gib(compiled):.2f} GiB")
 
 
 # ---------------------------------------------------------------------------
@@ -1064,7 +1081,7 @@ def _dense_cell_programs():
 
 
 @pytest.mark.parametrize("cell", ["mistral", "gigachat3.1", "glm-5",
-                                  "mellum2"])
+                                  "mellum2", "xing4.0"])
 def test_region_scopes_rename_no_mosaic_call(v5e, as_tpu, monkeypatch, cell):
     """XLA names a Mosaic custom call after the scope around it, and the
     accepted roofline metrics match ``closed_call|_unknown_``,
@@ -1085,6 +1102,9 @@ def test_region_scopes_rename_no_mosaic_call(v5e, as_tpu, monkeypatch, cell):
     build = {"mistral": _dense_cell_programs,
              "gigachat3.1": lambda: _mla_moe_programs(*_CELLS_MLA_MOE[0]),
              "glm-5": lambda: _mla_moe_programs(*_CELLS_MLA_MOE[1]),
+             # ``hc_pre`` / ``hc_post`` keep their names under ``hc.pre``
+             # / ``hc.post``: the cell's four metrics read them by name
+             "xing4.0": lambda: _mla_moe_programs(*_CELLS_MLA_MOE[2]),
              "mellum2": _swa_moe_programs}[cell]
 
     def names():

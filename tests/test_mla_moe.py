@@ -348,11 +348,15 @@ def test_absorbed_attention_equals_expanded(tiny):
     assert np.abs(np.asarray(got) - np.asarray(want)).max() < 2e-5
 
 
-def test_shares_add_up_to_the_uncut_layer(tiny):
+@pytest.mark.parametrize("held", [4, 16])
+def test_shares_add_up_to_the_uncut_layer(tiny, held):
     """The routed parts that all four shares of the expert layer give
     (the PROGRAM's layer, told which experts it holds), plus the shared
-    expert once, equal the reference's UNCUT layer."""
+    expert once, equal the reference's UNCUT layer — and so does the ONE
+    share of a layer that holds every expert (offset 0, held = total: a
+    pipeline stage's layer, ISSUE 49)."""
     cfg, _, _ = tiny
+    cfg = dataclasses.replace(cfg, experts_held=held)
     li, T = 1, 48
     h = jax.random.normal(jax.random.key(2), (T, cfg.dim), jnp.float32)
     total = jnp.zeros((T, cfg.dim), jnp.float32)
@@ -496,11 +500,23 @@ def test_grouped_gemm_over_held_experts():
 # ---------------------------------------------------------------------------
 
 
+@pytest.fixture(scope="module")
+def tiny_streams(tiny):
+    """``tiny`` inside a residual of four streams (the ``xing4_0`` block)."""
+    cfg = dataclasses.replace(tiny[0], hc_mult=4)
+    return (cfg, M.init_params(cfg, ref.weight_key(SEED)),
+            M.MlaMoeGenerator(cfg, max_seq=256, interpret=True))
+
+
+@pytest.mark.parametrize("block", ["one_stream", "streams"])
 @pytest.mark.parametrize("what", [
     "mesh", "int8_pools", "w8a8", "spec_k", "snapshot_dir", "snapshot",
     "restore", "drain", "migrate_in", "push_out", "admit_pushed"])
-def test_latent_pools_refuse_by_name(tiny, tmp_path, what):
-    cfg, params, gen = tiny
+def test_latent_pools_refuse_by_name(tiny, tiny_streams, tmp_path, what,
+                                     block):
+    """What the latent family refuses it refuses with several residual
+    streams too: none of these paths takes the ``streams`` seam."""
+    cfg, params, gen = tiny if block == "one_stream" else tiny_streams
     with pytest.raises(M.LatentPoolUnsupported):
         if what == "mesh":
             from jax.sharding import Mesh

@@ -9,6 +9,9 @@ a device trace's time belongs to (``benchmarks/regions.py`` reads them).
   model families, the engine's ``decode_horizon``, ``prefill_chunk`` and
   ``paged_decode`` lower to the same StableHLO text (no debug info) with
   ``region`` as written and with it patched to ``contextlib.nullcontext``;
+- INVARIANT (c), a config that says no ``hc_mult`` traces nothing new: the
+  seven one-stream families hand the layer loop no ``streams`` and none of
+  their programs holds an operation under ``hc.pre`` / ``hc.post``;
 - every seam's product carries its region in the lowered module's
   ``op_name`` path, the INNERMOST where two nest — and the dense family's
   paged programs hold no ``attn`` scope at all: their Mosaic call has no
@@ -42,9 +45,11 @@ from triton_dist_tpu.serve import programs as PR
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 FAMILIES = ("dense", "latent", "sparse", "window", "state", "matrix",
-            "gated")
+            "gated", "streams")
 # the four families the benchmark had before the layer loop took a ``mixer``
 QUARTET_FAMILIES = FAMILIES[:4]
+# the seven whose residual is ONE stream: every family but the last
+ONE_STREAM_FAMILIES = FAMILIES[:7]
 PROGRAMS = ("decode_horizon", "prefill_chunk", "paged_decode")
 # the modules that open regions (they import ``region`` by name)
 SCOPED = (G, M, PR, Y, GH, S)
@@ -89,9 +94,11 @@ def _build(family):
         gen = G.Generator(cfg, Mesh(np.array(jax.devices()[:1]), ("sp",)),
                           axis="sp", max_seq=256)
         params = llama.init_params(cfg, jax.random.key(3))
-    elif family in ("latent", "sparse"):
+    elif family in ("latent", "sparse", "streams"):
+        # "streams": the latent block inside a residual of four streams
         cfg = (M.MlaMoeConfig.tiny_sparse(n_layers=2) if family == "sparse"
-               else M.MlaMoeConfig.tiny(n_layers=2))
+               else M.MlaMoeConfig.tiny(n_layers=2,
+                                        hc_mult=4 * (family == "streams")))
         gen = M.MlaMoeGenerator(cfg, max_seq=256, interpret=True)
         params = M.init_params(cfg, jax.random.key(3))
     elif family == "state":
@@ -346,7 +353,7 @@ def _parent_layer_stack(params, tokens, pos, caches, *, cfg, project,
 
 @pytest.mark.parametrize("program", ("decode_horizon", "paged_decode",
                                      "all_rows_chunk"))
-@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("family", ONE_STREAM_FAMILIES)
 def test_with_no_row_to_keep_the_programs_are_the_parents(
         engines, monkeypatch, family, program):
     """A prefill chunk keeps the row its caller reads (ISSUE 42) and no
@@ -370,6 +377,43 @@ def test_with_no_row_to_keep_the_programs_are_the_parents(
     bare, _ = _build(family)              # fresh jits: nothing cached
     assert _lowered(bare, chunk, program,
                     all_rows=chunked).as_text() == written
+
+
+# ---------------------------------------------------------------------------
+# No ``hc_mult``, no ``streams``: the layer loop is the one-stream loop
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+@pytest.mark.parametrize("family", ONE_STREAM_FAMILIES)
+def test_without_streams_no_program_holds_a_mix(engines, family, program):
+    """The seven families whose configs say no ``hc_mult`` hand the layer
+    loop no ``streams`` — its one switch between ``x + f(norm(x))`` and the
+    mixes — so no operation of their programs lies under ``hc.pre`` or
+    ``hc.post``."""
+    eng, chunk = engines(family)
+    assert "streams" not in eng.gen.serve_hooks()
+    regions = {r for r, _ in _products(_lowered(eng, chunk, program))}
+    assert not regions & {"hc.pre", "hc.post"}
+
+
+def test_with_streams_the_residual_is_n_wide_and_the_mixes_are_calls(engines):
+    """The one family that says ``hc_mult``: its programs differ from the
+    one-stream block's, hold a pre-mix and a post-mix a sub-layer as Pallas
+    calls, and carry a residual ``hc_mult`` times as wide."""
+    eng, chunk = engines("streams")
+    one, _ = engines("latent")
+    cfg = eng.gen.cfg
+    assert set(eng.gen.serve_hooks()) - set(one.gen.serve_hooks()) == {
+        "streams"}
+    for program in PROGRAMS:
+        text = _lowered(eng, chunk, program).as_text()
+        assert text != _lowered(one, chunk, program).as_text()
+        rows = chunk if program == "prefill_chunk" else B
+        assert f"tensor<{rows}x{cfg.hc_mult * cfg.dim}x" in text
+        calls = {(r, p) for r, p in _products(_lowered(eng, chunk, program))
+                 if p == "pallas_call"}
+        assert {("hc.pre", "pallas_call"), ("hc.post", "pallas_call")} <= calls
 
 
 # ---------------------------------------------------------------------------
@@ -482,6 +526,8 @@ _SEAM_PRODUCTS = {
     "gdn.conv": ("matrix", "prefill_chunk", "dynamic_slice"),
     "gdn.rule": ("matrix", "decode_horizon", "exp"),
     "gdn.out": ("matrix", "prefill_chunk", "dot_general"),
+    "hc.pre": ("streams", "decode_horizon", "pallas_call"),
+    "hc.post": ("streams", "prefill_chunk", "pallas_call"),
 }
 
 

@@ -482,7 +482,7 @@ def _tail_start(kinds, n_layers: int) -> int:
 
 def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
                  ffn, write_kv, attend, kinds=None, mixer=None, shared=None,
-                 keep=None, read=None):
+                 keep=None, read=None, streams=None):
     """THE layer loop of serving and its oracles: tokens [B, T] at global
     positions pos [B, T] (a leading 1 broadcasts) through every layer of
     ``params`` -> (new caches, logits [B, T, V] float32).  Decode is this
@@ -556,8 +556,24 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     residual stream itself and norms its own rows (inside its last region),
     and the MLP's output is normed here.
 
-    The residual stream is ``[B, T, D]`` — and ``[B, D]`` at T = 1, by
-    evidence, not taste (PERF.md §6, PR 28): the chip's compiler folds
+    So is the residual PATH (``cfg.hc_mult``, ``models/mla_moe.py``): a
+    config of ``n > 1`` streams comes with ``streams``, the pair ``(pre,
+    post)`` of ``kernels/hyper_conn.py`` under its family's dispatch.  The
+    residual is then ``n`` streams a token, side by side along the last
+    axis (stream ``j`` is columns ``[j D, (j + 1) D)``); the embedding
+    fills every stream; a sub-layer — the attention with its norm, the MLP
+    with its norm — opens with ``pre(X, layer["hc_attn" | "hc_mlp"]) ->
+    (h, maps)``, runs on ``h`` exactly as it runs on ``x``, and closes with
+    ``post(X, y, maps) -> X'`` where one stream has ``x + y`` (regions
+    ``hc.pre`` / ``hc.post``); the streams are summed before the final
+    norm.  ``keep`` / ``read`` / the tail act on the whole row.  Without
+    ``streams`` nothing of this is traced: the program is the one it was.
+
+    The residual is ``[B, T, W]`` — and ``[B, W]`` at T = 1 —, ``W = D`` for
+    one stream and ``n D`` for ``n``; T is read off ``pos`` ([B | 1, T]),
+    which every caller passes at its own T (the tail's one row included).
+    The T = 1 layout is by evidence, not taste (PERF.md §6, PR 28): the
+    chip's compiler folds
     the ``[B, T, D] -> [B * T, D] -> [B, T, H, hd]`` reshapes around the
     dense q / k / v products into a windowed convolution over re-laid
     copies of the weights, which a 128-token prefill chunk repays (the
@@ -567,20 +583,39 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
     B, T = tokens.shape
     post = getattr(cfg, "norm_after", False)
     layers = params["layers"]
+    n = getattr(cfg, "hc_mult", 0) if streams is not None else 0
+
+    def enter(x, maps):
+        """A sub-layer's input off the residual: ``x`` itself, or the
+        streams' pre-mix (-> the one-stream layout, and what closes it)."""
+        if not n:
+            return x, None
+        with region("hc.pre"):
+            h, mixed = streams[0](x.reshape(-1, x.shape[-1]), maps)
+        return h.reshape(*x.shape[:-1], -1), mixed
+
+    def leave(x, y, mixed):
+        """The residual after a sub-layer's output ``y`` [rows, D]."""
+        if not n:
+            return x + y.reshape(x.shape)
+        with region("hc.post"):
+            return streams[1](x.reshape(-1, x.shape[-1]), y,
+                              mixed).reshape(x.shape)
 
     def block(li, x, pos, shared):
-        """Layer ``li`` over the residual stream ``x`` ([B, T, D]; [B, D] at
-        T = 1) -> (x', the layer's cache, shared')."""
-        layer, T = layers[li], 1 if x.ndim == 2 else x.shape[1]
+        """Layer ``li`` over the residual ``x`` ([B, T, W]; [B, W] at T = 1)
+        -> (x', the layer's cache, shared')."""
+        layer, T = layers[li], pos.shape[1]
+        xin, mixed = enter(x, layer.get("hc_attn"))
         if mixer is not None:
-            h = x if post else _norm(x, layer, "attn_norm", cfg)
+            h = xin if post else _norm(xin, layer, "attn_norm", cfg)
             rows, cache, shared = mixer(
                 li, h.reshape(B, T, -1), layer, pos, caches[li], shared,
                 write_kv=write_kv, attend=attend)
-            x = x + rows.reshape(x.shape)
+            x = leave(x, rows, mixed)
         else:
             with region("proj"):
-                h = _norm(x, layer, "attn_norm", cfg)
+                h = _norm(xin, layer, "attn_norm", cfg)
                 q, k, v = project(h.reshape(B, T, -1), layer, pos,
                                   **_kind_kw(kinds, li))
             with region("kv_write"):
@@ -588,24 +623,32 @@ def _layer_stack(params, tokens, pos, caches, *, cfg, project, out_proj,
             o = attend(li, q, cache)                     # [B, T, Hq, .]
             with region("out_proj"):
                 o2 = o.reshape(B * T, -1).astype(cfg.dtype)
-                x = x + out_proj(o2, layer).reshape(x.shape)
+                x = leave(x, out_proj(o2, layer), mixed)
         with region("ffn"):
+            xin, mixed = enter(x, layer.get("hc_mlp"))
             if post:
-                y = _norm(ffn(x.reshape(B * T, -1), layer), layer,
+                y = _norm(ffn(xin.reshape(B * T, -1), layer), layer,
                           "mlp_norm", cfg)
             else:
-                h2 = _norm(x, layer, "mlp_norm", cfg)
+                h2 = _norm(xin, layer, "mlp_norm", cfg)
                 y = ffn(h2.reshape(B * T, -1), layer)
-            x = x + y.reshape(x.shape)
+            x = leave(x, y, mixed)
         return x, cache, shared
 
     def head(x):
         with region("head"):
+            if n:       # the streams' sum, in float32, rounded once
+                D = x.shape[-1] // n
+                x = sum(jax.lax.slice_in_dim(x, j * D, (j + 1) * D, axis=-1)
+                        .astype(jnp.float32) for j in range(n)).astype(
+                            x.dtype)
             logits = _head(_norm(x, params, "final_norm", cfg), params, cfg)
         return logits.reshape(B, -1, logits.shape[-1])
 
     with region("embed"):
         x = params["embed"][tokens.reshape((B,) if T == 1 else (B, T))]
+        if n:           # every stream starts as the embedding
+            x = jnp.tile(x, n)
     one_row = keep is not None and T > 1
     tail_at = _tail_start(kinds, len(layers)) if one_row else len(layers)
     new_caches = []
@@ -949,7 +992,7 @@ def _write_chunk(cache, new, prefix_len, quantized):
 def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
                    project, out_proj, ffn, attend,
                    extent: int | None = None, n_valid=None, kinds=None,
-                   mixer=None):
+                   mixer=None, streams=None):
     """One prompt chunk [B, c] against the cached prefix; returns
     (new_caches, logits [B, c, V] — position i predicts the token after
     chunk[:, i] — or, with ``n_valid``, [B, 1, V]: the last valid row's):
@@ -1039,6 +1082,8 @@ def _chunk_forward(params, chunk, caches, prefix_len, *, cfg, quantized: bool,
     # from chunk to chunk must not scan a residual's padding
     more = {} if mixer is None else {
         "mixer": mixer, "shared": {"n_valid": n_valid}}
+    if streams is not None:     # a residual of several streams: its mixes
+        more["streams"] = streams
     return _layer_stack(params, chunk, positions[None], caches, cfg=cfg,
                         project=project, out_proj=out_proj, ffn=ffn,
                         write_kv=write_kv, attend=attend_views, kinds=kinds,
@@ -1104,7 +1149,7 @@ def _verify_forward(params, chunk, caches, kv_lens, *, cfg: LlamaConfig,
 
 
 def _prompt_forward(params, tokens, *, cfg, project, out_proj, ffn, attend,
-                    kinds=None, mixer=None):
+                    kinds=None, mixer=None, streams=None):
     """Full-sequence forward on replicated weights that also returns the
     per-layer cache rows (post-RoPE, cache layout [B, Hkv, S, .], one per
     plane) and logits: :func:`_layer_stack` with a pair that keeps the
@@ -1112,16 +1157,19 @@ def _prompt_forward(params, tokens, *, cfg, project, out_proj, ffn, attend,
     -> [B, S, Hq, .]`` is the family's causal attention over them
     (:func:`_attend_prompt`; ``mla_moe.attend_prompt``).  ``mixer``: a
     family's, handed on with nothing to tell it (``models/swa_moe.py``'s
-    gate)."""
+    gate); ``streams``: a family's residual mixes, likewise."""
     def attend_rows(li, q, kv):
         with region("attn"):
             return attend(q, *kv, **_kind_kw(kinds, li))
+
+    more = {} if mixer is None else {"mixer": mixer, "shared": {}}
+    if streams is not None:
+        more["streams"] = streams
 
     rows, logits = _layer_stack(
         params, tokens, jnp.arange(tokens.shape[1], dtype=jnp.int32)[None],
         [None] * len(params["layers"]), cfg=cfg, project=project,
         out_proj=out_proj, ffn=ffn,
         write_kv=lambda li, _, k, v: (k,) if v is None else (k, v),
-        attend=attend_rows, kinds=kinds,
-        **({} if mixer is None else {"mixer": mixer, "shared": {}}))
+        attend=attend_rows, kinds=kinds, **more)
     return [tuple(t.transpose(0, 2, 1, 3) for t in kv) for kv in rows], logits

@@ -104,12 +104,20 @@ class LatentPoolUnsupported(NotImplementedError):
     or where the entry point is called — never a quiet fallback."""
 
 
-# model_type -> the indexer keys its config.json may carry
+# model_type -> the keys its config.json may carry beyond the block's own:
+# an indexer's (``index*``), a residual path's (``hc_*`` / ``mhc_*``)
 _MODEL_TYPES = {
     "deepseek_v3": frozenset(),
     "glm_moe_dsa": frozenset({"index_n_heads", "index_head_dim",
                               "index_topk", "indexer_rope_interleave"}),
+    "xing4_0": frozenset({"hc_mult", "hc_sinkhorn_iters", "hc_eps",
+                          "mhc_h_res_clamp_min", "mhc_h_res_clamp_max"}),
 }
+_EXTRA_STEMS = ("index", "hc_", "mhc_")
+# fold_in tag of a layer's residual-path draws: sub-layer s (0: attention,
+# 1: MLP / experts) draws from fold_in(layer key, HC_FOLD + s) — the
+# sixteen subkeys split() hands a layer are all taken
+HC_FOLD = 4096
 
 
 @dataclasses.dataclass(frozen=True)
@@ -150,6 +158,12 @@ class MlaMoeConfig:
     index_head_dim: int = 128
     index_topk: int = 0
     index_norm_eps: float = 1e-6    # the index key's LayerNorm
+    # the residual path (0: ONE stream, ``x + f(norm(x))``; n > 1: n
+    # streams mixed per token by learned maps — kernels/hyper_conn.py)
+    hc_mult: int = 0
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_clamp: tuple = (-30.0, 30.0)
 
     # -- what the serving engine reads of a model config -------------------
     @property
@@ -192,6 +206,12 @@ class MlaMoeConfig:
     def is_moe_layer(self, li: int) -> bool:
         return li >= self.first_k_dense
 
+    def stream_kw(self) -> dict:
+        """What the residual path's two mixes take of the config."""
+        return dict(n=self.hc_mult, iters=self.hc_sinkhorn_iters,
+                    eps=self.hc_eps, clamp=tuple(self.hc_clamp),
+                    norm_eps=self.norm_eps)
+
     def row_tile(self, rows: int) -> int:
         """The grouped GEMMs' row tile in a program of ``rows`` rows: this
         family's is the one fixed option (``moe_block_m``), whatever the
@@ -202,21 +222,22 @@ class MlaMoeConfig:
     def from_hf(c: dict, *, max_seq: int, dtype=jnp.bfloat16,
                 experts_total: int | None = None, expert_offset: int = 0,
                 **over) -> "MlaMoeConfig":
-        """From the keys of a ``deepseek_v3`` or a ``glm_moe_dsa``
-        ``config.json`` (docs/serving.md lists them).  In a share's file
-        ``n_routed_experts`` counts the experts HELD and ``vocab_size``
-        the rows held; ``experts_total`` is the router's published width.
-        An unknown ``model_type`` or ``index*`` key is refused by name."""
+        """From the keys of a ``deepseek_v3``, a ``glm_moe_dsa`` or a
+        ``xing4_0`` ``config.json`` (docs/serving.md lists them).  In a
+        share's file ``n_routed_experts`` counts the experts HELD and
+        ``vocab_size`` the rows held; ``experts_total`` is the router's
+        published width.  An unknown ``model_type``, ``index*`` or
+        ``hc_*`` / ``mhc_*`` key is refused by name."""
         kind = c.get("model_type", "deepseek_v3")
         if kind not in _MODEL_TYPES:
             raise ValueError(f"model_type {kind!r}: served are "
                              f"{sorted(_MODEL_TYPES)}")
-        unknown = sorted(k for k in c if k.startswith("index")
+        unknown = sorted(k for k in c if k.startswith(_EXTRA_STEMS)
                          and k not in _MODEL_TYPES[kind])
         if unknown:
             raise ValueError(
-                f"{unknown}: not an indexer key this {kind} block serves "
-                f"({sorted(_MODEL_TYPES[kind]) or 'it has no indexer'})")
+                f"{unknown}: not a key this {kind} block serves (beyond "
+                f"the block's own: {sorted(_MODEL_TYPES[kind]) or 'none'})")
         rs = c.get("rope_scaling")
         yarn = None
         if rs:
@@ -240,6 +261,12 @@ class MlaMoeConfig:
                         index_n_heads=c["index_n_heads"],
                         index_head_dim=c["index_head_dim"],
                         index_topk=c["index_topk"], **over)
+        if kind == "xing4_0":
+            over = dict(hc_mult=int(c["hc_mult"]),
+                        hc_sinkhorn_iters=int(c["hc_sinkhorn_iters"]),
+                        hc_eps=float(c["hc_eps"]),
+                        hc_clamp=(float(c["mhc_h_res_clamp_min"]),
+                                  float(c["mhc_h_res_clamp_max"])), **over)
         for key, want in (("scoring_func", "sigmoid"),
                           ("topk_method", "noaux_tc"),
                           ("hidden_act", "silu")):
@@ -409,6 +436,23 @@ def _draw_experts(key, ids, denom, *, shape, dtype):
     return jax.vmap(one)(ids).astype(dtype)
 
 
+def _stream_maps(c: MlaMoeConfig, key) -> dict:
+    """One sub-layer's residual-path maps, float32, in the shapes
+    ``kernels/hyper_conn.py`` reads them: ``phi`` drawn at its published
+    shape [n D, 2n + n^2] (normal / sqrt(n D)) and stored transposed,
+    ``bias`` normal, ``alpha`` and ``gain`` 1 — every map then depends on
+    the token."""
+    n, D = c.hc_mult, c.dim
+    k = 2 * n + n * n
+    kp, kb = jax.random.split(key, 2)
+    f32 = jnp.float32
+    return {"phi_t": _draw(kp, f32(math.sqrt(n * D)), shape=(n * D, k),
+                           dtype=f32).T,
+            "alpha": jnp.ones((3,), f32),
+            "bias": _draw(kb, f32(1.0), shape=(k, 1), dtype=f32),
+            "gain": jnp.ones((1, n * D), f32)}
+
+
 def init_params(cfg: MlaMoeConfig, key) -> dict:
     """Seeded weights, drawn on the default device leaf by leaf.
 
@@ -469,6 +513,12 @@ def init_params(cfg: MlaMoeConfig, key) -> dict:
                 [experts(10, c.dim, (c.dim, F)),
                  experts(11, c.dim, (c.dim, F))], axis=-1)
             layer["w_down"] = experts(12, F, (F, c.dim))
+        if c.hc_mult > 1:
+            # subkeys of their own: the leaves above are what they are for
+            # a seed with streams and without
+            for s, name in enumerate(("hc_attn", "hc_mlp")):
+                layer[name] = _stream_maps(c, jax.random.fold_in(
+                    keys[2 + li], HC_FOLD + s))
         params["layers"].append(layer)
     return params
 
@@ -986,6 +1036,14 @@ class MlaMoeGenerator:
             "out_proj": functools.partial(out_proj, cfg=cfg),
             "ffn": functools.partial(ffn, tally=self.tally, **kw),
         }
+        if cfg.hc_mult > 1:
+            # the residual path's two mixes, imported where they are used:
+            # a config of one stream hands the layer loop no ``streams``
+            # and pays nothing for the module
+            from triton_dist_tpu.kernels import hyper_conn
+
+            self._hooks["streams"] = hyper_conn.mixes(
+                **cfg.stream_kw(), impl=impl, interpret=interpret)
         self._chunk_jit = jax.jit(
             named(self.wrap_program(functools.partial(
                 _chunk_forward, cfg=cfg, **self._hooks,
@@ -1025,18 +1083,51 @@ class MlaMoeGenerator:
         return combine_forms(self.cfg, rows, impl=ctx.impl,
                              interpret=ctx.interpret)
 
+    def stream_rows(self, rows: dict) -> dict:
+        """``summary()["hc"]`` of a block with residual streams ({} for
+        one stream): the streams, the sub-layers a row's mixes run in, the
+        rows each program carries through them (``rows``: program ->
+        rows), how the two calls are blocked there (``hyper_conn.blocking``;
+        {} where they run as XLA) and, by program, why its rows do not
+        reach them (``gaps``: the engine files these with its
+        ``kernel_gaps``)."""
+        c, ctx = self.cfg, self.attn.ctx
+        if c.hc_mult <= 1:
+            return {}
+        from triton_dist_tpu.kernels import hyper_conn
+
+        on_chip = resolve_impl(ctx.impl, ctx.interpret) != "xla"
+        item = jnp.dtype(c.dtype).itemsize
+        # (the interpreter tiles nothing: no shape misses the calls there)
+        gaps = {prog: hyper_conn.hc_gap(n, c.hc_mult, c.dim, item)
+                for prog, n in rows.items()} \
+            if on_chip and not ctx.interpret else {}
+        return {"streams": c.hc_mult, "sublayers": 2 * c.n_layers,
+                "rows": dict(rows),
+                "blocking": {prog: hyper_conn.blocking(
+                    n, c.hc_mult, c.dim, item) if on_chip else {}
+                    for prog, n in rows.items()},
+                "gaps": {hyper_conn.gap_key(prog): why
+                         for prog, why in gaps.items() if why}}
+
     def kernel_gaps(self, *, page_size: int, **_prefill_geometry) -> dict:
         """Attention paths that will NOT reach the latent Pallas kernel
         (``generate.attention_kernel_gaps`` for this family): decode and
         the absorbed prefill chunk share one kernel and one answer; a
         sparse block's EXPANDED chunk (``prefill_chunk`` queries over each
         rung of ``ladder``) has a call and an answer of its own — and so
-        has the chunk's expert combine (:func:`combine_kernel_gap`)."""
+        has the chunk's expert combine (:func:`combine_kernel_gap`), and
+        the residual streams' two mixes at the chunk's rows
+        (``hyper_conn.hc_gap``; :meth:`stream_rows` says it of the decode
+        programs' rows, which this call is not told)."""
         ctx, c = self.attn.ctx, self.cfg
         chunk = _prefill_geometry.get("prefill_chunk")
         why = chunk and combine_kernel_gap(c, chunk, impl=ctx.impl,
                                            interpret=ctx.interpret)
         gaps = {COMBINE_CALL: why} if why else {}
+        if chunk:
+            gaps.update(self.stream_rows({"prefill_chunk": chunk}).get(
+                "gaps", {}))
         if resolve_impl(ctx.impl, ctx.interpret) == "xla":
             why = ("impl='xla' was asked for" if ctx.impl == "xla" else
                    "impl='auto' resolves to XLA off a TPU (no interpreter)")

@@ -186,6 +186,7 @@ REGIONS = (
     "dsa.index", "dsa.select", "mla.expand", "sample",
     "ssm.in", "ssm.conv", "ssm.scan", "ssm.out", "gmu",
     "gdn.in", "gdn.conv", "gdn.rule", "gdn.out",
+    "hc.pre", "hc.post",
 )
 REGION_PREFIX = "rg_"
 

@@ -794,6 +794,11 @@ class ServeEngine:
             if self.horizon > 1:
                 rows["decode_horizon"] = max_batch
             self.metrics.moe_combine = gen.moe_combine_forms(rows)
+            # ... and, where the residual is several streams, the rows
+            # each program carries through the two mixes
+            if hasattr(gen, "stream_rows"):
+                self.metrics.hc = gen.stream_rows(rows)
+                self.kernel_gaps.update(self.metrics.hc.get("gaps", {}))
         # How the paged decode call is blocked (static, decided where the
         # programs are built — kernels/flash_decode.py): the KV heads a
         # step carries of the heads THIS rank holds, the grid steps of a

@@ -475,6 +475,10 @@ class ServeMetrics:
     # (program -> "walk" | "gather": mla_moe.combine_form; stamped once at
     # engine construction, empty for a family without expert layers)
     moe_combine: dict = field(default_factory=dict, repr=False)
+    # a residual of several streams (``MlaMoeGenerator.stream_rows``:
+    # streams, sub-layers, rows a program, the two mixes' blocking; stamped
+    # once at engine construction, empty for one stream)
+    hc: dict = field(default_factory=dict, repr=False)
     # per-step gauges as STREAMING aggregates (last / peak / running
     # sums) — never per-step lists, so a long-lived engine's metrics
     # stay O(1) regardless of how many steps it has served
@@ -602,6 +606,14 @@ class ServeMetrics:
                                  / self.dsa_indexed_tokens
                                  if self.dsa_indexed_tokens else 0.0)
         return out
+
+    def hc_stats(self) -> dict:
+        """summary()["hc"]: the residual streams (0: the block has one),
+        the sub-layers a row's mixes run in and how the two calls are
+        blocked in each program."""
+        return {"streams": self.hc.get("streams", 0),
+                "sublayers": self.hc.get("sublayers", 0),
+                "blocking": dict(self.hc.get("blocking", {}))}
 
     def swa_stats(self) -> dict:
         """summary()["swa"]: cached tokens the decode queries read on
@@ -1175,6 +1187,7 @@ class ServeMetrics:
             "compilation": self.compile_stats(),
             "kernel_gaps": dict(self.kernel_gaps),
             "paged_attn_blocking": dict(self.paged_attn_blocking),
+            "hc": self.hc_stats(),
             "requests": {rid: m.to_dict()
                          for rid, m in self.requests.items()},
         }
@@ -1357,6 +1370,8 @@ class ServeMetrics:
               self.paged_attn_blocking.get("pages_in_flight", 0),
               "slots of the paged decode attention kernel's page ring: "
               "the page being multiplied and the copies ahead of it")
+        gauge("serve_hc_streams", self.hc.get("streams", 0),
+              "residual streams a token carries (0: the block has one)")
         if self.recorder is not None:
             counter("serve_trace_events_total", self.recorder.emitted,
                     "flight-recorder events emitted")

@@ -108,7 +108,8 @@ def _by_group(tables, kinds, one: int):
 
 def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
                           cfg, page, project, out_proj, ffn, paged_attend,
-                          slots=_page_slots, kinds=None, mixer=None):
+                          slots=_page_slots, kinds=None, mixer=None,
+                          streams=None):
     """One decode token for every batch row over the paged pools:
     ``generate._layer_stack`` at T = 1 (the same math as
     ``Generator._step_impl`` — the greedy stream must be bit-identical to
@@ -136,7 +137,8 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
     STATE SLOT in its first column: the mixer is told ``shared["slot"]``
     [B] — an inactive row's redirected to the null slot 0, as its page
     writes are to the null block — and reads and writes the layer's state
-    planes there, in place."""
+    planes there, in place.  ``streams`` comes with a family whose
+    residual is several streams, and is handed on as it came."""
     inc = active.astype(kv_lens.dtype)
     by_group, group_of = _by_group(tables, kinds, one=2)
     where = [slots(t, kv_lens, active, page=page) for t in by_group]
@@ -145,6 +147,8 @@ def _paged_decode_forward(params, pools, tables, kv_lens, token, active, *,
         state = [k.group for k in kinds if k.state]
         more = {"mixer": mixer, "shared": {} if not state else {
             "slot": jnp.where(active, by_group[state[0]][:, 0], 0)}}
+    if streams is not None:
+        more["streams"] = streams
 
     def write_kv(li, pool, k, v):
         return _scatter_kv(pool, k[:, 0], None if v is None else v[:, 0],
